@@ -15,6 +15,11 @@ from typing import Iterable, List, Optional, Sequence
 _SKIP_DIRS = {
     ".git",
     ".pytest_jax_cache",
+    # git-ignored chip-tool scratch: debug scripts and unpacked archives,
+    # what a chip run brings back, the in-checkout compile cache
+    ".chipcheck",
+    "chiprun_out",
+    ".jax_cache",
     "__pycache__",
     ".eggs",
     "build",

@@ -7,13 +7,11 @@ version-dependent names):
   COMPAT001  no `jax.experimental.*` import or attribute access — the
              experimental namespace is where JAX renames things without
              deprecation cycles; every use funnels through compat.py.
-  COMPAT002  no direct use of a drift-table symbol (drift.py) under
-             EITHER of its spellings: `pltpu.CompilerParams` is exactly
-             as wrong as `pltpu.TPUCompilerParams` — one of the two
-             crashes on the JAX you are not testing on today.
-  COMPAT003  no drifted call keyword (`check_vma`/`check_rep`,
-             `ShapeDtypeStruct(vma=...)`) except on the compat wrappers
-             that normalize them.
+  COMPAT002  no direct use of a drift-table symbol (drift.py): a name
+             JAX has renamed before is spelled once, in compat.py, so the
+             next rename is a one-file change.
+  COMPAT003  no drift-table call keyword (`check_vma`,
+             `ShapeDtypeStruct(vma=...)`) except on the compat wrappers.
 
 Suppression: `# af2lint: disable=COMPAT002` on the offending line (used
 by code that is itself version-probing, which should be rare — prefer

@@ -1,17 +1,17 @@
-"""The JAX API drift table: symbols that were renamed/moved between JAX
+"""The JAX API drift table: symbols whose spelling has moved between JAX
 releases and therefore MUST resolve through `alphafold2_tpu/compat.py`.
 
-Each row documents one rename so the compat linter can flag EITHER
-spelling at a call site — code written against the old name breaks on new
-JAX, code written against the new name breaks on old JAX (the seed's
-actual failure: `pltpu.CompilerParams` on a 0.4.x image that only has
-`TPUCompilerParams`, 20+ red tier-1 tests from two call sites).
+compat.py is written for the one installed JAX and keeps ONE spelling of
+each name (no version branches); this table lists those names so the
+compat linter can flag a direct use at any other call site. The seed's
+failure was exactly that: two kernel files spelled a `pltpu` class
+directly, JAX renamed it, and 20+ tier-1 tests went red.
 
-Adding an entry when JAX renames something (docs/STATIC_ANALYSIS.md):
-  1. resolve the name once in compat.py with a version-gated fallback;
-  2. add a DriftEntry here with both spellings and the boundary version;
+When a JAX upgrade renames something (docs/STATIC_ANALYSIS.md):
+  1. change the one spelling in compat.py;
+  2. add or update the DriftEntry here;
   3. `python -m alphafold2_tpu.analysis --strict` then flags every direct
-     use of either spelling outside compat.py.
+     use outside compat.py.
 """
 
 from __future__ import annotations
@@ -22,15 +22,15 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class DriftEntry:
-    """One renamed symbol.
+    """One compat-routed symbol.
 
     attr_names: attribute spellings that identify the symbol at a call
         site (matched against the last attribute of a dotted access);
     full_names: dotted prefixes that also identify it (e.g. bare-module
         paths), matched exactly;
-    keywords: call keywords that drifted along with the symbol;
+    keywords: call keywords that belong to the symbol;
     compat_name: how call sites should spell it;
-    renamed_in: 'old -> new @ jax X.Y' documentation string.
+    renamed_in: what the installed JAX calls it (documentation string).
     """
 
     attr_names: Tuple[str, ...]
@@ -43,48 +43,42 @@ class DriftEntry:
 
 DRIFT_TABLE: Tuple[DriftEntry, ...] = (
     DriftEntry(
-        attr_names=("TPUCompilerParams", "CompilerParams"),
+        attr_names=("CompilerParams",),
         compat_name="compat.CompilerParams",
-        renamed_in="pltpu.TPUCompilerParams -> pltpu.CompilerParams @ jax 0.6",
-        note="same kwargs (dimension_semantics, ...); only the class name moved",
+        renamed_in="jax.experimental.pallas.tpu.CompilerParams",
     ),
     DriftEntry(
         attr_names=("shard_map",),
-        full_names=("jax.shard_map", "jax.experimental.shard_map.shard_map"),
-        keywords=("check_vma", "check_rep"),
+        full_names=("jax.shard_map",),
+        keywords=("check_vma",),
         compat_name="compat.shard_map",
-        renamed_in=(
-            "jax.experimental.shard_map.shard_map(check_rep=) -> "
-            "jax.shard_map(check_vma=) @ jax 0.6"
-        ),
+        renamed_in="jax.shard_map(check_vma=)",
     ),
     DriftEntry(
         attr_names=("typeof",),
         full_names=("jax.typeof",),
         compat_name="compat.typeof_vma",
-        renamed_in="jax.typeof (and avals' .vma) introduced @ jax 0.7",
-        note="pre-vma JAX has neither; compat returns an empty vma set there",
+        renamed_in="jax.typeof(x).vma",
     ),
     DriftEntry(
         attr_names=(),
         full_names=(),
         keywords=("vma",),
         compat_name="compat.out_struct",
-        renamed_in="ShapeDtypeStruct(vma=...) kwarg introduced @ jax 0.7",
+        renamed_in="jax.ShapeDtypeStruct(vma=...)",
         note="matched via the 'vma' call keyword on ShapeDtypeStruct calls",
     ),
     DriftEntry(
         attr_names=("pcast",),
         full_names=("jax.lax.pcast",),
         compat_name="compat.pcast",
-        renamed_in="jax.lax.pcast introduced @ jax 0.7 (vma era)",
-        note="identity on pre-vma JAX — there is no varying set to cast",
+        renamed_in="jax.lax.pcast",
     ),
     DriftEntry(
         attr_names=("create_hybrid_device_mesh",),
         full_names=("jax.experimental.mesh_utils.create_hybrid_device_mesh",),
         compat_name="compat.create_hybrid_device_mesh",
-        renamed_in="lives under jax.experimental.mesh_utils on all supported JAX",
+        renamed_in="jax.experimental.mesh_utils.create_hybrid_device_mesh",
         note="experimental-path import; routed through compat to keep the gate total",
     ),
 )

@@ -1,21 +1,16 @@
-"""Version-compat shim: every version-dependent JAX name resolves HERE, once.
+"""The one place JAX's drift-prone names are spelled.
 
-The seed's tier-1 suite went red on exactly the failure mode this module
-exists to prevent: `jax.experimental.pallas.tpu.CompilerParams` (JAX >=
-0.6) vs `TPUCompilerParams` (<= 0.5), `jax.shard_map(check_vma=...)` vs
-`jax.experimental.shard_map.shard_map(check_rep=...)`, and
-`jax.typeof`/`ShapeDtypeStruct(vma=...)` — all renamed between the JAX the
-code was written against and the JAX in the image, each one crashing at
-import or trace time after chip time was already scheduled. FastFold
-(arxiv 2203.00854) and ScaleFold (arxiv 2404.11068) both make the point
-that AlphaFold-scale iterations are too expensive to burn on avoidable
-crashes; API drift is the most avoidable of all.
+Written for the ONE installation this repo runs on (jax/jaxlib 0.9.0,
+libtpu 0.0.34): every name below has exactly one spelling and no
+fallback. A name the installed JAX lacks fails here, at import or at the
+first call, with JAX's own error — never as a quiet default (the retired
+`try/except -> return False` in `backend_initialized` kept the
+joined-after-backend-init guard dead for a whole JAX upgrade).
 
 Contract, enforced statically by `alphafold2_tpu.analysis` (the `compat`
 pass): no module outside this file touches `jax.experimental.*` or any
-symbol in the drift table (analysis/drift.py). When JAX renames something,
-the resolution moves here, the drift table gains a row, and every call
-site keeps working on both sides of the rename.
+symbol in the drift table (analysis/drift.py). When a JAX upgrade renames
+something, this file is the only one that changes.
 
 Import idiom:
 
@@ -35,7 +30,6 @@ from typing import Any, Optional
 import jax
 
 __all__ = [
-    "JAX_VERSION",
     "CompilerParams",
     "backend_initialized",
     "broadcast_one_to_all",
@@ -54,28 +48,13 @@ __all__ = [
 ]
 
 
-def _version_tuple(v: str) -> tuple:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple = _version_tuple(jax.__version__)
-
 # --- pallas ----------------------------------------------------------------
-# The pallas modules themselves live under jax.experimental on every JAX
-# this repo supports; re-exported so kernel files never spell the
-# experimental path (the compat linter forbids it outside this module).
-# Resolved LAZILY (PEP 562 module __getattr__): most consumers of this
-# module (parallel/mesh, sequence, pipeline, sp_trunk) only want
-# shard_map/pcast, and the eager Pallas import costs ~0.26 s on top of
-# jax's own import on every process start.
-#
-# `CompilerParams` (lazy too, it needs pallas_tpu): JAX >= 0.6 renamed
-# TPUCompilerParams -> CompilerParams (drift table row
-# `pltpu.CompilerParams`). Same kwargs (dimension_semantics, ...).
+# The pallas modules live under jax.experimental; re-exported so kernel
+# files never spell the experimental path (the compat linter forbids it
+# outside this module). Resolved LAZILY (PEP 562 module __getattr__): most
+# consumers of this module (parallel/mesh, sequence, pipeline, sp_trunk)
+# only want shard_map/pcast, and the eager Pallas import costs ~0.26 s on
+# top of jax's own import on every process start.
 
 
 def __getattr__(name: str):
@@ -90,118 +69,79 @@ def __getattr__(name: str):
         globals()["pallas_tpu"] = pallas_tpu
         return pallas_tpu
     if name == "CompilerParams":
-        ptpu = __getattr__("pallas_tpu")
-        cp = getattr(ptpu, "CompilerParams", None)
-        if cp is None:  # JAX <= 0.5 (e.g. 0.4.37): only the old spelling
-            cp = ptpu.TPUCompilerParams
+        cp = __getattr__("pallas_tpu").CompilerParams
         globals()["CompilerParams"] = cp
         return cp
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 # --- shard_map -------------------------------------------------------------
-# JAX >= 0.6: jax.shard_map(..., check_vma=...). Older: the experimental
-# module with the kwarg spelled check_rep. Semantics are the same knob
-# (disable the replication/varying-across-mesh-axes checker).
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if not _NEW_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
 
 
 def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """`jax.shard_map` across JAX versions; `check_vma` maps to the era's
-    checker kwarg (`check_rep` before the rename). Usable directly or as a
-    decorator factory (``f=None``), matching both eras' calling styles."""
+    """`jax.shard_map`, usable directly or as a decorator factory
+    (``f=None``). `check_vma=None` keeps JAX's default checker setting."""
     if f is None:
         return functools.partial(
             shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=check_vma,
         )
-    kwargs: dict = {}
-    if check_vma is not None:
-        kwargs["check_vma" if _NEW_SHARD_MAP else "check_rep"] = check_vma
-    impl = jax.shard_map if _NEW_SHARD_MAP else _old_shard_map
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    )
 
 
 # --- vma-aware ShapeDtypeStruct -------------------------------------------
-# JAX >= 0.7 tracks a `vma` (varying-across-mesh-axes) set on abstract
-# values and requires pallas_call out_shapes under shard_map to declare
-# theirs. Older JAX has neither jax.typeof nor the vma kwarg — there the
-# plain struct is exactly right.
-_HAS_VMA = hasattr(jax, "typeof") and "vma" in getattr(
-    getattr(jax.ShapeDtypeStruct.__init__, "__code__", None), "co_varnames", ()
-)
+# JAX tracks a `vma` (varying-across-mesh-axes) set on abstract values and
+# requires pallas_call out_shapes under shard_map to declare theirs.
 
 
 def typeof_vma(x: Any) -> frozenset:
-    """The value's varying-across-mesh-axes set (empty set pre-vma JAX)."""
-    if _HAS_VMA:
-        return frozenset(jax.typeof(x).vma)
-    return frozenset()
+    """The value's varying-across-mesh-axes set."""
+    return frozenset(jax.typeof(x).vma)
 
 
 def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
     """ShapeDtypeStruct whose `vma` is the union of the operands' — required
     for pallas_call under shard_map with vma checking (e.g. ring-attention
-    hops) on new JAX; collapses to a plain struct on old JAX."""
-    if _HAS_VMA:
-        vma = frozenset().union(*(typeof_vma(o) for o in operands))
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    hops)."""
+    vma = frozenset().union(*(typeof_vma(o) for o in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def pcast(x, axis_names, *, to: str = "varying"):
-    """`jax.lax.pcast` (vma-era JAX): mark a value varying/invariant over
-    mesh axes so shard_map carry types line up after collectives. Pre-vma
-    JAX tracks no such set — the identity is the exact semantic there."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_names, to=to)
-    return x
+    """`jax.lax.pcast`: mark a value varying/invariant over mesh axes so
+    shard_map carry types line up after collectives."""
+    return jax.lax.pcast(x, axis_names, to=to)
 
 
 # --- multi-host runtime ----------------------------------------------------
-# The multihost utilities live under jax.experimental on every JAX this
-# repo supports; resolved here so parallel/distributed.py and
-# training/checkpoint.py stay free of experimental imports (compat-lint
-# contract). `make_array_from_process_local_data` moved to the jax
-# namespace in 0.4.31 — older trees fall back to per-device assembly
-# from process-index slices (the "process-index slicing" route).
+# The multihost utilities live under jax.experimental; resolved here so
+# parallel/distributed.py and training/checkpoint.py stay free of
+# experimental imports (compat-lint contract).
 
 
-def enable_cpu_collectives() -> bool:
+def enable_cpu_collectives() -> None:
     """Select a cross-process collectives implementation for the CPU
     backend (Gloo). Without one, a multi-process CPU runtime enumerates
     the pod's devices but every cross-process computation dies with
     "Multiprocess computations aren't implemented on the CPU backend" —
     the 2-process test matrix (and any CPU-pod rehearsal) needs this set
-    BEFORE backend init. Returns False when this jaxlib has no such
-    option (TPU-only builds, future renames); harmless then, since only
-    CPU multi-process paths need it."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        return True
-    except Exception:
-        return False
+    BEFORE backend init. Harmless on non-CPU backends; raises if the
+    installed jaxlib has no such option."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def backend_initialized() -> bool:
     """True once any XLA backend has been created in this process — the
     point past which `jax.distributed.initialize` is too late (the
-    backend already enumerated only-local devices). Resolution is
-    version-tolerant: the public predicate when present, else the
-    backend cache xla_bridge maintains on every supported JAX."""
-    try:
-        from jax.lib import xla_bridge as xb
-    except Exception:  # pragma: no cover - layout drift
-        return False
-    fn = getattr(xb, "backends_are_initialized", None)
-    if fn is not None:
-        try:
-            return bool(fn())
-        except Exception:  # pragma: no cover
-            pass
-    return bool(getattr(xb, "_backends", None))
+    backend already enumerated only-local devices). jax keeps the
+    predicate private; a JAX that moves it fails this import loudly
+    instead of answering False for ever."""
+    from jax._src import xla_bridge
+
+    return bool(xla_bridge.backends_are_initialized())
 
 
 def sync_global_devices(name: str) -> None:
@@ -260,51 +200,10 @@ def make_global_array_from_host(x, sharding):
 
 
 def make_array_from_process_local_data(sharding, local_data, global_shape=None):
-    """`jax.make_array_from_process_local_data` across versions: assemble
-    a global jax.Array from this process's rows of the batch. On JAX
-    trees without the helper (< 0.4.31), falls back to
-    `make_array_from_single_device_arrays` over process-index slices of
-    the local data — each local device gets its addressable block."""
-    fn = getattr(jax, "make_array_from_process_local_data", None)
-    if fn is not None:
-        return fn(sharding, local_data, global_shape)
-    import numpy as np
-
-    local_data = np.asarray(local_data)
-    if global_shape is None:
-        # the real API infers the global shape by scaling sharded dims;
-        # the fallback cannot do that reliably (it would have to guess
-        # which dims are process-sharded), so require it explicitly —
-        # every in-repo caller passes it
-        raise ValueError(
-            "make_array_from_process_local_data fallback (JAX < 0.4.31) "
-            "requires an explicit global_shape"
-        )
-    addressable = sharding.addressable_devices_indices_map(tuple(global_shape))
-    # map each addressable device's GLOBAL index window into local
-    # coordinates: along every process-sharded dim this process owns a
-    # contiguous block, offset by the minimum start across its own
-    # addressable windows (computed PER DIM — two dims sharded across
-    # processes carry two different offsets)
-    offsets: dict = {}
-    arrays = []
-    for dev, idx in addressable.items():
-        loc = []
-        for d, sl in enumerate(idx):
-            start = 0 if sl.start is None else sl.start
-            stop = global_shape[d] if sl.stop is None else sl.stop
-            if global_shape[d] != local_data.shape[d]:
-                if d not in offsets:
-                    offsets[d] = min(
-                        (0 if s[d].start is None else s[d].start)
-                        for s in addressable.values()
-                    )
-                loc.append(slice(start - offsets[d], stop - offsets[d]))
-            else:
-                loc.append(sl)
-        arrays.append(jax.device_put(local_data[tuple(loc)], dev))
-    return jax.make_array_from_single_device_arrays(
-        tuple(global_shape), sharding, arrays
+    """`jax.make_array_from_process_local_data`: assemble a global
+    jax.Array from this process's rows of the batch."""
+    return jax.make_array_from_process_local_data(
+        sharding, local_data, global_shape
     )
 
 

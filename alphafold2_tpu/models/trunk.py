@@ -69,10 +69,9 @@ def _join_barrier_bwd(_, cts):
     return (cts,)
 
 
-# identity with an explicit gradient rule: jax 0.4.x has no
-# differentiation rule for optimization_barrier, and the barrier is a
-# schedule marker, not math — cotangents pass straight through (the
-# backward program carries no barrier)
+# identity with an explicit gradient rule: the barrier is a schedule
+# marker, not math — cotangents pass straight through (the backward
+# program carries no barrier)
 _join_barrier.defvjp(_join_barrier_fwd, _join_barrier_bwd)
 
 

@@ -88,9 +88,8 @@ _SPARSE_KERNEL_MIN_N = 4096
 
 def pallas_triton_lowerable() -> bool:
     """Whether this host can LOWER the flash-family kernels through
-    Pallas-Triton. The jax 0.4.x build in this image has no GPU client,
-    so the probe is honest-but-static: False until a CUDA/ROCm backend
-    is present. When it flips, a Triton kernel can register under the
+    Pallas-Triton. The installed jaxlib has no GPU client, so the probe
+    is honest-but-static: False until a CUDA/ROCm backend is present. When it flips, a Triton kernel can register under the
     existing ``gpu`` arm name — dispatch, env overrides, bench legs, and
     the parity tier all apply unchanged."""
     try:

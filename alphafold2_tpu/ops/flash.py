@@ -48,7 +48,18 @@ def stream_block(q, k_blk, v_blk, bias_blk, m, l, acc, scale,
     if bias2d_blk is not None:
         s = s + bias2d_blk.astype(logit_dtype)
 
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1).astype(jnp.float32))
+    # No gradient through the running max (jax.nn.softmax does the same):
+    # every consumer combines (m, l, acc) shift-invariantly (acc / l,
+    # m + log l), so the partial w.r.t. m is identically zero and dropping
+    # it is exact. It is also what keeps the backward finite on the TPU:
+    # with bf16 operands and the K/V blocks under `lax.scan`, every dq and
+    # dk came back NaN on a v5e (jax 0.9.0; finite in f32, with the blocks
+    # unrolled, or with f32 logits straight from the dot — PERF.md
+    # bring-up). reduce-max's gradient divides by the count of positions
+    # EQUAL to the max; a bf16-rounded max compared against logits XLA
+    # kept at higher precision matches nowhere, and 0/0 is the NaN.
+    m_new = jax.lax.stop_gradient(
+        jnp.maximum(m, jnp.max(s, axis=-1).astype(jnp.float32)))
     # alpha/p guards: -inf - -inf = nan. The exp ARGUMENT must be sanitized
     # too, not just the result: exp(nan) in the unselected where-branch has a
     # nan primal, and exp's vjp multiplies even a zero cotangent by it

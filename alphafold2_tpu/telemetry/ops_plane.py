@@ -345,7 +345,7 @@ class ProfileCapturer:
           * one thread for both ends, NON-daemon, because any daemon
             thread still inside the profiler (blocked start OR pending
             stop) at interpreter teardown SEGFAULTS in native code
-            (reproduced on jax 0.4.x CPU): threading._shutdown joins
+            (reproduced on the CPU backend): threading._shutdown joins
             non-daemon threads BEFORE teardown, and close() — wired
             into OpsServer.stop — aborts the wait early so exit never
             stalls a full capture window.

