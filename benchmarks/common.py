@@ -1,0 +1,282 @@
+"""What every kind of run shares: the files a cell is made of, the device
+check, the table of peaks, weights from the seed, and the result line."""
+from __future__ import annotations
+
+import importlib
+import json
+import math
+import os
+import shutil
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+
+
+def load_json(*parts):
+    with open(os.path.join(HERE, *parts)) as f:
+        return json.load(f)
+
+
+def load_cell(workload: str, root: str = ROOT):
+    """The cell's entry of BENCHMARK.json with its configuration and
+    traffic files, found by name."""
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        raise SystemExit(f"no workload {workload!r} in BENCHMARK.json; "
+                         f"it has {sorted(cells)}")
+    cell = cells[workload]
+    cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    with open(os.path.join(root, cfg_entry["file"])) as f:
+        config = json.load(f)
+    traffic = load_json("traffic", cell["traffic"] + ".json")
+    return bench, cell, config, traffic
+
+
+def cell_metrics(bench, cell_name: str, group: str):
+    """The `end_to_end` or `per_layer` entries this cell reports."""
+    return [m for m in bench[group]
+            if "workloads" not in m or cell_name in m["workloads"]]
+
+
+def metric_names(bench, cell_name: str, group: str):
+    return [m["name"] for m in cell_metrics(bench, cell_name, group)]
+
+
+def module(kind: str, name: str):
+    """benchmarks/<kind>/<name>.py, found by the name a data file gives."""
+    if HERE not in sys.path:
+        sys.path.insert(0, HERE)
+    return importlib.import_module(f"{kind}.{name}")
+
+
+def require_tpu(chips: int, dry: bool):
+    """The devices of this run. The measured path takes TPUs only."""
+    import jax
+
+    devices = jax.devices()
+    if dry:
+        return devices[:1]
+    if devices[0].platform != "tpu":
+        raise SystemExit(
+            f"benchmarks/run.py needs a TPU; JAX found {devices[0].platform!r} "
+            f"({devices[0].device_kind}). No result is printed without the chip "
+            f"(--dry rehearses the control flow at toy shapes).")
+    if len(devices) < chips:
+        raise SystemExit(f"the cell asks for {chips} chips; JAX found {len(devices)}")
+    return devices[:chips]
+
+
+def peaks_for(device_kind: str) -> dict:
+    table = load_json("peaks.json")["by_device_kind"]
+    if device_kind not in table:
+        raise SystemExit(f"no peaks recorded for device_kind {device_kind!r}; "
+                         f"add it to benchmarks/peaks.json with its source")
+    return table[device_kind]
+
+
+def seed_key(seed: int):
+    """A PRNG key from any whole number (seeds pass 2**31)."""
+    import jax
+
+    return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
+
+
+def key_name(k) -> str:
+    """One step of a tree path as a plain string."""
+    return str(getattr(k, "key", getattr(k, "idx", k)))
+
+
+def make_params(shapes, key, stacked=(), copies=1):
+    """Weights on the device in one jitted call, by each leaf's role:
+    tables N(0, 1); linear `w` and `b` U(+-1/sqrt(fan_in)); LayerNorm
+    scale 1, bias 0. `shapes` is a tree of ShapeDtypeStructs (the layout
+    the program reads); `stacked` names top-level subtrees whose leaves
+    carry a leading depth axis. With `copies` > 1 the same call is made
+    again for each further copy (one compiled program, no per-leaf copies)
+    and a list is returned."""
+    import jax
+    import jax.numpy as jnp
+
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+
+    index = {tuple(key_name(k) for k in path): leaf for path, leaf in leaves}
+
+    def fan_in(path):
+        w = index.get(path[:-1] + ("w",))
+        if w is None:
+            return 1
+        lead = 1 if any(path[:len(s)] == s for s in stacked) else 0
+        return max(1, math.prod(w.shape[lead:-1]))
+
+    def build(key):
+        # two draws for the whole tree (one uniform, one normal), cut into
+        # the leaves: a per-leaf draw compiles for most of a minute
+        k_u, k_n = jax.random.split(key)
+        sizes = [math.prod(leaf.shape) for leaf in index.values()]
+        uniform = jax.random.uniform(k_u, (sum(sizes),), jnp.float32, -1.0, 1.0)
+        tables = [n for path, n in zip(index, sizes) if path[-1] == "table"]
+        normal = jax.random.normal(k_n, (max(1, sum(tables)),), jnp.float32)
+        out, at_u, at_n = [], 0, 0
+        for (path, leaf), n in zip(index.items(), sizes):
+            role = path[-1]
+            if role == "table":
+                v = normal[at_n:at_n + n].reshape(leaf.shape)
+                at_n += n
+            elif role == "scale":
+                v = jnp.ones(leaf.shape, jnp.float32)
+            elif role == "bias":
+                v = jnp.zeros(leaf.shape, jnp.float32)
+            elif role in ("w", "b"):
+                v = uniform[at_u:at_u + n].reshape(leaf.shape) / math.sqrt(fan_in(path))
+            else:
+                raise ValueError(f"no rule for parameter leaf {'/'.join(path)}")
+            at_u += n
+            out.append(v.astype(leaf.dtype))
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    fn = jax.jit(build)
+    if copies == 1:
+        return fn(key)
+    return [fn(key) for _ in range(copies)]
+
+
+def device_block(devices, planned_peak_bytes: int = 0) -> dict:
+    """The `device` entry. `memory_peak_bytes` is the larger of the
+    runtime's peak counter and the compiler's planned peak of the largest
+    executable the window ran: on this runtime the counter sees live
+    arrays only, not a program's temporaries."""
+    peak = 0
+    for d in devices:
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices),
+            "memory_peak_bytes": max(peak, int(planned_peak_bytes))}
+
+
+def planned_peak(compiled) -> int:
+    ma = compiled.memory_analysis()
+    peak = getattr(ma, "peak_memory_in_bytes", 0)
+    if peak:
+        return int(peak)
+    return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
+               + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def log(*parts):
+    """An earlier line: to standard error, never the result line."""
+    print(*parts, file=sys.stderr, flush=True)
+
+
+class Setup:
+    """Set-up time by phase, from the start of the process."""
+
+    def __init__(self, t_process_start: float):
+        self.t0 = t_process_start
+        self.last = t_process_start
+        self.phases = []
+
+    def mark(self, name: str):
+        now = time.perf_counter()
+        self.phases.append((name, now - self.last))
+        self.last = now
+
+    def total(self) -> float:
+        return self.last - self.t0
+
+    def table(self) -> dict:
+        return {name: round(s, 3) for name, s in self.phases}
+
+
+def judge(compared: dict):
+    """`correct` and the printed comparison from {name: (value, limit)}:
+    every number at or under its limit, and finite."""
+    rows, ok = {}, True
+    for name, (value, limit) in compared.items():
+        good = value is not None and math.isfinite(value) and value <= limit
+        ok = ok and good
+        rows[name] = {"value": value, "limit": limit}
+    return ok, rows
+
+
+def finish(result: dict, compared_rows: dict):
+    """The comparison as the last lines of standard error, then the one
+    result line, with the comparison as its last key."""
+    for name, row in compared_rows.items():
+        log(f"compared {name} = {row['value']} (limit {row['limit']})")
+    result = dict(result)
+    result["compared"] = compared_rows
+    sys.stderr.flush()
+    print(json.dumps(result), flush=True)
+
+
+class CompileWatch:
+    """Counts programs compiled, or loaded from the persistent cache,
+    while armed. A window that compiles is a failed run, not a slow one."""
+
+    _COMPILE = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.armed = False
+        self.compiles = 0
+        self.cache_hits = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, _secs, **_kw):
+        if self.armed and event == self._COMPILE:
+            self.compiles += 1
+
+    def _on_event(self, event, **_kw):
+        if self.armed and event == self._HIT:
+            self.cache_hits += 1
+
+    def __enter__(self):
+        self.armed = True
+        return self
+
+    def __exit__(self, *exc):
+        self.armed = False
+
+    def check(self, what: str):
+        if self.compiles or self.cache_hits:
+            raise SystemExit(
+                f"{what}: {self.compiles} program(s) compiled and "
+                f"{self.cache_hits} loaded from the cache inside the measured "
+                f"window; every shape must be warm before it. Failed run.")
+
+
+def context(workload, seed, seconds, trace, dry, fault, t_process_start):
+    """Everything a kind of run is handed: the cell's files, the devices,
+    the compile cache in its fixed place, the built configuration."""
+    bench, cell, config, traffic = load_cell(workload)
+    setup = Setup(t_process_start)
+    from alphafold2_tpu.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    import jax
+
+    devices = require_tpu(cell["chips"], dry)
+    if not dry:
+        peaks_for(devices[0].device_kind)  # an unknown chip fails now, not later
+    setup.mark("imports_and_device")
+    seconds = seconds if seconds is not None else bench["run_seconds"]
+    log(f"cell {cell['name']} seed {seed} seconds {seconds} trace {int(trace)} "
+        f"cache {cache_dir} jax {jax.__version__}")
+    trace_dir = os.path.join(ROOT, ".bench_trace", cell["name"])
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    limits = load_json("limits", cell["name"] + ".json")["limits"]
+    return {
+        "bench": bench, "cell": cell, "config": config, "traffic": traffic,
+        "seed": seed, "seconds": seconds, "trace": trace, "dry": dry,
+        "devices": devices, "setup": setup, "limits": limits,
+        "trace_dir": trace_dir, "fault": fault,
+        "built": module("builders", config["builder"]).build(config, dry),
+    }
