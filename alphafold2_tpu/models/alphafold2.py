@@ -50,6 +50,7 @@ from alphafold2_tpu.ops.core import (
     linear,
     linear_init,
 )
+from alphafold2_tpu.telemetry.profiling import scope, scoped
 
 
 def _prenorm_attn_init(key, cfg: Alphafold2Config):
@@ -226,37 +227,38 @@ def alphafold2_apply(
     )
 
     # trunk (reference :528-535)
-    if trunk_fn is not None:
-        if cfg.reversible:
-            # params["trunk"] is the depth-STACKED pytree when reversible
-            # (reversible_trunk_init), not the layer list the hook's
-            # contract documents — reject rather than hand over the wrong
-            # structure
-            raise ValueError(
-                "trunk_fn overrides receive the sequential layer list; "
-                "set reversible=False"
+    with scope("trunk"):
+        if trunk_fn is not None:
+            if cfg.reversible:
+                # params["trunk"] is the depth-STACKED pytree when reversible
+                # (reversible_trunk_init), not the layer list the hook's
+                # contract documents — reject rather than hand over the wrong
+                # structure
+                raise ValueError(
+                    "trunk_fn overrides receive the sequential layer list; "
+                    "set reversible=False"
+                )
+            x, m = trunk_fn(params["trunk"], cfg, x, m, x_mask, m_mask, rng_trunk)
+        elif cfg.reversible:
+            x, m = reversible_trunk_apply(
+                params["trunk"],
+                cfg,
+                x,
+                m,
+                x_mask=x_mask,
+                msa_mask=m_mask,
+                rng=rng_trunk,
             )
-        x, m = trunk_fn(params["trunk"], cfg, x, m, x_mask, m_mask, rng_trunk)
-    elif cfg.reversible:
-        x, m = reversible_trunk_apply(
-            params["trunk"],
-            cfg,
-            x,
-            m,
-            x_mask=x_mask,
-            msa_mask=m_mask,
-            rng=rng_trunk,
-        )
-    else:
-        x, m = sequential_trunk_apply(
-            params["trunk"],
-            cfg,
-            x,
-            m,
-            x_mask=x_mask,
-            msa_mask=m_mask,
-            rng=rng_trunk,
-        )
+        else:
+            x, m = sequential_trunk_apply(
+                params["trunk"],
+                cfg,
+                x,
+                m,
+                x_mask=x_mask,
+                msa_mask=m_mask,
+                rng=rng_trunk,
+            )
 
     return alphafold2_head(params, cfg, x)
 
@@ -284,52 +286,53 @@ def alphafold2_front(
     """
     b, n = seq.shape
 
-    # pair representation: outer sum of token embeddings (reference :440-444)
-    e = embedding(params["token_emb"], seq, dtype=cfg.dtype)
-    x = e[:, :, None, :] + e[:, None, :, :]
-    x_mask = (
-        (mask[:, :, None] | mask[:, None, :]) if mask is not None else None
-    )
-
-    # axial positional embedding (reference :455-456)
-    if n > cfg.max_seq_len:
-        # out-of-range jnp.take fills NaN under jit (see MSA checks below)
-        raise ValueError(
-            f"sequence length {n} exceeds max_seq_len={cfg.max_seq_len}"
+    with scope("embed"):
+        # pair representation: outer sum of token embeddings (reference :440-444)
+        e = embedding(params["token_emb"], seq, dtype=cfg.dtype)
+        x = e[:, :, None, :] + e[:, None, :, :]
+        x_mask = (
+            (mask[:, :, None] | mask[:, None, :]) if mask is not None else None
         )
-    n_range = jnp.arange(n)
-    pos = (
-        embedding(params["pos_emb"], n_range, dtype=cfg.dtype)[:, None, :]
-        + embedding(params["pos_emb_ax"], n_range, dtype=cfg.dtype)[None, :, :]
-    )
-    x = x + pos[None]
 
-    # MSA stream (reference :460-472)
-    m = None
-    m_mask = msa_mask
-    if msa is not None:
-        rows, cols = msa.shape[1], msa.shape[2]
-        # out-of-range jnp.take fills NaN under jit — without these checks an
-        # oversized MSA silently poisons the whole forward
-        if rows > cfg.max_num_msa:
+        # axial positional embedding (reference :455-456)
+        if n > cfg.max_seq_len:
+            # out-of-range jnp.take fills NaN under jit (see MSA checks below)
             raise ValueError(
-                f"msa has {rows} rows but the row-position table holds "
-                f"max_num_msa={cfg.max_num_msa}; raise max_num_msa in the "
-                f"config (reference constants.py MAX_NUM_MSA)"
+                f"sequence length {n} exceeds max_seq_len={cfg.max_seq_len}"
             )
-        if cols > cfg.max_seq_len:
-            raise ValueError(
-                f"msa has {cols} columns but the position table holds "
-                f"max_seq_len={cfg.max_seq_len}"
-            )
-        m = embedding(params["token_emb"], msa, dtype=cfg.dtype)
-        m = m + embedding(params["msa_pos_emb"], jnp.arange(cols), dtype=cfg.dtype)[None, None]
-        m = m + embedding(params["msa_num_pos_emb"], jnp.arange(rows), dtype=cfg.dtype)[None, :, None, :]
-    elif embedds is not None:
-        p = linear(params["embedd_project"], embedds, dtype=cfg.dtype)
-        m = p[:, :, None, :] + p[:, None, :, :]  # (b, n, n, d) grid stream
-        if m_mask is None:
-            m_mask = x_mask  # the grid stream's validity is the pair mask
+        n_range = jnp.arange(n)
+        pos = (
+            embedding(params["pos_emb"], n_range, dtype=cfg.dtype)[:, None, :]
+            + embedding(params["pos_emb_ax"], n_range, dtype=cfg.dtype)[None, :, :]
+        )
+        x = x + pos[None]
+
+        # MSA stream (reference :460-472)
+        m = None
+        m_mask = msa_mask
+        if msa is not None:
+            rows, cols = msa.shape[1], msa.shape[2]
+            # out-of-range jnp.take fills NaN under jit — without these checks an
+            # oversized MSA silently poisons the whole forward
+            if rows > cfg.max_num_msa:
+                raise ValueError(
+                    f"msa has {rows} rows but the row-position table holds "
+                    f"max_num_msa={cfg.max_num_msa}; raise max_num_msa in the "
+                    f"config (reference constants.py MAX_NUM_MSA)"
+                )
+            if cols > cfg.max_seq_len:
+                raise ValueError(
+                    f"msa has {cols} columns but the position table holds "
+                    f"max_seq_len={cfg.max_seq_len}"
+                )
+            m = embedding(params["token_emb"], msa, dtype=cfg.dtype)
+            m = m + embedding(params["msa_pos_emb"], jnp.arange(cols), dtype=cfg.dtype)[None, None]
+            m = m + embedding(params["msa_num_pos_emb"], jnp.arange(rows), dtype=cfg.dtype)[None, :, None, :]
+        elif embedds is not None:
+            p = linear(params["embedd_project"], embedds, dtype=cfg.dtype)
+            m = p[:, :, None, :] + p[:, None, :, :]  # (b, n, n, d) grid stream
+            if m_mask is None:
+                m_mask = x_mask  # the grid stream's validity is the pair mask
 
     rng_tower, rng_trunk = (
         jax.random.split(rng) if rng is not None else (None, None)
@@ -357,7 +360,8 @@ def alphafold2_front(
             templates = jnp.searchsorted(
                 jnp.asarray(bins[:-1]), jnp.asarray(templates, jnp.float32)
             ).astype(jnp.int32)
-        x = _template_tower_apply(
+        x = scoped(
+            "template_tower", _template_tower_apply,
             params, cfg, x, x_mask, templates, templates_mask, rng_tower
         )
     return x, m, x_mask, m_mask, rng_trunk
@@ -365,6 +369,7 @@ def alphafold2_front(
 
 def alphafold2_head(params, cfg: Alphafold2Config, x):
     """Distogram head: symmetrize + LayerNorm + project (reference :543-545)."""
-    x = (x + jnp.swapaxes(x, 1, 2)) * 0.5
-    x = layer_norm(params["head_norm"], x)
-    return linear(params["head_out"], x, dtype=cfg.dtype)
+    with scope("distogram_head"):
+        x = (x + jnp.swapaxes(x, 1, 2)) * 0.5
+        x = layer_norm(params["head_norm"], x)
+        return linear(params["head_out"], x, dtype=cfg.dtype)

@@ -49,6 +49,7 @@ from alphafold2_tpu.models.trunk import (
     prenorm_ff_apply,
     trunk_layer_init,
 )
+from alphafold2_tpu.telemetry.profiling import REVERSIBLE_BWD_SCOPE, scope, scoped
 
 
 def reversible_trunk_init(key, cfg: Alphafold2Config):
@@ -138,10 +139,12 @@ def _layer_forward(cfg, lp, state, x_mask, msa_mask, rngs, sparse=False):
     # branches, joined (models/trunk.py schedule_join) before the cross
     # block; identical math either way, the reversible inversion below is
     # untouched (the join is the identity)
-    y1 = x1 + _f_seq(cfg, lp["seq_attn"], x2, x_mask, r_fs, sparse)
-    y2 = x2 + _ff(cfg, lp["seq_ff"], y1, r_gs)
-    n1 = m1 + _j_msa(cfg, lp["msa_attn"], m2, msa_mask, r_js)
-    n2 = m2 + _ff(cfg, lp["msa_ff"], n1, r_ks)
+    y1 = x1 + scoped("seq_attn", _f_seq, cfg, lp["seq_attn"], x2, x_mask, r_fs,
+                     sparse)
+    y2 = x2 + scoped("seq_ff", _ff, cfg, lp["seq_ff"], y1, r_gs)
+    n1 = m1 + scoped("msa_attn", _j_msa, cfg, lp["msa_attn"], m2, msa_mask,
+                     r_js)
+    n2 = m2 + scoped("msa_ff", _ff, cfg, lp["msa_ff"], n1, r_ks)
     if cfg.trunk_schedule == "branch_parallel":
         from alphafold2_tpu.models.trunk import schedule_join
 
@@ -149,12 +152,12 @@ def _layer_forward(cfg, lp, state, x_mask, msa_mask, rngs, sparse=False):
 
     # cross-attention block (reference reversible.py:168-182); note the msa
     # cross attends the UPDATED seq half z2
-    z1 = y1 + _cross(cfg, lp["seq_cross"], y2, n2, x_mask, msa_mask, r_fc,
-                     "pair_from_msa")
-    z2 = y2 + _ff(cfg, lp["seq_ff2"], z1, r_gc)
-    o1 = n1 + _cross(cfg, lp["msa_cross"], n2, z2, msa_mask, x_mask, r_jc,
-                     "msa_from_pair")
-    o2 = n2 + _ff(cfg, lp["msa_ff2"], o1, r_kc)
+    z1 = y1 + scoped("seq_cross", _cross, cfg, lp["seq_cross"], y2, n2, x_mask,
+                     msa_mask, r_fc, "pair_from_msa")
+    z2 = y2 + scoped("seq_ff2", _ff, cfg, lp["seq_ff2"], z1, r_gc)
+    o1 = n1 + scoped("msa_cross", _cross, cfg, lp["msa_cross"], n2, z2,
+                     msa_mask, x_mask, r_jc, "msa_from_pair")
+    o2 = n2 + scoped("msa_ff2", _ff, cfg, lp["msa_ff2"], o1, r_kc)
 
     return (z1, z2, o1, o2)
 
@@ -168,14 +171,15 @@ def _layer_backward(cfg, lp, state, cts, x_mask, msa_mask, rngs, sparse=False):
 
     # --- invert cross block (reference reversible.py:184-262) ---
     # k: o2 = n2 + K(o1)
-    ko1, k_vjp = jax.vjp(lambda p, t: _ff(cfg, p, t, r_kc), lp["msa_ff2"], o1)
+    ko1, k_vjp = jax.vjp(
+        lambda p, t: scoped("msa_ff2", _ff, cfg, p, t, r_kc), lp["msa_ff2"], o1)
     n2 = o2 - ko1
     dk, do1_k = k_vjp(do2)
     dn1 = do1 + do1_k
     # j: o1 = n1 + J(n2, z2)  — the y2-coupling (reference :213-225)
     jn2, j_vjp = jax.vjp(
-        lambda p, q, c: _cross(cfg, p, q, c, msa_mask, x_mask, r_jc,
-                               "msa_from_pair"),
+        lambda p, q, c: scoped("msa_cross", _cross, cfg, p, q, c, msa_mask,
+                               x_mask, r_jc, "msa_from_pair"),
         lp["msa_cross"],
         n2,
         z2,
@@ -185,14 +189,15 @@ def _layer_backward(cfg, lp, state, cts, x_mask, msa_mask, rngs, sparse=False):
     dn2 = do2 + dn2_j
     dz2_acc = dz2 + dz2_j
     # g: z2 = y2 + G(z1)
-    gz1, g_vjp = jax.vjp(lambda p, t: _ff(cfg, p, t, r_gc), lp["seq_ff2"], z1)
+    gz1, g_vjp = jax.vjp(
+        lambda p, t: scoped("seq_ff2", _ff, cfg, p, t, r_gc), lp["seq_ff2"], z1)
     y2 = z2 - gz1
     dg, dz1_g = g_vjp(dz2_acc)
     dy1 = dz1 + dz1_g
     # f: z1 = y1 + F(y2, n2)
     fy2, f_vjp = jax.vjp(
-        lambda p, q, c: _cross(cfg, p, q, c, x_mask, msa_mask, r_fc,
-                               "pair_from_msa"),
+        lambda p, q, c: scoped("seq_cross", _cross, cfg, p, q, c, x_mask,
+                               msa_mask, r_fc, "pair_from_msa"),
         lp["seq_cross"],
         y2,
         n2,
@@ -204,23 +209,27 @@ def _layer_backward(cfg, lp, state, cts, x_mask, msa_mask, rngs, sparse=False):
 
     # --- invert self block (reference reversible.py:85-156) ---
     # seq stream
-    gy1, gs_vjp = jax.vjp(lambda p, t: _ff(cfg, p, t, r_gs), lp["seq_ff"], y1)
+    gy1, gs_vjp = jax.vjp(
+        lambda p, t: scoped("seq_ff", _ff, cfg, p, t, r_gs), lp["seq_ff"], y1)
     x2 = y2 - gy1
     dgs, dy1_g = gs_vjp(dy2)
     dx1 = dy1 + dy1_g
     fx2, fs_vjp = jax.vjp(
-        lambda p, t: _f_seq(cfg, p, t, x_mask, r_fs, sparse), lp["seq_attn"], x2
+        lambda p, t: scoped("seq_attn", _f_seq, cfg, p, t, x_mask, r_fs, sparse),
+        lp["seq_attn"], x2
     )
     x1 = y1 - fx2
     dfs, dx2_f = fs_vjp(dx1)
     dx2 = dy2 + dx2_f
     # msa stream
-    kn1, ks_vjp = jax.vjp(lambda p, t: _ff(cfg, p, t, r_ks), lp["msa_ff"], n1)
+    kn1, ks_vjp = jax.vjp(
+        lambda p, t: scoped("msa_ff", _ff, cfg, p, t, r_ks), lp["msa_ff"], n1)
     m2 = n2 - kn1
     dks, dn1_k = ks_vjp(dn2)
     dm1 = dn1 + dn1_k
     jm2, js_vjp = jax.vjp(
-        lambda p, t: _j_msa(cfg, p, t, msa_mask, r_js), lp["msa_attn"], m2
+        lambda p, t: scoped("msa_attn", _j_msa, cfg, p, t, msa_mask, r_js),
+        lp["msa_attn"], m2
     )
     m1 = n1 - jm2
     djs, dm2_j = js_vjp(dm1)
@@ -314,9 +323,14 @@ def _reversible_core_bwd(meta, residuals, cts):
         )
         return (state, dstate), dlp
 
-    (_, (dx1, dx2, dm1, dm2)), dstacked = jax.lax.scan(
-        body, (out, cts), (stacked, jnp.arange(offset, offset + L)), reverse=True
-    )
+    # everything below the marker is the hand-written backward: a
+    # `jvp(<op>)` under it is the reconstruction of that op, a
+    # `transpose(jvp(<op>))` its backward (benchmarks/scope_reduce.py)
+    with scope(REVERSIBLE_BWD_SCOPE):
+        (_, (dx1, dx2, dm1, dm2)), dstacked = jax.lax.scan(
+            body, (out, cts), (stacked, jnp.arange(offset, offset + L)),
+            reverse=True,
+        )
     return (
         dstacked,
         dx1,

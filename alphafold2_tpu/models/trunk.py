@@ -44,6 +44,7 @@ from alphafold2_tpu.ops.attention import (
 from alphafold2_tpu.ops.core import layer_norm, layer_norm_init
 from alphafold2_tpu.ops.feedforward import feed_forward_apply, feed_forward_init
 from alphafold2_tpu.ops.sparse import sparse_attention_apply
+from alphafold2_tpu.telemetry.profiling import scoped
 
 
 _REMAT_POLICIES = {
@@ -356,7 +357,9 @@ def trunk_layer_apply(
     # block-sparse inner attention when sparse_fn is given — applied PER
     # LAYER, fixing the reference bug that ignores the per-layer tuple
     # (reference alphafold2.py:392)
-    x = prenorm_axial_apply(
+    x = scoped(
+        "seq_attn",
+        prenorm_axial_apply,
         layer["seq_attn"],
         self_cfg,
         x,
@@ -368,7 +371,9 @@ def trunk_layer_apply(
     if m is not None:
         # msa axial self-attention, optionally tied rows
         # (reference alphafold2.py:312)
-        m = prenorm_axial_apply(
+        m = scoped(
+            "msa_attn",
+            prenorm_axial_apply,
             layer["msa_attn"],
             self_cfg,
             m,
@@ -379,19 +384,23 @@ def trunk_layer_apply(
 
         # cross-attention both ways, flat or column-aligned
         # (reference alphafold2.py:316-317; cfg.cross_attn_mode)
-        x = cross_apply_grids(
+        x = scoped(
+            "seq_cross", cross_apply_grids,
             layer["seq_cross"], cfg, x, m, x_mask, msa_mask,
             rngs[2], "pair_from_msa",
         ) + x
-        m = cross_apply_grids(
+        m = scoped(
+            "msa_cross", cross_apply_grids,
             layer["msa_cross"], cfg, m, x, msa_mask, x_mask,
             rngs[3], "msa_from_pair",
         ) + m
 
     # feed-forwards (reference alphafold2.py:321-324)
-    x = prenorm_ff_apply(layer["seq_ff"], cfg, x, rng=rngs[4]) + x
+    x = scoped("seq_ff", prenorm_ff_apply, layer["seq_ff"], cfg, x,
+               rng=rngs[4]) + x
     if m is not None:
-        m = prenorm_ff_apply(layer["msa_ff"], cfg, m, rng=rngs[5]) + m
+        m = scoped("msa_ff", prenorm_ff_apply, layer["msa_ff"], cfg, m,
+                   rng=rngs[5]) + m
     return x, m
 
 
@@ -434,7 +443,8 @@ def branch_parallel_layer_apply(
     """
     self_cfg = cfg.self_attn_config()
 
-    x1 = prenorm_axial_apply(
+    x1 = scoped(
+        "seq_attn", prenorm_axial_apply,
         layer["seq_attn"], self_cfg, x,
         mask=x_mask, rng=rngs[0], attention_fn=sparse_fn,
     ) + x
@@ -448,7 +458,8 @@ def branch_parallel_layer_apply(
         # scopes its own pre-join region), so the coupling must flow
         # through ordinary value ops.
         m = m + (0.0 * jnp.sum(x1)).astype(m.dtype)
-    m1 = prenorm_axial_apply(
+    m1 = scoped(
+        "msa_attn", prenorm_axial_apply,
         layer["msa_attn"], self_cfg, m,
         mask=msa_mask, tie_row=cfg.msa_tie_row_attn, rng=rngs[1],
     ) + m
@@ -457,11 +468,13 @@ def branch_parallel_layer_apply(
 
     # the exchange (reference alphafold2.py:316-317): the ONLY cross-track
     # dataflow — msa<-pair reads the UPDATED pair stream, like serial
-    x2 = cross_apply_grids(
+    x2 = scoped(
+        "seq_cross", cross_apply_grids,
         layer["seq_cross"], cfg, x1, m1, x_mask, msa_mask,
         rngs[2], "pair_from_msa",
     ) + x1
-    m2 = cross_apply_grids(
+    m2 = scoped(
+        "msa_cross", cross_apply_grids,
         layer["msa_cross"], cfg, m1, x2, msa_mask, x_mask,
         rngs[3], "msa_from_pair",
     ) + m1
@@ -471,8 +484,10 @@ def branch_parallel_layer_apply(
     # start here instead of reaching back through the shared exchange
     x2 = schedule_fork(x2)
     m2 = schedule_fork(m2)
-    x3 = prenorm_ff_apply(layer["seq_ff"], cfg, x2, rng=rngs[4]) + x2
-    m3 = prenorm_ff_apply(layer["msa_ff"], cfg, m2, rng=rngs[5]) + m2
+    x3 = scoped("seq_ff", prenorm_ff_apply, layer["seq_ff"], cfg, x2,
+                rng=rngs[4]) + x2
+    m3 = scoped("msa_ff", prenorm_ff_apply, layer["msa_ff"], cfg, m2,
+                rng=rngs[5]) + m2
     return x3, m3
 
 

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from alphafold2_tpu.ops.core import _uniform, linear, linear_init, dropout
 from alphafold2_tpu.ops.flash import flash_attention
+from alphafold2_tpu.telemetry.profiling import scope
 
 # switch to the blockwise path when the full logit tensor (B*h*i*j) would
 # exceed this many elements (2^27 f32 = 512 MB)
@@ -236,12 +237,14 @@ def attention_apply(
     ctx = context if has_context else x
     dtype = cfg.dtype
 
-    q = linear(params["to_q"], x, dtype=dtype)
-    kv = linear(params["to_kv"], ctx, dtype=dtype)
-    k, v = jnp.split(kv, 2, axis=-1)
+    with scope("qkv_proj"):
+        q = linear(params["to_q"], x, dtype=dtype)
+        kv = linear(params["to_kv"], ctx, dtype=dtype)
+        k, v = jnp.split(kv, 2, axis=-1)
 
     if cfg.compress_ratio > 1 and has_context:
-        k, v, context_mask = _compress_kv(params, cfg, k, v, context_mask)
+        with scope("kv_compress"):
+            k, v, context_mask = _compress_kv(params, cfg, k, v, context_mask)
 
     h, dh = cfg.heads, cfg.dim_head
     scale = dh ** -0.5
@@ -256,9 +259,10 @@ def attention_apply(
     # fused into the Pallas kernel on the flash path, exact epilogue on
     # the dense/tied paths — both multiply sigmoid(gate) into the head
     # outputs before to_out
-    gate_logits = (
-        linear(params["to_gate"], x, dtype=dtype) if cfg.gate else None
-    )
+    gate_logits = None
+    if cfg.gate:
+        with scope("qkv_proj"):
+            gate_logits = linear(params["to_gate"], x, dtype=dtype)
 
     # blockwise streaming path: same math, bounded memory (see ops/flash.py).
     # Key-side masking only — masked query rows yield finite garbage masked
@@ -279,7 +283,7 @@ def attention_apply(
             ).astype(jnp.float32)
         )
         # Pallas fused kernel on TPU (supported shapes), XLA streaming
-        # otherwise (ops/flash.py dispatch)
+        # otherwise (ops/flash.py dispatch, which names it `attn_core`)
         if cfg.flash_qb_target is None:
             qb = None
         else:
@@ -297,8 +301,22 @@ def attention_apply(
             logit_dtype=dtype if cfg.flash_compute_dtype_logits else None,
         )
         out = out.reshape(out.shape[0], i, h * dh)
+    else:
+        with scope("attn_core"):
+            out = _dense_attention(cfg, q, k, v, mask, context_mask, tie_dim,
+                                   has_context, rng, gate_logits)
+    with scope("out_proj"):
         return linear(params["to_out"], out, dtype=dtype)
 
+
+def _dense_attention(cfg, q, k, v, mask, context_mask, tie_dim, has_context,
+                     rng, gate_logits):
+    """The materialized-logits arm of `attention_apply`: q, k, v split by
+    head, (b, n, h, dh); returns the head outputs, (b, i, h * dh)."""
+    h, dh = cfg.heads, cfg.dim_head
+    scale = dh ** -0.5
+    i, j = q.shape[1], k.shape[1]
+    dtype = cfg.dtype
     if tie_dim is not None:
         # (b*r, n, h, dh) -> (b, r, n, h, dh); share logits across rows r with
         # the extra r^-0.5 scale (reference alphafold2.py:142-150).
@@ -339,7 +357,7 @@ def attention_apply(
         from alphafold2_tpu.ops.flash import apply_output_gate
 
         out = apply_output_gate(out, gate_logits)
-    return linear(params["to_out"], out, dtype=dtype)
+    return out
 
 
 def _batch_chunked_attention(params, cfg: AttentionConfig, x, *, context, mask, context_mask):

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from alphafold2_tpu.ops.core import dropout, linear, linear_init
+from alphafold2_tpu.telemetry.profiling import scope
 
 
 def feed_forward_init(key, dim: int, mult: int = 4):
@@ -30,11 +31,12 @@ def feed_forward_init(key, dim: int, mult: int = 4):
 
 
 def _ff_core(params, x, dropout_rate, rng, dtype):
-    y = linear(params["proj_in"], x, dtype=dtype)
-    value, gate = jnp.split(y, 2, axis=-1)
-    y = value * jax.nn.gelu(gate, approximate=False)
-    y = dropout(rng, y, dropout_rate)
-    return linear(params["proj_out"], y, dtype=dtype)
+    with scope("geglu"):
+        y = linear(params["proj_in"], x, dtype=dtype)
+        value, gate = jnp.split(y, 2, axis=-1)
+        y = value * jax.nn.gelu(gate, approximate=False)
+        y = dropout(rng, y, dropout_rate)
+        return linear(params["proj_out"], y, dtype=dtype)
 
 
 def feed_forward_apply(

@@ -22,6 +22,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from alphafold2_tpu.telemetry.profiling import scope
+
 _NEG_INF = float("-inf")
 
 
@@ -393,6 +395,18 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
     kernel, the gate applies as an exact epilogue over the blockwise
     result and pair-bias streams through `streamed_fused_attention`.
     """
+    # whichever arm runs, its device operations carry the one name
+    with scope("attn_core"):
+        return _flash_attention_arms(
+            q, k, v, key_bias, pair_bias=pair_bias, gate=gate, scale=scale,
+            use_kernel=use_kernel, kernel_qb=kernel_qb, kernel_kb=kernel_kb,
+            **blockwise_kwargs,
+        )
+
+
+def _flash_attention_arms(q, k, v, key_bias, *, pair_bias, gate, scale,
+                          use_kernel, kernel_qb, kernel_kb,
+                          **blockwise_kwargs):
     from alphafold2_tpu.ops import flash_kernel
 
     B, i, h, dh = q.shape

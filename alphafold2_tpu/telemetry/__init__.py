@@ -115,8 +115,10 @@ def add_telemetry_args(ap):
 
 
 def tracer_from_args(args) -> Tracer:
-    """A live tracer when --trace-out was given, NULL_TRACER otherwise."""
-    if getattr(args, "trace_out", None):
+    """A live tracer when --trace-out or (the trainers') --profile-dir was
+    given, NULL_TRACER otherwise: a profiler capture then holds the host
+    spans beside the device's operations (telemetry/trace.py)."""
+    if getattr(args, "trace_out", None) or getattr(args, "profile_dir", None):
         return Tracer(enabled=True, max_spans=args.trace_max_spans)
     return NULL_TRACER
 

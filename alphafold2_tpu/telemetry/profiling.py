@@ -16,6 +16,9 @@ through the metric registry and the span tracer:
     so MFU can be derived from any metrics scrape.
   * `profile_trace` — the jax.profiler context manager (migrated from
     utils/observability.py; re-exported there for back-compat).
+  * `SCOPES` / `scope` / `scoped` — the names the training step carries
+    into every device trace (`jax.named_scope`): trace-time metadata
+    only, the compiled program is the same with or without them.
 """
 
 from __future__ import annotations
@@ -28,6 +31,62 @@ import jax
 
 from alphafold2_tpu.telemetry.registry import MetricRegistry
 from alphafold2_tpu.telemetry.trace import NULL_TRACER, Tracer
+
+
+# --- names inside the program ------------------------------------------------
+#
+# Two levels, no third. An OUTER name says which part of the step an
+# operation belongs to; an INNER name says which piece of an attention or
+# feed-forward block. The reduction (benchmarks/scope_reduce.py) keys device
+# time by the innermost outer name on an operation's name stack, plus the
+# innermost inner name after it: `seq_attn/attn_core`, `seq_ff/geglu`,
+# `mds`. The tuples are the documented list: the code below, the reduction,
+# its tests and docs/OBSERVABILITY.md all read them.
+
+#: the eight trunk ops, named by the parameter key each already has
+TRUNK_OP_SCOPES = (
+    "seq_attn", "seq_ff", "msa_attn", "msa_ff",
+    "seq_cross", "seq_ff2", "msa_cross", "msa_ff2",
+)
+#: around the trunk (models/alphafold2.py); `trunk` holds the eight ops
+MODEL_SCOPES = ("embed", "template_tower", "trunk", "distogram_head")
+#: the structure tail of the end-to-end loss (training/e2e.py)
+TAIL_SCOPES = (
+    "center_distogram", "mds", "sidechain_lift", "refiner", "kabsch_loss",
+    "dispersion",
+)
+#: opt.update + apply_updates + global_norm (training/harness.py)
+OPTIMIZER_SCOPE = "optimizer"
+OUTER_SCOPES = MODEL_SCOPES + TRUNK_OP_SCOPES + TAIL_SCOPES + (OPTIMIZER_SCOPE,)
+#: inside attention (ops/attention.py) and feed-forward (ops/feedforward.py)
+INNER_SCOPES = ("qkv_proj", "attn_core", "out_proj", "kv_compress", "geglu")
+#: phase marker: the body of the reversible trunk's hand-written backward,
+#: so that a `jvp(...)` under it reads as the reconstruction and not as the
+#: primal forward (models/reversible.py)
+REVERSIBLE_BWD_SCOPE = "reversible_bwd"
+SCOPES = OUTER_SCOPES + INNER_SCOPES + (REVERSIBLE_BWD_SCOPE,)
+
+
+# the one place a name enters the program; tests swap it for a no-op to
+# show that the lowered program is the same without the names
+_named_scope = jax.named_scope
+
+
+def scope(name: str):
+    """`jax.named_scope(name)` for a documented name; any other name is a
+    ValueError at trace time, so the list above cannot fall behind the
+    code. Costs nothing per step: it runs only while a function is traced."""
+    if name not in SCOPES:
+        raise ValueError(
+            f"{name!r} is not a documented scope; add it to "
+            f"telemetry/profiling.py SCOPES (and docs/OBSERVABILITY.md)")
+    return _named_scope(name)
+
+
+def scoped(name: str, fn, *args, **kwargs):
+    """`fn(*args, **kwargs)` under `scope(name)`."""
+    with scope(name):
+        return fn(*args, **kwargs)
 
 
 @contextlib.contextmanager

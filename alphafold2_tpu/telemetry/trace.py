@@ -13,6 +13,13 @@ into a nestable, thread-safe span with attributes, exportable as:
   * an in-process summary (`summary()`): per-span-name count / total /
     mean / max seconds, the payload `ServingEngine.stats()` embeds.
 
+One clock with the device: every span of an ENABLED tracer also enters a
+`jax.profiler.TraceAnnotation` of the same name for its lifetime, so any
+profiler capture taken while it runs (`--profile-dir`, `/profilez`) holds
+the span on `/host:CPU` beside the device's operations, and
+benchmarks/scope_reduce.py can name the device's idle gaps by it. Outside a
+capture the annotation is a flag test in native code.
+
 Cost contract: a DISABLED tracer is near-zero-cost — `span()` returns a
 shared no-op singleton (no allocation, no lock, no record), so
 instrumentation can stay in production code paths unconditionally. Use
@@ -43,6 +50,8 @@ import time
 import uuid
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 
 def new_trace_id() -> str:
     """A fresh 16-hex-char request trace id (random, not time-derived:
@@ -72,7 +81,8 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One live span; created by `Tracer.span` and recorded on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "_depth",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict):
         self._tracer = tracer
@@ -87,11 +97,14 @@ class _Span:
 
     def __enter__(self):
         self._depth = self._tracer._push()
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = self._tracer._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = self._tracer._clock() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
@@ -138,7 +151,10 @@ class Tracer:
             end_at: Optional[float] = None, **attrs):
         """Record a span measured elsewhere (e.g. queue wait computed from
         a request's submit timestamp): ends at `end_at` (default: now) on
-        this tracer's clock, started `duration_s` earlier."""
+        this tracer's clock, started `duration_s` earlier. Such a span
+        (`serving.queue_wait`, the pipelined `serving.execute`) is in this
+        tracer's exports only: a profiler annotation cannot be written
+        after the fact, so it is not on `/host:CPU` of a capture."""
         if not self.enabled:
             return
         end = self._clock() if end_at is None else end_at

@@ -45,6 +45,7 @@ from alphafold2_tpu.models import (
     refiner_apply,
     refiner_init,
 )
+from alphafold2_tpu.telemetry.profiling import scope, scoped
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,13 +123,16 @@ def predict_structure(params, ecfg: E2EConfig, seq, mask=None, rng=None, msa=Non
     # geometry runs in float32 regardless of the trunk's compute dtype:
     # the distogram -> MDS pipeline divides by pairwise distances (Guttman
     # B-matrix) and small weights, which overflows/NaNs in bfloat16
-    logits = logits.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    distances, weights = center_distogram(probs)
+    with scope("center_distogram"):
+        logits = logits.astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        distances, weights = center_distogram(probs)
 
     # chirality masks over the flat (L*3) backbone atom axis
     n_mask, ca_mask = scn_backbone_mask(seq, l_aa=3)
-    coords, _ = mdscaling(
+    coords, _ = scoped(
+        "mds",
+        mdscaling,
         distances,
         weights=weights,
         iters=ecfg.mds_iters,
@@ -141,8 +145,9 @@ def predict_structure(params, ecfg: E2EConfig, seq, mask=None, rng=None, msa=Non
         init=ecfg.mds_init,
     )  # (b, 3, 3L)
 
-    backbone = jnp.transpose(coords, (0, 2, 1))  # (b, 3L, 3)
-    proto = sidechain_container(backbone, place_oxygen=ecfg.place_oxygen)  # (b, L, 14, 3)
+    with scope("sidechain_lift"):
+        backbone = jnp.transpose(coords, (0, 2, 1))  # (b, 3L, 3)
+        proto = sidechain_container(backbone, place_oxygen=ecfg.place_oxygen)  # (b, L, 14, 3)
 
     cloud_mask = scn_cloud_mask(seq)  # (b, L, 14)
     if mask is not None:
@@ -152,7 +157,8 @@ def predict_structure(params, ecfg: E2EConfig, seq, mask=None, rng=None, msa=Non
     atom_tokens = jnp.broadcast_to(
         jnp.arange(NUM_COORDS_PER_RES)[None, None, :], cloud_mask.shape
     ).reshape(b, num_atoms)
-    refined, _ = refiner_apply(
+    refined, _ = scoped(
+        "refiner", refiner_apply,
         params["refiner"], ecfg.refiner,
         atom_tokens, proto.reshape(b, num_atoms, 3),
         mask=cloud_mask.reshape(b, num_atoms),
@@ -193,25 +199,27 @@ def make_e2e_loss_fn(model_apply_fn=None):
         if atom_mask is not None:
             w = w * atom_mask.reshape(b, num_atoms).astype(jnp.float32)
 
-        pred = jnp.transpose(out["refined"].reshape(b, num_atoms, 3), (0, 2, 1))
-        true = jnp.transpose(
-            jnp.asarray(batch["coords"], jnp.float32).reshape(b, num_atoms, 3),
-            (0, 2, 1),
-        )
-        pred_aligned, true_centered = kabsch(pred, true, weights=w)
+        with scope("kabsch_loss"):
+            pred = jnp.transpose(out["refined"].reshape(b, num_atoms, 3), (0, 2, 1))
+            true = jnp.transpose(
+                jnp.asarray(batch["coords"], jnp.float32).reshape(b, num_atoms, 3),
+                (0, 2, 1),
+            )
+            pred_aligned, true_centered = kabsch(pred, true, weights=w)
 
-        sq = jnp.sum(jnp.square(pred_aligned - true_centered), axis=-2)  # (b, A)
-        denom = jnp.maximum(jnp.sum(w, axis=-1), 1.0)
-        rmsd = jnp.sqrt(jnp.sum(sq * w, axis=-1) / denom)  # (b,)
+            sq = jnp.sum(jnp.square(pred_aligned - true_centered), axis=-2)  # (b, A)
+            denom = jnp.maximum(jnp.sum(w, axis=-1), 1.0)
+            rmsd = jnp.sqrt(jnp.sum(sq * w, axis=-1) / denom)  # (b,)
 
         # dispersion penalty over UNCENSORED pairs only: censored pairs
         # (weight hard-zeroed by center_distogram for beyond-last-bucket
         # predictions) would add a huge ~1/eps constant with exactly zero
         # gradient, drowning the RMSD signal in the reported loss
-        dw = out["distogram_weights"]
-        valid = (dw > 0).astype(jnp.float32)
-        per_pair = jnp.abs(1.0 / (dw + ecfg.weights_eps) - 1.0) * valid
-        dispersion = jnp.sum(per_pair) / jnp.maximum(jnp.sum(valid), 1.0)
+        with scope("dispersion"):
+            dw = out["distogram_weights"]
+            valid = (dw > 0).astype(jnp.float32)
+            per_pair = jnp.abs(1.0 / (dw + ecfg.weights_eps) - 1.0) * valid
+            dispersion = jnp.sum(per_pair) / jnp.maximum(jnp.sum(valid), 1.0)
         return jnp.mean(rmsd) + ecfg.dispersion_weight * dispersion
 
     return loss_fn

@@ -30,6 +30,7 @@ import optax
 
 from alphafold2_tpu.models import Alphafold2Config, alphafold2_apply, alphafold2_init
 from alphafold2_tpu.ops.quant import reject_quant_training
+from alphafold2_tpu.telemetry.profiling import OPTIMIZER_SCOPE, scope
 from alphafold2_tpu.training.losses import bucketed_distance_matrix, distogram_cross_entropy
 
 
@@ -183,14 +184,16 @@ def make_train_step(
         loss = loss_sum / n
         grads = jax.tree_util.tree_map(lambda g: g / n, grad_sum)
 
-        updates, opt_state = opt.update(grads, state["opt_state"], params)
-        params = optax.apply_updates(params, updates)
-        new_state = {
-            "params": params,
-            "opt_state": opt_state,
-            "step": state["step"] + 1,
-        }
-        return new_state, {"loss": loss, "grad_norm": optax.global_norm(grads)}
+        with scope(OPTIMIZER_SCOPE):
+            updates, opt_state = opt.update(grads, state["opt_state"], params)
+            params = optax.apply_updates(params, updates)
+            new_state = {
+                "params": params,
+                "opt_state": opt_state,
+                "step": state["step"] + 1,
+            }
+            return new_state, {"loss": loss,
+                               "grad_norm": optax.global_norm(grads)}
 
     return train_step
 
@@ -318,14 +321,16 @@ def make_axis_accum_train_step(
             [b / denom for b in red], params_shape, treedef, buckets
         )
 
-        updates, opt_state = opt.update(grads, state["opt_state"], params)
-        new_params = optax.apply_updates(params, updates)
-        new_state = {
-            "params": new_params,
-            "opt_state": opt_state,
-            "step": state["step"] + 1,
-        }
-        return new_state, {"loss": loss, "grad_norm": optax.global_norm(grads)}
+        with scope(OPTIMIZER_SCOPE):
+            updates, opt_state = opt.update(grads, state["opt_state"], params)
+            new_params = optax.apply_updates(params, updates)
+            new_state = {
+                "params": new_params,
+                "opt_state": opt_state,
+                "step": state["step"] + 1,
+            }
+            return new_state, {"loss": loss,
+                               "grad_norm": optax.global_norm(grads)}
 
     return train_step
 

@@ -397,60 +397,6 @@ elif leg == "ops_detail":
         ),
         axial_params["attn_height"], x,
     )
-elif leg == "profile":
-    # op-level breakdown via a perfetto trace of one trunk fwd+bwd step:
-    # the perfetto JSON jax.profiler emits is parseable by hand. Reports
-    # top ops by total duration, not busy/idle shares (ROADMAP S0).
-    import glob
-    import gzip
-    import os
-    import shutil
-
-    state = e2e_train_state_init(key, ecfg, tcfg)
-    params = state["params"]["model"]
-
-    def fwd(p):
-        logits = alphafold2_apply(
-            p, cfg, seq3, batch["msa"], mask=mask3,
-            msa_mask=batch["msa_mask"], rng=None,
-        )
-        return jnp.mean(jnp.square(logits.astype(jnp.float32)))
-
-    compiled = jax.jit(jax.value_and_grad(fwd)).lower(params).compile()
-    out = compiled(params)
-    jax.tree_util.tree_map(np.asarray, out)  # warmup + fetch
-
-    tmpdir = os.path.join(os.getcwd(), "profile_tmp")
-    shutil.rmtree(tmpdir, ignore_errors=True)
-    with jax.profiler.trace(tmpdir, create_perfetto_trace=True):
-        out = compiled(params)
-        jax.tree_util.tree_map(np.asarray, out)
-
-    traces = glob.glob(
-        os.path.join(tmpdir, "**", "*perfetto_trace.json.gz"), recursive=True
-    )
-    if not traces:
-        raise SystemExit(f"no perfetto trace produced under {tmpdir}")
-    with gzip.open(traces[0], "rt") as f:
-        events = json.load(f).get("traceEvents", [])
-    totals = {}
-    for ev in events:
-        if ev.get("ph") != "X":
-            continue
-        name = ev.get("name", "?")
-        dur = ev.get("dur", 0)  # microseconds
-        t = totals.setdefault(name, [0.0, 0])
-        t[0] += dur
-        t[1] += 1
-    top = sorted(totals.items(), key=lambda kv: -kv[1][0])[:25]
-    for name, (dur_us, count) in top:
-        report(leg="profile_op", depth=depth, name=name[:120],
-               total_ms=round(dur_us / 1e3, 1), count=count)
-    report(leg="profile_total", depth=depth,
-           total_ms=round(sum(v[0] for v in totals.values()) / 1e3, 1),
-           events=len(events))
-    shutil.rmtree(tmpdir, ignore_errors=True)
-
 else:
     raise SystemExit(f"unknown leg {leg!r}")
 """
@@ -515,7 +461,7 @@ def main():
                     # transfer), ops_s (the decisive per-op split of the
                     # 378 ms/layer forward), then the rest.
                     default="trunk_fwd,fetch_bw,ops_s,ops_detail,"
-                            "trunk_vg_s,geom_vg_s,profile")
+                            "trunk_vg_s,geom_vg_s")
     ap.add_argument("--timeout", type=int, default=1800)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CPU shapes: validates the worker end-to-end "
@@ -531,8 +477,7 @@ def main():
     marker = {"ops": "op_ff_msa2",
               "ops_s": "op_s_ff_msa2",
               "ops_detail": "detail_pair_attn_rowpass",
-              "fetch_bw": "fetch_bw_256MB",
-              "profile": "profile_total"}
+              "fetch_bw": "fetch_bw_256MB"}
     done = set()
     if not args.force_all and os.path.exists(OUT):
         with open(OUT) as f:
@@ -547,13 +492,10 @@ def main():
     failed = []
     for leg in args.legs.split(","):
         leg = leg.strip()
-        # profile runs at depth 2: the per-layer op mix is depth-invariant
-        # and a short trace stays small enough to bring back
-        depth = 2 if leg == "profile" else args.depth
-        if not args.smoke and (marker.get(leg, leg), depth) in done:
+        if not args.smoke and (marker.get(leg, leg), args.depth) in done:
             print(f"skip {leg}: already recorded in {OUT}", flush=True)
             continue
-        rows, wall = run_leg(leg, depth, args.timeout, smoke=args.smoke)
+        rows, wall = run_leg(leg, args.depth, args.timeout, smoke=args.smoke)
         with open(OUT, "a") as f:
             for row in rows:
                 row["wall"] = round(wall, 1)

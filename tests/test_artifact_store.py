@@ -478,8 +478,6 @@ def test_feature_bundles_replay_from_store_on_resubmission(tmp_path):
     try:
         seq = seq_of(10)
         fleet.predict(seq)
-        feats_before = fleet.stats()["artifact_store"]
-        assert feats_before["disk"]["writes"] >= 2  # result + features
         # resubmit: the RESULT hit wins outright, but drop the result
         # entry to force the featurize path and prove the bundle replays
         ftag = fleet._feature_tag()
@@ -489,9 +487,19 @@ def test_feature_bundles_replay_from_store_on_resubmission(tmp_path):
         bundle = store.lookup_features(ftag, fkey)[0]
         rkey = request_key(bundle.seq, bundle.msa, rtag,
                            msa_mask=bundle.msa_mask)
+        # predict() returns when the leader's future resolves, which is
+        # BEFORE the settle path persists the result (persistence rides
+        # the dispatch callback thread) — wait for the file, not the clock
+        result_path = store._path("result", rtag, rkey)
+        deadline = time.monotonic() + 10
+        while not os.path.exists(result_path) and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert os.path.exists(result_path)
+        writes = fleet.stats()["artifact_store"]["disk"]["writes"]
+        assert writes >= 2  # result + features
         # evict the result from ring+disk, keep the features
         store._ring.pop(("result", rtag, rkey), None)
-        os.unlink(store._path("result", rtag, rkey))
+        os.unlink(result_path)
         h = fleet.submit(seq)
         r = h.result(timeout=10)
         assert not r.from_cache

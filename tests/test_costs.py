@@ -285,21 +285,29 @@ def test_fake_engine_registers_cells_and_feeds_measured_columns():
 
 def test_real_engine_excludes_compile_from_execute_ema(tiny_params):
     """The first batch of a bucket carries its AOT compile; the cost
-    EMA must price EXECUTION — on this tiny model the compile is orders
-    of magnitude above a single forward, so inclusion is unmissable."""
+    EMA must price EXECUTION. The compile and the execution are disjoint
+    stretches of the one predict() call, so an EMA that leaves the
+    compile out fits beside it inside the call's wall time whatever the
+    machine's load (with a warm compile cache the "compile" is a 0.2 s
+    cache load, only four times the forward: a ratio between the two
+    raced the clock under xdist). One that included it would count the
+    compile twice and overrun the call by the compile's length."""
     eng = ServingEngine(tiny_params, TINY, ServingConfig(
         buckets=(8,), max_batch=1, max_wait_s=0.0, mds_iters=2,
         cache_capacity=0))
     try:
+        t0 = time.monotonic()
         eng.predict(seq_of(5))
+        wall = time.monotonic() - t0
         compile_s = eng.metrics.compile_seconds_total()
         assert compile_s > 0
         cell = eng.stats()["costs"]["cells"][0]
         assert cell["requests"] == 1
-        assert cell["ema_batch_seconds"] < 0.5 * compile_s
+        assert cell["ema_batch_seconds"] + compile_s <= wall
         gp = eng.stats()["serve_goodput"]["replicas"]["engine"]["buckets"]
-        assert gp["compile"] == pytest.approx(compile_s, rel=0.5)
-        assert gp["execute"] < 0.5 * compile_s
+        # the ledger's compile stretch encloses the tracker's
+        assert gp["compile"] >= 0.9 * compile_s
+        assert gp["compile"] + gp["execute"] <= wall
     finally:
         eng.shutdown(timeout=30)
 

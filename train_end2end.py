@@ -303,7 +303,7 @@ def main():
     logger = MetricsLogger(
         jsonl_path=args.metrics_jsonl, print_every=10,
         process_index=jax.process_index() if procs > 1 else None)
-    tracer = tracer_from_args(args)  # NULL_TRACER unless --trace-out
+    tracer = tracer_from_args(args)  # NULL_TRACER unless --trace-out / --profile-dir
     registry = MetricRegistry(
         enabled=tracer.enabled or observability_enabled(args))
     from alphafold2_tpu.utils.flops import train_step_flops
