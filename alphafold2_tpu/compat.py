@@ -35,6 +35,7 @@ __all__ = [
     "broadcast_one_to_all",
     "enable_cpu_collectives",
     "create_hybrid_device_mesh",
+    "describe_topology",
     "make_array_from_process_local_data",
     "make_global_array_from_host",
     "out_struct",
@@ -114,6 +115,22 @@ def pcast(x, axis_names, *, to: str = "varying"):
     """`jax.lax.pcast`: mark a value varying/invariant over mesh axes so
     shard_map carry types line up after collectives."""
     return jax.lax.pcast(x, axis_names, to=to)
+
+
+# --- compiling for a chip that is not attached ------------------------------
+
+
+def describe_topology(platform: str, topology_name: str):
+    """A described (not attached) accelerator topology, e.g.
+    ("tpu", "v5e:2x2"): its `.devices` take shardings and
+    `jit(...).lower(...).compile()` then compiles for that chip on any
+    host (tests/test_chip_compile.py). Loads the platform's compiler
+    library into this process: call it from a test or a fixture, never
+    while a module is imported."""
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform=platform, topology_name=topology_name)
 
 
 # --- multi-host runtime ----------------------------------------------------

@@ -115,9 +115,9 @@ class Alphafold2Config:
     # Bigger tiles = better MXU utilization, more live memory
     attn_flash_tile_elems: int = 1 << 25
     attn_flash_kv_block: int = 2048
-    # Pallas flash-kernel QUERY block-size target (None = auto): each
-    # attention shape picks its own unpadded block up to this size (see
-    # ops/attention.py AttentionConfig.flash_qb_target)
+    # Pallas flash-kernel QUERY block-size target for block tuning of
+    # the streaming form; None = the kernel picks form and blocks from
+    # the shape (see ops/attention.py AttentionConfig.flash_qb_target)
     attn_flash_qb_target: Optional[int] = None
     # XLA streaming attention: materialize score/probability tiles in the
     # compute dtype instead of f32 (AttentionConfig

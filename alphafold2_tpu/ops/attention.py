@@ -68,13 +68,12 @@ class AttentionConfig:
     # MXU utilization, more live memory — tune per chip generation
     flash_tile_elems: int = 1 << 25
     flash_kv_block: int = 2048
-    # Pallas-kernel QUERY block-size target (None = auto). The actual
-    # block is pick_block(i, target=this) per attention shape, so short
-    # axes are never padded up: at target 1152, a 1152-long axis gets
-    # whole-row blocks (grid collapsed 3x vs the default 512 cap) while
-    # 384/128-long axes keep their unpadded blocks. Key blocks stay auto
-    # (a (1152, 384) f32 logit tile fits VMEM headroom; qb=kb=1152 would
-    # not). Surfaced up to Alphafold2Config for the e2e sweep.
+    # Block tuning of the Pallas kernel's STREAMING form: a QUERY
+    # block-size target, the block being pick_block(i, target=this) per
+    # attention shape (key blocks stay auto). None (the default) lets the
+    # kernel choose its form and blocks from the shape — whole-row at the
+    # pair stream's 1152 x 1152 (ops/flash_kernel.py rows_plan); setting
+    # this takes that choice away, so no preset does.
     flash_qb_target: Optional[int] = None
     # materialize the XLA streaming path's score/probability tiles in the
     # COMPUTE dtype instead of f32 (ops/flash.py stream_block): those
@@ -237,17 +236,38 @@ def attention_apply(
     ctx = context if has_context else x
     dtype = cfg.dtype
 
-    with scope("qkv_proj"):
-        q = linear(params["to_q"], x, dtype=dtype)
-        kv = linear(params["to_kv"], ctx, dtype=dtype)
-        k, v = jnp.split(kv, 2, axis=-1)
-
-    if cfg.compress_ratio > 1 and has_context:
-        with scope("kv_compress"):
-            k, v, context_mask = _compress_kv(params, cfg, k, v, context_mask)
-
     h, dh = cfg.heads, cfg.dim_head
     scale = dh ** -0.5
+    compress = cfg.compress_ratio > 1 and has_context
+
+    def streams(j):
+        """Whether the core streams (ops/flash.py) instead of
+        materializing the full logit tensor, at key length j."""
+        return tie_dim is None and not dropout_live and (
+            cfg.flash is True or (
+                cfg.flash == "auto"
+                and x.shape[0] * h * x.shape[1] * j > _FLASH_AUTO_THRESHOLD))
+
+    with scope("qkv_proj"):
+        q = linear(params["to_q"], x, dtype=dtype)
+        if not compress and streams(ctx.shape[1]):
+            # K and V as two projections from the halves of the weight:
+            # the streaming core's kernel arm takes them as two arrays, and
+            # two slices of one fused output would each be copied through
+            # HBM on the way in (qkv_proj +20% at the pair stream's shape)
+            k, v = (
+                linear({name: jnp.split(t, 2, axis=-1)[half]
+                        for name, t in params["to_kv"].items()},
+                       ctx, dtype=dtype)
+                for half in (0, 1)
+            )
+        else:
+            kv = linear(params["to_kv"], ctx, dtype=dtype)
+            k, v = jnp.split(kv, 2, axis=-1)
+
+    if compress:
+        with scope("kv_compress"):
+            k, v, context_mask = _compress_kv(params, cfg, k, v, context_mask)
 
     def split_heads(t):
         b, n, _ = t.shape
@@ -267,10 +287,7 @@ def attention_apply(
     # blockwise streaming path: same math, bounded memory (see ops/flash.py).
     # Key-side masking only — masked query rows yield finite garbage masked
     # downstream, exactly like the dense path's uniform-attention rows.
-    use_flash = cfg.flash is True or (
-        cfg.flash == "auto" and q.shape[0] * h * i * j > _FLASH_AUTO_THRESHOLD
-    )
-    if use_flash and tie_dim is None and not dropout_live:
+    if streams(j):
         if context_mask is None and mask is not None and not has_context:
             context_mask = mask
         key_bias = (

@@ -52,6 +52,7 @@ keyspace (serving/engine.py).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Callable, Dict, Optional, Tuple
 
@@ -65,10 +66,12 @@ __all__ = [
     "ARM_XLA_REF",
     "Arm",
     "OpSpec",
+    "decisions",
     "get",
     "main",
     "ops",
     "pallas_triton_lowerable",
+    "reset_decisions",
     "resolution_table",
     "resolution_tag",
     "resolve",
@@ -176,9 +179,45 @@ def _platform() -> str:
     return jax.devices()[0].platform
 
 
+# every "auto" decision `resolve` has made in this process, by
+# (op, arm, shapes): what a traced program's call sites were given
+_DECISIONS: "collections.Counter[Tuple[str, str, Tuple]]" = collections.Counter()
+
+
+def decisions() -> Dict[str, int]:
+    """The tally of `resolve`'s "auto" decisions since the last
+    `reset_decisions()`, one entry a distinct (op, arm, shapes):
+    ``{"flash_attention -> pallas_tpu @ i=1152 j=1152 dh=64": 4, ...}``.
+
+    Resolution happens while a program is TRACED, so read it after the
+    step has compiled: it says which arm every call site of the compiled
+    program runs, at the shapes it saw (the probe-shape tag of
+    `resolution_tag()` cannot). Forced requests (use_kernel=True/False)
+    and the introspection helpers are not decisions and are not counted;
+    an env override is, under the arm it forced."""
+    return {
+        f"{op} -> {arm} @ " + " ".join(f"{k}={v}" for k, v in shapes): n
+        for (op, arm, shapes), n in sorted(_DECISIONS.items(), key=repr)
+    }
+
+
+def reset_decisions() -> None:
+    _DECISIONS.clear()
+
+
 def resolve(op: str, request="auto", platform: Optional[str] = None,
             **shapes) -> str:
-    """THE resolution point: (op, shapes, platform, env) -> arm name.
+    """THE resolution point: (op, shapes, platform, env) -> arm name,
+    tallied for `decisions()` when the request was "auto"."""
+    arm = _resolve(op, request, platform, **shapes)
+    if request == "auto":
+        _DECISIONS[(op, arm, tuple(shapes.items()))] += 1
+    return arm
+
+
+def _resolve(op: str, request="auto", platform: Optional[str] = None,
+             **shapes) -> str:
+    """(op, shapes, platform, env) -> arm name, untallied.
 
     `request` is the call-site tri-state (the old `use_kernel`): True
     forces the op's kernel arm, False forces `xla_ref`, "auto" consults
@@ -290,7 +329,8 @@ register(OpSpec(
     name="flash_attention",
     arms=(
         Arm(ARM_PALLAS_TPU, _flash_supported,
-            "ops/flash_kernel.py flash_attention_tpu (interpret off-TPU)"),
+            "ops/flash_kernel.py flash_attention_bnhd: whole-row or "
+            "streaming form from the shape (interpret off-TPU)"),
         Arm(ARM_GPU, _always,
             "XLA blockwise streaming (ops/flash.py blockwise_attention); "
             "Pallas-Triton slot when lowerable"),
@@ -447,8 +487,8 @@ def resolution_table(platform: Optional[str] = None):
             for a in spec.arms
         }
         try:
-            resolved = resolve(name, request="auto", platform=platform,
-                               **spec.probe)
+            resolved = _resolve(name, request="auto", platform=platform,
+                                **spec.probe)
         except ValueError as e:  # forced-unknown / forced-unsupported env
             resolved = f"ERROR: {e}"
         rows.append((name, dict(spec.probe), supp, resolved))
@@ -467,7 +507,7 @@ def resolution_tag(platform: Optional[str] = None) -> str:
         platform = _platform()
     parts = []
     for name, spec in _REGISTRY.items():
-        arm = resolve(name, request="auto", platform=platform, **spec.probe)
+        arm = _resolve(name, request="auto", platform=platform, **spec.probe)
         parts.append(f"{name}={arm}")
     return f"dispatch[{platform}](" + ",".join(parts) + ")"
 
@@ -481,7 +521,7 @@ def resolved_arm(op: str, platform: Optional[str] = None) -> str:
     if platform is None:
         platform = _platform()
     spec = get(op)
-    return resolve(op, request="auto", platform=platform, **spec.probe)
+    return _resolve(op, request="auto", platform=platform, **spec.probe)
 
 
 def main(argv=None) -> int:

@@ -341,7 +341,8 @@ def kernel_dispatch(i: int, j: int, dh: int, use_kernel,
     True forces the kernel (ValueError on unsupported shapes — forcing
     must not silently fall back), False forces XLA streaming, "auto" =
     the registry heuristic (kernel on TPU for supported shapes with
-    j >= auto_min_j(), the measured short-j crossover). `fused` selects
+    j >= auto_min_j(), the lowest key length the kernel was measured to
+    win at: ops/knobs.py FLASH_AUTO_MIN_J_DEFAULT). `fused` selects
     the fused-epilogue op (its shape gate is `supported_fused`:
     2-D pair bias / in-kernel gating, ops/flash_kernel.py).
     """
@@ -381,11 +382,14 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
     (B, j, h, dh); key-side (B, j) additive bias). use_kernel: True forces
     the kernel (interpret mode off-TPU — for tests), False forces XLA
     streaming, "auto" uses the kernel on TPU for supported shapes
-    (ops/flash_kernel.py `supported`) with j >= auto_min_j() — below the
-    measured short-j crossover XLA streaming is faster end-to-end
-    (PERF.md session 4), so "auto" prefers it there. kernel_qb/kernel_kb override the
-    kernel's query/key block sizes (None = padding-aware pick_block) —
-    kernel path only, used for block tuning (scripts/bench_kernels.py).
+    (ops/flash_kernel.py `supported`) with j >= auto_min_j() — the
+    pair stream's axial passes (i = j = 1152), where its whole-row form
+    keeps the logit tile in VMEM (PERF.md section 5); the short crosses
+    below it were not measured to win and stay on XLA streaming. The
+    kernel picks its own form and blocks from (i, j, h, dh)
+    (ops/flash_kernel.py `flash_attention_bnhd`); kernel_qb/kernel_kb
+    force its streaming form at those query/key blocks — kernel path
+    only, used for block tuning (scripts/bench_kernels.py).
 
     Fused epilogue: `pair_bias` (B, h, i, j) f32 full 2-D additive bias
     tiles and/or `gate` (B, i, h, dh) pre-sigmoid output-gate logits.
@@ -492,20 +496,16 @@ def _flash_attention_arms(q, k, v, key_bias, *, pair_bias, gate, scale,
                 f"{use_kernel!r}); disable the kernel for this A/B"
             )
 
-        def fold(t):
-            return t.transpose(0, 2, 1, 3).reshape(B * h, t.shape[1], dh)
-
         bias = (
             jnp.zeros((B, j), jnp.float32)
             if key_bias is None
             else jnp.broadcast_to(key_bias, (B, j)).astype(jnp.float32)
         )
-        bias = jnp.repeat(bias, h, axis=0)  # per (batch, head) grid row
-        out = flash_kernel.flash_attention_tpu(
-            fold(q), fold(k), fold(v), bias, scale,
-            qb=kernel_qb, kb=kernel_kb,
+        # the kernel takes the model's layout and picks its own form
+        # (whole-row or streaming) from (i, j, h, dh)
+        return flash_kernel.flash_attention_bnhd(
+            q, k, v, bias, scale, qb=kernel_qb, kb=kernel_kb,
         )
-        return out.reshape(B, h, i, dh).transpose(0, 2, 1, 3)
 
     return blockwise_attention(
         q, k, v, key_bias, scale=scale, **blockwise_kwargs

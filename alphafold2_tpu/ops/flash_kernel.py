@@ -8,6 +8,11 @@ block-sparse kernel (ops/sparse_kernel.py), without the index table, and
 supporting CROSS attention (query and key lengths differ) — the shape the
 aligned cross-attention mode produces (models/trunk.py).
 
+Two forms, chosen from the shape by `flash_attention_bnhd`: the
+WHOLE-ROW form (second half of this file) where every key of a
+(batch, head group) fits one grid step — the pair stream's axial passes,
+i = j = 1152 — and the STREAMING form below everywhere else.
+
 Streaming layout: each kernel runs a 3-D grid whose LAST dimension walks
 the contraction blocks sequentially (dimension_semantics "arbitrary") with
 running statistics in VMEM scratch, while Mosaic's pipeline double-buffers
@@ -445,6 +450,323 @@ def _bwd_lse(scale, qb, kb, res, gs):
 
 
 _flash_core_lse.defvjp(_fwd_lse, _bwd_lse)
+
+
+# ---------------------------------------------------------------------------
+# whole-row form: one grid step holds every key of a (batch, head group)
+# ---------------------------------------------------------------------------
+#
+# At the pair stream's axial shape (i = j = 1152, dh = 64) a whole
+# (1152, 1152) f32 logit tile is 5.3 MB: it fits VMEM, so nothing streams.
+# One grid step takes a (batch, head GROUP) row straight out of the
+# model's (B, n, h*dh) layout — the group is the heads that fill 128
+# lanes (two at dh = 64), picked by the BlockSpec's index, so q/k/v/out
+# never transpose through HBM — and walks the queries in chunks of
+# `rows` with K and V resident:
+#
+#   * no running max, no alpha, no accumulator read-modify-write: max,
+#     exp, row sum and the AV dot see every key at once;
+#   * a head's logits come from the 128-lane q block against K with the
+#     OTHER heads' lanes zeroed. The MXU pads a 64-deep contraction to
+#     128 anyway, so the zeros cost nothing and no lane is ever sliced;
+#     p @ (V with the other lanes zeroed) lands each head's output in its
+#     own lanes of one lane-dense (rows, 128) tile;
+#   * K and V are transposed ONCE a grid step, so every dot in the
+#     chunk loop is a plain (M, K) @ (K, N);
+#   * the backward is ONE kernel: s and p are recomputed once and feed
+#     dq (written a chunk at a time) and the dk / dv accumulators;
+#     delta = rowsum(dO * O) is taken in the kernel from the O block.
+#
+# Numerics are the streaming form's: f32 logits from the dot's
+# accumulator, finite max sentinel, p -> operand dtype for AV and dv,
+# ds -> operand dtype for dq / dk, +inf lse on zero-mass rows.
+
+# whole-row VMEM ceiling: what one grid step may plan (double-buffered
+# blocks + resident K / K^T / V^T + the chunk's logit tiles). A shape
+# over it streams through the 3-D grid above instead.
+_ROWS_VMEM_CAP = 48 * 1024 * 1024
+# largest f32 (rows, j) logit tile inside a grid step: the whole
+# 1152 x 1152 tile (5.1 MiB) is one chunk — measured 5% faster than three
+# chunks of 384 (5.9 against 6.2 us a row forward, PERF.md section 5)
+_ROWS_TILE_BYTES = 6 * 1024 * 1024
+# past this many keys K and V stream: the form measured for j >= 4096
+_ROWS_MAX_KEYS = 2048
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _rows_vmem_bytes(ip, jp, g, lanes, rows, itemsize):
+    """Planned VMEM of the backward step (the larger of the two
+    kernels): q, o, dO, dq and k, v, dk, dv blocks double-buffered, the
+    f32 dk / dv accumulators, K / K^T / V^T per head of the group, and
+    six live (rows, j) f32 tiles."""
+    blocks = 2 * 4 * (ip + jp) * lanes * itemsize
+    resident = 2 * jp * lanes * 4 + 3 * g * jp * lanes * itemsize
+    return blocks + resident + 6 * rows * jp * 4
+
+
+def rows_plan(i: int, j: int, h: int, dh: int, itemsize: int = 2):
+    """(heads a grid step, query rows a chunk) when the whole-row form
+    takes (i, j, h, dh), else None (the shape streams).
+
+    Heads group to 128 lanes (dh = 64 -> 2, dh = 32 -> 4; dh a multiple
+    of 128 -> 1), so h must divide by the group. `rows` is the largest
+    128-multiple divisor of the padded i whose f32 logit tile stays
+    within _ROWS_TILE_BYTES (1152 x 1152 -> all 1152 rows)."""
+    if dh % 128 == 0:
+        g = 1
+    elif 128 % dh == 0:
+        g = 128 // dh
+    else:
+        return None
+    ip, jp = _round_up(i, 128), _round_up(j, 128)
+    if h % g or jp > _ROWS_MAX_KEYS:
+        return None
+    n = ip // 128
+    rows = 128 * max(
+        d for d in range(1, n + 1)
+        if n % d == 0 and 128 * d * jp * 4 <= _ROWS_TILE_BYTES
+    )
+    if _rows_vmem_bytes(ip, jp, g, g * dh, rows, itemsize) > _ROWS_VMEM_CAP:
+        return None
+    return g, rows
+
+
+def _rows_params(ip, jp, g, lanes, rows, itemsize):
+    est = _rows_vmem_bytes(ip, jp, g, lanes, rows, itemsize)
+    return compat.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        # from the shape: twice the plan (Mosaic's own temporaries), never
+        # under its 16 MiB default
+        vmem_limit_bytes=max(16 << 20, 2 * est),
+    )
+
+
+def _head_sels(g, dh, lanes):
+    """Per head of the group, the (1, lanes) mask of ITS lanes; [None]
+    when the group is one head."""
+    if g == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return [(lane >= hh * dh) & (lane < (hh + 1) * dh) for hh in range(g)]
+
+
+def _keep(sel, x):
+    return x if sel is None else jnp.where(sel, x, 0.0)
+
+
+def _chunks(n_chunks, body):
+    """Run body(c) for every query chunk: inline when there is one."""
+    if n_chunks == 1:
+        body(0)
+    else:
+        def step(c, carry):
+            body(c)
+            return carry
+
+        jax.lax.fori_loop(0, n_chunks, step, 0)
+
+
+def _chunk_start(c, rows):
+    return c * rows if isinstance(c, int) else pl.multiple_of(c * rows, rows)
+
+
+def _rows_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref, *,
+                     scale, g, dh, rows, n_chunks):
+    dtype = k_ref.dtype
+    lanes = k_ref.shape[-1]
+    k32 = k_ref[0].astype(jnp.float32)        # (j, lanes)
+    v32 = v_ref[0].astype(jnp.float32)
+    b = bias_ref[0]                           # (1, j) f32
+    sels = _head_sels(g, dh, lanes)
+    # resident per head: K^T (lanes, j) and V (j, lanes), other heads zeroed
+    kts = [_keep(sel, k32).T.astype(dtype) for sel in sels]
+    vs = [_keep(sel, v32).astype(dtype) for sel in sels]
+
+    def chunk(c):
+        r0 = _chunk_start(c, rows)
+        q = q_ref[0, pl.ds(r0, rows), :]      # (rows, lanes)
+        out = jnp.zeros((rows, lanes), jnp.float32)
+        for hh in range(g):
+            s = jnp.dot(q, kts[hh], preferred_element_type=jnp.float32)
+            s = s * scale + b                 # (rows, j) f32
+            m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), _M0)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            o = jnp.dot(p.astype(dtype), vs[hh],
+                        preferred_element_type=jnp.float32)
+            safe = jnp.where(l > 0, l, 1.0)
+            out = out + jnp.where(l > 0, o / safe, 0.0)
+            # +inf on zero-mass rows, as the streaming form
+            lse = jnp.where(l > 0, m + jnp.log(safe), jnp.inf)
+            lse_ref[0, hh, c] = lse[:, 0]
+        out_ref[0, pl.ds(r0, rows), :] = out.astype(out_ref.dtype)
+
+    _chunks(n_chunks, chunk)
+
+
+# contract dim 0 of both operands: (rows, j)^T @ (rows, lanes) -> (j, lanes)
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _rows_bwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, do_ref, lse_ref,
+                     dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                     scale, g, dh, rows, n_chunks):
+    dtype = k_ref.dtype
+    lanes = k_ref.shape[-1]
+    k32 = k_ref[0].astype(jnp.float32)
+    v32 = v_ref[0].astype(jnp.float32)
+    b = bias_ref[0]
+    sels = _head_sels(g, dh, lanes)
+    # resident per head, other heads zeroed: K (j, lanes), K^T, V^T
+    ks = [_keep(sel, k32) for sel in sels]
+    kts = [t.T.astype(dtype) for t in ks]
+    ks = [t.astype(dtype) for t in ks]
+    vts = [_keep(sel, v32).T.astype(dtype) for sel in sels]
+    dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+    dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    def chunk(c):
+        r0 = _chunk_start(c, rows)
+        q = q_ref[0, pl.ds(r0, rows), :]
+        do = do_ref[0, pl.ds(r0, rows), :]
+        q32, do32 = q.astype(jnp.float32), do.astype(jnp.float32)
+        # delta's summand; each head sums its own lanes below
+        do_o = do32 * o_ref[0, pl.ds(r0, rows), :].astype(jnp.float32)
+        dq = jnp.zeros((rows, lanes), jnp.float32)
+        for hh in range(g):
+            delta = jnp.sum(_keep(sels[hh], do_o), axis=-1, keepdims=True)
+            lse = lse_ref[0, hh, c][:, None]
+            s = jnp.dot(q, kts[hh], preferred_element_type=jnp.float32)
+            p = jnp.exp(s * scale + b - lse)  # (rows, j) f32
+            dp = jnp.dot(do, vts[hh], preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta)).astype(dtype)
+            dq = dq + jnp.dot(ds, ks[hh], preferred_element_type=jnp.float32)
+            # the small operand carries the head's lanes, so each head's
+            # dk / dv lands in its own lanes of the one accumulator
+            dv_scr[...] += jax.lax.dot_general(
+                p.astype(dtype), _keep(sels[hh], do32).astype(dtype), _TN,
+                preferred_element_type=jnp.float32)
+            dk_scr[...] += jax.lax.dot_general(
+                ds, _keep(sels[hh], q32).astype(dtype), _TN,
+                preferred_element_type=jnp.float32)
+        dq_ref[0, pl.ds(r0, rows), :] = (dq * scale).astype(dq_ref.dtype)
+
+    _chunks(n_chunks, chunk)
+    dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _rows_specs(ip, jp, g, lanes, n_chunks, rows):
+    blk_q = pl.BlockSpec((1, ip, lanes), lambda b, p: (b, 0, p))
+    blk_k = pl.BlockSpec((1, jp, lanes), lambda b, p: (b, 0, p))
+    blk_b = pl.BlockSpec((1, 1, jp), lambda b, p: (b, 0, 0))
+    blk_lse = pl.BlockSpec((1, g, n_chunks, rows), lambda b, p: (b, p, 0, 0))
+    return blk_q, blk_k, blk_b, blk_lse
+
+
+def _rows_forward(q, k, v, bias, scale, g, dh, rows):
+    """q: (B, i, h*dh); k, v: (B, j, h*dh); bias: (B, j) additive f32.
+    Returns the output cut to i and the padded residuals."""
+    B, i0, H = q.shape
+    q, k, v, bias, ip, jp = _pad_args(q, k, v, bias, 128, 128)
+    bias3 = bias[:, None, :]
+    lanes, n_chunks = g * dh, ip // rows
+    blk_q, blk_k, blk_b, blk_lse = _rows_specs(ip, jp, g, lanes, n_chunks, rows)
+    out, lse = pl.pallas_call(
+        functools.partial(_rows_fwd_kernel, scale=scale, g=g, dh=dh,
+                          rows=rows, n_chunks=n_chunks),
+        out_shape=[
+            _out_struct((B, ip, H), q.dtype, q, k, v, bias3),
+            _out_struct((B, H // dh, n_chunks, rows), jnp.float32,
+                        q, k, v, bias3),
+        ],
+        grid=(B, H // lanes),
+        in_specs=[blk_q, blk_k, blk_k, blk_b],
+        out_specs=[blk_q, blk_lse],
+        compiler_params=_rows_params(ip, jp, g, lanes, rows,
+                                     q.dtype.itemsize),
+        interpret=_interpret(),
+    )(q, k, v, bias3)
+    return out[:, :i0], (q, k, v, bias3, out, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _rows_core(q, k, v, key_bias, scale, g, dh, rows):
+    return _rows_forward(q, k, v, key_bias, scale, g, dh, rows)[0]
+
+
+def _rows_fwd(q, k, v, key_bias, scale, g, dh, rows):
+    out, res = _rows_forward(q, k, v, key_bias, scale, g, dh, rows)
+    return out, res + (q.shape[1], k.shape[1])
+
+
+def _rows_bwd(scale, g, dh, rows, res, do):
+    qp, kp, vp, bias3, out, lse, i0, j0 = res
+    B, ip, H = qp.shape
+    jp = kp.shape[1]
+    lanes, n_chunks = g * dh, ip // rows
+    if ip != i0:
+        do = jnp.pad(do, ((0, 0), (0, ip - i0), (0, 0)))
+    blk_q, blk_k, blk_b, blk_lse = _rows_specs(ip, jp, g, lanes, n_chunks, rows)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_rows_bwd_kernel, scale=scale, g=g, dh=dh,
+                          rows=rows, n_chunks=n_chunks),
+        out_shape=[
+            _out_struct((B, ip, H), qp.dtype, qp, kp, vp, do),
+            _out_struct((B, jp, H), kp.dtype, qp, kp, vp, do),
+            _out_struct((B, jp, H), vp.dtype, qp, kp, vp, do),
+        ],
+        grid=(B, H // lanes),
+        in_specs=[blk_q, blk_k, blk_k, blk_b, blk_q, blk_q, blk_lse],
+        out_specs=[blk_q, blk_k, blk_k],
+        scratch_shapes=[
+            pltpu.VMEM((jp, lanes), jnp.float32),
+            pltpu.VMEM((jp, lanes), jnp.float32),
+        ],
+        compiler_params=_rows_params(ip, jp, g, lanes, rows,
+                                     qp.dtype.itemsize),
+        interpret=_interpret(),
+    )(qp, kp, vp, bias3, out, do, lse)
+    # the bias is a mask, not a parameter: its cotangent is declared zero
+    return dq[:, :i0], dk[:, :j0], dv[:, :j0], jnp.zeros((B, j0), jnp.float32)
+
+
+_rows_core.defvjp(_rows_fwd, _rows_bwd)
+
+
+def flash_attention_bnhd(q, k, v, key_bias, scale, qb=None, kb=None):
+    """Dense flash attention in the model's layout. q: (B, i, h, dh);
+    k, v: (B, j, h, dh); key_bias: (B, j) additive f32. Returns
+    (B, i, h, dh).
+
+    The kernel chooses its form from (i, j, h, dh): the whole-row form
+    where `rows_plan` takes the shape, else heads folded into the batch
+    and the streaming form at `pick_block` blocks. qb / kb force the
+    streaming form at those blocks (block tuning)."""
+    B, i, h, dh = q.shape
+    j = k.shape[1]
+    plan = None
+    if qb is None and kb is None:
+        plan = rows_plan(i, j, h, dh, q.dtype.itemsize)
+    if plan is not None:
+        g, rows = plan
+        out = _rows_core(
+            q.reshape(B, i, h * dh), k.reshape(B, j, h * dh),
+            v.reshape(B, j, h * dh), key_bias, scale, g, dh, rows,
+        )
+        return out.reshape(B, i, h, dh)
+
+    def fold(t):
+        return t.transpose(0, 2, 1, 3).reshape(B * h, t.shape[1], dh)
+
+    out = flash_attention_tpu(
+        fold(q), fold(k), fold(v), jnp.repeat(key_bias, h, axis=0), scale,
+        qb=qb, kb=kb,
+    )
+    return out.reshape(B, h, i, dh).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------

@@ -55,11 +55,16 @@ __all__ = [
 _TRUE = ("1", "true", "yes", "on")
 _FALSE = ("0", "false", "no", "off")
 
-#: default Pallas auto-dispatch key-length threshold — measured on-chip
-#: (PERF_SWEEP.jsonl 2026-07-31): blanket kernel dispatch costs 14% e2e
-#: at the short-axis shapes, while the long-j streaming shapes need the
-#: kernel (XLA streaming compile >550 s there, PERF.md).
-FLASH_AUTO_MIN_J_DEFAULT = 4096
+#: default Pallas auto-dispatch key-length threshold: the lowest key
+#: length at which the kernel was measured to win on the chip. At
+#: i = j = 1152, dh = 64 (one 96-row batch chunk of the pair stream, v5e,
+#: jax 0.9.0) the whole-row form takes 6.2 us a (batch, head) row forward
+#: and 16.1 with its backward against the XLA streaming arm's 17.9 and
+#: 39.4; the streaming form at 384-blocks ties XLA there (16.8 / 41.4), so
+#: a shape the whole-row form does not take loses nothing
+#: (benchmarks/records/micro_attn_core_pr26.jsonl, PERF.md section 5).
+#: Nothing shorter was measured: the crosses (j = 32, 864) stay on XLA.
+FLASH_AUTO_MIN_J_DEFAULT = 1152
 
 
 @dataclasses.dataclass(frozen=True)
@@ -248,7 +253,7 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("AF2_FLASH_AUTO_MIN_J", "integer",
          str(FLASH_AUTO_MIN_J_DEFAULT), "ops/dispatch.py",
          "Minimum key length for flash-family Pallas arms in auto mode "
-         "(measured short-j crossover; 0 = kernel everywhere supported)."),
+         "(lowest j measured to win; 0 = kernel everywhere supported)."),
     Knob("AF2_QUANT_KERNEL", "force, off, auto", "auto",
          "ops/dispatch.py",
          "Legacy quant_matmul arm override (recorded sweep rows use it); "

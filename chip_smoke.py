@@ -243,6 +243,7 @@ def _config_summary(ecfg, crop, msa_rows) -> dict:
 def phase_trainer(run: Run, cache_dir: str):
     import jax
 
+    from alphafold2_tpu.ops import dispatch
     from alphafold2_tpu.training import (
         e2e_loss_fn,
         e2e_train_state_init,
@@ -253,9 +254,16 @@ def phase_trainer(run: Run, cache_dir: str):
     state = e2e_train_state_init(jax.random.PRNGKey(0), ecfg, tcfg)
     step_fn = jax.jit(make_train_step(ecfg, tcfg, loss_fn=e2e_loss_fn),
                       donate_argnums=(0,))
+    dispatch.reset_decisions()
     state, obs = _three_steps("trainer", step_fn, state, batches(),
                               jax.random.PRNGKey(1))
     del state
+    # which arm every dispatched call site of the compiled step runs
+    decisions = dispatch.decisions()
+    if not run.dry:
+        check(any(k.startswith("flash_attention -> pallas_tpu @ i=1152 j=1152")
+                  for k in decisions),
+              f"the pair axial attention did not take the kernel: {decisions}")
 
     stats = jax.devices()[0].memory_stats()
     if stats is None:
@@ -264,6 +272,7 @@ def phase_trainer(run: Run, cache_dir: str):
     earlier = [r["trainer"]["compile_seconds"] for r in prior_runs(run.dry)
                if "trainer" in r]
     run.emit("trainer", config=_config_summary(ecfg, crop, msa_rows), **obs,
+             dispatch_decisions=decisions,
              peak_bytes_in_use=stats["peak_bytes_in_use"],
              memory_stats=stats,
              compile_seconds_earlier_runs=earlier,
@@ -557,7 +566,7 @@ def kernel_sites(run: Run) -> list:
     h, dh = (2, 8) if dry else (8, 64)
     for name, B, i, j, request in (
         (("flash_chunk", 2, 32, 37, True) if dry
-         else ("flash_chunk_256x1152x1152x64", 32, 1152, 1152, True)),
+         else ("flash_chunk_256x1152x1152x64", 32, 1152, 1152, "auto")),
         (("flash_long_j", 1, 24, 48, True) if dry
          else ("flash_auto_8x1152x4096x64", 1, 1152, 4096, "auto")),
     ):

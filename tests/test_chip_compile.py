@@ -1,0 +1,74 @@
+"""The main path's Pallas kernel compiled for a described (not attached)
+TPU v5e at the training cell's real widths: Mosaic refuses here what it
+would refuse on the chip (VMEM over the limit, a slice off the tiling),
+at no chip time. Nothing runs, so this says nothing about results or
+times. All such compiles live in this ONE file: the worker that gets it
+loads the TPU's library, and only a fixture may describe the topology."""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from alphafold2_tpu import compat
+from alphafold2_tpu.ops import flash_kernel
+from alphafold2_tpu.ops.flash import flash_attention
+
+CALL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    try:
+        topo = compat.describe_topology("tpu", "v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_mode(monkeypatch):
+    """Trace the kernels for Mosaic, not for the interpreter, and keep the
+    undeserializable TPU executables out of the compile cache."""
+    monkeypatch.setenv("AF2_PALLAS_INTERPRET", "0")
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+def _chunk(one_chip, B, i, j, h=8, dh=64):
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # as the projections hand them over: heads still folded in the width
+    return (sd((B, i, h * dh)), sd((B, j, h * dh)), sd((B, j, h * dh)),
+            sd((B, j), jnp.float32))
+
+
+@pytest.mark.parametrize("i,j,form", [(1152, 1152, "whole-row"),
+                                      (1152, 4096, "streaming")])
+def test_flash_kernel_compiles_for_v5e_at_real_widths(one_chip, compiled_mode,
+                                                      i, j, form):
+    """Forward and backward of one batch chunk: the pair stream's axial
+    shape in the whole-row form (one backward kernel), a long-j shape in
+    the streaming form (dq and dk/dv kernels)."""
+    plan = flash_kernel.rows_plan(i, j, 8, 64)
+    assert (plan is not None) == (form == "whole-row")
+    args = _chunk(one_chip, 4, i, j)
+
+    def fwd(q, k, v, bias):
+        q, k, v = (t.reshape(*t.shape[:2], 8, 64) for t in (q, k, v))
+        out = flash_attention(q, k, v, bias, use_kernel=True)
+        return out.reshape(*out.shape[:2], 8 * 64)
+
+    def loss(q, k, v, bias):
+        return jnp.sum(fwd(q, k, v, bias).astype(jnp.float32))
+
+    assert jax.jit(fwd).lower(*args).compile().as_text().count(CALL) == 1
+    grad = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args).compile().as_text()
+    assert grad.count(CALL) == (2 if form == "whole-row" else 3)
+    if form == "whole-row":
+        # q, k, v and the output keep the model's (B, n, h*dh) layout:
+        # nothing transposes or copies them through HBM around the kernels
+        assert " transpose(" not in grad and " copy(" not in grad

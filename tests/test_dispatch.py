@@ -293,9 +293,77 @@ def test_env_forcing_unsupported_shape_raises(monkeypatch):
                          i=16, j=16, dh=7)
 
 
+# the shapes the training cell's trunk resolves (config 5, crop 384):
+# the pair stream's axial self-attention, the two aligned crosses, and
+# the serving engine's largest bucket
+_PAIR_AXIAL = dict(i=1152, j=1152, dh=64)
+_BELOW_CROSSOVER = [dict(i=3456, j=32, dh=64), dict(i=128, j=864, dh=64),
+                    dict(i=384, j=384, dh=64)]
+
+
+@pytest.mark.parametrize("op", ["flash_attention", "fused_attention",
+                                "merge_lse"])
+def test_auto_takes_pallas_from_the_measured_crossover_up(op):
+    assert knobs.FLASH_AUTO_MIN_J_DEFAULT == 1152
+    assert dispatch.resolve(op, platform="tpu", **_PAIR_AXIAL) == "pallas_tpu"
+    assert dispatch.resolve(op, platform="tpu", i=1152, j=1151,
+                            dh=64) == "xla_ref"
+
+
+@pytest.mark.parametrize("shapes", _BELOW_CROSSOVER,
+                         ids=lambda s: f"{s['i']}x{s['j']}")
+def test_auto_keeps_xla_below_the_crossover(shapes):
+    assert dispatch.resolve("flash_attention", platform="tpu",
+                            **shapes) == "xla_ref"
+
+
+@pytest.mark.parametrize("shapes", [_PAIR_AXIAL] + _BELOW_CROSSOVER,
+                         ids=lambda s: f"{s['i']}x{s['j']}")
+def test_auto_is_xla_everywhere_on_cpu(shapes):
+    assert dispatch.resolve("flash_attention", platform="cpu",
+                            **shapes) == "xla_ref"
+
+
+def test_decisions_tally_counts_what_a_traced_trunk_resolved():
+    """`decisions()` after tracing a toy trunk: one entry a distinct
+    (op, arm, shapes), counting every call site; forced requests and the
+    introspection helpers add nothing."""
+    from alphafold2_tpu.models import (Alphafold2Config, alphafold2_apply,
+                                       alphafold2_init)
+
+    dispatch.reset_decisions()
+    assert dispatch.decisions() == {}
+    dispatch.resolution_tag()
+    dispatch.resolution_table()
+    dispatch.resolve("flash_attention", request=False, platform="cpu",
+                     i=8, j=8, dh=8)
+    assert dispatch.decisions() == {}
+
+    cfg = Alphafold2Config(dim=16, depth=2, heads=2, dim_head=8,
+                           max_seq_len=16, attn_flash=True)
+    params = jax.eval_shape(lambda k: alphafold2_init(k, cfg),
+                            jax.random.PRNGKey(0))
+    jax.eval_shape(lambda p, s, m: alphafold2_apply(p, cfg, s, msa=m), params,
+                   jax.ShapeDtypeStruct((1, 8), jnp.int32),
+                   jax.ShapeDtypeStruct((1, 3, 8), jnp.int32))
+    tally = dispatch.decisions()
+    flash = {k: n for k, n in tally.items() if k.startswith("flash_attention")}
+    # every flash call site of the toy trunk resolved to the XLA arm here;
+    # the pair stream's axial passes see i = j = 8 at dh = 8
+    assert flash and all(" -> xla_ref @ " in k for k in flash)
+    assert flash["flash_attention -> xla_ref @ i=8 j=8 dh=8"] >= 2 * cfg.depth
+    assert sum(tally.values()) >= sum(flash.values())
+    # a second trace of the same program adds to the same entries
+    dispatch.resolve("flash_attention", platform="tpu", **_PAIR_AXIAL)
+    assert dispatch.decisions()[
+        "flash_attention -> pallas_tpu @ i=1152 j=1152 dh=64"] == 1
+    dispatch.reset_decisions()
+    assert dispatch.decisions() == {}
+
+
 def test_auto_heuristics_per_platform():
     long_j = dict(i=1152, j=4096, dh=64)
-    short_j = dict(i=1152, j=1152, dh=64)
+    short_j = dict(i=1152, j=864, dh=64)
     assert dispatch.resolve("flash_attention", platform="tpu",
                             **long_j) == "pallas_tpu"
     assert dispatch.resolve("flash_attention", platform="tpu",

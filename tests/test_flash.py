@@ -350,10 +350,11 @@ def test_kernel_disable_env_var(monkeypatch):
 
 
 def test_kernel_auto_min_j_heuristic(monkeypatch):
-    """auto-mode dispatch is shape-aware: below the measured short-j
-    crossover XLA streaming wins (27.75 vs 24.43 s/step e2e with blanket
-    kernel dispatch, PERF_SWEEP 2026-07-31), so "auto" only takes the
-    kernel at j >= auto_min_j(). use_kernel=True still forces it."""
+    """auto-mode dispatch is shape-aware: "auto" takes the kernel from
+    the lowest key length it was measured to win at (j = 1152, the
+    whole-row form: 6.2 against 17.9 us a row, PERF.md section 5) and
+    leaves the short crosses to XLA streaming. use_kernel=True still
+    forces it."""
     import alphafold2_tpu.ops.flash as flash_mod
     from alphafold2_tpu.ops import flash_kernel
     from alphafold2_tpu.ops.flash import kernel_dispatch
@@ -367,14 +368,19 @@ def test_kernel_auto_min_j_heuristic(monkeypatch):
     # force-kernel setting) must not leak into the default-threshold asserts
     monkeypatch.delenv("AF2_FLASH_AUTO_MIN_J", raising=False)
 
-    # default threshold: short-j auto -> streaming; long-j auto -> kernel
-    assert not kernel_dispatch(1152, 1152, 64, "auto")
+    # default threshold: short-j auto -> streaming; from the pair
+    # stream's axial shape up -> kernel
+    assert flash_mod._AUTO_MIN_J == 1152
+    assert not kernel_dispatch(128, 864, 64, "auto")
+    assert not kernel_dispatch(3456, 32, 64, "auto")
+    assert not kernel_dispatch(1152, flash_mod._AUTO_MIN_J - 1, 64, "auto")
     assert kernel_dispatch(1152, flash_mod._AUTO_MIN_J, 64, "auto")
+    assert kernel_dispatch(1152, 4096, 64, "auto")
     # forcing bypasses the heuristic at any shape
     assert kernel_dispatch(16, 16, 8, True)
     # env override re-admits short-j (the sweep's kernel-on legs)
     monkeypatch.setenv("AF2_FLASH_AUTO_MIN_J", "0")
-    assert kernel_dispatch(1152, 1152, 64, "auto")
+    assert kernel_dispatch(128, 864, 64, "auto")
     # malformed override fails loudly, not silently-default
     monkeypatch.setenv("AF2_FLASH_AUTO_MIN_J", "many")
     with pytest.raises(ValueError):

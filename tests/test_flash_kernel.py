@@ -196,3 +196,148 @@ def test_kernel_matches_dense_fuzzed_shapes():
             B, i, j, qb, kb, jnp.float32, seed=t,
             label=f"trial {t}: B={B} i={i} j={j} qb={qb} kb={kb}",
         )
+
+
+# ---------------------------------------------------------------------------
+# whole-row form: one grid step holds every key of a (batch, head group)
+# ---------------------------------------------------------------------------
+
+
+def _rows_case(B, i, j, h, dh, dtype, seed=3):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (B, i, h, dh), dtype)
+    k = jax.random.normal(ks[1], (B, j, h, dh), dtype)
+    v = jax.random.normal(ks[2], (B, j, h, dh), dtype)
+    keep = jax.random.bernoulli(ks[3], 0.7, (B, j)).at[:, 0].set(True)
+    keep = keep.at[0].set(False)  # batch row 0: every key masked
+    bias = jnp.where(keep, 0.0, float("-inf")).astype(jnp.float32)
+    return q, k, v, bias
+
+
+def test_rows_plan_follows_the_shape():
+    from alphafold2_tpu.ops.flash_kernel import rows_plan
+
+    # the pair stream's axial shape: two heads fill 128 lanes, and the
+    # whole 1152-row logit tile is one chunk
+    assert rows_plan(1152, 1152, 8, 64) == (2, 1152)
+    assert rows_plan(1152, 1152, 8, 128) == (1, 1152)
+    assert rows_plan(1000, 1100, 4, 32) == (4, 1024)  # padded lengths
+    # the chunk shrinks to a divisor of the padded i when the tile grows
+    assert rows_plan(2048, 2048, 8, 64) == (2, 512)
+    # long keys stream (the form measured for j >= 4096), as do heads
+    # that do not group to 128 lanes
+    assert rows_plan(1152, 4096, 8, 64) is None
+    assert rows_plan(128, 4096, 8, 64) is None
+    assert rows_plan(1152, 1152, 3, 64) is None
+    assert rows_plan(1152, 1152, 8, 48) is None
+    assert rows_plan(64, 64, 2, 8) is None
+
+
+@pytest.mark.parametrize(
+    "B,i,j,h,dh",
+    [
+        (2, 256, 256, 4, 64),   # multiples of 128, two head groups
+        (2, 200, 300, 2, 64),   # neither length a multiple of 128
+        (2, 128, 256, 4, 32),   # four heads a grid step
+        (2, 130, 128, 2, 128),  # one head a grid step
+    ],
+)
+def test_whole_row_matches_blockwise_forward_and_gradients(B, i, j, h, dh):
+    """float32, masked keys and a fully masked batch row, against
+    `blockwise_attention`: the output and the gradients of q, k, v."""
+    from alphafold2_tpu.ops.flash import blockwise_attention
+    from alphafold2_tpu.ops.flash_kernel import rows_plan
+
+    assert rows_plan(i, j, h, dh, 4) is not None
+    q, k, v, bias = _rows_case(B, i, j, h, dh, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(11), q.shape)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    kern = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, bias, use_kernel=True)
+    ref = lambda q, k, v: blockwise_attention(q, k, v, bias)  # noqa: E731
+    got, want = kern(q, k, v), ref(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    assert not np.asarray(got[0]).any()  # the fully masked row: zeros
+    g1 = jax.grad(loss(kern), (0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-6)
+    # masked keys take exactly no gradient
+    dead = np.asarray(bias) == float("-inf")
+    assert not np.asarray(g1[1])[dead].any()
+    assert not np.asarray(g1[2])[dead].any()
+
+
+def test_whole_row_chunked_queries_match_one_chunk(monkeypatch):
+    """The query-chunk loop (a tile budget under the whole tile) gives
+    the single chunk's numbers."""
+    from alphafold2_tpu.ops import flash_kernel
+
+    q, k, v, bias = _rows_case(2, 384, 256, 2, 64, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(12), q.shape)
+
+    def run():
+        f = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, bias, use_kernel=True)
+        return f(q, k, v), jax.grad(
+            lambda *a: jnp.sum(f(*a) * w), (0, 1, 2))(q, k, v)
+
+    assert flash_kernel.rows_plan(384, 256, 2, 64, 4) == (2, 384)
+    one_out, one_g = run()
+    monkeypatch.setattr(flash_kernel, "_ROWS_TILE_BYTES", 128 * 256 * 4)
+    assert flash_kernel.rows_plan(384, 256, 2, 64, 4) == (2, 128)
+    out, g = run()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(one_out), atol=1e-6)
+    for a, b in zip(g, one_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)
+
+
+def test_whole_row_bf16_operands():
+    """bf16 operands (the TPU workload's dot layout: p and ds cast to
+    bf16, f32 accumulation) against the f32 oracle."""
+    B, i, j, h, dh = 2, 128, 256, 2, 64
+    q, k, v, bias = _rows_case(B, i, j, h, dh, jnp.bfloat16)
+    bias = bias.at[0, :8].set(0.0)
+    got = flash_attention(q, k, v, bias, use_kernel=True)
+    assert got.dtype == jnp.bfloat16
+    want = _dense(q.astype(jnp.float32), k.astype(jnp.float32),
+                  v.astype(jnp.float32), bias, dh ** -0.5)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=2e-2)
+    g = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+        q, k, v, bias, use_kernel=True).astype(jnp.float32))), (0, 1, 2))(q, k, v)
+    g32 = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(_dense(
+        q, k, v, bias, dh ** -0.5))), (0, 1, 2))(
+            *(t.astype(jnp.float32) for t in (q, k, v)))
+    for a, b in zip(g, g32):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=5e-2, rtol=2e-2)
+
+
+def test_streaming_form_unchanged_at_long_j():
+    """j >= 4096 keeps the streaming (multi-key-block) form, bit for bit
+    what the folded-heads kernel gives, and equal to the XLA arm."""
+    from alphafold2_tpu.ops.flash import blockwise_attention
+    from alphafold2_tpu.ops.flash_kernel import pick_block, rows_plan
+
+    B, i, j, h, dh = 1, 128, 4096 + 40, 2, 64
+    assert rows_plan(i, j, h, dh, 4) is None
+    assert pick_block(j) == 512
+    q, k, v, bias = _rows_case(B, i, j, h, dh, jnp.float32)
+    bias = bias.at[0, ::3].set(0.0)
+
+    def fold(t):
+        return t.transpose(0, 2, 1, 3).reshape(B * h, t.shape[1], dh)
+
+    got = flash_attention(q, k, v, bias, use_kernel=True)
+    direct = flash_attention_tpu(
+        fold(q), fold(k), fold(v), jnp.repeat(bias, h, axis=0), dh ** -0.5
+    ).reshape(B, h, i, dh).transpose(0, 2, 1, 3)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(direct))
+    want = blockwise_attention(q, k, v, bias)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)

@@ -478,6 +478,12 @@ def main():
             with tracer.span("train.metrics_fetch", cat="train",
                              step=step), telemetry.account(step_bucket):
                 logger.log(step, metrics)
+            if step == start:
+                # the step has traced and compiled: which arm every
+                # dispatched call site of it runs, at the shapes it saw
+                from alphafold2_tpu.ops import dispatch
+
+                logger.event(step, "dispatch", decisions=dispatch.decisions())
             telemetry.step_complete(step)
             if args.eval_every and (step + 1) % args.eval_every == 0:
                 # structure quality on the last microbatch (the reference's
