@@ -38,6 +38,7 @@ __all__ = [
     "describe_topology",
     "make_array_from_process_local_data",
     "make_global_array_from_host",
+    "megablox",
     "out_struct",
     "pallas",
     "pallas_tpu",
@@ -69,6 +70,12 @@ def __getattr__(name: str):
 
         globals()["pallas_tpu"] = pallas_tpu
         return pallas_tpu
+    if name == "megablox":
+        # JAX's grouped-product kernels (gmm with a custom VJP through tgmm)
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        globals()["megablox"] = megablox
+        return megablox
     if name == "CompilerParams":
         cp = __getattr__("pallas_tpu").CompilerParams
         globals()["CompilerParams"] = cp

@@ -11,6 +11,11 @@ from alphafold2_tpu.models.alphafold2 import (
     alphafold2_head,
 )
 from alphafold2_tpu.models.convert import convert_alphafold2
+from alphafold2_tpu.models.decoder import (
+    DecoderConfig,
+    decoder_apply,
+    decoder_init,
+)
 from alphafold2_tpu.models.trunk import (
     trunk_layer_init,
     sequential_trunk_apply,
@@ -36,6 +41,9 @@ from alphafold2_tpu.models.embedder import (
 )
 
 __all__ = [
+    "DecoderConfig",
+    "decoder_apply",
+    "decoder_init",
     "EmbedderConfig",
     "convert_esm_state_dict",
     "convert_hf_esm_state_dict",

@@ -5,7 +5,8 @@ hardware stack by putting one dispatch surface over per-hardware
 kernels; FastFold (arxiv 2203.00854) chose the execution strategy per
 workload shape. This module is that surface for this repo: every hot op
 (dense/fused flash attention, the int8 fused-dequant matmul, block-
-sparse attention, the ring-attention hop) registers named ARMS —
+sparse attention, the ring-attention hop, the experts' grouped product)
+registers named ARMS —
 
   * ``pallas_tpu`` — the Pallas Mosaic kernel (interpret mode off-TPU,
     which is what the chip-free parity tier exercises);
@@ -280,9 +281,11 @@ def _always(platform, **shapes) -> bool:
     return True
 
 
-def _flash_supported(platform, *, i, j, dh, **_):
+def _flash_supported(platform, *, i, j, dh, dv=None, causal=False, **_):
     from alphafold2_tpu.ops import flash_kernel
 
+    if causal:  # self-attention under the causal mask, v heads of dv
+        return flash_kernel.supported_causal(i, j, dh, dh if dv is None else dv)
     return flash_kernel.supported(i, j, dh)
 
 
@@ -330,7 +333,8 @@ register(OpSpec(
     arms=(
         Arm(ARM_PALLAS_TPU, _flash_supported,
             "ops/flash_kernel.py flash_attention_bnhd: whole-row or "
-            "streaming form from the shape (interpret off-TPU)"),
+            "streaming form from the shape, the causal streaming form "
+            "where the call is causal (interpret off-TPU)"),
         Arm(ARM_GPU, _always,
             "XLA blockwise streaming (ops/flash.py blockwise_attention); "
             "Pallas-Triton slot when lowerable"),
@@ -466,6 +470,34 @@ register(OpSpec(
     probe={"i": 512, "j": 512, "dh": 64},
     parity_test="test_parity_merge_lse",
     unsupported_msg=_flash_unsupported_msg,
+))
+
+def _grouped_supported(platform, *, m, k, n, **_):
+    from alphafold2_tpu.ops import moe
+
+    return moe.grouped_kernel_supported(m, k, n)
+
+
+def _grouped_auto(platform: str, s: dict) -> str:
+    if platform == "tpu" and _grouped_supported(platform, **s):
+        return ARM_PALLAS_TPU
+    return ARM_GPU if platform in _GPU_PLATFORMS else ARM_XLA_REF
+
+
+register(OpSpec(
+    name="grouped_matmul",
+    arms=(
+        Arm(ARM_PALLAS_TPU, _grouped_supported,
+            "ops/moe.py: JAX's megablox grouped-product kernels (gmm, and "
+            "tgmm for the weights' gradient), tiles past the last group "
+            "never visited (interpret off-TPU)"),
+        Arm(ARM_GPU, _always, "jax.lax.ragged_dot, as xla_ref"),
+        Arm(ARM_XLA_REF, _always,
+            "jax.lax.ragged_dot over rows sorted by group (ops/moe.py)"),
+    ),
+    auto=_grouped_auto,
+    probe={"m": 4096, "k": 2048, "n": 768, "groups": 16},
+    parity_test="test_parity_grouped_matmul",
 ))
 
 

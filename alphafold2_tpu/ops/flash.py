@@ -243,6 +243,79 @@ def blockwise_attention(
     return out[:, :i] if pad_i else out
 
 
+def causal_blockwise_attention(q, k, v, *, scale=None, block: int = 1024,
+                               remat: bool = True, logit_dtype=None):
+    """Exact causal self-attention, streamed: softmax(QK^T * scale) V under
+    the lower-triangular mask, with a value head size of its own.
+
+    q, k: (B, n, h, dh); v: (B, n, h, dv). Queries walk in tiles of
+    `block`; tile t streams the t key blocks below the diagonal unmasked
+    (`stream_block` under `lax.scan`) and then the one block the diagonal
+    crosses under its triangular mask. Blocks above the diagonal are never
+    built: the loop over tiles is a Python loop, so each tile's trip count
+    is static. Returns (B, n, h, dv) in q.dtype."""
+    B, n, h, dh = q.shape
+    dv = v.shape[-1]
+    scale = dh ** -0.5 if scale is None else scale
+    logit_dtype = jnp.float32 if logit_dtype is None else logit_dtype
+    block = min(block, n)
+    pad = (-n) % block
+    if pad:  # a padded key lies past every real query
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (q, k, v))
+    nb = (n + pad) // block
+    at = jnp.arange(block)
+    diagonal = jnp.where(at[None, :] <= at[:, None], 0.0, _NEG_INF)[None, None]
+
+    def cut(t):  # (nb, B, block, h, d)
+        return t.reshape(B, nb, block, h, t.shape[-1]).transpose(1, 0, 2, 3, 4)
+
+    ks, vs = cut(k), cut(v)
+
+    def tile(qt, k_below, v_below, k_diag, v_diag):
+        carry = (jnp.full((B, h, block), _NEG_INF, jnp.float32),
+                 jnp.zeros((B, h, block), jnp.float32),
+                 jnp.zeros((B, h, block, dv), jnp.float32))
+
+        def body(c, blk):
+            return stream_block(qt, blk[0], blk[1], None, *c, scale,
+                                logit_dtype), None
+
+        if k_below.shape[0]:
+            carry, _ = jax.lax.scan(body, carry, (k_below, v_below))
+        _, l, acc = stream_block(qt, k_diag, v_diag, None, *carry, scale,
+                                 logit_dtype, bias2d_blk=diagonal)
+        return jnp.transpose(acc / l[..., None], (0, 2, 1, 3)).astype(q.dtype)
+
+    if remat:
+        tile = jax.checkpoint(tile)
+    out = [tile(q[:, t * block:(t + 1) * block], ks[:t], vs[:t], ks[t], vs[t])
+           for t in range(nb)]
+    out = jnp.concatenate(out, axis=1) if nb > 1 else out[0]
+    return out[:, :n] if pad else out
+
+
+def _causal_attention_arms(q, k, v, key_bias, scale, use_kernel, kernel_qb,
+                           kernel_kb, blockwise_kwargs):
+    from alphafold2_tpu.ops import dispatch, flash_kernel
+
+    if key_bias is not None:
+        raise ValueError("causal flash_attention takes no key bias: the "
+                         "mask is the causal one alone")
+    i, dh = q.shape[1], q.shape[-1]
+    j, dv = k.shape[1], v.shape[-1]
+    arm = dispatch.resolve("flash_attention", request=use_kernel, i=i, j=j,
+                           dh=dh, dv=dv, causal=True)
+    if arm == dispatch.ARM_PALLAS_TPU:
+        return flash_kernel.flash_attention_causal_bnhd(
+            q, k, v, scale, qb=kernel_qb, kb=kernel_kb)
+    kwargs = {name: blockwise_kwargs[name] for name in ("remat", "logit_dtype")
+              if name in blockwise_kwargs}
+    if "kv_block" in blockwise_kwargs:
+        kwargs["block"] = blockwise_kwargs["kv_block"]
+    return causal_blockwise_attention(q, k, v, scale=scale, **kwargs)
+
+
 def apply_output_gate(out, gate):
     """The UNFUSED sigmoid output-gate epilogue: sigmoid in f32 on the
     f32 output, one cast at the end — the exact math the fused kernel's
@@ -374,7 +447,7 @@ def hop_attention_lse(qf, kf, vf, bias, scale):
 
 
 def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
-                    scale=None, use_kernel="auto",
+                    scale=None, use_kernel="auto", causal=False,
                     kernel_qb=None, kernel_kb=None, **blockwise_kwargs):
     """Exact attention: fused Pallas kernel on TPU, XLA blockwise otherwise.
 
@@ -398,9 +471,23 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
     gate-multiply stop costing separate HBM logit/output passes); off
     kernel, the gate applies as an exact epilogue over the blockwise
     result and pair-bias streams through `streamed_fused_attention`.
+
+    `causal=True` is self-attention (i = j) under the lower-triangular
+    mask alone, and there `v`'s head size is free of q's and k's
+    (ops/flash_kernel.py `flash_attention_causal_bnhd`, or
+    `causal_blockwise_attention` off the kernel): both arms skip the
+    tiles wholly above the diagonal. No key bias, pair bias or gate.
     """
     # whichever arm runs, its device operations carry the one name
     with scope("attn_core"):
+        if causal:
+            if pair_bias is not None or gate is not None:
+                raise ValueError("causal flash_attention takes no pair "
+                                 "bias and no gate")
+            scale = q.shape[-1] ** -0.5 if scale is None else scale
+            return _causal_attention_arms(
+                q, k, v, key_bias, scale, use_kernel, kernel_qb, kernel_kb,
+                blockwise_kwargs)
         return _flash_attention_arms(
             q, k, v, key_bias, pair_bias=pair_bias, gate=gate, scale=scale,
             use_kernel=use_kernel, kernel_qb=kernel_qb, kernel_kb=kernel_kb,

@@ -38,6 +38,7 @@ keeping one code path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -1147,3 +1148,294 @@ def flash_attention_fused(q, k, v, bias, scale, *, gate=None, qb=None,
     if not gated:
         gate = jnp.zeros((q.shape[0], 1, dh), q.dtype)
     return _fused_core(q, k, v, bias, gate, scale, qb, kb, bias2d, gated)
+
+
+# ---------------------------------------------------------------------------
+# causal streaming form: self-attention under the lower-triangular mask,
+# with a value head size of its own
+# ---------------------------------------------------------------------------
+#
+# The decoder's latent attention (models/decoder.py) attends i = j tokens
+# under the causal mask with q / k heads of 192 and v heads of 128. The
+# grid and the recurrence are the streaming form's; two things differ:
+#
+#   * a (query block, key block) tile that lies wholly ABOVE the diagonal
+#     is skipped, not masked: its compute sits under `pl.when`, and the
+#     BlockSpec's index clamps to the last tile the query block needs (the
+#     first, for the dk / dv kernel's query walk), so the skipped steps
+#     fetch nothing either. Only the tiles the diagonal crosses build the
+#     iota mask;
+#   * v, the output and their cotangents carry `dv` lanes, q and k `dh`.
+#
+# There is no key bias (the mask is the causal one alone) and every row
+# sees its own key, so no row is without mass and lse stays finite.
+
+
+def supported_causal(i: int, j: int, dh: int, dv: int) -> bool:
+    """Shapes the causal form takes: self-attention (i = j) within the
+    streaming form's row-vector budget, both head sizes lane-aligned."""
+    return i == j and supported(i, j, dh) and dv % 8 == 0 and dv <= 512
+
+
+def _causal_mask(s, q0, k0):
+    rows = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(cols <= rows, s, _M0)
+
+
+def _causal_tiles(qi, ki, qb, kb, body):
+    """Run body(masked) for the tile (qi, ki) unless it lies wholly above
+    the diagonal; `masked` says whether the diagonal crosses it."""
+    q0, k0 = qi * qb, ki * kb
+    crossed = k0 + kb - 1 > q0  # some key past the block's first query
+
+    @pl.when(jnp.logical_and(k0 <= q0 + qb - 1, crossed))
+    def _diagonal():
+        body(True)
+
+    @pl.when(jnp.logical_not(crossed))
+    def _below():
+        body(False)
+
+
+def _causal_logits(q, k, qi, ki, qb, kb, scale, masked):
+    s = jax.lax.dot_general(
+        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    return _causal_mask(s, qi * qb, ki * kb) if masked else s
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, out_ref, lse_ref,
+                       m_scr, l_scr, acc_scr, *, nkb, qb, kb, scale):
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, _M0, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def tile(masked):
+        v = v_ref[0]
+        s = _causal_logits(q_ref[0], k_ref[0], qi, ki, qb, kb, scale, masked)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        m_scr[...] = m_new
+
+    _causal_tiles(qi, ki, qb, kb, tile)
+
+    @pl.when(ki == nkb - 1)
+    def _finish():
+        l = l_scr[...]
+        out_ref[0] = (acc_scr[...] / l).astype(out_ref.dtype)
+        lse_ref[0, qi] = (m_scr[...] + jnp.log(l))[:, 0]
+
+
+def _causal_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                      dq_ref, dq_scr, *, nkb, qb, kb, scale):
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+
+    def tile(masked):
+        k = k_ref[0]
+        s = _causal_logits(q_ref[0], k, qi, ki, qb, kb, scale, masked)
+        p = jnp.exp(s - lse_ref[0, qi][:, None])
+        dp = jax.lax.dot_general(
+            g_ref[0], v_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = (p * (dp - delta_ref[0, qi][:, None])).astype(k.dtype)
+        dq_scr[...] = dq_scr[...] + jnp.dot(
+            ds, k, preferred_element_type=jnp.float32
+        )
+
+    _causal_tiles(qi, ki, qb, kb, tile)
+
+    @pl.when(ki == nkb - 1)
+    def _finish():
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+
+
+def _causal_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                       dk_ref, dv_ref, dk_scr, dv_scr, *, nqb, qb, kb, scale):
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    def tile(masked):
+        q = q_ref[0]
+        g = g_ref[0]
+        s = _causal_logits(q, k_ref[0], qi, ki, qb, kb, scale, masked)
+        p = jnp.exp(s - lse_ref[0, qi][:, None])
+        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
+            p.astype(g.dtype), g, dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dp = jax.lax.dot_general(
+            g, v_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = (p * (dp - delta_ref[0, qi][:, None])).astype(q.dtype)
+        dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
+            ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    _causal_tiles(qi, ki, qb, kb, tile)
+
+    @pl.when(qi == nqb - 1)
+    def _finish():
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _causal_forward(q, k, v, scale, qb, kb):
+    """q, k: (BH, n, dh); v: (BH, n, dv), n a multiple of both blocks."""
+    BH, n, dh = q.shape
+    dv = v.shape[-1]
+    nqb, nkb = n // qb, n // kb
+
+    def last_key(qi):  # the last key block query block qi needs
+        return (qi * qb + qb - 1) // kb
+
+    blk_k = lambda b, qi, ki: (b, jnp.minimum(ki, last_key(qi)), 0)  # noqa: E731
+    out, lse = pl.pallas_call(
+        functools.partial(_causal_fwd_kernel, nkb=nkb, qb=qb, kb=kb,
+                          scale=scale),
+        out_shape=[
+            _out_struct((BH, n, dv), q.dtype, q, k, v),
+            _out_struct((BH, nqb, qb), jnp.float32, q, k, v),
+        ],
+        grid=(BH, nqb, nkb),
+        in_specs=[
+            pl.BlockSpec((1, qb, dh), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, kb, dh), blk_k),
+            pl.BlockSpec((1, kb, dv), blk_k),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, qb, dv), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, nqb, qb), lambda b, qi, ki: (b, 0, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, dv), jnp.float32),
+        ],
+        compiler_params=_FWD_PARAMS,
+        interpret=_interpret(),
+    )(q, k, v)
+    return out, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _causal_core(q, k, v, scale, qb, kb):
+    return _causal_forward(q, k, v, scale, qb, kb)[0]
+
+
+def _causal_fwd(q, k, v, scale, qb, kb):
+    out, lse = _causal_forward(q, k, v, scale, qb, kb)
+    return out, (q, k, v, out, lse)
+
+
+def _causal_bwd(scale, qb, kb, res, g):
+    q, k, v, out, lse = res
+    BH, n, dh = q.shape
+    dv = v.shape[-1]
+    nqb, nkb = n // qb, n // kb
+    delta = jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    ).reshape(BH, nqb, qb)
+    rows_q = pl.BlockSpec((1, nqb, qb), lambda b, x, y: (b, 0, 0))
+
+    def last_key(qi):
+        return (qi * qb + qb - 1) // kb
+
+    def first_query(ki):  # the first query block that sees key block ki
+        return (ki * kb) // qb
+
+    key_in = lambda b, qi, ki: (b, jnp.minimum(ki, last_key(qi)), 0)  # noqa: E731
+    dq = pl.pallas_call(
+        functools.partial(_causal_dq_kernel, nkb=nkb, qb=qb, kb=kb,
+                          scale=scale),
+        out_shape=_out_struct((BH, n, dh), q.dtype, q, k, v, g),
+        grid=(BH, nqb, nkb),
+        in_specs=[
+            pl.BlockSpec((1, qb, dh), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, kb, dh), key_in),
+            pl.BlockSpec((1, kb, dv), key_in),
+            pl.BlockSpec((1, qb, dv), lambda b, qi, ki: (b, qi, 0)),
+            rows_q, rows_q,
+        ],
+        out_specs=pl.BlockSpec((1, qb, dh), lambda b, qi, ki: (b, qi, 0)),
+        scratch_shapes=[pltpu.VMEM((qb, dh), jnp.float32)],
+        compiler_params=_BWD_PARAMS,
+        interpret=_interpret(),
+    )(q, k, v, g, lse, delta)
+
+    query_in = lambda b, ki, qi: (b, jnp.maximum(qi, first_query(ki)), 0)  # noqa: E731
+    dk, dv_ = pl.pallas_call(
+        functools.partial(_causal_dkv_kernel, nqb=nqb, qb=qb, kb=kb,
+                          scale=scale),
+        out_shape=[
+            _out_struct((BH, n, dh), k.dtype, q, k, v, g),
+            _out_struct((BH, n, dv), v.dtype, q, k, v, g),
+        ],
+        grid=(BH, nkb, nqb),
+        in_specs=[
+            pl.BlockSpec((1, qb, dh), query_in),
+            pl.BlockSpec((1, kb, dh), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, kb, dv), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, qb, dv), query_in),
+            rows_q, rows_q,
+        ],
+        out_specs=[
+            pl.BlockSpec((1, kb, dh), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, kb, dv), lambda b, ki, qi: (b, ki, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((kb, dh), jnp.float32),
+            pltpu.VMEM((kb, dv), jnp.float32),
+        ],
+        compiler_params=_BWD_PARAMS,
+        interpret=_interpret(),
+    )(q, k, v, g, lse, delta)
+    return dq, dk, dv_
+
+
+_causal_core.defvjp(_causal_fwd, _causal_bwd)
+
+
+def flash_attention_causal_bnhd(q, k, v, scale, qb=None, kb=None):
+    """Causal self-attention in the model's layout. q, k: (B, n, h, dh);
+    v: (B, n, h, dv). Returns (B, n, h, dv). Heads fold into the batch;
+    n pads to the blocks (a padded key lies past every real query, a
+    padded query row is cut away)."""
+    B, n, h, dh = q.shape
+    dv = v.shape[-1]
+    target = _block_target(max(dh, dv))
+    qb = pick_block(n, target=target) if qb is None else qb
+    kb = pick_block(n, target=target) if kb is None else kb
+    pad = (-n) % math.lcm(qb, kb)
+
+    def fold(t):
+        t = t.transpose(0, 2, 1, 3).reshape(B * h, n, t.shape[-1])
+        return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
+
+    out = _causal_core(fold(q), fold(k), fold(v), scale, qb, kb)
+    return out[:, :n].reshape(B, h, n, dv).transpose(0, 2, 1, 3)

@@ -55,11 +55,24 @@ TAIL_SCOPES = (
     "center_distogram", "mds", "sidechain_lift", "refiner", "kabsch_loss",
     "dispersion",
 )
+#: the decoder language model (models/decoder.py, training/lm.py):
+#: `decoder_layers` is the scans over the layers (what is left to it: the
+#: slices of the stacked parameters, the gradient's write-back), `mla_attn`,
+#: `dense_mlp` and `moe` a layer's halves, `lm_head_loss` the final norm,
+#: the head and the cross-entropy
+DECODER_SCOPES = ("lm_embed", "decoder_layers", "mla_attn", "dense_mlp", "moe",
+                  "lm_head_loss")
 #: opt.update + apply_updates + global_norm (training/harness.py)
 OPTIMIZER_SCOPE = "optimizer"
-OUTER_SCOPES = MODEL_SCOPES + TRUNK_OP_SCOPES + TAIL_SCOPES + (OPTIMIZER_SCOPE,)
+OUTER_SCOPES = (MODEL_SCOPES + TRUNK_OP_SCOPES + TAIL_SCOPES + DECODER_SCOPES
+                + (OPTIMIZER_SCOPE,))
 #: inside attention (ops/attention.py) and feed-forward (ops/feedforward.py)
-INNER_SCOPES = ("qkv_proj", "attn_core", "out_proj", "kv_compress", "geglu")
+TRUNK_INNER_SCOPES = ("qkv_proj", "attn_core", "out_proj", "kv_compress", "geglu")
+#: inside latent attention (models/decoder.py; it shares `qkv_proj`,
+#: `attn_core` and `out_proj`) and inside the expert layer (ops/moe.py)
+DECODER_INNER_SCOPES = ("kv_down_up", "rope", "router", "dispatch", "experts",
+                        "combine", "shared_expert")
+INNER_SCOPES = TRUNK_INNER_SCOPES + DECODER_INNER_SCOPES
 #: phase marker: the body of the reversible trunk's hand-written backward,
 #: so that a `jvp(...)` under it reads as the reconstruction and not as the
 #: primal forward (models/reversible.py)

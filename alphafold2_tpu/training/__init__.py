@@ -45,6 +45,13 @@ from alphafold2_tpu.training.e2e import (
     e2e_train_state_init,
     predict_structure,
 )
+from alphafold2_tpu.training.lm import (
+    lm_aux_update,
+    lm_loss_fn,
+    lm_params_init,
+    lm_train_state_init,
+    zipf_token_batches,
+)
 from alphafold2_tpu.training.presets import (
     north_star_e2e_config,
 )
@@ -116,4 +123,9 @@ __all__ = [
     "sidechainnet_structure_batches",
     "north_star_e2e_config",
     "make_segmented_train_step",
+    "lm_aux_update",
+    "lm_loss_fn",
+    "lm_params_init",
+    "lm_train_state_init",
+    "zipf_token_batches",
 ]

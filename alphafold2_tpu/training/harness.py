@@ -148,38 +148,59 @@ distogram_loss_fn = make_distogram_loss_fn(alphafold2_apply)
 
 
 def make_train_step(
-    cfg: Alphafold2Config,
+    cfg,
     tcfg: TrainConfig,
     loss_fn: Callable[..., Any] = distogram_loss_fn,
+    aux_update: Optional[Callable[..., Any]] = None,
 ):
     """Build the jitted train step.
 
     The returned step consumes a batch whose leaves carry a leading
     microbatch axis (grad_accum, per_device_batch, ...) and scans over it.
+
+    `cfg` is whatever `loss_fn(params, cfg, batch, rng)` reads: an
+    Alphafold2Config, an E2EConfig, a DecoderConfig. With `aux_update`,
+    `loss_fn` returns `(loss, aux)`; the aux of the microbatches is summed
+    and, after the optimizer, `aux_update(params, aux) -> (params, step
+    metrics)` does what lies outside the gradient (training/lm.py: the
+    router's selection bias, the expert load).
     """
     reject_quant_training(cfg, "make_train_step")
     opt = make_optimizer(tcfg)
+    has_aux = aux_update is not None
 
     def microbatch_grads(params, batch, rng):
-        return jax.value_and_grad(loss_fn)(params, cfg, batch, rng)
+        out, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(
+            params, cfg, batch, rng)
+        loss, aux = out if has_aux else (out, None)
+        return loss, aux, grads
 
     def train_step(state, batch, rng=None):
         params = state["params"]
 
         def accum(carry, inp):
-            loss_sum, grad_sum = carry
+            loss_sum, grad_sum, aux_sum = carry
             mb, i = inp
             mb_rng = jax.random.fold_in(rng, i) if rng is not None else None
-            loss, grads = microbatch_grads(params, mb, mb_rng)
+            loss, aux, grads = microbatch_grads(params, mb, mb_rng)
             return (
                 loss_sum + loss,
                 jax.tree_util.tree_map(jnp.add, grad_sum, grads),
+                jax.tree_util.tree_map(jnp.add, aux_sum, aux),
             ), None
 
         zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
         n = tcfg.grad_accum
-        (loss_sum, grad_sum), _ = jax.lax.scan(
-            accum, (jnp.zeros((), jnp.float32), zeros), (batch, jnp.arange(n))
+        aux_zeros = None  # an empty pytree: nothing rides the scan
+        if has_aux:
+            first = jax.tree_util.tree_map(lambda t: t[0], batch)
+            aux_zeros = jax.tree_util.tree_map(
+                lambda t: jnp.zeros(t.shape, t.dtype),
+                jax.eval_shape(lambda p, mb: loss_fn(p, cfg, mb, rng)[1],
+                               params, first))
+        (loss_sum, grad_sum, aux_sum), _ = jax.lax.scan(
+            accum, (jnp.zeros((), jnp.float32), zeros, aux_zeros),
+            (batch, jnp.arange(n))
         )
         loss = loss_sum / n
         grads = jax.tree_util.tree_map(lambda g: g / n, grad_sum)
@@ -187,13 +208,16 @@ def make_train_step(
         with scope(OPTIMIZER_SCOPE):
             updates, opt_state = opt.update(grads, state["opt_state"], params)
             params = optax.apply_updates(params, updates)
+            extra = {}
+            if has_aux:
+                params, extra = aux_update(params, aux_sum)
             new_state = {
                 "params": params,
                 "opt_state": opt_state,
                 "step": state["step"] + 1,
             }
             return new_state, {"loss": loss,
-                               "grad_norm": optax.global_norm(grads)}
+                               "grad_norm": optax.global_norm(grads), **extra}
 
     return train_step
 

@@ -138,3 +138,52 @@ def train_step_flops(
     """
     mult = 4.0 if (cfg.reversible or cfg.remat) else 3.0
     return grad_accum * mult * model_fwd_flops(cfg, n, r, c)
+
+
+def decoder_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
+    """Matmul FLOPs one forward of the decoder language model REQUIRES on
+    `batch` sequences of `length` tokens, by op (models/decoder.py,
+    training/lm.py), summed over the layers. `cfg` is any object with
+    DecoderConfig's fields.
+
+    The attention core counts the causal half of the logits only: each
+    query and the keys at or before it, L (L + 1) / 2 pairs a sequence and
+    head, at `qk_head_dim` for q k^T and `v_head_dim` for p v. The routed
+    experts count the token-assignments HELD here: `assignments` a MoE
+    layer where given (the router's own count), else the uniform
+    expectation N * top_k * held / n_routed_experts. The head counts the
+    L - 1 rows of a sequence that have a target."""
+    n = batch * length
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    layers = cfg.num_hidden_layers
+    n_dense = cfg.first_k_dense_replace
+    n_moe = layers - n_dense
+    lo, hi = cfg.experts_held or (0, cfg.n_routed_experts)
+    if assignments is None:
+        assignments = n * cfg.num_experts_per_tok * (hi - lo) / cfg.n_routed_experts
+    pairs = batch * h * length * (length + 1) / 2.0
+    f = cfg.moe_intermediate_size
+    return {
+        "mla_proj": layers * 2.0 * n * (
+            d * h * (nope + rope) + d * (cfg.kv_lora_rank + rope)
+            + cfg.kv_lora_rank * h * (nope + dv) + h * dv * d),
+        "attn_core": layers * 2.0 * pairs * (nope + rope + dv),
+        "dense_mlp": n_dense * 2.0 * n * 3 * d * cfg.intermediate_size,
+        "router": n_moe * 2.0 * n * d * cfg.n_routed_experts,
+        "experts": n_moe * 2.0 * assignments * 3 * d * f,
+        "shared_expert": n_moe * 2.0 * n * 3 * d * cfg.n_shared_experts * f,
+        "head": 2.0 * batch * (length - 1) * d * cfg.vocab_size,
+    }
+
+
+def decoder_fwd_flops(cfg, batch: int, length: int, assignments=None) -> float:
+    return sum(decoder_fwd_op_flops(cfg, batch, length, assignments).values())
+
+
+def decoder_required_train_flops(cfg, batch: int, length: int,
+                                 assignments=None) -> float:
+    """Operations one optimizer step REQUIRES: forward once, backward at
+    twice the forward; what `jax.checkpoint` computes again is not
+    counted."""
+    return 3.0 * decoder_fwd_flops(cfg, batch, length, assignments)

@@ -1,0 +1,441 @@
+"""A language-model training cell: one donated jitted optimizer step per
+call of the decoder's next-token loss on a fresh token batch from the
+seed, the loss fetched every step, until `--seconds` have passed.
+`train_step_s` is the whole window over all its steps.
+
+As `train_steps` does: set-up builds ONE object (the compiled step with
+its state), drives it through its first `check_steps` steps by the
+window's own call and feed (the warm-up too) and hands the same object to
+the window; after the window the plain reference
+(`reference/decoder_lm.py`) follows those steps from the same weights and
+batches. The weights are drawn HERE from the seed (`param_maker`: by each
+leaf's role, at the scales the configuration file assumes; only the
+layout comes from the program), so that a fault of the program's own
+init cannot hide on both sides; `tests/test_lm_cell.py` holds the
+program's init to the same scales. What differs from `train_steps`: NO
+second copy of the weights sits on the device through the window (the
+same call makes them again when the change and the reference need them);
+the router's picks that `route_mismatch_share` reads come from the
+program's forward on those weights after the window, not from the timed
+step; a traced run reduces its own trace by scope before it returns,
+since `run.py` removes the trace directory before the readers run.
+"""
+from __future__ import annotations
+
+import gc
+import time
+
+import numpy as np
+
+import common
+import compare
+from common import log
+
+
+def token_batch(vocab: int, batch: int, length: int, seed: int, index: int,
+                exponent: float) -> np.ndarray:
+    """(batch, length) int32 ids, a function of (seed, index): ranks from
+    a Zipf law p(rank) ~ rank^-exponent over the vocabulary, the rank ->
+    id map a permutation drawn from the seed."""
+    p = np.arange(1, vocab + 1, dtype=np.float64) ** -exponent
+    cdf = np.cumsum(p / p.sum())
+    ids = np.random.default_rng([seed, 11]).permutation(vocab)
+    u = np.random.default_rng([seed, 12, index]).random((batch, length))
+    return ids[np.minimum(np.searchsorted(cdf, u), vocab - 1)].astype(np.int32)
+
+
+def shape_of(ctx):
+    traffic = ctx["traffic"]
+    part = traffic["dry"] if ctx["dry"] else traffic
+    return part["batch"], part["length"]
+
+
+def reference_hp(ctx) -> dict:
+    cfg, config = ctx["built"]["cfg"], ctx["config"]
+    blocks = ({"attn_block": 0, "ff_block": 0, "loss_block": 0} if ctx["dry"]
+              else config["reference"])
+    return {"heads": cfg.num_attention_heads, "nope": cfg.qk_nope_head_dim,
+            "rope": cfg.qk_rope_head_dim, "dv": cfg.v_head_dim,
+            "lora": cfg.kv_lora_rank, "eps": cfg.rms_norm_eps,
+            "theta": float(cfg.rope_theta), "top_k": cfg.num_experts_per_tok,
+            "scaling": cfg.routed_scaling_factor, "norm_topk": cfg.norm_topk_prob,
+            "held": tuple(cfg.held), "lr": ctx["built"]["tcfg"].learning_rate,
+            "bias_rate": cfg.bias_update_rate, **blocks}
+
+
+def _find_mu(opt_state):
+    """Adam's first moment inside the optimizer's state."""
+    if hasattr(opt_state, "mu"):
+        return opt_state.mu
+    if isinstance(opt_state, (tuple, list)):
+        for part in opt_state:
+            found = _find_mu(part)
+            if found is not None:
+                return found
+    return None
+
+
+#: the projections that end a residual branch: `scaled_init_layers`
+#: narrows them (the configuration file's `assumed.initializer`)
+_BRANCH_ENDS = ("o", "down")
+
+
+def param_maker(shapes, assumed: dict):
+    """The jitted key -> weights on the device, by each leaf's role and the
+    configuration file's `assumed_values`: `table` and `w` N(0,
+    initializer_range), the `w` that ends a residual branch (`o`, every
+    `down`) N(0, initializer_range / sqrt(2 * scaled_init_layers)),
+    RMSNorm `scale` 1, the router's selection `bias` 0. `shapes` is a
+    tree of ShapeDtypeStructs: the layout the program reads, and nothing
+    else of it."""
+    import jax
+    import jax.numpy as jnp
+
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    std = assumed["initializer_range"]
+    narrow = std / (2.0 * assumed["scaled_init_layers"]) ** 0.5
+
+    def build(key):
+        out = []
+        for i, (path, leaf) in enumerate(leaves):
+            path = [common.key_name(k) for k in path]
+            role = path[-1]
+            if role in ("table", "w"):
+                scale = narrow if role == "w" and path[-2] in _BRANCH_ENDS else std
+                v = scale * jax.random.normal(jax.random.fold_in(key, i), leaf.shape,
+                                              jnp.float32)
+            elif role == "scale":
+                v = jnp.ones(leaf.shape, jnp.float32)
+            elif role == "bias":
+                v = jnp.zeros(leaf.shape, jnp.float32)
+            else:
+                raise ValueError(f"no rule for parameter leaf {'/'.join(path)}")
+            out.append(v.astype(leaf.dtype))
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    return jax.jit(build)
+
+
+class Weights:
+    """The seed's weights by `param_maker`, made again whenever asked: no
+    second copy lives through the window."""
+
+    def __init__(self, ctx):
+        import jax
+
+        from alphafold2_tpu.training.lm import lm_params_init
+
+        cfg = ctx["built"]["cfg"]
+        self.key = common.seed_key(ctx["seed"])
+        self.shapes = jax.eval_shape(lambda k: lm_params_init(k, cfg), self.key)
+        self.make = param_maker(self.shapes, ctx["config"]["assumed_values"])
+
+    def __call__(self):
+        return self.make(self.key)
+
+
+class Runner:
+    """The timed path: the compiled step, its state, its feed."""
+
+    def __init__(self, ctx, state, compiled):
+        self.ctx, self.state, self.compiled = ctx, state, compiled
+        self.vocab = ctx["built"]["cfg"].vocab_size
+        self.batch, self.length = shape_of(ctx)
+        self.index = 0
+        self.metrics = None
+
+    def feed(self):
+        import jax
+
+        tokens = token_batch(self.vocab, self.batch, self.length, self.ctx["seed"],
+                             self.index, self.ctx["traffic"]["zipf_exponent"])
+        self.index += 1
+        fed = tokens
+        if self.ctx.get("fault") == "half_batch":  # tests only
+            # the timed path trains on the first half of its sequences
+            # alone (twice); the reference is handed the whole batch
+            half = tokens[:self.batch // 2]
+            fed = np.concatenate([half, half])
+        return tokens, jax.device_put({"tokens": fed[None]})
+
+    def step(self):
+        """One optimizer step through the compiled, donated call; returns
+        the host tokens and the fetched loss."""
+        import jax
+
+        tokens, dev = self.feed()
+        rng = jax.random.fold_in(jax.random.PRNGKey(1), self.index)
+        if self.ctx.get("fault") == "state_unchanged":  # tests only
+            kept = jax.tree_util.tree_map(lambda t: t.copy(), self.state)
+            _, self.metrics = self.compiled(self.state, dev, rng)
+            self.state = kept
+        else:
+            self.state, self.metrics = self.compiled(self.state, dev, rng)
+        return tokens, float(np.asarray(self.metrics["loss"]))
+
+
+def build_runner(ctx, setup, weights):
+    import jax
+    import jax.numpy as jnp
+
+    from alphafold2_tpu.training.harness import make_optimizer, make_train_step
+    from alphafold2_tpu.training.lm import lm_aux_update, lm_loss_fn
+
+    cfg, tcfg = ctx["built"]["cfg"], ctx["built"]["tcfg"]
+    params = weights()
+    state = {"params": params,
+             "opt_state": jax.jit(make_optimizer(tcfg).init)(params),
+             "step": jnp.zeros((), jnp.int32)}
+    jax.block_until_ready(state)
+    setup.mark("weights_and_state_on_device")
+    step = make_train_step(cfg, tcfg, loss_fn=lm_loss_fn,
+                           aux_update=lm_aux_update(cfg))
+    batch, length = shape_of(ctx)
+    example = {"tokens": np.zeros((1, batch, length), np.int32)}
+    compiled = (jax.jit(step, donate_argnums=(0,))
+                .lower(state, example, jax.random.PRNGKey(1)).compile())
+    setup.mark("trace_and_compile_or_cache_load")
+    return Runner(ctx, state, compiled)
+
+
+def first_steps(runner, weights, n):
+    """The first n steps through the window's own call: each loss, the
+    first gradient's norms (from Adam's first moment after one step: mu =
+    (1 - b1) g), and the norms of the parameters' change after n against
+    the seed's weights made again."""
+    import jax
+
+    batches, losses, grad = [], [], None
+    for i in range(n):
+        t_step = time.perf_counter()
+        tokens, value = runner.step()
+        log(f"first step {i + 1} through the timed call: {time.perf_counter() - t_step:.4f} s")
+        batches.append(tokens)
+        losses.append(value)
+        if i == 0:
+            mu = _find_mu(runner.state["opt_state"])
+            grad = [g / 0.1 for g in compare.norms(mu)]
+    params0 = weights()
+    change = compare.delta_norms(runner.state["params"], params0)
+    del params0
+    jax.block_until_ready(runner.state)
+    return {"batches": batches, "losses": losses, "grad": grad, "change": change}
+
+
+def program_picks(ctx, weights, tokens):
+    """The experts the program's router picks for `tokens` on the seed's
+    weights, (MoE layers, tokens, top_k): the program's forward at the
+    timed sizes and precision, outside the timed step."""
+    import jax
+
+    from alphafold2_tpu.models.decoder import decoder_apply
+
+    cfg = ctx["built"]["cfg"]
+    picks = jax.jit(lambda p, t: decoder_apply(p, cfg, t)[1]["picks"])(
+        weights(), jax.device_put(tokens))
+    return np.asarray(picks)
+
+
+def memory_line(devices, where):
+    stats = devices[0].memory_stats() or {}
+    log(f"memory after the {where}: {stats.get('bytes_in_use', 0) / 1e9:.2f} GB in use, "
+        f"peak {stats.get('peak_bytes_in_use', 0) / 1e9:.2f} GB")
+
+
+def follow_reference(ctx, weights, batches, q=None):
+    """The plain reference over the same first steps from the same
+    weights: losses, the first step's picks, first gradient's norms,
+    change norms. It
+    walks the UNSTACKED parameters (`decoder_lm.value_and_grad_layers`)
+    and keeps Adam's moments on the host while a gradient is computed:
+    that is what fits beside float32 activations."""
+    import jax
+
+    from reference import decoder_lm
+
+    hp = reference_hp(ctx)
+
+    def leaves(tree):
+        return jax.tree_util.tree_leaves(tree)
+
+    def fresh():
+        return decoder_lm.unstack(weights())
+
+    outer, layers, kinds = fresh()
+    memory_line(ctx["devices"], "reference's weights")
+    opt = None
+    losses, picks, grad = [], None, None
+    for i, tokens in enumerate(batches):
+        t_step = time.perf_counter()
+        value, grads, idx, load = decoder_lm.value_and_grad_layers(
+            outer, layers, kinds, jax.device_put(tokens), hp, q)
+        losses.append(float(value))
+        log(f"reference step {i + 1}: {time.perf_counter() - t_step:.1f} s")
+        if i == 0:
+            picks = np.asarray(idx)
+            grad = leaves(decoder_lm.stacked_norms(grads[0], grads[1], kinds))
+        opt = (decoder_lm.adam_init((outer, layers)) if opt is None
+               else jax.device_put(opt))
+        (outer, layers), opt = decoder_lm.train_step_layers(
+            outer, layers, kinds, opt, grads, load, hp)
+        del grads
+        if i + 1 < len(batches):
+            opt = jax.device_get(opt)  # off the device for the next gradient
+    del opt
+    outer0, layers0, _ = fresh()
+    sub = jax.jit(lambda a, b: jax.tree_util.tree_map(lambda x, y: x - y, a, b))
+    change = decoder_lm.stacked_norms(
+        sub(outer, outer0), [sub(a, b) for a, b in zip(layers, layers0)], kinds)
+    return {"losses": losses, "picks": picks, "grad": grad, "change": leaves(change)}
+
+
+def route_mismatch_share(prog_picks, ref_picks) -> float:
+    """Share of the program's token-expert picks of the first step that
+    the reference did not make for the same token and layer."""
+    same = (prog_picks[..., :, None] == ref_picks[..., None, :]).any(-1)
+    return float(1.0 - same.mean())
+
+
+def compared_numbers(prog, ref, names):
+    """{name: value} of every number `correct` holds, and which leaf."""
+    out = {}
+    for i, (a, b) in enumerate(zip(prog["losses"], ref["losses"])):
+        out[f"loss{i + 1}_gap"] = compare.rel(a, b)
+    gaps = compare.leaf_gaps(prog["grad"], ref["grad"])
+    for i in sorted(range(len(gaps)), key=lambda i: -gaps[i])[:4]:
+        log(f"gradient leaf {names[i]}: gap {gaps[i]:.4g} prog {prog['grad'][i]:.6g} "
+            f"ref {ref['grad'][i]:.6g}")
+    log(f"grad_gap over all leaves (not held): {max(gaps):.6g}")
+    gap, where = compare.worst_leaf_gap(prog["grad"], ref["grad"],
+                                        compare.larger_half(ref["grad"]))
+    out["grad_gap"] = gap
+    log(f"worst gradient leaf of the larger half: {names[where]} prog "
+        f"{prog['grad'][where]:.6g} ref {ref['grad'][where]:.6g}")
+    keep = compare.moved_leaves(ref["grad"])
+    gap, where = compare.worst_leaf_gap(prog["change"], ref["change"], keep)
+    out["change_gap"] = gap
+    log(f"worst change leaf: {names[where]} prog {prog['change'][where]:.6g} "
+        f"ref {ref['change'][where]:.6g}; {sum(keep)} of {len(keep)} leaves held")
+    out["route_mismatch_share"] = route_mismatch_share(prog["picks"], ref["picks"])
+    return out
+
+
+def control(ctx, q):
+    """The control's numbers: the reference with `q` on every operand put
+    in the program's place, against the reference itself."""
+    weights = Weights(ctx)
+    batch, length = shape_of(ctx)
+    vocab = ctx["built"]["cfg"].vocab_size
+    batches = [token_batch(vocab, batch, length, ctx["seed"], i,
+                           ctx["traffic"]["zipf_exponent"])
+               for i in range(ctx["traffic"]["check_steps"])]
+    ref = follow_reference(ctx, weights, batches)
+    ctl = follow_reference(ctx, weights, batches, q)
+    log("losses control", ctl["losses"], "reference", ref["losses"])
+    return compared_numbers(ctl, ref, compare.leaf_paths(weights.shapes))
+
+
+def run(ctx):
+    import jax
+
+    setup, traffic = ctx["setup"], ctx["traffic"]
+    watch = common.CompileWatch()
+    weights = Weights(ctx)
+    runner = build_runner(ctx, setup, weights)
+    n_check = traffic["check_steps"]
+    prog_first = first_steps(runner, weights, n_check)
+    # the two small reductions above compile once; run the first again so
+    # that nothing is left to compile in the window
+    compare.norms(_find_mu(runner.state["opt_state"]))
+    setup.mark("first_steps_through_the_timed_call")
+    log("setup phases (s):", setup.table())
+    setup_s = setup.total()
+
+    gc.collect()
+    gc.freeze()
+    seconds, trace = ctx["seconds"], ctx["trace"]
+    step_times, losses = [], []
+    traced, traced_metrics = None, []
+    with watch:
+        if trace:
+            traced = ctx["trace_dir"]
+            jax.profiler.start_trace(traced)
+            with jax.profiler.TraceAnnotation("bench.window"):
+                for _ in range(traffic["trace_steps"]):
+                    with jax.profiler.TraceAnnotation("bench.step"):
+                        _, value = runner.step()
+                    losses.append(value)
+                    traced_metrics.append(runner.metrics)
+            jax.profiler.stop_trace()
+        t0 = time.perf_counter()
+        while True:
+            t_step = time.perf_counter()
+            _, value = runner.step()
+            now = time.perf_counter()
+            step_times.append(now - t_step)
+            losses.append(value)
+            if now - t0 >= seconds or ctx["dry"] and len(step_times) >= 2:
+                break
+        window_s = time.perf_counter() - t0
+    watch.check(ctx["cell"]["name"])
+    steps = len(step_times)
+    train_step_s = window_s / steps
+    log(f"window: {steps} steps in {window_s:.4f} s; per-step min "
+        f"{min(step_times):.4f} median {sorted(step_times)[steps // 2]:.4f} "
+        f"max {max(step_times):.4f}; each {[round(t, 4) for t in step_times]}; "
+        f"last loss {losses[-1]:.5f}")
+
+    # the router's own counts: the traced steps' where there are any, else
+    # the window's last step
+    counted = traced_metrics or [runner.metrics]
+    held = float(np.mean([np.asarray(m["moe_assignments_held"]) for m in counted]))
+    skew = float(np.mean([np.asarray(m["moe_load_max_over_mean"]) for m in counted]))
+    log(f"expert load: {held:.1f} assignments held a MoE layer, most-loaded over "
+        f"mean {skew:.4f}")
+    scopes = None
+    if traced and not ctx["dry"]:
+        import scope_reduce
+
+        scopes = scope_reduce.reduce_scopes(traced)
+        log(scope_reduce.format_table(scopes))
+        for label, scope_key, phase, secs in scopes["top_paths"][:16]:
+            log(f"top path {secs:.4f} s {scope_key} {phase}: {label[-150:]}")
+        for label, scope_key, phase, secs in scopes["top_unscoped"][:8]:
+            log(f"unscoped {secs:.4f} s {phase}: {label[-150:]}")
+
+    planned = common.planned_peak(runner.compiled)
+    device = common.device_block(ctx["devices"], planned)
+    names = compare.leaf_paths(runner.state["params"])
+    from alphafold2_tpu.ops import dispatch
+
+    log("dispatch decisions:", dispatch.decisions())
+    runner.state = runner.compiled = runner.metrics = None
+    del counted, traced_metrics
+    gc.collect()
+    memory_line(ctx["devices"], "program's state was dropped")
+
+    t_ref = time.perf_counter()
+    prog_first["picks"] = program_picks(ctx, weights, prog_first["batches"][0])
+    log(f"program's picks of step 1: {time.perf_counter() - t_ref:.1f} s")
+    ref_first = follow_reference(ctx, weights, prog_first["batches"])
+    log(f"reference: {n_check} steps in {time.perf_counter() - t_ref:.1f} s")
+    log("losses program", prog_first["losses"], "reference", ref_first["losses"])
+    values = compared_numbers(prog_first, ref_first, names)
+    finite = all(np.isfinite(losses))
+    values["nonfinite_losses"] = 0.0 if finite else 1.0
+    limits = dict(ctx["limits"], nonfinite_losses=0.0)
+    for name in sorted(set(values) - set(limits)):
+        # no upper reading separates it from the control (limits/<cell>.json)
+        log(f"read, not held: {name} = {values[name]}")
+    correct, rows = common.judge({k: (values[k], limits[k]) for k in values if k in limits})
+
+    facts = {
+        "train_step_s": train_step_s, "setup_s": setup_s, "steps": steps,
+        "window_s": window_s, "model_cfg": ctx["built"]["cfg"],
+        "lm_shape": shape_of(ctx), "planned_hbm_bytes": planned,
+        "trace_dir": traced, "trace_steps": traffic["trace_steps"],
+        "scopes": scopes, "assignments_held": held,
+        "moe_load_max_over_mean": skew,
+    }
+    return {"correct": correct, "attempted": steps + n_check, "failed": 0 if finite else 1,
+            "facts": facts, "device": device, "compared": rows}

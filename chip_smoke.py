@@ -9,6 +9,11 @@ random from a seed), and checks what comes out by the repo's own means:
            Pallas interpret mode (must be off), the kernel dispatch table
   trainer  three donated optimizer steps of `north_star_e2e_config(depth=2)`
            driven as train_end2end.py drives them
+  decoder  two donated optimizer steps of the `deepseek_v3` decoder
+           (train_lm.py's step) at the widths of the benchmark's
+           configuration, depth cut to one dense and one MoE layer, one
+           sequence of 8192 tokens: the causal attention must take the
+           Pallas kernel and the experts' grouped product is tallied
   server   a `ServingEngine` built as serve.py builds it (buckets 128/256/384,
            batch 2, precompiled) answering six seeded requests
   kernels  every `pallas_call` site compiled by Mosaic (forced: `auto` picks
@@ -277,6 +282,74 @@ def phase_trainer(run: Run, cache_dir: str):
              memory_stats=stats,
              compile_seconds_earlier_runs=earlier,
              compile_cache_entries=cache_entries(cache_dir))
+
+
+DECODER_CONFIG = os.path.join(REPO, "benchmarks", "configs",
+                              "kanana2_30b_a3b_ep8_l5.json")
+
+
+def phase_decoder(run: Run):
+    """Two steps of the language-model trainer as train_lm.py builds them."""
+    import jax
+    import numpy as np
+
+    import train_lm
+    from alphafold2_tpu.ops import dispatch
+    from alphafold2_tpu.training import (
+        TrainConfig,
+        lm_aux_update,
+        lm_loss_fn,
+        lm_train_state_init,
+        make_train_step,
+        stack_microbatches,
+        zipf_token_batches,
+    )
+
+    with open(DECODER_CONFIG) as f:
+        learning_rate = json.load(f)["train"]["learning_rate"]
+    if run.dry:
+        cfg = train_lm.DecoderConfig(dtype="float32", **train_lm._TOY)
+        length = 64
+    else:
+        cfg = dataclasses.replace(
+            train_lm.config_from_file(DECODER_CONFIG, "bfloat16"),
+            num_hidden_layers=2)
+        length = 8192
+    tcfg = TrainConfig(learning_rate=learning_rate, grad_accum=1)
+    state = lm_train_state_init(jax.random.PRNGKey(0), cfg, tcfg)
+    step_fn = jax.jit(make_train_step(cfg, tcfg, loss_fn=lm_loss_fn,
+                                      aux_update=lm_aux_update(cfg)),
+                      donate_argnums=(0,))
+    batches = stack_microbatches(
+        zipf_token_batches(cfg.vocab_size, 1, length, seed=0), 1)
+    dispatch.reset_decisions()
+    losses, seconds, load = [], [], None
+    for step in range(2):
+        t0 = time.perf_counter()
+        state, metrics = step_fn(state, next(batches), jax.random.PRNGKey(step))
+        loss = float(np.asarray(metrics["loss"]))  # waits for the device
+        seconds.append(round(time.perf_counter() - t0, 3))
+        check(np.isfinite(loss), f"decoder: step {step} loss is {loss}")
+        losses.append(loss)
+        load = {k: np.asarray(v).tolist() for k, v in metrics.items()
+                if k.startswith("moe_")}
+    del state
+    decisions = dispatch.decisions()
+    check(any(k.startswith("grouped_matmul -> ") for k in decisions),
+          f"the experts' grouped product was not dispatched: {decisions}")
+    if not run.dry:
+        check(any(k.startswith("flash_attention -> pallas_tpu @ i=8192 j=8192")
+                  and "causal=True" in k for k in decisions),
+              f"the causal attention did not take the kernel: {decisions}")
+    run.emit("decoder",
+             config={"hidden_size": cfg.hidden_size, "heads": cfg.num_attention_heads,
+                     "qk_head_dim": cfg.qk_head_dim, "v_head_dim": cfg.v_head_dim,
+                     "layers": cfg.num_hidden_layers, "experts_held": list(cfg.held),
+                     "router_width": cfg.n_routed_experts,
+                     "vocab_size": cfg.vocab_size, "tokens": length,
+                     "dtype": cfg.dtype},
+             losses=losses, step_seconds=seconds, expert_load=load,
+             dispatch_decisions=decisions)
 
 
 def phase_trainer_sp(run: Run, shards: int):
@@ -679,6 +752,7 @@ def main(argv=None) -> int:
         phase_trainer_sp(run, args.sp_shards)
     else:
         phase_trainer(run, cache_dir)
+        phase_decoder(run)
         phase_server(run)
         phase_kernels(run)
 

@@ -1,0 +1,279 @@
+"""The decoder language model (models/decoder.py, ops/moe.py,
+training/lm.py) against its plain reference
+(benchmarks/reference/decoder_lm.py) at a small size on the CPU, the
+causal flash attention of both arms against materialised logits, and the
+expert layer's share of a deployment."""
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from alphafold2_tpu.models.decoder import (DecoderConfig, decoder_apply,
+                                            decoder_init)
+from alphafold2_tpu.ops import moe
+from alphafold2_tpu.ops.flash import flash_attention
+from alphafold2_tpu.training.harness import (TrainConfig, make_optimizer,
+                                             make_train_step)
+from alphafold2_tpu.training.lm import (lm_aux_update, lm_loss_fn,
+                                        zipf_token_batches)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(name, *parts):
+    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, *parts))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+reference = _load("bench_reference_decoder_lm", "benchmarks", "reference",
+                  "decoder_lm.py")
+
+CFG = DecoderConfig(
+    vocab_size=256, hidden_size=64, num_hidden_layers=3,
+    num_attention_heads=4, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, kv_lora_rank=32, intermediate_size=128,
+    moe_intermediate_size=32, n_routed_experts=8, num_experts_per_tok=2,
+    n_shared_experts=2, routed_scaling_factor=2.448, rope_theta=1e6,
+    dtype="float32")
+
+
+def _hp(cfg, **over):
+    hp = {"heads": cfg.num_attention_heads, "nope": cfg.qk_nope_head_dim,
+          "rope": cfg.qk_rope_head_dim, "dv": cfg.v_head_dim,
+          "lora": cfg.kv_lora_rank, "eps": cfg.rms_norm_eps,
+          "theta": cfg.rope_theta, "top_k": cfg.num_experts_per_tok,
+          "scaling": cfg.routed_scaling_factor, "norm_topk": cfg.norm_topk_prob,
+          "held": cfg.held, "lr": 3e-4, "bias_rate": cfg.bias_update_rate,
+          "attn_block": 16, "ff_block": 32, "loss_block": 64}
+    return dict(hp, **over)
+
+
+def _tokens(seed=5, batch=2, length=64):
+    return next(zipf_token_batches(CFG.vocab_size, batch, length, seed))["tokens"]
+
+
+@pytest.fixture(scope="module")
+def params():
+    p = decoder_init(jax.random.PRNGKey(0), CFG)
+    # a bias that matters: the picks must be of s + b, the weights of s
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(9), p["moe"]["mlp"]["bias"].shape)
+    p["moe"]["mlp"]["bias"] = bias
+    return p
+
+
+def _worst(a, b):
+    gaps = jax.tree_util.tree_map(
+        lambda x, y: float(jnp.max(jnp.abs(x - y)) / (jnp.max(jnp.abs(y)) + 1e-12)), a, b)
+    return max(jax.tree_util.tree_leaves(gaps))
+
+
+@pytest.mark.parametrize("held", [None, (2, 6)], ids=["all_experts", "share_2_6"])
+def test_loss_and_gradients_match_reference(params, held):
+    cfg = dataclasses.replace(CFG, experts_held=held)
+    p = params
+    if held:
+        lo, hi = held
+        experts = jax.tree_util.tree_map(lambda t: t[:, lo:hi],
+                                         params["moe"]["mlp"]["experts"])
+        p = {**params, "moe": {**params["moe"], "mlp": {
+            **params["moe"]["mlp"], "experts": experts}}}
+    tokens = _tokens()
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        lambda q: lm_loss_fn(q, cfg, {"tokens": tokens}), has_aux=True))(p)
+    want, want_grads, picks, load = reference.value_and_grad(p, tokens, _hp(cfg))
+    assert abs(float(loss) - float(want)) < 2e-5 * float(want)
+    assert _worst(grads, want_grads) < 2e-3
+    assert float(jnp.max(jnp.abs(grads["moe"]["mlp"]["bias"]))) == 0.0
+    assert set(aux) == {"load"}  # what a step sums; the picks are per token
+    np.testing.assert_array_equal(aux["load"], load)
+    got_picks = decoder_apply(p, cfg, tokens)[1]["picks"]
+    np.testing.assert_array_equal(np.sort(got_picks, -1), np.sort(picks, -1))
+
+
+def test_two_train_steps_follow_reference(params):
+    tcfg = TrainConfig(grad_accum=1)
+    step = jax.jit(make_train_step(CFG, tcfg, loss_fn=lm_loss_fn,
+                                   aux_update=lm_aux_update(CFG)))
+    state = {"params": params, "opt_state": make_optimizer(tcfg).init(params),
+             "step": jnp.zeros((), jnp.int32)}
+    ref_p = jax.tree_util.tree_map(jnp.copy, params)
+    opt, hp = reference.adam_init(ref_p), _hp(CFG)
+    for i in range(2):
+        tokens = _tokens(seed=7 + i)
+        state, metrics = step(state, {"tokens": tokens[None]}, None)
+        want, grads, _, load = reference.value_and_grad(ref_p, tokens, hp)
+        ref_p, opt = reference.train_step(ref_p, opt, grads, load, hp)
+        assert abs(float(metrics["loss"]) - float(want)) < 1e-4 * float(want)
+        held = np.asarray(load)[:, :]
+        np.testing.assert_allclose(metrics["moe_assignments_held"], held.sum(-1))
+        np.testing.assert_allclose(metrics["moe_load_max_over_mean"],
+                                   held.max(-1) / held.mean(-1), rtol=1e-6)
+        assert set(metrics) == {"loss", "grad_norm", "moe_assignments_held",
+                                "moe_load_max_over_mean"}
+    # Adam moves every leaf by about lr a step: compare the changes
+    moved = jax.tree_util.tree_map(lambda a, b: a - b, state["params"], params)
+    want_moved = jax.tree_util.tree_map(lambda a, b: a - b, ref_p, params)
+    assert _worst(moved, want_moved) < 0.05
+    np.testing.assert_allclose(state["params"]["moe"]["mlp"]["bias"],
+                               ref_p["moe"]["mlp"]["bias"], atol=1e-7)
+
+
+def test_scaled_init_narrows_the_residual_branches_last_projections():
+    cfg = dataclasses.replace(CFG, scaled_init_layers=8)  # 0.02 / sqrt(16)
+    p = decoder_init(jax.random.PRNGKey(1), cfg)
+    narrow = [p["dense"]["attn"]["o"], p["moe"]["attn"]["o"], p["dense"]["mlp"]["down"],
+              p["moe"]["mlp"]["experts"]["down"], p["moe"]["mlp"]["shared"]["down"]]
+    wide = [p["dense"]["attn"]["q"], p["moe"]["mlp"]["experts"]["up"],
+            p["moe"]["mlp"]["router"], p["head"]]
+    for leaf in narrow:
+        assert abs(float(jnp.std(leaf["w"])) / 0.005 - 1.0) < 0.1
+    for leaf in wide:
+        assert abs(float(jnp.std(leaf["w"])) / 0.02 - 1.0) < 0.1
+    assert abs(float(jnp.std(p["embed"]["table"])) / 0.02 - 1.0) < 0.1
+
+
+@pytest.mark.parametrize("n_tokens,top_k,n_held,n_experts,want", [
+    (16384, 6, 16, 128, 24576),  # twice the 12288 expected, whole tiles
+    (16384, 6, 128, 128, 98304),  # all experts held: the worst case
+    (1000, 6, 16, 128, 1536),  # 1500 rounded up to tiles of 512
+    (128, 2, 2, 8, 256),  # small: the worst case bounds it
+])
+def test_chunk_rows_follow_the_expected_load(n_tokens, top_k, n_held, n_experts, want):
+    assert moe.chunk_rows_for(n_tokens, top_k, n_held, n_experts) == want
+
+
+def test_bias_update_moves_toward_the_mean():
+    load = jnp.array([[4.0, 0.0, 2.0, 2.0], [1.0, 1.0, 1.0, 5.0]])
+    out = moe.bias_update(jnp.zeros((2, 4)), load, 0.001)
+    np.testing.assert_allclose(out, [[-0.001, 0.001, 0.0, 0.0],
+                                     [0.001, 0.001, 0.001, -0.001]])
+
+
+def _moe_params(key, d=64, f=32, n_experts=8, shared=64):
+    p = decoder_init(key, dataclasses.replace(CFG, num_hidden_layers=2))
+    return jax.tree_util.tree_map(lambda t: t[0], p["moe"]["mlp"])
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """The routed parts of all four shares [0,2) .. [6,8), with the shared
+    experts counted once, equal the uncut reference's layer."""
+    p = _moe_params(jax.random.PRNGKey(3))
+    x = jax.random.normal(jax.random.PRNGKey(4), (96, 64))
+    kw = dict(top_k=2, scaling=2.448, norm_topk=True)
+    idx, w, _ = moe.route(p, x, **kw)
+    routed = sum(
+        moe.experts_apply(
+            jax.tree_util.tree_map(lambda t: t[lo:lo + 2], p["experts"]),
+            x, idx, w, held=(lo, lo + 2), chunk_rows=40)
+        for lo in range(0, 8, 2))
+    whole = routed + moe.swiglu(p["shared"], x, jnp.float32)
+    want, _, _ = reference.moe(p, x, _hp(CFG, held=(0, 8)))
+    np.testing.assert_allclose(whole, want, rtol=2e-5, atol=2e-6)
+
+
+def test_every_token_to_one_held_expert_drops_none():
+    p = _moe_params(jax.random.PRNGKey(5))
+    x = jax.random.normal(jax.random.PRNGKey(6), (80, 64))
+    # expert 5 is picked by every token, first; held here with its neighbour
+    p = {**p, "bias": jnp.zeros((8,)).at[5].set(10.0)}
+    kw = dict(top_k=2, scaling=2.448, norm_topk=True)
+    idx, w, load = moe.route(p, x, **kw)
+    assert float(load[5]) == 80.0
+    share = jax.tree_util.tree_map(lambda t: t[4:6], p["experts"])
+    # chunks of 16 rows: the 80 assignments of expert 5 span five of them
+    got = moe.experts_apply(share, x, idx, w, held=(4, 6), chunk_rows=16)
+    w5 = jnp.sum(jnp.where(idx == 5, w, 0.0), -1)
+    w4 = jnp.sum(jnp.where(idx == 4, w, 0.0), -1)
+    e = lambda i: jax.tree_util.tree_map(lambda t: t[i], p["experts"])  # noqa: E731
+    want = (w5[:, None] * moe.swiglu(e(5), x, jnp.float32)
+            + w4[:, None] * moe.swiglu(e(4), x, jnp.float32))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    assert float(jnp.min(jnp.abs(w5))) > 0.0  # every token's part is there
+
+
+def test_rows_of_no_group_stay_out_of_result_and_gradient(monkeypatch):
+    """On a TPU a grouped product leaves the rows past the groups' sum
+    unwritten, forward and in x's gradient. With NaN there, the expert
+    layer's result and all its gradients are what they are without."""
+    p = _moe_params(jax.random.PRNGKey(7))
+    x = jax.random.normal(jax.random.PRNGKey(8), (96, 64))
+    share = jax.tree_util.tree_map(lambda t: t[2:4], p["experts"])
+    idx, w, _ = moe.route(p, x, top_k=2, scaling=2.448, norm_topk=True)
+
+    def poison(t, sizes):
+        return jnp.where((jnp.arange(t.shape[0]) < jnp.sum(sizes))[:, None], t, jnp.nan)
+
+    @jax.custom_vjp
+    def poisoned(x, w, sizes):
+        return poison(jax.lax.ragged_dot(x, w, sizes), sizes)
+
+    def fwd(x, w, sizes):
+        return poisoned(x, w, sizes), (x, w, sizes)
+
+    def bwd(res, ct):
+        x, w, sizes = res
+        dx, dw = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes), x, w)[1](
+            jnp.where(jnp.isnan(ct), 0.0, ct))
+        return poison(dx, sizes), dw, None
+
+    poisoned.defvjp(fwd, bwd)
+
+    def run():
+        return jax.value_and_grad(
+            lambda share, x, w: jnp.sum(jnp.sin(moe.experts_apply(
+                share, x, idx, w, held=(2, 4), chunk_rows=64))),
+            argnums=(0, 1, 2))(share, x, w)
+
+    want = run()
+    monkeypatch.setattr(moe, "grouped_matmul", lambda x, w, sizes: poisoned(x, w, sizes))
+    got = run()
+    for g, t in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, t, rtol=1e-6, atol=1e-7)
+
+
+def _materialised(q, k, v, scale):
+    s = jnp.einsum("bihd,bjhd->bhij", q, k) * scale
+    n = q.shape[1]
+    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -jnp.inf)
+    return jnp.einsum("bhij,bjhd->bihd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("arm", [
+    pytest.param(dict(use_kernel=False, kv_block=64), id="xla_stream"),
+    pytest.param(dict(use_kernel=True, kernel_qb=64, kernel_kb=64), id="kernel"),
+    pytest.param(dict(use_kernel=True, kernel_qb=128, kernel_kb=64),
+                 id="kernel_uneven_blocks"),
+])
+@pytest.mark.parametrize("n", [256, 200])
+def test_causal_flash_attention_unequal_head_sizes(arm, n):
+    """qk heads of 24, v heads of 16, against materialised logits:
+    forward and all three gradients (the kernel in interpret mode)."""
+    keys = jax.random.split(jax.random.PRNGKey(n), 3)
+    q, k = (jax.random.normal(kk, (2, n, 2, 24)) for kk in keys[:2])
+    v = jax.random.normal(keys[2], (2, n, 2, 16))
+    scale = 24 ** -0.5
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2))(q, k, v)
+
+    want, want_grads = run(lambda *a: _materialised(*a, scale))
+    got, grads = run(lambda *a: flash_attention(*a, causal=True, **arm))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, w in zip(grads, want_grads):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+    assert flash_attention(q, k, v, causal=True, **arm).shape == (2, n, 2, 16)
+
+
+def test_causal_takes_no_bias_and_noncausal_is_untouched():
+    q = jnp.ones((1, 8, 1, 8))
+    with pytest.raises(ValueError, match="no key bias"):
+        flash_attention(q, q, q, jnp.zeros((1, 8)), causal=True, use_kernel=False)
+    with pytest.raises(ValueError, match="no pair bias"):
+        flash_attention(q, q, q, gate=q, causal=True, use_kernel=False)

@@ -162,6 +162,78 @@ def test_parity_sparse_attention(monkeypatch, dtype, n):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal_n", [64, 40])  # 40: padded to the blocks
+def test_parity_flash_attention_causal(monkeypatch, dtype, causal_n):
+    """The causal call (v heads of their own size) resolves through the
+    same op: kernel arm (interpret) == the XLA streaming arm."""
+    from alphafold2_tpu.ops import flash_kernel
+
+    n = causal_n
+    q, k, _, _ = _qkv(2, n, n, 2, 24, dtype, seed=3)
+    v = jax.random.normal(jax.random.PRNGKey(4), (2, n, 2, 16), dtype)
+    outs = {}
+    for arm in ("pallas_tpu", "xla_ref"):
+        monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", arm)
+        assert dispatch.resolve("flash_attention", request="auto", i=n, j=n,
+                                dh=24, dv=16, causal=True) == arm
+        outs[arm] = np.asarray(flash_attention(
+            q, k, v, causal=True, kernel_qb=32, kernel_kb=32, kv_block=32),
+            np.float32)
+    np.testing.assert_allclose(outs["pallas_tpu"], outs["xla_ref"],
+                               atol=2e-5 if dtype == jnp.float32 else 2e-2)
+    # cross lengths are not causal self-attention: the gate says so
+    assert not flash_kernel.supported_causal(64, 128, 24, 16)
+    with pytest.raises(ValueError, match="does not support"):
+        dispatch.resolve("flash_attention", request=True, platform="tpu",
+                         i=64, j=128, dh=24, dv=16, causal=True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_parity_grouped_matmul(monkeypatch, dtype):
+    """Kernel arm (megablox, interpret) == `jax.lax.ragged_dot` == a loop
+    over the groups, forward and both gradients, with the rows past the
+    groups' sum (unspecified) left out of the comparison."""
+    from alphafold2_tpu.ops.moe import grouped_matmul
+
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    x = jax.random.normal(ks[0], (256, 128), dtype)
+    w = jax.random.normal(ks[1], (4, 128, 256), dtype) / 8
+    counts = [70, 0, 130, 24]  # 32 rows in no group
+    sizes, live = jnp.array(counts, jnp.int32), sum(counts)
+
+    def loop(x, w):
+        at, parts = 0, []
+        for e, n in enumerate(counts):
+            parts.append(x[at:at + n].astype(jnp.float32) @ w[e].astype(jnp.float32))
+            at += n
+        return jnp.concatenate(parts)
+
+    def run(fn):
+        out, grads = jax.value_and_grad(
+            lambda x, w: jnp.sum(jnp.sin(fn(x, w)[:live].astype(jnp.float32))),
+            argnums=(0, 1))(x, w)
+        return [np.asarray(t, np.float32) for t in (out, grads[0][:live], grads[1])]
+
+    want = run(loop)
+    for arm in ("pallas_tpu", "xla_ref"):
+        monkeypatch.setenv("AF2_KERNEL_BACKEND_GROUPED_MATMUL", arm)
+        dispatch.reset_decisions()
+        got = run(lambda x, w: grouped_matmul(x, w, sizes))
+        assert dispatch.decisions() == {
+            f"grouped_matmul -> {arm} @ m=256 k=128 n=256 groups=4": 1}
+        for g, t in zip(got, want):
+            np.testing.assert_allclose(
+                g, t, atol=1e-4 if dtype == jnp.float32 else 0.3,
+                rtol=1e-4 if dtype == jnp.float32 else 0.05)
+    # rows that fill no tile, or sides off the 128 lanes: ragged_dot only
+    monkeypatch.delenv("AF2_KERNEL_BACKEND_GROUPED_MATMUL")
+    assert dispatch.resolve("grouped_matmul", platform="tpu", m=40, k=16, n=8,
+                            groups=4) == "xla_ref"
+    assert dispatch.resolve("grouped_matmul", platform="tpu", m=32768, k=2048,
+                            n=768, groups=16) == "pallas_tpu"
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("n,nk", [(32, 32), (24, 19)])  # 19: padded hop
 def test_parity_merge_lse(monkeypatch, dtype, n, nk):
     """The ring hop's two arms compute one hop + log-space merge vs the
@@ -222,7 +294,7 @@ def test_parity_merge_lse(monkeypatch, dtype, n, nk):
 def test_registry_shape():
     assert dispatch.ops() == ("flash_attention", "fused_attention",
                               "quant_matmul", "sparse_attention",
-                              "merge_lse")
+                              "merge_lse", "grouped_matmul")
     for op in dispatch.ops():
         spec = dispatch.get(op)
         assert "xla_ref" in spec.arm_names()

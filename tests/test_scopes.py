@@ -1,7 +1,7 @@
 """The names the training step carries into a device trace
-(telemetry/profiling.py SCOPES): every documented name is in the compiled
-toy step, the trunk's in the primal forward AND under the reversible
-backward; the lowered program is the same without them; an enabled
+(telemetry/profiling.py SCOPES): every documented name is in a compiled
+toy step (the end-to-end trainer's, or the language model's), the trunk's
+in the primal forward AND under the reversible backward; the lowered program is the same without them; an enabled
 tracer's spans are on the profiler's clock."""
 import contextlib
 import glob
@@ -19,7 +19,7 @@ from alphafold2_tpu.training.harness import make_train_step
 
 # inside the trunk every one of these runs forward, reconstructed and
 # backward; `kv_compress` needs the toy's compress ratio of 2
-IN_TRUNK = profiling.TRUNK_OP_SCOPES + profiling.INNER_SCOPES
+IN_TRUNK = profiling.TRUNK_OP_SCOPES + profiling.TRUNK_INNER_SCOPES
 
 
 def lower_toy_step():
@@ -51,6 +51,32 @@ def lower_template_forward():
         jax.ShapeDtypeStruct((1, 2, 8, 8), jnp.int32))
 
 
+def lower_toy_lm_step():
+    """The decoder's train step (one dense and one MoE layer) at toy
+    widths: the names of models/decoder.py, ops/moe.py, training/lm.py."""
+    from alphafold2_tpu.models.decoder import DecoderConfig
+    from alphafold2_tpu.training.harness import make_optimizer
+    from alphafold2_tpu.training.lm import (lm_aux_update, lm_loss_fn,
+                                            lm_params_init)
+
+    cfg = DecoderConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=2, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=8, kv_lora_rank=16, intermediate_size=64,
+        moe_intermediate_size=16, n_routed_experts=4, num_experts_per_tok=2,
+        n_shared_experts=1, routed_scaling_factor=2.0, experts_held=(0, 2),
+        dtype="float32")
+    tcfg = TrainConfig(grad_accum=1)
+    state = jax.eval_shape(
+        lambda k: (lambda p: {"params": p, "opt_state": make_optimizer(tcfg).init(p),
+                              "step": jnp.zeros((), jnp.int32)})(
+            lm_params_init(k, cfg)), jax.random.PRNGKey(0))
+    step = make_train_step(cfg, tcfg, loss_fn=lm_loss_fn,
+                           aux_update=lm_aux_update(cfg))
+    return jax.jit(step).lower(
+        state, {"tokens": jax.ShapeDtypeStruct((1, 2, 16), jnp.int32)}, None)
+
+
 def op_paths(compiled):
     """Every operation's name stack in the compiled module, wrappers of
     JAX's transformations (`jvp(...)`, `transpose(...)`) taken off."""
@@ -65,7 +91,8 @@ def op_paths(compiled):
 @pytest.fixture(scope="module")
 def paths():
     return (op_paths(lower_toy_step().compile())
-            | op_paths(lower_template_forward().compile()))
+            | op_paths(lower_template_forward().compile())
+            | op_paths(lower_toy_lm_step().compile()))
 
 
 @pytest.mark.parametrize("name", profiling.SCOPES)
