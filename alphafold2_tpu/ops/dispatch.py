@@ -333,8 +333,8 @@ register(OpSpec(
     arms=(
         Arm(ARM_PALLAS_TPU, _flash_supported,
             "ops/flash_kernel.py flash_attention_bnhd: whole-row or "
-            "streaming form from the shape, the causal streaming form "
-            "where the call is causal (interpret off-TPU)"),
+            "streaming form from the shape, the causal form (triangular "
+            "grid) where the call is causal (interpret off-TPU)"),
         Arm(ARM_GPU, _always,
             "XLA blockwise streaming (ops/flash.py blockwise_attention); "
             "Pallas-Triton slot when lowerable"),

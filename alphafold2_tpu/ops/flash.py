@@ -473,10 +473,10 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
     result and pair-bias streams through `streamed_fused_attention`.
 
     `causal=True` is self-attention (i = j) under the lower-triangular
-    mask alone, and there `v`'s head size is free of q's and k's
-    (ops/flash_kernel.py `flash_attention_causal_bnhd`, or
-    `causal_blockwise_attention` off the kernel): both arms skip the
-    tiles wholly above the diagonal. No key bias, pair bias or gate.
+    mask alone, with `v`'s head size free of q's and k's (ops/flash_kernel.py
+    `flash_attention_causal_bnhd`, where kernel_qb / kernel_kb force the
+    block and the sub-tile of a step; `causal_blockwise_attention` off the
+    kernel): only tiles on or below the diagonal. No bias, no gate.
     """
     # whichever arm runs, its device operations carry the one name
     with scope("attn_core"):
@@ -597,3 +597,15 @@ def _flash_attention_arms(q, k, v, key_bias, *, pair_bias, gate, scale,
     return blockwise_attention(
         q, k, v, key_bias, scale=scale, **blockwise_kwargs
     )
+
+
+def causal_kernel_plan(n: int, h: int, dh: int, dv: int, dtype) -> dict | None:
+    """What the causal kernel makes of self-attention over n positions
+    with h heads of dh (q, k) and dv (v): heads a grid step, block,
+    sub-tile, grid steps a (batch, head group) row (the tiles on or below
+    the diagonal) and planned VMEM; None where the kernel does not take
+    the shape. For a trainer's start-up log (train_lm.py)."""
+    from alphafold2_tpu.ops import flash_kernel
+
+    plan = flash_kernel.causal_plan(n, h, dh, dv, jnp.dtype(dtype).itemsize)
+    return None if plan is None else plan._asdict()

@@ -38,7 +38,7 @@ keeping one code path.
 from __future__ import annotations
 
 import functools
-import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -1151,271 +1151,407 @@ def flash_attention_fused(q, k, v, bias, scale, *, gate=None, qb=None,
 
 
 # ---------------------------------------------------------------------------
-# causal streaming form: self-attention under the lower-triangular mask,
-# with a value head size of its own
+# causal form: self-attention under the lower-triangular mask, with a value
+# head size of its own, in the model's layout
 # ---------------------------------------------------------------------------
 #
 # The decoder's latent attention (models/decoder.py) attends i = j tokens
-# under the causal mask with q / k heads of 192 and v heads of 128. The
-# grid and the recurrence are the streaming form's; two things differ:
+# under the causal mask with q / k heads of 192 and v heads of 128. One
+# forward and ONE backward kernel, both on a grid (B, head groups, tiles):
 #
-#   * a (query block, key block) tile that lies wholly ABOVE the diagonal
-#     is skipped, not masked: its compute sits under `pl.when`, and the
-#     BlockSpec's index clamps to the last tile the query block needs (the
-#     first, for the dk / dv kernel's query walk), so the skipped steps
-#     fetch nothing either. Only the tiles the diagonal crosses build the
-#     iota mask;
-#   * v, the output and their cotangents carry `dv` lanes, q and k `dh`.
+#   * the last grid axis walks only the (query block, key block) pairs on
+#     or below the diagonal, `causal_schedule`'s tables handed to the
+#     BlockSpecs by scalar prefetch: nb (nb + 1) / 2 steps a row, none
+#     empty. The forward walks query blocks outermost (its statistics and
+#     its accumulator belong to a query block), the backward key blocks
+#     outermost (dk, dv belong to a key block) with dq for the WHOLE row
+#     resident in VMEM scratch, written once a row;
+#   * a step works a (qb, qb) tile in sub-tiles of kb keys (the backward:
+#     kb queries) in an unrolled loop, so that one sub-tile's dots can
+#     issue under another's softmax; on a diagonal step a sub-tile takes
+#     only the queries (keys) that see it, and only there is the iota mask
+#     built;
+#   * a sub-tile's logits are held TRANSPOSED, keys down the sublanes and
+#     queries across the lanes: a query's statistics (running max, sum,
+#     lse, delta) are then (1, qb) lane vectors that broadcast down a tile
+#     for nothing, where a (qb, 1) column costs a vector register for
+#     every eight queries in every pass. The forward's accumulator is
+#     (dv, qb) and is transposed once a query block;
+#   * q, k: (B, n, h * dh) and v, out: (B, n, h * dv), as the projections
+#     hand them over, `g` heads a grid step (`causal_plan`: whole 128-lane
+#     blocks). A head's lanes are never sliced off the tiling: its dots
+#     run over the 128-aligned window that holds them, with the OTHER
+#     head's lanes zeroed in one operand (q once a query block in the
+#     forward, k once a key block in the backward). For dh = 192 the
+#     window is 256 lanes, which is what the 128-wide array makes of a
+#     contraction of 192 anyway;
+#   * the backward builds s, p, dp, ds once a tile for all three
+#     gradients: five dots and one exp.
 #
-# There is no key bias (the mask is the causal one alone) and every row
-# sees its own key, so no row is without mass and lse stays finite.
+# Numerics are the streaming form's: operands in the input dtype, f32
+# logits from the dot's accumulator, finite max sentinel, p and ds cast to
+# the operand dtype for their dots. There is no key bias (the mask is the
+# causal one alone) and every row sees its own key, so no row is without
+# mass and lse stays finite. What each part bought on the chip: PERF.md
+# section 5, the micro-measurement of PR 28.
+
+# what a causal kernel's grid step may plan in VMEM (v5e: 128 MiB); a row
+# whose resident dq needs more takes the XLA arm
+_CAUSAL_VMEM_CAP = 96 * 1024 * 1024
+# the block and the sub-tile the plan starts from (PERF.md section 5: the
+# micro-measurement that chose them)
+_CAUSAL_QB = 1024
+_CAUSAL_KB = 256
+
+
+class CausalPlan(NamedTuple):
+    g: int        # heads a grid step
+    qb: int       # query block = the grid's key block
+    kb: int       # sub-tile inside a step
+    tiles: int    # grid steps a (batch, head group) row
+    vmem: int     # planned bytes of the backward step, the larger kernel
+
+
+def _lane_group(dh: int, dv: int) -> int:
+    """The fewest heads whose q / k and v lanes are whole 128-lane blocks."""
+    g = 1
+    while (g * dh) % 128 or (g * dv) % 128:
+        g += 1
+    return g
+
+
+def _causal_group(h: int, dh: int, dv: int) -> int:
+    """Heads a grid step: `_lane_group`'s where it divides h, else all of
+    them (a block as wide as the array)."""
+    g = _lane_group(dh, dv)
+    return h if h % g else g
+
+
+def _causal_vmem_bytes(n, g, dh, dv, qb, kb, itemsize):
+    """The backward step: dq for the row (f32 scratch + its output block,
+    double-buffered), the q, k, v, dO, dk, dv blocks double-buffered, the
+    dk / dv accumulators and the masked k, and six live (kb, qb) f32
+    tiles."""
+    lanes = g * (dh + dv)
+    resident = n * g * dh * (4 + 2 * itemsize)
+    blocks = 2 * 3 * qb * lanes * itemsize
+    scratch = qb * lanes * 4 + 2 * qb * _round_up(g * dh, 128) * (4 + itemsize)
+    return resident + blocks + scratch + 6 * kb * qb * 4
+
+
+def causal_plan(n: int, h: int, dh: int, dv: int, itemsize: int = 2,
+                qb: int | None = None, kb: int | None = None):
+    """The causal form's plan for self-attention over n positions with h
+    heads of dh (q, k) and dv (v), or None where the row's resident dq
+    passes _CAUSAL_VMEM_CAP (the call then takes the XLA arm). qb / kb
+    force the blocks (tests, block tuning); kb must divide qb."""
+    if qb is None:
+        qb = pick_block(n, target=_CAUSAL_QB)
+    if kb is None:
+        kb = _CAUSAL_KB
+        while qb % kb:
+            kb //= 2
+    if qb % kb:
+        raise ValueError(f"causal kernel: the sub-tile {kb} must divide "
+                         f"the block {qb}")
+    g = _causal_group(h, dh, dv)
+    nb = _round_up(n, qb) // qb
+    vmem = _causal_vmem_bytes(nb * qb, g, dh, dv, qb, kb, itemsize)
+    if vmem > _CAUSAL_VMEM_CAP:
+        return None
+    return CausalPlan(g, qb, kb, nb * (nb + 1) // 2, vmem)
 
 
 def supported_causal(i: int, j: int, dh: int, dv: int) -> bool:
-    """Shapes the causal form takes: self-attention (i = j) within the
-    streaming form's row-vector budget, both head sizes lane-aligned."""
-    return i == j and supported(i, j, dh) and dv % 8 == 0 and dv <= 512
+    """Shapes the causal form takes: self-attention (i = j), both head
+    sizes sublane-aligned, and a row whose resident dq fits the plan (at
+    the head group the head sizes ask for, float32 operands)."""
+    return (i == j and dh % 8 == 0 and dh <= 512 and dv % 8 == 0
+            and dv <= 512
+            and causal_plan(i, _lane_group(dh, dv), dh, dv, 4) is not None)
 
 
-def _causal_mask(s, q0, k0):
-    rows = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(cols <= rows, s, _M0)
+def causal_schedule(nb: int, key_major: bool = False):
+    """The triangular walk over nb x nb blocks as a (4, nb (nb + 1) / 2)
+    int32 table: rows `query block`, `key block`, `first`, `last`. Every
+    pair with key <= query appears once. Query-major (the forward): a
+    query block's keys ascend, `first` / `last` flag its first and last
+    tile. Key-major (the backward): a key block's queries ascend from the
+    diagonal, the flags are the key block's."""
+    import numpy as np
+
+    if key_major:
+        pairs = [(qi, ki) for ki in range(nb) for qi in range(ki, nb)]
+        flags = [(qi == ki, qi == nb - 1) for qi, ki in pairs]
+    else:
+        pairs = [(qi, ki) for qi in range(nb) for ki in range(qi + 1)]
+        flags = [(ki == 0, ki == qi) for qi, ki in pairs]
+    return np.array([[q for q, _ in pairs], [k for _, k in pairs],
+                     [f for f, _ in flags], [l for _, l in flags]], np.int32)
 
 
-def _causal_tiles(qi, ki, qb, kb, body):
-    """Run body(masked) for the tile (qi, ki) unless it lies wholly above
-    the diagonal; `masked` says whether the diagonal crosses it."""
-    q0, k0 = qi * qb, ki * kb
-    crossed = k0 + kb - 1 > q0  # some key past the block's first query
-
-    @pl.when(jnp.logical_and(k0 <= q0 + qb - 1, crossed))
-    def _diagonal():
-        body(True)
-
-    @pl.when(jnp.logical_not(crossed))
-    def _below():
-        body(False)
+def _window_bounds(g, d):
+    """Per head of the group, the 128-aligned lane window [w0, w1) of a
+    (.., g * d) block that holds the head's d lanes. The windows of one
+    group may differ in width."""
+    return [(hh * d // 128 * 128, min(g * d, _round_up((hh + 1) * d, 128)))
+            for hh in range(g)]
 
 
-def _causal_logits(q, k, qi, ki, qb, kb, scale, masked):
-    s = jax.lax.dot_general(
-        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    return _causal_mask(s, qi * qb, ki * kb) if masked else s
+def _head_windows(g, d):
+    """(w0, w1, sel) per head, inside a kernel: its window and the
+    (1, w1 - w0) mask of ITS lanes there (None where the window is the
+    head's own)."""
+    wins = []
+    for hh, (w0, w1) in enumerate(_window_bounds(g, d)):
+        lo, hi = hh * d, (hh + 1) * d
+        sel = None
+        if (w0, w1) != (lo, hi):
+            lane = w0 + jax.lax.broadcasted_iota(jnp.int32, (1, w1 - w0), 1)
+            sel = (lane >= lo) & (lane < hi)
+        wins.append((w0, w1, sel))
+    return wins
 
 
-def _causal_fwd_kernel(q_ref, k_ref, v_ref, out_ref, lse_ref,
-                       m_scr, l_scr, acc_scr, *, nkb, qb, kb, scale):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _own_lanes(sel, x):
+    """x with the other heads' lanes of its window zeroed."""
+    return x if sel is None else _keep(sel, x.astype(jnp.float32)).astype(x.dtype)
 
-    @pl.when(ki == 0)
+
+def _causal_mask_t(st, k0, q0):
+    """Transposed logits (keys down, queries across) under the mask."""
+    keys = k0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+    queries = q0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+    return jnp.where(keys <= queries, st, _M0)
+
+
+_NT = (((1,), (1,)), ((), ()))  # (m, d) x (n, d) -> (m, n)
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _on_diagonal(sched_ref, t, tile):
+    """tile(True) where step t's tile lies on the diagonal, else
+    tile(False)."""
+    diagonal = sched_ref[0, t] == sched_ref[1, t]
+    pl.when(diagonal)(lambda: tile(True))
+    pl.when(jnp.logical_not(diagonal))(lambda: tile(False))
+
+
+def _causal_fwd_kernel(sched_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
+                       qm_scr, m_scr, l_scr, acc_scr, *, scale, g, dh, dv, kb):
+    t = pl.program_id(2)
+    qi = sched_ref[0, t]
+    qb = q_ref.shape[1]
+    wins = _head_windows(g, dh)
+
+    @pl.when(sched_ref[2, t] == 1)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, _M0, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+        for hh, (w0, w1, sel) in enumerate(wins):
+            qm_scr[hh, :, :w1 - w0] = _own_lanes(sel, q_ref[0, :, w0:w1])
 
-    def tile(masked):
-        v = v_ref[0]
-        s = _causal_logits(q_ref[0], k_ref[0], qi, ki, qb, kb, scale, masked)
-        m = m_scr[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
+    def tile(diagonal):
+        for hh, (w0, w1, _) in enumerate(wins):
+            for c in range(qb // kb):
+                # on the diagonal, sub-tile c is seen from its own queries on
+                lo = c * kb if diagonal else 0
+                keys = slice(c * kb, (c + 1) * kb)
+                st = _dot(k_ref[0, keys, w0:w1], qm_scr[hh, lo:, :w1 - w0],
+                          _NT) * scale
+                if diagonal:
+                    st = _causal_mask_t(st, 0, 0)
+                v = v_ref[0, keys, hh * dv:(hh + 1) * dv]
+                m = m_scr[hh, :, lo:]                       # (1, qb - lo)
+                m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                pt = jnp.exp(st - m_new)
+                l_scr[hh, :, lo:] = l_scr[hh, :, lo:] * alpha + jnp.sum(
+                    pt, axis=0, keepdims=True)
+                acc_scr[hh, :, lo:] = acc_scr[hh, :, lo:] * alpha + _dot(
+                    v, pt.astype(v.dtype), _TN)             # (dv, qb - lo)
+                m_scr[hh, :, lo:] = m_new
 
-    _causal_tiles(qi, ki, qb, kb, tile)
+    _on_diagonal(sched_ref, t, tile)
 
-    @pl.when(ki == nkb - 1)
+    @pl.when(sched_ref[3, t] == 1)
     def _finish():
-        l = l_scr[...]
-        out_ref[0] = (acc_scr[...] / l).astype(out_ref.dtype)
-        lse_ref[0, qi] = (m_scr[...] + jnp.log(l))[:, 0]
+        for hh in range(g):
+            l = l_scr[hh]
+            out_ref[0, :, hh * dv:(hh + 1) * dv] = (acc_scr[hh] / l).T.astype(
+                out_ref.dtype)
+            lse_ref[0, hh, qi] = (m_scr[hh] + jnp.log(l))[0]
 
 
-def _causal_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                      dq_ref, dq_scr, *, nkb, qb, kb, scale):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _causal_bwd_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                       delta_ref, dq_ref, dk_ref, dv_ref,
+                       km_scr, dq_scr, dk_scr, dv_scr, *,
+                       scale, g, dh, dv, kb, tiles):
+    t = pl.program_id(2)
+    qi = sched_ref[0, t]
+    qb = k_ref.shape[1]
+    wins = _head_windows(g, dh)
 
-    @pl.when(ki == 0)
-    def _init():
+    @pl.when(t == 0)
+    def _row():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    def tile(masked):
-        k = k_ref[0]
-        s = _causal_logits(q_ref[0], k, qi, ki, qb, kb, scale, masked)
-        p = jnp.exp(s - lse_ref[0, qi][:, None])
-        dp = jax.lax.dot_general(
-            g_ref[0], v_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta_ref[0, qi][:, None])).astype(k.dtype)
-        dq_scr[...] = dq_scr[...] + jnp.dot(
-            ds, k, preferred_element_type=jnp.float32
-        )
-
-    _causal_tiles(qi, ki, qb, kb, tile)
-
-    @pl.when(ki == nkb - 1)
-    def _finish():
-        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
-
-
-def _causal_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_scr, dv_scr, *, nqb, qb, kb, scale):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
+    @pl.when(sched_ref[2, t] == 1)
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+        for hh, (w0, w1, sel) in enumerate(wins):
+            km_scr[hh, :, :w1 - w0] = _own_lanes(sel, k_ref[0, :, w0:w1])
 
-    def tile(masked):
-        q = q_ref[0]
-        g = g_ref[0]
-        s = _causal_logits(q, k_ref[0], qi, ki, qb, kb, scale, masked)
-        p = jnp.exp(s - lse_ref[0, qi][:, None])
-        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-            p.astype(g.dtype), g, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            g, v_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta_ref[0, qi][:, None])).astype(q.dtype)
-        dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
-            ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def tile(diagonal):
+        r0 = pl.multiple_of(qi * qb, qb)
+        for hh, (w0, w1, _) in enumerate(wins):
+            lanes = slice(hh * dv, (hh + 1) * dv)
+            for c in range(qb // kb):
+                # on the diagonal, query chunk c sees the keys up to its own
+                hi = (c + 1) * kb if diagonal else qb
+                rows = slice(c * kb, (c + 1) * kb)
+                q = q_ref[0, rows, w0:w1]
+                do = do_ref[0, rows, lanes]
+                k = km_scr[hh, :hi, :w1 - w0]
+                st = _dot(k, q, _NT) * scale                # (hi, kb)
+                if diagonal:
+                    st = _causal_mask_t(st, 0, c * kb)
+                pt = jnp.exp(st - lse_ref[0, hh, qi, rows][None, :])
+                dpt = _dot(v_ref[0, :hi, lanes], do, _NT)
+                dst = (pt * (dpt - delta_ref[0, hh, qi, rows][None, :])).astype(
+                    q.dtype)
+                dv_scr[:hi, lanes] += _dot(pt.astype(do.dtype), do)
+                dk_scr[hh, :hi, :w1 - w0] += _dot(dst, q)
+                dq_scr[pl.ds(r0 + c * kb, kb), w0:w1] += _dot(dst, k, _TN)
 
-    _causal_tiles(qi, ki, qb, kb, tile)
+    _on_diagonal(sched_ref, t, tile)
 
-    @pl.when(qi == nqb - 1)
+    @pl.when(sched_ref[3, t] == 1)
     def _finish():
-        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        # q's windows carried the other heads' lanes into dk: each head
+        # adds its own to a zeroed block (every lane gets one addend, so
+        # the round trip through the block's dtype is exact)
+        shared = any(sel is not None for _, _, sel in wins)
+        if shared:
+            dk_ref[0] = jnp.zeros(dk_ref.shape[1:], dk_ref.dtype)
+        for hh, (w0, w1, sel) in enumerate(wins):
+            own = dk_scr[hh, :, :w1 - w0] * scale
+            if shared:
+                own = dk_ref[0, :, w0:w1].astype(jnp.float32) + (
+                    own if sel is None else jnp.where(sel, own, 0.0))
+            dk_ref[0, :, w0:w1] = own.astype(dk_ref.dtype)
+
+    @pl.when(t == tiles - 1)
+    def _row_done():
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _causal_forward(q, k, v, scale, qb, kb):
-    """q, k: (BH, n, dh); v: (BH, n, dv), n a multiple of both blocks."""
-    BH, n, dh = q.shape
-    dv = v.shape[-1]
-    nqb, nkb = n // qb, n // kb
+def _causal_params(plan):
+    return compat.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        # from the shape, as `_rows_params`: twice the plan (Mosaic's own
+        # temporaries), never under 32 MiB nor over what a v5e core has
+        vmem_limit_bytes=min(max(32 << 20, 2 * plan.vmem), 120 << 20),
+    )
 
-    def last_key(qi):  # the last key block query block qi needs
-        return (qi * qb + qb - 1) // kb
 
-    blk_k = lambda b, qi, ki: (b, jnp.minimum(ki, last_key(qi)), 0)  # noqa: E731
-    out, lse = pl.pallas_call(
-        functools.partial(_causal_fwd_kernel, nkb=nkb, qb=qb, kb=kb,
-                          scale=scale),
+def _causal_specs(plan, n, dh, dv):
+    """Blocks of the (B, n, h * d) operands at a step's query block (row 0
+    of the schedule) and key block (row 1), the per-row vectors, and the
+    widest head window."""
+    g, qb = plan.g, plan.qb
+    ww = max(w1 - w0 for w0, w1 in _window_bounds(g, dh))
+    at_q = lambda b, p, t, sched: (b, sched[0, t], p)  # noqa: E731
+    at_k = lambda b, p, t, sched: (b, sched[1, t], p)  # noqa: E731
+    rows = pl.BlockSpec((1, g, n // qb, qb), lambda b, p, t, sched: (b, p, 0, 0))
+    return (pl.BlockSpec((1, qb, g * dh), at_q), pl.BlockSpec((1, qb, g * dv), at_q),
+            pl.BlockSpec((1, qb, g * dh), at_k), pl.BlockSpec((1, qb, g * dv), at_k),
+            rows, ww)
+
+
+def _causal_forward(q, k, v, scale, dh, plan):
+    """q, k: (B, n, h * dh); v: (B, n, h * dv), n a multiple of plan.qb.
+    Returns out (B, n, h * dv) and lse (B, h, n / qb, qb)."""
+    B, n, H = q.shape
+    h = H // dh
+    dv = v.shape[-1] // h
+    g, qb, kb = plan.g, plan.qb, plan.kb
+    q_at_q, v_at_q, k_at_k, v_at_k, rows, ww = _causal_specs(plan, n, dh, dv)
+    return pl.pallas_call(
+        functools.partial(_causal_fwd_kernel, scale=scale, g=g, dh=dh, dv=dv,
+                          kb=kb),
         out_shape=[
-            _out_struct((BH, n, dv), q.dtype, q, k, v),
-            _out_struct((BH, nqb, qb), jnp.float32, q, k, v),
+            _out_struct((B, n, h * dv), q.dtype, q, k, v),
+            _out_struct((B, h, n // qb, qb), jnp.float32, q, k, v),
         ],
-        grid=(BH, nqb, nkb),
-        in_specs=[
-            pl.BlockSpec((1, qb, dh), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, kb, dh), blk_k),
-            pl.BlockSpec((1, kb, dv), blk_k),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, qb, dv), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, nqb, qb), lambda b, qi, ki: (b, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((qb, 1), jnp.float32),
-            pltpu.VMEM((qb, 1), jnp.float32),
-            pltpu.VMEM((qb, dv), jnp.float32),
-        ],
-        compiler_params=_FWD_PARAMS,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, h // g, plan.tiles),
+            in_specs=[q_at_q, k_at_k, v_at_k],
+            out_specs=[v_at_q, rows],
+            scratch_shapes=[
+                pltpu.VMEM((g, qb, ww), q.dtype),
+                pltpu.VMEM((g, 1, qb), jnp.float32),
+                pltpu.VMEM((g, 1, qb), jnp.float32),
+                pltpu.VMEM((g, dv, qb), jnp.float32),
+            ],
+        ),
+        compiler_params=_causal_params(plan),
         interpret=_interpret(),
-    )(q, k, v)
-    return out, lse
+    )(jnp.asarray(causal_schedule(n // qb)), q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _causal_core(q, k, v, scale, qb, kb):
-    return _causal_forward(q, k, v, scale, qb, kb)[0]
+def _causal_core(q, k, v, scale, dh, plan):
+    return _causal_forward(q, k, v, scale, dh, plan)[0]
 
 
-def _causal_fwd(q, k, v, scale, qb, kb):
-    out, lse = _causal_forward(q, k, v, scale, qb, kb)
+def _causal_fwd(q, k, v, scale, dh, plan):
+    out, lse = _causal_forward(q, k, v, scale, dh, plan)
     return out, (q, k, v, out, lse)
 
 
-def _causal_bwd(scale, qb, kb, res, g):
+def _causal_bwd(scale, dh, plan, res, do):
     q, k, v, out, lse = res
-    BH, n, dh = q.shape
-    dv = v.shape[-1]
-    nqb, nkb = n // qb, n // kb
+    B, n, H = q.shape
+    h = H // dh
+    dv = v.shape[-1] // h
+    g, qb, kb = plan.g, plan.qb, plan.kb
     delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).reshape(BH, nqb, qb)
-    rows_q = pl.BlockSpec((1, nqb, qb), lambda b, x, y: (b, 0, 0))
-
-    def last_key(qi):
-        return (qi * qb + qb - 1) // kb
-
-    def first_query(ki):  # the first query block that sees key block ki
-        return (ki * kb) // qb
-
-    key_in = lambda b, qi, ki: (b, jnp.minimum(ki, last_key(qi)), 0)  # noqa: E731
-    dq = pl.pallas_call(
-        functools.partial(_causal_dq_kernel, nkb=nkb, qb=qb, kb=kb,
-                          scale=scale),
-        out_shape=_out_struct((BH, n, dh), q.dtype, q, k, v, g),
-        grid=(BH, nqb, nkb),
-        in_specs=[
-            pl.BlockSpec((1, qb, dh), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, kb, dh), key_in),
-            pl.BlockSpec((1, kb, dv), key_in),
-            pl.BlockSpec((1, qb, dv), lambda b, qi, ki: (b, qi, 0)),
-            rows_q, rows_q,
-        ],
-        out_specs=pl.BlockSpec((1, qb, dh), lambda b, qi, ki: (b, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((qb, dh), jnp.float32)],
-        compiler_params=_BWD_PARAMS,
-        interpret=_interpret(),
-    )(q, k, v, g, lse, delta)
-
-    query_in = lambda b, ki, qi: (b, jnp.maximum(qi, first_query(ki)), 0)  # noqa: E731
-    dk, dv_ = pl.pallas_call(
-        functools.partial(_causal_dkv_kernel, nqb=nqb, qb=qb, kb=kb,
-                          scale=scale),
+        (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(B, n, h, dv),
+        axis=-1).transpose(0, 2, 1).reshape(B, h, n // qb, qb)
+    q_at_q, v_at_q, k_at_k, v_at_k, rows, ww = _causal_specs(plan, n, dh, dv)
+    whole = pl.BlockSpec((1, n, g * dh), lambda b, p, t, sched: (b, 0, p))
+    return tuple(pl.pallas_call(
+        functools.partial(_causal_bwd_kernel, scale=scale, g=g, dh=dh, dv=dv,
+                          kb=kb, tiles=plan.tiles),
         out_shape=[
-            _out_struct((BH, n, dh), k.dtype, q, k, v, g),
-            _out_struct((BH, n, dv), v.dtype, q, k, v, g),
+            _out_struct(q.shape, q.dtype, q, k, v, do),
+            _out_struct(k.shape, k.dtype, q, k, v, do),
+            _out_struct(v.shape, v.dtype, q, k, v, do),
         ],
-        grid=(BH, nkb, nqb),
-        in_specs=[
-            pl.BlockSpec((1, qb, dh), query_in),
-            pl.BlockSpec((1, kb, dh), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, kb, dv), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, qb, dv), query_in),
-            rows_q, rows_q,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, kb, dh), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, kb, dv), lambda b, ki, qi: (b, ki, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((kb, dh), jnp.float32),
-            pltpu.VMEM((kb, dv), jnp.float32),
-        ],
-        compiler_params=_BWD_PARAMS,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, h // g, plan.tiles),
+            in_specs=[q_at_q, k_at_k, v_at_k, v_at_q, rows, rows],
+            out_specs=[whole, k_at_k, v_at_k],
+            scratch_shapes=[
+                pltpu.VMEM((g, qb, ww), k.dtype),
+                pltpu.VMEM((n, g * dh), jnp.float32),
+                pltpu.VMEM((g, qb, ww), jnp.float32),
+                pltpu.VMEM((qb, g * dv), jnp.float32),
+            ],
+        ),
+        compiler_params=_causal_params(plan),
         interpret=_interpret(),
-    )(q, k, v, g, lse, delta)
-    return dq, dk, dv_
+    )(jnp.asarray(causal_schedule(n // qb, key_major=True)), q, k, v, do, lse,
+      delta))
 
 
 _causal_core.defvjp(_causal_fwd, _causal_bwd)
@@ -1423,19 +1559,23 @@ _causal_core.defvjp(_causal_fwd, _causal_bwd)
 
 def flash_attention_causal_bnhd(q, k, v, scale, qb=None, kb=None):
     """Causal self-attention in the model's layout. q, k: (B, n, h, dh);
-    v: (B, n, h, dv). Returns (B, n, h, dv). Heads fold into the batch;
-    n pads to the blocks (a padded key lies past every real query, a
-    padded query row is cut away)."""
+    v: (B, n, h, dv). Returns (B, n, h, dv). The kernels read the operands
+    as (B, n, h * d), `causal_plan`'s heads a grid step; n pads to the
+    block (a padded key lies past every real query, a padded query row is
+    cut away). qb / kb force the block and the sub-tile."""
     B, n, h, dh = q.shape
     dv = v.shape[-1]
-    target = _block_target(max(dh, dv))
-    qb = pick_block(n, target=target) if qb is None else qb
-    kb = pick_block(n, target=target) if kb is None else kb
-    pad = (-n) % math.lcm(qb, kb)
+    plan = causal_plan(n, h, dh, dv, q.dtype.itemsize, qb, kb)
+    if plan is None:
+        raise ValueError(
+            f"causal kernel: a row of n={n} at h={h} dh={dh} dv={dv} does "
+            "not fit VMEM (flash_kernel.causal_plan); use_kernel=False "
+            "streams it through XLA")
+    pad = (-n) % plan.qb
 
-    def fold(t):
-        t = t.transpose(0, 2, 1, 3).reshape(B * h, n, t.shape[-1])
+    def flat(t):
+        t = t.reshape(B, n, h * t.shape[-1])
         return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
 
-    out = _causal_core(fold(q), fold(k), fold(v), scale, qb, kb)
-    return out[:, :n].reshape(B, h, n, dv).transpose(0, 2, 1, 3)
+    out = _causal_core(flat(q), flat(k), flat(v), scale, dh, plan)
+    return out[:, :n].reshape(B, n, h, dv)

@@ -72,3 +72,36 @@ def test_flash_kernel_compiles_for_v5e_at_real_widths(one_chip, compiled_mode,
         # q, k, v and the output keep the model's (B, n, h*dh) layout:
         # nothing transposes or copies them through HBM around the kernels
         assert " transpose(" not in grad and " copy(" not in grad
+
+
+def test_causal_kernel_compiles_for_v5e_at_the_decoders_widths(one_chip,
+                                                               compiled_mode):
+    """The language-model cell's core, 2 x 8192 tokens, 32 heads of 192
+    (q, k) and 128 (v): one forward and ONE backward kernel at the plan
+    the shape gets, reading q, k, v as the projections hand them over."""
+    B, n, h, dh, dv = 2, 8192, 32, 192, 128
+    plan = flash_kernel.causal_plan(n, h, dh, dv)
+    assert plan.g == 2 and plan.tiles == (n // plan.qb) * (n // plan.qb + 1) // 2
+
+    def sd(width):
+        return jax.ShapeDtypeStruct((B, n, h * width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def fwd(q, k, v):
+        out = flash_attention(q.reshape(B, n, h, dh), k.reshape(B, n, h, dh),
+                              v.reshape(B, n, h, dv), causal=True,
+                              use_kernel=True)
+        return out.reshape(B, n, h * dv)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    args = (sd(dh), sd(dh), sd(dv))
+    text = jax.jit(fwd).lower(*args).compile().as_text()
+    assert text.count(CALL) == 1
+    # nothing folds heads into the batch or pads around the kernel
+    for op in (" transpose(", " copy(", " pad("):
+        assert op not in text
+    grad = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args).compile().as_text()
+    assert grad.count(CALL) == 2
+    assert " transpose(" not in grad and " pad(" not in grad

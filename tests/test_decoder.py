@@ -244,20 +244,45 @@ def _materialised(q, k, v, scale):
     return jnp.einsum("bhij,bjhd->bihd", jax.nn.softmax(s, -1), v)
 
 
-@pytest.mark.parametrize("arm", [
-    pytest.param(dict(use_kernel=False, kv_block=64), id="xla_stream"),
-    pytest.param(dict(use_kernel=True, kernel_qb=64, kernel_kb=64), id="kernel"),
-    pytest.param(dict(use_kernel=True, kernel_qb=128, kernel_kb=64),
-                 id="kernel_uneven_blocks"),
+_KERNEL_64 = dict(use_kernel=True, kernel_qb=64, kernel_kb=64)
+# the query block larger than the key sub-tile: two sub-tiles a step
+_KERNEL_128_64 = dict(use_kernel=True, kernel_qb=128, kernel_kb=64)
+
+
+@pytest.mark.parametrize("arm,n,h,dh,dv", [
+    pytest.param(dict(use_kernel=False, kv_block=64), 256, 2, 24, 16,
+                 id="xla_stream-256"),
+    pytest.param(dict(use_kernel=False, kv_block=64), 200, 2, 24, 16,
+                 id="xla_stream-200"),
+    # n = 256 at blocks of 64: four query blocks, so tiles below the
+    # diagonal, on it and never visited all occur; 200 pads to the blocks
+    pytest.param(_KERNEL_64, 256, 2, 24, 16, id="kernel-256"),
+    pytest.param(_KERNEL_64, 200, 2, 24, 16, id="kernel-200"),
+    pytest.param(_KERNEL_128_64, 256, 2, 24, 16, id="kernel_uneven_blocks-256"),
+    pytest.param(_KERNEL_128_64, 200, 2, 24, 16, id="kernel_uneven_blocks-200"),
+    # four heads in the (B, n, h * d) layout: one group of four at 24 / 16
+    # (a block as wide as the array), two groups of two at the decoder's
+    # 192 / 128 (each head in the 256-lane window that holds its lanes)
+    pytest.param(_KERNEL_64, 200, 4, 24, 16, id="kernel-200-h4"),
+    pytest.param(_KERNEL_128_64, 384, 4, 24, 16, id="kernel_uneven_blocks-384-h4"),
+    pytest.param(_KERNEL_64, 192, 2, 192, 128, id="kernel-192-mla_heads"),
+    pytest.param(_KERNEL_128_64, 200, 2, 192, 128,
+                 id="kernel_uneven_blocks-200-mla_heads"),
+    pytest.param(_KERNEL_128_64, 384, 4, 192, 128,
+                 id="kernel_uneven_blocks-384-h4-mla_heads"),
+    pytest.param(dict(use_kernel=True), 200, 4, 192, 128,
+                 id="kernel_own_plan-200-h4-mla_heads"),
+    # heads of 96: the four windows of a group are 128, 256, 256, 128 wide
+    pytest.param(_KERNEL_128_64, 200, 4, 96, 32, id="kernel_uneven_blocks-200-h4-96"),
 ])
-@pytest.mark.parametrize("n", [256, 200])
-def test_causal_flash_attention_unequal_head_sizes(arm, n):
-    """qk heads of 24, v heads of 16, against materialised logits:
-    forward and all three gradients (the kernel in interpret mode)."""
+def test_causal_flash_attention_unequal_head_sizes(arm, n, h, dh, dv):
+    """qk heads of dh, v heads of dv, against materialised logits in
+    float32: forward and all three gradients (the kernel in interpret
+    mode)."""
     keys = jax.random.split(jax.random.PRNGKey(n), 3)
-    q, k = (jax.random.normal(kk, (2, n, 2, 24)) for kk in keys[:2])
-    v = jax.random.normal(keys[2], (2, n, 2, 16))
-    scale = 24 ** -0.5
+    q, k = (jax.random.normal(kk, (2, n, h, dh)) for kk in keys[:2])
+    v = jax.random.normal(keys[2], (2, n, h, dv))
+    scale = dh ** -0.5
 
     def run(fn):
         return jax.value_and_grad(
@@ -268,7 +293,65 @@ def test_causal_flash_attention_unequal_head_sizes(arm, n):
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for g, w in zip(grads, want_grads):
         np.testing.assert_allclose(g, w, atol=2e-5)
-    assert flash_attention(q, k, v, causal=True, **arm).shape == (2, n, 2, 16)
+    assert flash_attention(q, k, v, causal=True, **arm).shape == (2, n, h, dv)
+
+
+@pytest.mark.parametrize("nb", [1, 2, 3, 8, 16])
+def test_causal_schedule_lists_the_tiles_on_or_below_the_diagonal(nb):
+    """Both walks list exactly the nb (nb + 1) / 2 pairs with key <= query,
+    each once; `first` / `last` flag a query block's first and last tile
+    in the forward's walk and a key block's in the backward's."""
+    from alphafold2_tpu.ops.flash_kernel import causal_plan, causal_schedule
+
+    want = {(qi, ki) for qi in range(nb) for ki in range(qi + 1)}
+    for key_major in (False, True):
+        table = causal_schedule(nb, key_major=key_major)
+        assert table.shape == (4, nb * (nb + 1) // 2) and table.dtype == np.int32
+        pairs = list(zip(table[0].tolist(), table[1].tolist()))
+        assert len(pairs) == len(set(pairs)) and set(pairs) == want
+        # the block the accumulators belong to: the query block in the
+        # forward's walk, the key block in the backward's
+        own = table[1] if key_major else table[0]
+        for t, (qi, ki) in enumerate(pairs):
+            first = t == 0 or own[t - 1] != own[t]
+            last = t == len(pairs) - 1 or own[t + 1] != own[t]
+            assert (bool(table[2, t]), bool(table[3, t])) == (first, last)
+            if key_major:  # a key block is met first on the diagonal
+                assert bool(table[2, t]) == (qi == ki)
+                assert bool(table[3, t]) == (qi == nb - 1)
+            else:          # a query block ends on it
+                assert bool(table[2, t]) == (ki == 0)
+                assert bool(table[3, t]) == (qi == ki)
+    # the plan's count of grid steps a row is the table's length
+    assert causal_plan(8192, 32, 192, 128, qb=512, kb=512).tiles == 136
+    assert causal_plan(8192, 32, 192, 128, qb=1024, kb=1024).tiles == 36
+
+
+def test_causal_kernel_bound_in_n_and_the_xla_arm_beyond_it(monkeypatch):
+    """The backward keeps dq for a whole row in VMEM, so `supported_causal`
+    bounds n; past the bound "auto" resolves to the XLA arm on a TPU, and
+    forcing the kernel raises."""
+    from alphafold2_tpu.ops import dispatch, flash_kernel
+
+    plan = flash_kernel.causal_plan(8192, 32, 192, 128)
+    assert plan is not None and plan.g == 2
+    assert plan.vmem <= flash_kernel._CAUSAL_VMEM_CAP
+    assert flash_kernel.supported_causal(8192, 8192, 192, 128)
+    assert not flash_kernel.supported_causal(32768, 32768, 192, 128)
+    assert flash_kernel.causal_plan(32768, 32, 192, 128) is None
+    # narrower heads leave room for a longer row
+    assert flash_kernel.supported_causal(32768, 32768, 64, 64)
+    shape = dict(dh=192, dv=128, causal=True)
+    assert dispatch.resolve("flash_attention", request="auto", platform="tpu",
+                            i=8192, j=8192, **shape) == dispatch.ARM_PALLAS_TPU
+    assert dispatch.resolve("flash_attention", request="auto", platform="tpu",
+                            i=32768, j=32768, **shape) == dispatch.ARM_XLA_REF
+    with pytest.raises(ValueError, match="does not support"):
+        dispatch.resolve("flash_attention", request=True, platform="tpu",
+                         i=32768, j=32768, **shape)
+    # the sub-tile divides the block
+    with pytest.raises(ValueError, match="must divide"):
+        flash_kernel.causal_plan(256, 2, 24, 16, qb=64, kb=48)
 
 
 def test_causal_takes_no_bias_and_noncausal_is_untouched():

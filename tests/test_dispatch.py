@@ -162,22 +162,30 @@ def test_parity_sparse_attention(monkeypatch, dtype, n):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("causal_n", [64, 40])  # 40: padded to the blocks
-def test_parity_flash_attention_causal(monkeypatch, dtype, causal_n):
+@pytest.mark.parametrize("causal_n,h,dh,dv,sub", [
+    (64, 2, 24, 16, 32),
+    (40, 2, 24, 16, 32),     # padded to the blocks
+    (96, 2, 24, 16, 32),     # three query blocks: below, on and above the diagonal
+    (96, 4, 24, 16, 16),     # the query block larger than the sub-tile; four heads
+    (80, 2, 192, 128, 32),   # the decoder's head sizes, padded
+    (96, 4, 192, 128, 16),   # two head groups a batch row
+])
+def test_parity_flash_attention_causal(monkeypatch, dtype, causal_n, h, dh, dv,
+                                       sub):
     """The causal call (v heads of their own size) resolves through the
     same op: kernel arm (interpret) == the XLA streaming arm."""
     from alphafold2_tpu.ops import flash_kernel
 
     n = causal_n
-    q, k, _, _ = _qkv(2, n, n, 2, 24, dtype, seed=3)
-    v = jax.random.normal(jax.random.PRNGKey(4), (2, n, 2, 16), dtype)
+    q, k, _, _ = _qkv(2, n, n, h, dh, dtype, seed=3)
+    v = jax.random.normal(jax.random.PRNGKey(4), (2, n, h, dv), dtype)
     outs = {}
     for arm in ("pallas_tpu", "xla_ref"):
         monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", arm)
         assert dispatch.resolve("flash_attention", request="auto", i=n, j=n,
-                                dh=24, dv=16, causal=True) == arm
+                                dh=dh, dv=dv, causal=True) == arm
         outs[arm] = np.asarray(flash_attention(
-            q, k, v, causal=True, kernel_qb=32, kernel_kb=32, kv_block=32),
+            q, k, v, causal=True, kernel_qb=32, kernel_kb=sub, kv_block=32),
             np.float32)
     np.testing.assert_allclose(outs["pallas_tpu"], outs["xla_ref"],
                                atol=2e-5 if dtype == jnp.float32 else 2e-2)
