@@ -301,7 +301,7 @@ def _targets() -> Dict[str, Callable[[], None]]:
             spec = dispatch.get(op)
             arm_names = set(spec.arm_names())
             assert "xla_ref" in arm_names, op
-            for platform in ("tpu", "gpu", "cpu"):
+            for platform in ("tpu", "cpu"):
                 arm = dispatch.resolve(op, request="auto",
                                        platform=platform, **spec.probe)
                 assert arm in arm_names, (op, platform, arm)

@@ -40,10 +40,10 @@ def depth_aware_attn_defaults(depth: int) -> dict:
     proven-to-fit values — the deep config has no headroom to spend.
 
     This is THE resolver for the two knobs: the training preset
-    (training/presets.py) routes through it, so the bench scripts that
-    inherit preset defaults (bench.py, scripts/bench_sweep.py legs without
-    explicit overrides) measure against it, and the `e2e_chunk32` /
-    `e2e_tile25` sweep legs A/B the old values against it on chip.
+    (training/presets.py) routes through it, so everything that inherits
+    preset defaults (the benchmark's builder, bench.py) measures against
+    it. Both values stay config fields until ROADMAP S5 re-measures them:
+    the benchmark cell's configuration file names them under `assumed`.
     """
     if depth <= _ATTN_HEADROOM_MAX_DEPTH:
         return {"attn_batch_chunk": 96, "attn_flash_tile_elems": 1 << 26}
@@ -115,15 +115,6 @@ class Alphafold2Config:
     # Bigger tiles = better MXU utilization, more live memory
     attn_flash_tile_elems: int = 1 << 25
     attn_flash_kv_block: int = 2048
-    # Pallas flash-kernel QUERY block-size target for block tuning of
-    # the streaming form; None = the kernel picks form and blocks from
-    # the shape (see ops/attention.py AttentionConfig.flash_qb_target)
-    attn_flash_qb_target: Optional[int] = None
-    # XLA streaming attention: materialize score/probability tiles in the
-    # compute dtype instead of f32 (AttentionConfig
-    # flash_compute_dtype_logits) — halves the streaming path's dominant
-    # HBM traffic under bf16 at ~0.5% probability error
-    attn_flash_compute_dtype_logits: bool = False
     # sigmoid output gating on every attention op: out = sigmoid(W_g x) *
     # attn(x) before the output projection (the AF2-style gate the
     # reference omits). Gate weights init to (w=0, b=1) so a freshly
@@ -174,12 +165,6 @@ class Alphafold2Config:
             raise ValueError(
                 f"cross_attn_mode must be 'flat' or 'aligned', "
                 f"got {self.cross_attn_mode!r}"
-            )
-        t = self.attn_flash_qb_target
-        if t is not None and (t <= 0 or t % 128):
-            raise ValueError(
-                f"attn_flash_qb_target must be a positive multiple of 128 "
-                f"(TPU lane alignment), got {t}"
             )
         if self.remat_policy not in (None, "dots", "dots_no_batch"):
             raise ValueError(
@@ -234,8 +219,6 @@ class Alphafold2Config:
             batch_chunk=self.attn_batch_chunk,
             flash_tile_elems=self.attn_flash_tile_elems,
             flash_kv_block=self.attn_flash_kv_block,
-            flash_qb_target=self.attn_flash_qb_target,
-            flash_compute_dtype_logits=self.attn_flash_compute_dtype_logits,
             gate=self.attn_gate,
         )
 
@@ -251,7 +234,5 @@ class Alphafold2Config:
             batch_chunk=self.attn_batch_chunk,
             flash_tile_elems=self.attn_flash_tile_elems,
             flash_kv_block=self.attn_flash_kv_block,
-            flash_qb_target=self.attn_flash_qb_target,
-            flash_compute_dtype_logits=self.attn_flash_compute_dtype_logits,
             gate=self.attn_gate,
         )

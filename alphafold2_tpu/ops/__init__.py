@@ -6,9 +6,9 @@ TPU-native answer to the reference's `torch.nn.Module` ops layer
 (reference alphafold2_pytorch/alphafold2.py:30-286).
 
 Hot ops (flash/fused attention, quant matmul, sparse attention, the
-ring hop) resolve their backend arm — pallas_tpu / gpu / xla_ref —
-through ONE registry, `ops/dispatch.py` (`resolve`), with every AF2_*
-env knob defined once in `ops/knobs.py`.
+ring hop, the experts' grouped product) resolve their backend arm —
+pallas_tpu / xla_ref — through ONE registry, `ops/dispatch.py`
+(`resolve`), with every AF2_* env knob defined once in `ops/knobs.py`.
 """
 
 from alphafold2_tpu.ops.core import (

@@ -63,25 +63,12 @@ class AttentionConfig:
     # active. Not used for tied-row attention (its logits are already
     # row-contracted and small).
     flash: Union[bool, str] = "auto"
-    # XLA streaming-path tile knobs (ignored by the Pallas kernel): target
-    # logit-tile elements and K/V streaming block. Bigger tiles = better
-    # MXU utilization, more live memory — tune per chip generation
+    # XLA streaming-path tile knobs (ignored by the Pallas kernel, which
+    # picks its form and blocks from the shape): target logit-tile
+    # elements and K/V streaming block. Bigger tiles = better MXU
+    # utilization, more live memory — tune per chip generation
     flash_tile_elems: int = 1 << 25
     flash_kv_block: int = 2048
-    # Block tuning of the Pallas kernel's STREAMING form: a QUERY
-    # block-size target, the block being pick_block(i, target=this) per
-    # attention shape (key blocks stay auto). None (the default) lets the
-    # kernel choose its form and blocks from the shape — whole-row at the
-    # pair stream's 1152 x 1152 (ops/flash_kernel.py rows_plan); setting
-    # this takes that choice away, so no preset does.
-    flash_qb_target: Optional[int] = None
-    # materialize the XLA streaming path's score/probability tiles in the
-    # COMPUTE dtype instead of f32 (ops/flash.py stream_block): those
-    # tiles dominate the path's HBM traffic, and the AV dot consumes p in
-    # the compute dtype anyway — bf16 halves the dominant traffic at
-    # ~0.5% probability error (running max/sum stats stay f32). Off by
-    # default pending the on-chip A/B (sweep leg e2e_logit_bf16).
-    flash_compute_dtype_logits: bool = False
     # process the (folded) batch axis in chunks of this many elements under
     # jax.checkpoint (0 = off). Flash tiling bounds the LOGITS, but the
     # QKV/output projections still materialize over the whole folded batch —
@@ -300,13 +287,8 @@ def attention_apply(
             ).astype(jnp.float32)
         )
         # Pallas fused kernel on TPU (supported shapes), XLA streaming
-        # otherwise (ops/flash.py dispatch, which names it `attn_core`)
-        if cfg.flash_qb_target is None:
-            qb = None
-        else:
-            from alphafold2_tpu.ops.flash_kernel import pick_block
-
-            qb = pick_block(i, target=cfg.flash_qb_target)
+        # otherwise (ops/dispatch.py decides; ops/flash.py names it
+        # `attn_core`)
         out = flash_attention(
             q, k, v, key_bias, scale=scale,
             gate=(
@@ -314,8 +296,6 @@ def attention_apply(
                 if gate_logits is not None else None
             ),
             tile_elems=cfg.flash_tile_elems, kv_block=cfg.flash_kv_block,
-            kernel_qb=qb,
-            logit_dtype=dtype if cfg.flash_compute_dtype_logits else None,
         )
         out = out.reshape(out.shape[0], i, h * dh)
     else:

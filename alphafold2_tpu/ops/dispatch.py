@@ -6,41 +6,35 @@ kernels; FastFold (arxiv 2203.00854) chose the execution strategy per
 workload shape. This module is that surface for this repo: every hot op
 (dense/fused flash attention, the int8 fused-dequant matmul, block-
 sparse attention, the ring-attention hop, the experts' grouped product)
-registers named ARMS —
+registers two named ARMS —
 
   * ``pallas_tpu`` — the Pallas Mosaic kernel (interpret mode off-TPU,
     which is what the chip-free parity tier exercises);
-  * ``gpu``        — the GPU arm. Pallas-Triton lowering for these
-    kernels is not available on this JAX build
-    (`pallas_triton_lowerable`), so the arm is the optimized-XLA
-    blockwise path (the `streamed_fused_attention`-style streaming
-    recurrence) — XLA's GPU fusion pipeline keeps it memory-bounded,
-    and a Triton kernel can slot into the same arm name later;
   * ``xla_ref``    — the pure-XLA reference arm: runs anywhere,
     bit-stable, the parity oracle every kernel arm is pinned against.
+    Every platform that is not ``tpu`` resolves to it.
 
-and resolution happens in ONE place (`resolve`): platform detection ->
-shape gate -> env override. The override generalizes the tri-state
-pattern that used to live in three hand-rolled copies
-(ops/flash.py `kernel_dispatch`, ops/quant.py `quant_dispatch`,
-ops/sparse.py's inline auto block):
+and the choice of arm happens in ONE place (`resolve`): platform ->
+shape gate and measured crossover -> override. The op modules
+(ops/flash.py, ops/quant.py, ops/sparse.py, ops/moe.py,
+parallel/sequence.py) call `resolve(op, request, **shapes)`, compare
+with `ARM_PALLAS_TPU` and keep their own wiring. What can steer it:
 
-  * a caller's ``use_kernel=True/False`` still forces the kernel/XLA arm
+  * a caller's ``use_kernel=True/False`` forces the kernel/XLA arm
     (loud `ValueError` when forcing an unsupported shape — forcing must
     never silently fall back);
   * ``AF2_KERNEL_BACKEND=<arm>`` forces one arm globally,
     ``AF2_KERNEL_BACKEND_<OP>`` per op (op name upper-cased); ``off``
     means the op's ``xla_ref`` arm, ``auto``/unset keeps the heuristic
-    (ops/knobs.py `kernel_backend_override`);
-  * legacy per-op knobs (``AF2_QUANT_KERNEL=force/off``, the
-    ``AF2_DISABLE_*_KERNEL`` kill-switches, ``AF2_FLASH_AUTO_MIN_J``)
-    keep their documented meaning — they feed the same single resolver.
+    (ops/knobs.py `kernel_backend_override`). There is no other channel.
 
-`flash_attention()` / `linear()` / `sparse_attention_apply()` /
-`ring_attention()` call sites are unchanged: the op modules ask this
-registry which arm to run and keep their own wiring. af2lint's
-``dispatch`` pass enforces the monopoly: every registered op has an
-``xla_ref`` arm and a registered chip-free parity test, no module
+Whether attention streams at all or materializes its logits is decided
+above this surface (ops/attention.py `attention_apply`); which FORM the
+kernel arm takes and at what blocks is decided below it, from the shape
+(ops/flash_kernel.py `rows_plan`, `causal_plan`, `supported*`).
+
+af2lint's ``dispatch`` pass enforces the monopoly: every registered op
+has an ``xla_ref`` arm and a registered chip-free parity test, no module
 outside ``ops/`` imports a kernel module directly, and no module
 outside ``ops/knobs.py`` parses an AF2_* env var.
 
@@ -62,7 +56,6 @@ import jax
 from alphafold2_tpu.ops import knobs
 
 __all__ = [
-    "ARM_GPU",
     "ARM_PALLAS_TPU",
     "ARM_XLA_REF",
     "Arm",
@@ -71,7 +64,6 @@ __all__ = [
     "get",
     "main",
     "ops",
-    "pallas_triton_lowerable",
     "reset_decisions",
     "resolution_table",
     "resolution_tag",
@@ -79,27 +71,22 @@ __all__ = [
 ]
 
 ARM_PALLAS_TPU = "pallas_tpu"
-ARM_GPU = "gpu"
 ARM_XLA_REF = "xla_ref"
 
-# platforms jax reports for the GPU backends
-_GPU_PLATFORMS = ("gpu", "cuda", "rocm")
+# measured crossover for the flash family (dense, fused, ring hop): the
+# lowest key length at which the kernel was measured to win on the chip. At
+# i = j = 1152, dh = 64 (one 96-row batch chunk of the pair stream, v5e,
+# jax 0.9.0) the whole-row form takes 6.2 us a (batch, head) row forward
+# and 16.1 with its backward against the XLA streaming arm's 17.9 and
+# 39.4; the streaming form at 384-blocks ties XLA there (16.8 / 41.4), so
+# a shape the whole-row form does not take loses nothing
+# (benchmarks/records/micro_attn_core_pr26.jsonl, PERF.md section 5).
+# Nothing shorter was measured: the crosses (j = 32, 864) stay on XLA.
+_FLASH_KERNEL_MIN_J = 1152
 
 # measured crossover for the block-sparse kernel (v5e @ block=128:
 # kernel 2.2x faster at n=8192, XLA ~1.3x faster at n=2048 — ops/sparse.py)
 _SPARSE_KERNEL_MIN_N = 4096
-
-
-def pallas_triton_lowerable() -> bool:
-    """Whether this host can LOWER the flash-family kernels through
-    Pallas-Triton. The installed jaxlib has no GPU client, so the probe
-    is honest-but-static: False until a CUDA/ROCm backend is present. When it flips, a Triton kernel can register under the
-    existing ``gpu`` arm name — dispatch, env overrides, bench legs, and
-    the parity tier all apply unchanged."""
-    try:
-        return any(d.platform in _GPU_PLATFORMS for d in jax.devices())
-    except RuntimeError:  # no backend at all
-        return False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,8 +111,7 @@ class OpSpec:
     introspection table / serving tag resolve at; `parity_test` names
     the chip-free parity test function in tests/test_dispatch.py that
     pins kernel-arm == xla_ref (af2lint's dispatch pass fails CI when
-    the op has none); `legacy_override` adapts a pre-registry env knob
-    (e.g. AF2_QUANT_KERNEL) into the common override channel."""
+    the op has none)."""
 
     name: str
     arms: Tuple[Arm, ...]
@@ -133,7 +119,6 @@ class OpSpec:
     probe: Dict[str, object]
     parity_test: str
     kernel_arm: str = ARM_PALLAS_TPU
-    legacy_override: Optional[Callable[[], Optional[str]]] = None
     unsupported_msg: Optional[Callable[[str, dict], str]] = None
 
     def arm(self, name: str) -> Optional[Arm]:
@@ -222,11 +207,10 @@ def _resolve(op: str, request="auto", platform: Optional[str] = None,
 
     `request` is the call-site tri-state (the old `use_kernel`): True
     forces the op's kernel arm, False forces `xla_ref`, "auto" consults
-    the env override (AF2_KERNEL_BACKEND_<OP> > AF2_KERNEL_BACKEND >
-    the op's legacy knob) and then the platform/shape heuristic. Forcing
-    an unknown arm or an unsupported shape raises — a forced arm that
-    silently fell back would record one arm's numbers under another's
-    name."""
+    the env override (AF2_KERNEL_BACKEND_<OP> > AF2_KERNEL_BACKEND)
+    and then the platform/shape heuristic. Forcing an unknown arm or an
+    unsupported shape raises — a forced arm that silently fell back
+    would record one arm's numbers under another's name."""
     spec = get(op)
     if platform is None:
         platform = _platform()
@@ -238,8 +222,6 @@ def _resolve(op: str, request="auto", platform: Optional[str] = None,
         forced = ARM_XLA_REF
     elif request == "auto":
         override = knobs.kernel_backend_override(op)
-        if override is None and spec.legacy_override is not None:
-            override = spec.legacy_override()
         if override == "off":
             forced = ARM_XLA_REF
         elif override is not None:
@@ -305,24 +287,16 @@ def _flash_unsupported_msg(arm, s):
 
 def _flash_family_auto(supported):
     """The measured flash heuristic, shared by the dense, fused, and
-    ring-hop ops: Pallas on TPU for supported shapes past the short-j
-    crossover (AF2_FLASH_AUTO_MIN_J, kill-switch honored), the GPU arm
-    on GPU platforms, XLA streaming elsewhere."""
+    ring-hop ops: Pallas on TPU for supported shapes from the measured
+    crossover (`_FLASH_KERNEL_MIN_J`) up, XLA streaming elsewhere."""
 
     def auto(platform: str, s: dict) -> str:
-        # knobs parse FIRST, unconditionally: a typo'd value must raise
-        # on every host, not only where the knob would have mattered
-        disabled = knobs.flash_kernel_disabled()
-        min_j = knobs.flash_auto_min_j()
         if (
             platform == "tpu"
-            and not disabled
-            and s["j"] >= min_j
+            and s["j"] >= _FLASH_KERNEL_MIN_J
             and supported(platform, **s)
         ):
             return ARM_PALLAS_TPU
-        if platform in _GPU_PLATFORMS:
-            return ARM_GPU
         return ARM_XLA_REF
 
     return auto
@@ -335,9 +309,6 @@ register(OpSpec(
             "ops/flash_kernel.py flash_attention_bnhd: whole-row or "
             "streaming form from the shape, the causal form (triangular "
             "grid) where the call is causal (interpret off-TPU)"),
-        Arm(ARM_GPU, _always,
-            "XLA blockwise streaming (ops/flash.py blockwise_attention); "
-            "Pallas-Triton slot when lowerable"),
         Arm(ARM_XLA_REF, _always,
             "ops/flash.py blockwise_attention — the parity oracle"),
     ),
@@ -353,9 +324,6 @@ register(OpSpec(
         Arm(ARM_PALLAS_TPU, _fused_supported,
             "ops/flash_kernel.py flash_attention_fused (2-D pair bias + "
             "in-kernel gate)"),
-        Arm(ARM_GPU, _always,
-            "ops/flash.py streamed_fused_attention — the fusion-tuned "
-            "blockwise path"),
         Arm(ARM_XLA_REF, _always,
             "ops/flash.py streamed_fused_attention / gate epilogue"),
     ),
@@ -373,23 +341,9 @@ def _quant_supported(platform, *, m, k, n, x_dtype, **_):
 
 
 def _quant_auto(platform: str, s: dict) -> str:
-    disabled = knobs.quant_kernel_disabled()  # parse on every host
-    if (
-        platform == "tpu"
-        and not disabled
-        and _quant_supported(platform, **s)
-    ):
+    if platform == "tpu" and _quant_supported(platform, **s):
         return ARM_PALLAS_TPU
-    if platform in _GPU_PLATFORMS:
-        return ARM_GPU
     return ARM_XLA_REF
-
-
-def _quant_legacy_override() -> Optional[str]:
-    ov = knobs.quant_kernel_override()  # AF2_QUANT_KERNEL force/off/auto
-    if ov is None:
-        return None
-    return ARM_PALLAS_TPU if ov else "off"
 
 
 def _quant_unsupported_msg(arm, s):
@@ -409,9 +363,6 @@ register(OpSpec(
         Arm(ARM_PALLAS_TPU, _quant_supported,
             "ops/quant_kernel.py quant_matmul_tpu — int8 tiles cross HBM, "
             "dequant in the epilogue"),
-        Arm(ARM_GPU, _always,
-            "ops/quant.py quant_matmul_xla (XLA fuses dequant+matmul on "
-            "GPU; Triton slot when lowerable)"),
         Arm(ARM_XLA_REF, _always,
             "ops/quant.py quant_matmul_xla — materialized-dequant "
             "reference"),
@@ -419,22 +370,13 @@ register(OpSpec(
     auto=_quant_auto,
     probe={"m": 4096, "k": 512, "n": 512, "x_dtype": "float32"},
     parity_test="test_parity_quant_matmul",
-    legacy_override=_quant_legacy_override,
     unsupported_msg=_quant_unsupported_msg,
 ))
 
 
 def _sparse_auto(platform: str, s: dict) -> str:
-    disabled = knobs.flash_kernel_disabled()  # the shared kill-switch;
-    # parsed on every host so a typo'd value raises everywhere
-    if (
-        platform == "tpu"
-        and not disabled
-        and s["n"] >= _SPARSE_KERNEL_MIN_N
-    ):
+    if platform == "tpu" and s["n"] >= _SPARSE_KERNEL_MIN_N:
         return ARM_PALLAS_TPU
-    if platform in _GPU_PLATFORMS:
-        return ARM_GPU
     return ARM_XLA_REF
 
 
@@ -444,8 +386,6 @@ register(OpSpec(
         Arm(ARM_PALLAS_TPU, _always,
             "ops/sparse_kernel.py block_sparse_attention_tpu (blocks "
             "stream; no per-row residency bound)"),
-        Arm(ARM_GPU, _always,
-            "ops/sparse.py block_sparse_attention — XLA block-gather"),
         Arm(ARM_XLA_REF, _always,
             "ops/sparse.py block_sparse_attention — the parity oracle"),
     ),
@@ -460,9 +400,6 @@ register(OpSpec(
         Arm(ARM_PALLAS_TPU, _flash_supported,
             "ops/flash_kernel.py flash_attention_lse per hop, log-space "
             "merge (ops/flash.py merge_lse)"),
-        Arm(ARM_GPU, _always,
-            "XLA stream_block hop recurrence (the blockwise streaming "
-            "path)"),
         Arm(ARM_XLA_REF, _always,
             "ops/flash.py stream_block hop recurrence"),
     ),
@@ -481,7 +418,7 @@ def _grouped_supported(platform, *, m, k, n, **_):
 def _grouped_auto(platform: str, s: dict) -> str:
     if platform == "tpu" and _grouped_supported(platform, **s):
         return ARM_PALLAS_TPU
-    return ARM_GPU if platform in _GPU_PLATFORMS else ARM_XLA_REF
+    return ARM_XLA_REF
 
 
 register(OpSpec(
@@ -491,7 +428,6 @@ register(OpSpec(
             "ops/moe.py: JAX's megablox grouped-product kernels (gmm, and "
             "tgmm for the weights' gradient), tiles past the last group "
             "never visited (interpret off-TPU)"),
-        Arm(ARM_GPU, _always, "jax.lax.ragged_dot, as xla_ref"),
         Arm(ARM_XLA_REF, _always,
             "jax.lax.ragged_dot over rows sorted by group (ops/moe.py)"),
     ),
@@ -570,12 +506,12 @@ def main(argv=None) -> int:
                          "explicit in runbooks)")
     ap.add_argument("--platform", default=None,
                     help="resolve for an explicit platform instead of "
-                         "this host's (tpu/gpu/cpu)")
+                         "this host's (tpu, cpu; anything else "
+                         "resolves as cpu does)")
     args = ap.parse_args(argv)
 
     platform = args.platform or _platform()
-    print(f"kernel dispatch registry @ platform={platform} "
-          f"(pallas_triton_lowerable={pallas_triton_lowerable()})")
+    print(f"kernel dispatch registry @ platform={platform}")
     for name, probe, supp, resolved in resolution_table(platform):
         probe_s = " ".join(f"{k}={v}" for k, v in probe.items())
         supp_s = " ".join(

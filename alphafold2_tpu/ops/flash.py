@@ -28,7 +28,7 @@ _NEG_INF = float("-inf")
 
 
 def stream_block(q, k_blk, v_blk, bias_blk, m, l, acc, scale,
-                 logit_dtype=jnp.float32, bias2d_blk=None):
+                 bias2d_blk=None):
     """One flash-attention accumulation step against a K/V block.
 
     q: (b, nq, h, d); k_blk/v_blk: (b, nk, h, d); bias_blk: (b, nk) additive
@@ -36,19 +36,14 @@ def stream_block(q, k_blk, v_blk, bias_blk, m, l, acc, scale,
     bias2d_blk: optional (b, h, nq, nk) full pair-bias block added to the
     logits (the XLA twin of the fused kernel's streamed 2-D bias tiles);
     bias_blk may be None when it is given (fold masks into the 2-D bias).
-
-    logit_dtype: dtype the (b, h, nq, nk) score/probability tiles are
-    MATERIALIZED in. These tiles dominate the path's HBM traffic (the
-    running stats and accumulator are f32 regardless, and the AV dot
-    casts p to v's dtype anyway) — bf16 halves the dominant traffic at
-    ~0.5% probability error, the same order as the bf16 activation
-    quantization the model already carries. Running max/sum stay f32.
+    The (b, h, nq, nk) score and probability tiles are float32, like the
+    running stats and the accumulator; the AV dot casts p to v's dtype.
     """
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k_blk).astype(logit_dtype) * scale
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k_blk).astype(jnp.float32) * scale
     if bias_blk is not None:
-        s = s + bias_blk[:, None, None, :].astype(logit_dtype)
+        s = s + bias_blk[:, None, None, :].astype(jnp.float32)
     if bias2d_blk is not None:
-        s = s + bias2d_blk.astype(logit_dtype)
+        s = s + bias2d_blk.astype(jnp.float32)
 
     # No gradient through the running max (jax.nn.softmax does the same):
     # every consumer combines (m, l, acc) shift-invariantly (acc / l,
@@ -72,12 +67,10 @@ def stream_block(q, k_blk, v_blk, bias_blk, m, l, acc, scale,
     )
     p = jnp.where(
         jnp.isneginf(s),
-        jnp.zeros((), logit_dtype),
-        jnp.exp(jnp.where(jnp.isneginf(s), jnp.zeros((), logit_dtype), s)
-                - m_safe[..., None].astype(logit_dtype)),
+        0.0,
+        jnp.exp(jnp.where(jnp.isneginf(s), 0.0, s) - m_safe[..., None]),
     )
-    # f32 ACCUMULATION without materializing an f32 copy of p
-    l_new = l * alpha + jnp.sum(p, axis=-1, dtype=jnp.float32)
+    l_new = l * alpha + jnp.sum(p, axis=-1)
     acc_new = acc * alpha[..., None] + jnp.einsum(
         "bhqk,bkhd->bhqd", p.astype(v_blk.dtype), v_blk
     ).astype(jnp.float32)
@@ -125,7 +118,7 @@ def _largest_divisor_leq(n: int, cap: int) -> int:
     return 1
 
 
-def _tile_attention(q, k, v, bias, scale, kv_block, logit_dtype=jnp.float32):
+def _tile_attention(q, k, v, bias, scale, kv_block):
     """Exact attention for one query tile, streaming K/V blocks."""
     b, nq, h, dh = q.shape
     j = k.shape[1]
@@ -134,8 +127,7 @@ def _tile_attention(q, k, v, bias, scale, kv_block, logit_dtype=jnp.float32):
     acc0 = jnp.zeros((b, h, nq, dh), jnp.float32)
 
     if kv_block is None or j <= kv_block:
-        m, l, acc = stream_block(q, k, v, bias, m0, l0, acc0, scale,
-                                 logit_dtype)
+        m, l, acc = stream_block(q, k, v, bias, m0, l0, acc0, scale)
     else:
         pad = (-j) % kv_block
         if pad:
@@ -150,8 +142,7 @@ def _tile_attention(q, k, v, bias, scale, kv_block, logit_dtype=jnp.float32):
         def body(carry, blk):
             mm, ll, aa = carry
             kb, vb, bb = blk
-            return stream_block(q, kb, vb, bb, mm, ll, aa, scale,
-                                logit_dtype), None
+            return stream_block(q, kb, vb, bb, mm, ll, aa, scale), None
 
         (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), (ks, vs, bs))
 
@@ -169,7 +160,6 @@ def blockwise_attention(
     tile_elems: int = 1 << 25,
     kv_block: int = 2048,
     remat: bool = True,
-    logit_dtype=None,
 ):
     """Exact softmax(QK^T * scale + bias)V with bounded-memory tiling.
 
@@ -186,18 +176,18 @@ def blockwise_attention(
       tile_elems: target max elements per (batch*h*q*kv) logit tile
         (default 2^25 = 128 MB in f32).
       kv_block: stream K/V in blocks of this length when j exceeds it.
+        Both stay function arguments: Alphafold2Config's
+        attn_flash_tile_elems / attn_flash_kv_block reach them, and
+        scripts/micro_attn_core.py sweeps tile_elems (ROADMAP S5 settles
+        the config fields with the cell's re-measurement).
       remat: jax.checkpoint each tile so backward recomputes instead of
         storing tile activations.
-      logit_dtype: dtype the score/probability tiles are materialized in
-        (None = float32). These tiles dominate HBM traffic; bf16 halves
-        it at ~0.5% probability error (see stream_block).
 
     Returns: (B, i, h, dh) in q.dtype. Fully-masked query rows return zeros.
     """
     B, i, h, dh = q.shape
     j = k.shape[1]
     scale = dh ** -0.5 if scale is None else scale
-    logit_dtype = jnp.float32 if logit_dtype is None else logit_dtype
     if key_bias is None:
         key_bias = jnp.zeros((B, j), jnp.float32)
 
@@ -208,7 +198,7 @@ def blockwise_attention(
     kvb = kv_block if (kv_block and j > kv_block) else None
 
     def tile(qt, kt, vt, bt):
-        return _tile_attention(qt, kt, vt, bt, scale, kvb, logit_dtype)
+        return _tile_attention(qt, kt, vt, bt, scale, kvb)
 
     if remat:
         tile = jax.checkpoint(tile)
@@ -244,7 +234,7 @@ def blockwise_attention(
 
 
 def causal_blockwise_attention(q, k, v, *, scale=None, block: int = 1024,
-                               remat: bool = True, logit_dtype=None):
+                               remat: bool = True):
     """Exact causal self-attention, streamed: softmax(QK^T * scale) V under
     the lower-triangular mask, with a value head size of its own.
 
@@ -257,7 +247,6 @@ def causal_blockwise_attention(q, k, v, *, scale=None, block: int = 1024,
     B, n, h, dh = q.shape
     dv = v.shape[-1]
     scale = dh ** -0.5 if scale is None else scale
-    logit_dtype = jnp.float32 if logit_dtype is None else logit_dtype
     block = min(block, n)
     pad = (-n) % block
     if pad:  # a padded key lies past every real query
@@ -278,13 +267,12 @@ def causal_blockwise_attention(q, k, v, *, scale=None, block: int = 1024,
                  jnp.zeros((B, h, block, dv), jnp.float32))
 
         def body(c, blk):
-            return stream_block(qt, blk[0], blk[1], None, *c, scale,
-                                logit_dtype), None
+            return stream_block(qt, blk[0], blk[1], None, *c, scale), None
 
         if k_below.shape[0]:
             carry, _ = jax.lax.scan(body, carry, (k_below, v_below))
         _, l, acc = stream_block(qt, k_diag, v_diag, None, *carry, scale,
-                                 logit_dtype, bias2d_blk=diagonal)
+                                 bias2d_blk=diagonal)
         return jnp.transpose(acc / l[..., None], (0, 2, 1, 3)).astype(q.dtype)
 
     if remat:
@@ -309,8 +297,9 @@ def _causal_attention_arms(q, k, v, key_bias, scale, use_kernel, kernel_qb,
     if arm == dispatch.ARM_PALLAS_TPU:
         return flash_kernel.flash_attention_causal_bnhd(
             q, k, v, scale, qb=kernel_qb, kb=kernel_kb)
-    kwargs = {name: blockwise_kwargs[name] for name in ("remat", "logit_dtype")
-              if name in blockwise_kwargs}
+    kwargs = {}
+    if "remat" in blockwise_kwargs:
+        kwargs["remat"] = blockwise_kwargs["remat"]
     if "kv_block" in blockwise_kwargs:
         kwargs["block"] = blockwise_kwargs["kv_block"]
     return causal_blockwise_attention(q, k, v, scale=scale, **kwargs)
@@ -328,8 +317,7 @@ def apply_output_gate(out, gate):
 
 
 def streamed_fused_attention(q, k, v, key_bias, pair_bias, gate, scale,
-                             kv_block: int = 2048, remat: bool = True,
-                             logit_dtype=None):
+                             kv_block: int = 2048, remat: bool = True):
     """XLA twin of the fused-epilogue kernel: 2-D pair bias + output gate.
 
     q: (B, i, h, dh); k, v: (B, j, h, dh); pair_bias: (B, h, i, j) f32
@@ -339,14 +327,10 @@ def streamed_fused_attention(q, k, v, key_bias, pair_bias, gate, scale,
     (B, h, i, kv_block) — bounded along j only (the 2-D bias itself is a
     caller-materialized (B, h, i, j) input, so there is no q-tiling win to
     chase here; the Pallas kernel is the production TPU path).
-    logit_dtype: dtype of the live score/probability tiles (None = f32) —
-    same knob as `blockwise_attention`, so the
-    attn_flash_compute_dtype_logits A/B stays honest on this path too.
     Exact at f32; the parity oracle for the fused kernel's interpret-mode
     tests."""
     B, i, h, dh = q.shape
     j = k.shape[1]
-    logit_dtype = jnp.float32 if logit_dtype is None else logit_dtype
     bias = pair_bias.astype(jnp.float32)
     if key_bias is not None:
         bias = bias + key_bias[:, None, None, :].astype(jnp.float32)
@@ -357,7 +341,6 @@ def streamed_fused_attention(q, k, v, key_bias, pair_bias, gate, scale,
         acc0 = jnp.zeros((B, h, i, dh), jnp.float32)
         if j <= kv_block:
             m, l, acc = stream_block(q, k, v, None, m0, l0, acc0, scale,
-                                     logit_dtype=logit_dtype,
                                      bias2d_blk=bias)
         else:
             pad = (-j) % kv_block
@@ -375,7 +358,6 @@ def streamed_fused_attention(q, k, v, key_bias, pair_bias, gate, scale,
                 mm, ll, aa = carry
                 kb, vb, bb = blk
                 return stream_block(q, kb, vb, None, mm, ll, aa, scale,
-                                    logit_dtype=logit_dtype,
                                     bias2d_blk=bb), None
 
             (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), (ks, vs, bs))
@@ -388,44 +370,6 @@ def streamed_fused_attention(q, k, v, key_bias, pair_bias, gate, scale,
     if gate is not None:
         out = out * jax.nn.sigmoid(gate.astype(jnp.float32))
     return out.astype(q.dtype)
-
-
-# The env knobs this module used to parse inline live in ops/knobs.py
-# now (one validated definition per knob); the names are re-exported for
-# existing importers (ops/sparse.py, tests). No env logic here — the
-# af2lint `dispatch` pass enforces that.
-from alphafold2_tpu.ops.knobs import (  # noqa: E402
-    FLASH_AUTO_MIN_J_DEFAULT as _AUTO_MIN_J,
-    flash_auto_min_j as auto_min_j,
-    flash_kernel_disabled as kernel_env_disabled,
-    gate_epilogue_unfused,
-)
-
-
-def kernel_dispatch(i: int, j: int, dh: int, use_kernel,
-                    fused: bool = False) -> bool:
-    """Resolve the tri-state `use_kernel` into a concrete kernel decision.
-
-    Thin adapter over the ONE resolution point, ops/dispatch.py
-    `resolve` — flash_attention and ring_attention
-    (parallel/sequence.py) both route here, so the
-    AF2_DISABLE_FLASH_KERNEL escape hatch, the AF2_KERNEL_BACKEND[_<OP>]
-    overrides, and the loud unsupported-shape error hold everywhere.
-    True forces the kernel (ValueError on unsupported shapes — forcing
-    must not silently fall back), False forces XLA streaming, "auto" =
-    the registry heuristic (kernel on TPU for supported shapes with
-    j >= auto_min_j(), the lowest key length the kernel was measured to
-    win at: ops/knobs.py FLASH_AUTO_MIN_J_DEFAULT). `fused` selects
-    the fused-epilogue op (its shape gate is `supported_fused`:
-    2-D pair bias / in-kernel gating, ops/flash_kernel.py).
-    """
-    from alphafold2_tpu.ops import dispatch
-
-    op = "fused_attention" if fused else "flash_attention"
-    return (
-        dispatch.resolve(op, request=use_kernel, i=i, j=j, dh=dh)
-        == dispatch.ARM_PALLAS_TPU
-    )
 
 
 def hop_attention_lse(qf, kf, vf, bias, scale):
@@ -454,15 +398,18 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
     Same contract as `blockwise_attention` (q (B, i, h, dh); k, v
     (B, j, h, dh); key-side (B, j) additive bias). use_kernel: True forces
     the kernel (interpret mode off-TPU — for tests), False forces XLA
-    streaming, "auto" uses the kernel on TPU for supported shapes
-    (ops/flash_kernel.py `supported`) with j >= auto_min_j() — the
-    pair stream's axial passes (i = j = 1152), where its whole-row form
-    keeps the logit tile in VMEM (PERF.md section 5); the short crosses
-    below it were not measured to win and stay on XLA streaming. The
-    kernel picks its own form and blocks from (i, j, h, dh)
-    (ops/flash_kernel.py `flash_attention_bnhd`); kernel_qb/kernel_kb
-    force its streaming form at those query/key blocks — kernel path
-    only, used for block tuning (scripts/bench_kernels.py).
+    streaming, "auto" asks ops/dispatch.py `resolve`, which takes the
+    kernel on TPU for supported shapes (ops/flash_kernel.py `supported`)
+    from the measured crossover in key length up — the pair stream's
+    axial passes (i = j = 1152), where its whole-row form keeps the
+    logit tile in VMEM (PERF.md section 5); the short crosses below it
+    were not measured to win and stay on XLA streaming. The kernel picks
+    its own form and blocks from (i, j, h, dh) (ops/flash_kernel.py
+    `flash_attention_bnhd`). kernel_qb/kernel_kb force its streaming
+    form at those query/key blocks (kernel path only). They stay
+    function arguments because tests force small blocks so that
+    interpret mode walks a multi-block schedule, and
+    scripts/micro_attn_core.py sweeps them; no config field reaches them.
 
     Fused epilogue: `pair_bias` (B, h, i, j) f32 full 2-D additive bias
     tiles and/or `gate` (B, i, h, dh) pre-sigmoid output-gate logits.
@@ -498,32 +445,19 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
 def _flash_attention_arms(q, k, v, key_bias, *, pair_bias, gate, scale,
                           use_kernel, kernel_qb, kernel_kb,
                           **blockwise_kwargs):
-    from alphafold2_tpu.ops import flash_kernel
+    from alphafold2_tpu.ops import dispatch, flash_kernel
 
     B, i, h, dh = q.shape
     j = k.shape[1]
     scale = dh ** -0.5 if scale is None else scale
     fused = pair_bias is not None or gate is not None
 
-    if gate is not None and pair_bias is None and gate_epilogue_unfused():
-        # control arm (AF2_UNFUSE_GATE_EPILOGUE): same use_kernel policy
-        # for the core, gate as an exact XLA epilogue — identical math to
-        # the fused path, one extra HBM out-read/multiply/write pass
-        out = flash_attention(
-            q, k, v, key_bias, scale=scale, use_kernel=use_kernel,
-            kernel_qb=kernel_qb, kernel_kb=kernel_kb, **blockwise_kwargs,
-        )
-        return apply_output_gate(out, gate)
+    def takes_kernel(op):
+        return dispatch.resolve(
+            op, request=use_kernel, i=i, j=j, dh=dh
+        ) == dispatch.ARM_PALLAS_TPU
 
-    if fused and kernel_dispatch(i, j, dh, use_kernel, fused=True):
-        ldt = blockwise_kwargs.get("logit_dtype")
-        if ldt is not None and ldt != jnp.float32:
-            raise ValueError(
-                "logit_dtype (flash_compute_dtype_logits) applies only "
-                "to the XLA streaming path, but the fused Pallas kernel "
-                f"dispatched here (i={i}, j={j}, use_kernel="
-                f"{use_kernel!r}); disable the kernel for this A/B"
-            )
+    if fused and takes_kernel("fused_attention"):
 
         def fold(t):
             return t.transpose(0, 2, 1, 3).reshape(B * h, t.shape[1], dh)
@@ -550,16 +484,13 @@ def _flash_attention_arms(q, k, v, key_bias, *, pair_bias, gate, scale,
         return out.reshape(B, h, i, dh).transpose(0, 2, 1, 3)
 
     if pair_bias is not None:
-        # XLA twin of the 2-D-bias mode: j-streamed, exact at f32.
-        # logit_dtype threads through (the bf16-logits A/B must not
-        # silently record f32 math here — the kernel branch above raises
-        # for the same knob); tile_elems is structurally inapplicable
-        # (the 2-D bias is a caller-materialized (B, h, i, j) input, so
-        # there is no q-tiling win — see streamed_fused_attention).
+        # XLA twin of the 2-D-bias mode: j-streamed, exact at f32;
+        # tile_elems is structurally inapplicable (the 2-D bias is a
+        # caller-materialized (B, h, i, j) input, so there is no
+        # q-tiling win — see streamed_fused_attention).
         return streamed_fused_attention(
             q, k, v, key_bias, pair_bias, gate, scale,
             kv_block=blockwise_kwargs.get("kv_block", 2048),
-            logit_dtype=blockwise_kwargs.get("logit_dtype"),
         )
     if gate is not None:
         # gate-only: the plain blockwise path plus the exact epilogue
@@ -569,20 +500,7 @@ def _flash_attention_arms(q, k, v, key_bias, *, pair_bias, gate, scale,
         )
         return apply_output_gate(out, gate)
 
-    if kernel_dispatch(i, j, dh, use_kernel):
-        ldt = blockwise_kwargs.get("logit_dtype")
-        if ldt is not None and ldt != jnp.float32:
-            # the Pallas kernel keeps its logit tiles in VMEM (no HBM
-            # materialization to halve) and computes them f32: recording
-            # a "bf16-logits" measurement that actually ran the kernel
-            # would misattribute the A/B — fail loudly instead
-            raise ValueError(
-                "logit_dtype (flash_compute_dtype_logits) applies only "
-                "to the XLA streaming path, but the Pallas kernel "
-                f"dispatched here (i={i}, j={j}, use_kernel="
-                f"{use_kernel!r}); disable the kernel for this A/B"
-            )
-
+    if takes_kernel("flash_attention"):
         bias = (
             jnp.zeros((B, j), jnp.float32)
             if key_bias is None

@@ -1,11 +1,10 @@
 """One validated home for every AF2_* environment knob.
 
 Before this module, each env knob was parsed where it was consumed —
-`ops/flash.py` grew three parsers, `ops/quant.py` two more,
-`parallel/overlap.py` and `parallel/distributed.py` their own — with
-three different ideas of what "0"/"false"/"off" mean and silent
-acceptance of typos (`AF2_DISABLE_FLASH_KERNEL=flase` disabled the
-kernel). This module is the single registry:
+`ops/flash.py`, `ops/quant.py`, `parallel/overlap.py` and
+`parallel/distributed.py` each had parsers of their own — with three
+different ideas of what "0"/"false"/"off" mean and silent acceptance of
+typos. This module is the single registry:
 
   * every knob has exactly ONE definition (`KNOBS`) carrying its type,
     default, accepted values, and the module that consumes it;
@@ -40,31 +39,15 @@ __all__ = [
     "comm_overlap_enabled",
     "coordinator",
     "flag",
-    "flash_auto_min_j",
-    "flash_kernel_disabled",
-    "gate_epilogue_unfused",
     "generate_table",
     "kernel_backend_override",
     "num_processes",
     "pallas_interpret_override",
     "process_id",
-    "quant_kernel_disabled",
-    "quant_kernel_override",
 ]
 
 _TRUE = ("1", "true", "yes", "on")
 _FALSE = ("0", "false", "no", "off")
-
-#: default Pallas auto-dispatch key-length threshold: the lowest key
-#: length at which the kernel was measured to win on the chip. At
-#: i = j = 1152, dh = 64 (one 96-row batch chunk of the pair stream, v5e,
-#: jax 0.9.0) the whole-row form takes 6.2 us a (batch, head) row forward
-#: and 16.1 with its backward against the XLA streaming arm's 17.9 and
-#: 39.4; the streaming form at 384-blocks ties XLA there (16.8 / 41.4), so
-#: a shape the whole-row form does not take loses nothing
-#: (benchmarks/records/micro_attn_core_pr26.jsonl, PERF.md section 5).
-#: Nothing shorter was measured: the crosses (j = 32, 864) stay on XLA.
-FLASH_AUTO_MIN_J_DEFAULT = 1152
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,52 +96,6 @@ def env_int(name: str, default: int) -> int:
 # --- kernel-dispatch knobs ---------------------------------------------------
 
 
-def flash_kernel_disabled() -> bool:
-    """AF2_DISABLE_FLASH_KERNEL kill-switch, shared by BOTH flash-family
-    Pallas kernels (dense in ops/flash.py, block-sparse in ops/sparse.py):
-    bench.py's kernel-off retry must leave no Pallas in the program.
-    Auto-mode only; explicit forcing wins."""
-    return flag("AF2_DISABLE_FLASH_KERNEL")
-
-
-def quant_kernel_disabled() -> bool:
-    """AF2_DISABLE_QUANT_KERNEL kill-switch (auto mode only), same
-    contract as AF2_DISABLE_FLASH_KERNEL."""
-    return flag("AF2_DISABLE_QUANT_KERNEL")
-
-
-def gate_epilogue_unfused() -> bool:
-    """AF2_UNFUSE_GATE_EPILOGUE: keep the Pallas kernel for the attention
-    CORE but apply the sigmoid output gate as a separate XLA epilogue —
-    the control arm that isolates the epilogue fusion (ops/flash.py)."""
-    return flag("AF2_UNFUSE_GATE_EPILOGUE")
-
-
-def flash_auto_min_j() -> int:
-    """AF2_FLASH_AUTO_MIN_J: minimum key length for the Pallas kernel in
-    "auto" mode (0 force-prefers the kernel everywhere supported — the
-    sweep's kernel-on legs)."""
-    return env_int("AF2_FLASH_AUTO_MIN_J", FLASH_AUTO_MIN_J_DEFAULT)
-
-
-def quant_kernel_override() -> Optional[bool]:
-    """AF2_QUANT_KERNEL legacy sweep override for auto-mode dispatch:
-    "force" -> kernel everywhere (loud error on unsupported shapes),
-    "off" -> XLA reference arm, ""/"auto" -> the platform/shape
-    heuristic. Superseded by AF2_KERNEL_BACKEND_QUANT_MATMUL but kept —
-    recorded sweep rows and runbooks use it."""
-    raw = _raw("AF2_QUANT_KERNEL").lower()
-    if raw in ("", "auto"):
-        return None
-    if raw == "force":
-        return True
-    if raw == "off":
-        return False
-    raise ValueError(
-        f"AF2_QUANT_KERNEL must be force, off, or auto/empty, got {raw!r}"
-    )
-
-
 def comm_overlap_enabled() -> bool:
     """AF2_COMM_OVERLAP: communication-compute overlap schedules
     (double-buffered ring attention, backward-overlapped DP reduction).
@@ -171,7 +108,7 @@ def pallas_interpret_override() -> Optional[bool]:
     off (0/false); ""/unset -> None (platform default, resolved by
     ops/core.py pallas_interpret)."""
     raw = _raw("AF2_PALLAS_INTERPRET")
-    if not raw:  # empty string = unset, like the kill-switches
+    if not raw:  # empty string = unset
         return None
     if raw.lower() in ("0", "false"):
         return False
@@ -234,7 +171,7 @@ _BOOL = "1/true/yes/on, 0/false/no/off"
 
 KNOBS: Tuple[Knob, ...] = (
     Knob("AF2_KERNEL_BACKEND",
-         "auto, off, or an arm name (pallas_tpu, gpu, xla_ref)", "auto",
+         "auto, off, or an arm name (pallas_tpu, xla_ref)", "auto",
          "ops/dispatch.py",
          "Global backend-arm override for every registered hot op: an arm "
          "name forces it (loud error if unsupported), off forces xla_ref, "
@@ -244,23 +181,6 @@ KNOBS: Tuple[Knob, ...] = (
          "ops/dispatch.py",
          "Per-op override (OP = registered op name upper-cased, e.g. "
          "AF2_KERNEL_BACKEND_QUANT_MATMUL); wins over the global knob."),
-    Knob("AF2_DISABLE_FLASH_KERNEL", _BOOL, "0", "ops/dispatch.py",
-         "Kill-switch: auto-mode dispatch never picks a flash-family "
-         "Pallas arm (dense, fused, sparse, ring hop). Forcing wins."),
-    Knob("AF2_DISABLE_QUANT_KERNEL", _BOOL, "0", "ops/dispatch.py",
-         "Kill-switch: auto-mode dispatch never picks the int8 "
-         "fused-dequant Pallas arm."),
-    Knob("AF2_FLASH_AUTO_MIN_J", "integer",
-         str(FLASH_AUTO_MIN_J_DEFAULT), "ops/dispatch.py",
-         "Minimum key length for flash-family Pallas arms in auto mode "
-         "(lowest j measured to win; 0 = kernel everywhere supported)."),
-    Knob("AF2_QUANT_KERNEL", "force, off, auto", "auto",
-         "ops/dispatch.py",
-         "Legacy quant_matmul arm override (recorded sweep rows use it); "
-         "superseded by AF2_KERNEL_BACKEND_QUANT_MATMUL."),
-    Knob("AF2_UNFUSE_GATE_EPILOGUE", _BOOL, "0", "ops/flash.py",
-         "A/B control arm: Pallas attention core, sigmoid output gate as "
-         "a separate XLA epilogue (isolates the epilogue fusion)."),
     Knob("AF2_PALLAS_INTERPRET", "1/true, 0/false", "platform default",
          "ops/core.py",
          "Force Pallas interpret mode on or off (default: interpret "

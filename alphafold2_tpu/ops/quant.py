@@ -28,10 +28,9 @@ arms when parity is pinned per-op; this module arms the int8 lever:
     the kernel epilogue) on TPU for supported shapes, the pure-XLA
     dequant reference arm (`quant_matmul_xla` — materializes the
     dequantized weight, the baseline the kernel exists to beat)
-    elsewhere. Auto-dispatch mirrors ops/flash.py `kernel_dispatch`:
-    tri-state use_kernel, loud error on forced-unsupported,
-    AF2_DISABLE_QUANT_KERNEL kill-switch, AF2_QUANT_KERNEL=force/off
-    sweep override.
+    elsewhere. The arm comes from ops/dispatch.py `resolve` (op
+    "quant_matmul"): tri-state use_kernel, loud error on
+    forced-unsupported, AF2_KERNEL_BACKEND[_QUANT_MATMUL] override.
 
 Quantized weights are INFERENCE-ONLY: `quant_matmul` installs a
 custom-vjp backward that raises, and the training entry points
@@ -58,7 +57,6 @@ __all__ = [
     "is_quantized_linear",
     "quant_matmul",
     "quant_matmul_xla",
-    "quant_dispatch",
     "tree_weight_bytes",
     "quantized_path_bytes",
     "reject_quant_training",
@@ -269,33 +267,6 @@ def iter_linear_dicts(params, path: str = ""):
 # ---------------------------------------------------------------------------
 
 
-# env parsing lives in ops/knobs.py now (one validated definition per
-# knob); re-exported for existing importers. No env logic here — the
-# af2lint `dispatch` pass enforces that.
-from alphafold2_tpu.ops.knobs import (  # noqa: E402
-    quant_kernel_disabled as quant_kernel_env_disabled,
-    quant_kernel_override,
-)
-
-
-def quant_dispatch(m: int, k: int, n: int, x_dtype, use_kernel) -> bool:
-    """Resolve tri-state `use_kernel` into a concrete kernel decision —
-    a thin adapter over the ONE resolution point (ops/dispatch.py
-    `resolve`, op "quant_matmul"). True forces the kernel (ValueError on
-    unsupported shapes/dtypes — forcing must not silently fall back),
-    False forces the XLA dequant arm, "auto" = the registry heuristic
-    (kernel on TPU for supported shapes), honoring the env kill-switch,
-    the legacy AF2_QUANT_KERNEL sweep override, and the
-    AF2_KERNEL_BACKEND[_QUANT_MATMUL] overrides."""
-    from alphafold2_tpu.ops import dispatch
-
-    return (
-        dispatch.resolve("quant_matmul", request=use_kernel,
-                         m=m, k=k, n=n, x_dtype=x_dtype)
-        == dispatch.ARM_PALLAS_TPU
-    )
-
-
 def quant_matmul_xla(x, qw, scale):
     """Pure-XLA dequant reference arm: materialize the dequantized f32
     weight, matmul with f32 accumulation, cast once at the end — the
@@ -344,8 +315,12 @@ def quant_matmul(x, qw, scale, *, use_kernel="auto", dtype=None):
     kernel); qw: (d_in, d_out) int8; scale: per-output-channel (d_out,)
     f32, or a scalar per-tensor scale (broadcast). `dtype` casts the
     activations first (the `linear` compute-dtype contract); the output
-    is in the activation compute dtype. use_kernel: True / False /
-    "auto" (see `quant_dispatch`). Inference-only — the backward raises."""
+    is in the activation compute dtype. use_kernel: True forces the
+    kernel (ValueError on unsupported shapes/dtypes), False the XLA
+    dequant arm, "auto" asks ops/dispatch.py `resolve`. Inference-only —
+    the backward raises."""
+    from alphafold2_tpu.ops import dispatch
+
     if dtype is not None:
         x = x.astype(dtype)
     if qw.ndim != 2:
@@ -367,8 +342,9 @@ def quant_matmul(x, qw, scale, *, use_kernel="auto", dtype=None):
     for s in lead:
         m *= int(s)
     x2 = x.reshape(m, d_in)
-    kernel = quant_dispatch(m, d_in, d_out, x2.dtype, use_kernel)
-    y = _quant_core(x2, qw, scale, kernel)
+    arm = dispatch.resolve("quant_matmul", request=use_kernel,
+                           m=m, k=d_in, n=d_out, x_dtype=x2.dtype)
+    y = _quant_core(x2, qw, scale, arm == dispatch.ARM_PALLAS_TPU)
     return y.reshape(lead + (d_out,))
 
 

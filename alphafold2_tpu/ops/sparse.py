@@ -196,9 +196,8 @@ def sparse_attention_apply(
     """
     b, n, _ = x.shape
     # ONE resolution point (ops/dispatch.py, op "sparse_attention"):
-    # the shared AF2_DISABLE_FLASH_KERNEL kill-switch covers every
-    # flash-family Pallas arm, AF2_KERNEL_BACKEND[_SPARSE_ATTENTION]
-    # forces an arm, and auto picks the kernel only on real TPUs past
+    # AF2_KERNEL_BACKEND[_SPARSE_ATTENTION] forces an arm (`off` = no
+    # Pallas), and auto picks the kernel only on real TPUs past
     # the measured n >= 4096 crossover (off-TPU it would run in the
     # Pallas interpreter, orders of magnitude slower than the XLA path)
     from alphafold2_tpu.ops import dispatch

@@ -11,8 +11,8 @@ critical path. This module holds the framework-wide pieces of that story:
     Every overlapped path (`ring_attention`'s double-buffered schedule,
     the DP-overlap train step) defaults to the environment
     (`AF2_COMM_OVERLAP`, default on) so A/B legs — the MULTICHIP dryrun's
-    overlap pair, `scripts/bench_sweep.py`'s overlap legs — flip one env
-    var in a subprocess instead of threading a flag through every layer.
+    overlap pair — flip one env var in a subprocess instead of threading
+    a flag through every layer.
 
   * gradient bucketing (`plan_buckets` / `flatten_buckets` /
     `unflatten_buckets`) — the param pytree has hundreds of small leaves

@@ -69,13 +69,14 @@ def ring_attention(q, k, v, axis_name: str, mask=None, use_kernel="auto",
         alphafold2.py:156-161 / DeepSpeed attn_mask_mode='add').
       use_kernel: per-hop compute path. "auto" uses the Pallas flash
         kernel on TPU for supported shapes whose PER-HOP key length
-        nk_local >= ops/flash.py auto_min_j() (each hop emits (out, lse)
-        and hops combine in log space —
-        ops/flash_kernel.flash_attention_lse); below that threshold the
+        nk_local reaches the flash family's measured crossover
+        (ops/dispatch.py; each hop emits (out, lse) and hops combine in
+        log space — ops/flash_kernel.flash_attention_lse); below it the
         hop runs the XLA stream_block recurrence — the crossover was
-        measured on single-device e2e shapes (PERF.md session 4), not on
+        measured on single-device e2e shapes (PERF.md section 5), not on
         ring hops, so force with True (interpret mode off-TPU, for tests)
-        or AF2_FLASH_AUTO_MIN_J=0 to get the kernel on short shards.
+        or AF2_KERNEL_BACKEND_MERGE_LSE=pallas_tpu to get the kernel on
+        short shards.
       overlap: schedule selection. True = double-buffered (issue hop
         i+1's ppermute BEFORE computing hop i's block, so the ICI
         transfer hides under the current block's compute); False = the
@@ -106,8 +107,8 @@ def ring_attention(q, k, v, axis_name: str, mask=None, use_kernel="auto",
     perm = [(i, (i + 1) % num_shards) for i in range(num_shards)]
 
     # the SHARED resolution point (ops/dispatch.py, op "merge_lse" — the
-    # ring hop's registered name): honors AF2_DISABLE_FLASH_KERNEL and
-    # the AF2_KERNEL_BACKEND[_MERGE_LSE] overrides, and raises loudly
+    # ring hop's registered name): honors the
+    # AF2_KERNEL_BACKEND[_MERGE_LSE] overrides, and raises loudly
     # when forcing an unsupported shape
     if _dispatch.resolve(
         "merge_lse", request=use_kernel, i=n_local, j=nk_local, dh=d

@@ -4,15 +4,15 @@ BASELINE.md's operational target is defined over ONE workload (config 5):
 the full end-to-end structure train step — reversible tied-row trunk on
 the (3*384)^2 pair grid, MSA 128 rows, aligned cross-attention, distogram
 -> 200-iter MDS -> sidechain lift -> EGNN refiner -> weighted Kabsch RMSD
-loss — dim 256, heads 8, bf16 compute. Three scripts time it (bench.py,
-scripts/bench_sweep.py, scripts/bench_decompose.py) and their numbers are
-only comparable if they run the SAME program, so the config lives here
-and the scripts import it instead of hand-copying kwargs.
+loss — dim 256, heads 8, bf16 compute. The benchmark's builder
+(benchmarks/builders), bench.py and chip_smoke.py time it, and their
+numbers are only comparable if they run the SAME program, so the config
+lives here and they import it instead of hand-copying kwargs.
 
 Three tiers exist: "north_star" (the real target), "smoke" (tiny
 CPU-safe shapes for rehearsing a code path end-to-end — chip_smoke.py
---dry, bench_decompose.py --smoke; its timings mean nothing and are
-never recorded as measurements), and "proportional" (1/8-crop shapes
+--dry; its timings mean nothing and are never recorded as
+measurements), and "proportional" (1/8-crop shapes
 preserving the north star's structural ratios — what the multichip
 dryrun's scaled leg and MULTICHIP_r0N.json run). `smoke=True` is the
 legacy spelling of tier="smoke".

@@ -59,8 +59,8 @@ def main():
         checks.append((f"{name}_fwdbwd", fwdbwd, (q, k, v, bias)))
         checks.append((f"{name}_lse", lse, (q, k, v, bias)))
 
-    # whole-row query blocks (attn_flash_qb_target=1152): the e2e sweep
-    # leg forcing this died in compile on an earlier shared chip; the
+    # the streaming form forced to one 1152-row query block: an e2e run
+    # forcing this died in compile on an earlier shared chip; the
     # lowering itself passes
     qw = jax.ShapeDtypeStruct((256, 1152, 64), jnp.bfloat16)
     bw = jax.ShapeDtypeStruct((256, 1152), jnp.float32)

@@ -222,48 +222,3 @@ def test_remat_policy_unknown_raises():
     # typo'd policy would otherwise be silently ignored with remat=False)
     with pytest.raises(ValueError, match="remat_policy"):
         Alphafold2Config(dim=16, remat_policy="bogus")
-
-
-def test_flash_qb_target_plumbs_to_kernel(monkeypatch):
-    """attn_flash_qb_target reaches both attention configs, is validated,
-    and the attention op resolves it per-shape via pick_block — spied at
-    the flash_attention call so dropped plumbing cannot pass silently."""
-    import dataclasses
-
-    import jax
-    import numpy as np
-
-    from alphafold2_tpu.ops import attention as attention_mod
-    from alphafold2_tpu.ops.attention import attention_init, attention_apply
-
-    cfg = Alphafold2Config(
-        dim=32, depth=1, heads=2, dim_head=8, max_seq_len=64,
-        attn_flash_qb_target=256,
-    )
-    assert cfg.self_attn_config().flash_qb_target == 256
-    assert cfg.cross_attn_config().flash_qb_target == 256
-
-    with pytest.raises(ValueError, match="multiple of 128"):
-        Alphafold2Config(dim=32, depth=1, heads=2, dim_head=8,
-                         max_seq_len=64, attn_flash_qb_target=100)
-
-    captured = {}
-    real = attention_mod.flash_attention
-
-    def spy(q, k, v, bias=None, **kw):
-        captured.update(kw)
-        return real(q, k, v, bias, **kw)
-
-    monkeypatch.setattr(attention_mod, "flash_attention", spy)
-    acfg = dataclasses.replace(
-        cfg.self_attn_config(), flash=True, flash_qb_target=256
-    )
-    params = attention_init(jax.random.PRNGKey(0), acfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 300, 32))
-    out = attention_apply(params, acfg, x)
-    assert np.isfinite(np.asarray(out)).all()
-    # i=300, target 256 -> pick_block(300, 256): largest 128-multiple
-    # within padding tolerance of the best
-    from alphafold2_tpu.ops.flash_kernel import pick_block
-
-    assert captured["kernel_qb"] == pick_block(300, target=256)

@@ -7,10 +7,11 @@ Three layers of pinning:
     arm, over f32/bf16 and at least one PADDED shape (not a block
     multiple). These are the tests the af2lint `dispatch` pass requires
     every op to register — an op without one fails CI.
-  * **Resolution semantics** — the ONE resolver's contract: caller
-    forcing, AF2_KERNEL_BACKEND global/per-op overrides, legacy knob
-    adaptation, loud errors on unknown arms / unsupported shapes, and
-    the introspection CLI output.
+  * **Resolution semantics** — the ONE resolver's contract: the
+    decision table of the attention core (`test_attention_core_choice`),
+    caller forcing, the AF2_KERNEL_BACKEND global/per-op overrides (the
+    one override channel), loud errors on unknown arms / unsupported
+    shapes, and the introspection CLI output.
   * **The lint pass itself** — fires on fixture violations (missing
     xla_ref arm, unregistered parity test, kernel import outside ops/,
     AF2_* env read outside knobs.py) and stays silent on this repo.
@@ -49,10 +50,7 @@ _ALL_BACKEND_ENVS = (
 @pytest.fixture(autouse=True)
 def _clean_backend_env(monkeypatch):
     """No inherited override may leak into resolution asserts."""
-    for name in _ALL_BACKEND_ENVS + ["AF2_QUANT_KERNEL",
-                                     "AF2_DISABLE_FLASH_KERNEL",
-                                     "AF2_DISABLE_QUANT_KERNEL",
-                                     "AF2_FLASH_AUTO_MIN_J"]:
+    for name in _ALL_BACKEND_ENVS:
         monkeypatch.delenv(name, raising=False)
     yield
 
@@ -78,7 +76,7 @@ def _qkv(B, i, j, h, dh, dtype, seed=0):
 def test_parity_flash_attention(monkeypatch, dtype, i, j):
     q, k, v, bias = _qkv(2, i, j, 2, 8, dtype)
     outs = {}
-    for arm in ("pallas_tpu", "xla_ref", "gpu"):
+    for arm in ("pallas_tpu", "xla_ref"):
         monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", arm)
         assert dispatch.resolve("flash_attention", request="auto",
                                 i=i, j=j, dh=8) == arm
@@ -88,8 +86,6 @@ def test_parity_flash_attention(monkeypatch, dtype, i, j):
     atol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(outs["pallas_tpu"], outs["xla_ref"],
                                atol=atol)
-    # the gpu arm is the XLA streaming path: exact vs xla_ref
-    np.testing.assert_allclose(outs["gpu"], outs["xla_ref"], atol=1e-6)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -305,7 +301,9 @@ def test_registry_shape():
                               "merge_lse", "grouped_matmul")
     for op in dispatch.ops():
         spec = dispatch.get(op)
-        assert "xla_ref" in spec.arm_names()
+        # two arms an op: the kernel, and the reference every platform
+        # that is not a TPU resolves to
+        assert spec.arm_names() == ("pallas_tpu", "xla_ref")
         assert spec.parity_test.startswith("test_parity_")
     with pytest.raises(ValueError, match="unknown dispatch op"):
         dispatch.get("nonesuch")
@@ -373,35 +371,92 @@ def test_env_forcing_unsupported_shape_raises(monkeypatch):
                          i=16, j=16, dh=7)
 
 
-# the shapes the training cell's trunk resolves (config 5, crop 384):
-# the pair stream's axial self-attention, the two aligned crosses, and
-# the serving engine's largest bucket
+# The decision table of "which attention core runs", in one place. A row
+# is (platform, env, op, shapes) -> (arm, form): the arm is what
+# ops/dispatch.py resolves, the form what ops/flash_kernel.py makes of
+# the shape (`rows_plan` / `causal_plan`; None where the kernel does not
+# run or the op has one form). Shapes: the training cell's trunk (config
+# 5, crop 384: the pair stream's axial self-attention, the two aligned
+# crosses), the serving engine's largest bucket, the dispatcher's probe,
+# and the decoder cell's causal core (32 heads of 192 / 128).
 _PAIR_AXIAL = dict(i=1152, j=1152, dh=64)
+_PROBE = dict(i=1152, j=4096, dh=64)
+_CAUSAL_8K = dict(i=8192, j=8192, dh=192, dv=128, causal=True)
+_QUANT = dict(m=4096, k=512, n=512, x_dtype="float32")
+_CELL_PLAN = {"g": 2, "qb": 1024, "kb": 256, "tiles": 36}
 _BELOW_CROSSOVER = [dict(i=3456, j=32, dh=64), dict(i=128, j=864, dh=64),
                     dict(i=384, j=384, dh=64)]
 
 
-@pytest.mark.parametrize("op", ["flash_attention", "fused_attention",
-                                "merge_lse"])
-def test_auto_takes_pallas_from_the_measured_crossover_up(op):
-    assert knobs.FLASH_AUTO_MIN_J_DEFAULT == 1152
-    assert dispatch.resolve(op, platform="tpu", **_PAIR_AXIAL) == "pallas_tpu"
-    assert dispatch.resolve(op, platform="tpu", i=1152, j=1151,
-                            dh=64) == "xla_ref"
+def _row(platform, op, shapes, arm, form=None, env=None):
+    tag = "-".join(f"{k}{v}" for k, v in shapes.items()
+                   if k in ("i", "j", "dh", "n"))
+    return pytest.param(platform, env, op, shapes, arm, form,
+                        id=f"{platform}{'-off' if env else ''}-{op}-{tag}")
 
 
-@pytest.mark.parametrize("shapes", _BELOW_CROSSOVER,
-                         ids=lambda s: f"{s['i']}x{s['j']}")
-def test_auto_keeps_xla_below_the_crossover(shapes):
-    assert dispatch.resolve("flash_attention", platform="tpu",
-                            **shapes) == "xla_ref"
+_CORE_CHOICE = [
+    # the measured path of train_e2e: the whole-row form
+    _row("tpu", "flash_attention", _PAIR_AXIAL, "pallas_tpu", "whole-row"),
+    # past the whole-row form's 2048 keys the kernel streams
+    _row("tpu", "flash_attention", _PROBE, "pallas_tpu", "streaming"),
+    # the measured path of train_lm_moe_8k: the triangular grid
+    _row("tpu", "flash_attention", _CAUSAL_8K, "pallas_tpu", _CELL_PLAN),
+    # a row whose resident dq does not fit (past 14 336 at 192 / 128)
+    _row("tpu", "flash_attention", dict(_CAUSAL_8K, i=16384, j=16384),
+         "xla_ref"),
+    # a head size off the sublanes: `supported` says no
+    _row("tpu", "flash_attention", dict(i=1152, j=1152, dh=60), "xla_ref"),
+    _row("tpu", "fused_attention", _PAIR_AXIAL, "pallas_tpu"),
+    _row("tpu", "merge_lse", _PAIR_AXIAL, "pallas_tpu"),
+    # one key short of the measured crossover
+    *[_row("tpu", op, dict(i=1152, j=1151, dh=64), "xla_ref")
+      for op in ("flash_attention", "fused_attention", "merge_lse")],
+    *[_row("tpu", "flash_attention", s, "xla_ref") for s in _BELOW_CROSSOVER],
+    *[_row("cpu", "flash_attention", s, "xla_ref")
+      for s in [_PAIR_AXIAL] + _BELOW_CROSSOVER],
+    # a platform that is not a TPU has the reference arm, whatever it is
+    *[_row(platform, op, shapes, "xla_ref")
+      for platform in ("gpu", "cuda")
+      for op, shapes in (("flash_attention", _PROBE),
+                         ("quant_matmul", _QUANT))],
+    # "no Pallas anywhere", at shapes where auto takes the kernel
+    *[_row("tpu", op, shapes, "xla_ref", env={"AF2_KERNEL_BACKEND": "off"})
+      for op, shapes in (("flash_attention", _PROBE),
+                         ("fused_attention", _PROBE),
+                         ("quant_matmul", _QUANT),
+                         ("sparse_attention", dict(n=8192)),
+                         ("merge_lse", _PAIR_AXIAL),
+                         ("grouped_matmul",
+                          dict(m=32768, k=2048, n=768, groups=16)))],
+]
 
 
-@pytest.mark.parametrize("shapes", [_PAIR_AXIAL] + _BELOW_CROSSOVER,
-                         ids=lambda s: f"{s['i']}x{s['j']}")
-def test_auto_is_xla_everywhere_on_cpu(shapes):
-    assert dispatch.resolve("flash_attention", platform="cpu",
-                            **shapes) == "xla_ref"
+def _kernel_form(op, arm, s, h):
+    """What ops/flash_kernel.py makes of a dense or causal call the
+    kernel arm took."""
+    from alphafold2_tpu.ops import flash_kernel
+
+    if op != "flash_attention" or arm != "pallas_tpu":
+        return None
+    if s.get("causal"):
+        plan = flash_kernel.causal_plan(s["i"], h, s["dh"], s["dv"])
+        return {k: v for k, v in plan._asdict().items() if k != "vmem"}
+    return ("streaming" if flash_kernel.rows_plan(s["i"], s["j"], h, s["dh"])
+            is None else "whole-row")
+
+
+@pytest.mark.parametrize("platform,env,op,shapes,arm,form", _CORE_CHOICE)
+def test_attention_core_choice(monkeypatch, platform, env, op, shapes, arm,
+                               form):
+    if env:  # the row means something: without it auto takes the kernel
+        assert dispatch.resolve(op, platform=platform,
+                                **shapes) == "pallas_tpu"
+        for name, value in env.items():
+            monkeypatch.setenv(name, value)
+    assert dispatch.resolve(op, platform=platform, **shapes) == arm
+    heads = 32 if shapes.get("causal") else 8
+    assert _kernel_form(op, arm, shapes, heads) == form
 
 
 def test_decisions_tally_counts_what_a_traced_trunk_resolved():
@@ -448,8 +503,6 @@ def test_auto_heuristics_per_platform():
                             **long_j) == "pallas_tpu"
     assert dispatch.resolve("flash_attention", platform="tpu",
                             **short_j) == "xla_ref"  # measured crossover
-    assert dispatch.resolve("flash_attention", platform="gpu",
-                            **long_j) == "gpu"
     assert dispatch.resolve("flash_attention", platform="cpu",
                             **long_j) == "xla_ref"
     assert dispatch.resolve("sparse_attention", platform="tpu",
@@ -458,37 +511,27 @@ def test_auto_heuristics_per_platform():
                             n=2048) == "xla_ref"
     assert dispatch.resolve("quant_matmul", platform="tpu", m=64, k=64,
                             n=64, x_dtype=jnp.float32) == "pallas_tpu"
-    assert dispatch.resolve("quant_matmul", platform="gpu", m=64, k=64,
-                            n=64, x_dtype=jnp.float32) == "gpu"
+    assert dispatch.resolve("quant_matmul", platform="cpu", m=64, k=64,
+                            n=64, x_dtype=jnp.float32) == "xla_ref"
 
 
 def test_kill_switches_still_downgrade_auto(monkeypatch):
+    """The one kill-switch is `off` on the override channel: global for
+    every op, per-op for one; a caller's forcing still wins."""
     long_j = dict(i=1152, j=4096, dh=64)
-    monkeypatch.setenv("AF2_DISABLE_FLASH_KERNEL", "1")
+    quant = dict(m=64, k=64, n=64, x_dtype=jnp.float32)
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", "off")
     assert dispatch.resolve("flash_attention", platform="tpu",
                             **long_j) == "xla_ref"
+    assert dispatch.resolve("quant_matmul", platform="tpu",
+                            **quant) == "pallas_tpu"  # per-op: the others stay
+    monkeypatch.setenv("AF2_KERNEL_BACKEND", "off")
     assert dispatch.resolve("sparse_attention", platform="tpu",
                             n=8192) == "xla_ref"
-    monkeypatch.setenv("AF2_DISABLE_QUANT_KERNEL", "1")
-    assert dispatch.resolve("quant_matmul", platform="tpu", m=64, k=64,
-                            n=64, x_dtype=jnp.float32) == "xla_ref"
-    # forcing still wins over the kill-switch
+    assert dispatch.resolve("quant_matmul", platform="tpu",
+                            **quant) == "xla_ref"
     assert dispatch.resolve("flash_attention", request=True,
                             platform="cpu", i=16, j=16, dh=8) == "pallas_tpu"
-
-
-def test_legacy_quant_knob_adapts(monkeypatch):
-    shapes = dict(m=8, k=16, n=8, x_dtype=jnp.float32)
-    monkeypatch.setenv("AF2_QUANT_KERNEL", "force")
-    assert dispatch.resolve("quant_matmul", platform="cpu",
-                            **shapes) == "pallas_tpu"
-    monkeypatch.setenv("AF2_QUANT_KERNEL", "off")
-    assert dispatch.resolve("quant_matmul", platform="tpu",
-                            **shapes) == "xla_ref"
-    # the new knob outranks the legacy one
-    monkeypatch.setenv("AF2_KERNEL_BACKEND_QUANT_MATMUL", "pallas_tpu")
-    assert dispatch.resolve("quant_matmul", platform="tpu",
-                            **shapes) == "pallas_tpu"
 
 
 def test_resolution_tag_and_table(monkeypatch):
@@ -515,9 +558,11 @@ def test_resolution_tag_and_table(monkeypatch):
 def test_check_cli_output_pinned(capsys):
     assert dispatch.main(["--check", "--platform", "cpu"]) == 0
     out = capsys.readouterr().out
-    assert "kernel dispatch registry @ platform=cpu" in out
+    assert out.startswith("kernel dispatch registry @ platform=cpu\n")
     for op in dispatch.ops():
         assert op in out
+    # two arms a row, the same two for every op
+    assert out.count("pallas_tpu=yes xla_ref=yes  -> ") == len(dispatch.ops())
     assert out.count("-> xla_ref") == len(dispatch.ops())
     assert "tag: dispatch[cpu](" in out
 
@@ -528,37 +573,46 @@ def test_check_cli_output_pinned(capsys):
 
 
 def test_knob_strict_values(monkeypatch):
-    monkeypatch.setenv("AF2_DISABLE_FLASH_KERNEL", "flase")  # the typo
-    with pytest.raises(ValueError, match="AF2_DISABLE_FLASH_KERNEL"):
-        knobs.flash_kernel_disabled()
-    monkeypatch.setenv("AF2_DISABLE_FLASH_KERNEL", "0")
-    assert not knobs.flash_kernel_disabled()
-    monkeypatch.setenv("AF2_DISABLE_FLASH_KERNEL", "yes")
-    assert knobs.flash_kernel_disabled()
-    monkeypatch.setenv("AF2_FLASH_AUTO_MIN_J", "many")
-    with pytest.raises(ValueError, match="AF2_FLASH_AUTO_MIN_J"):
-        knobs.flash_auto_min_j()
-    monkeypatch.delenv("AF2_FLASH_AUTO_MIN_J")
-    assert knobs.flash_auto_min_j() == knobs.FLASH_AUTO_MIN_J_DEFAULT
-    monkeypatch.setenv("AF2_QUANT_KERNEL", "bogus")
-    with pytest.raises(ValueError, match="AF2_QUANT_KERNEL"):
-        knobs.quant_kernel_override()
+    monkeypatch.setenv("AF2_COMM_OVERLAP", "flase")  # the typo
+    with pytest.raises(ValueError, match="AF2_COMM_OVERLAP"):
+        knobs.comm_overlap_enabled()
     monkeypatch.setenv("AF2_COMM_OVERLAP", "off")
     assert not knobs.comm_overlap_enabled()
     monkeypatch.delenv("AF2_COMM_OVERLAP")
     assert knobs.comm_overlap_enabled()  # default ON
+    monkeypatch.setenv("AF2_AUTO_INIT", "yes")
+    assert knobs.auto_init()
+    monkeypatch.setenv("AF2_NUM_PROCESSES", "many")
+    with pytest.raises(ValueError, match="AF2_NUM_PROCESSES"):
+        knobs.num_processes()
+    monkeypatch.setenv("AF2_PALLAS_INTERPRET", "maybe")
+    with pytest.raises(ValueError, match="AF2_PALLAS_INTERPRET"):
+        knobs.pallas_interpret_override()
+    # the override channel hands an arm name on verbatim: the dispatcher
+    # is what knows the arms, and refuses a name it does not have
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_QUANT_MATMUL", "Force")
+    assert knobs.kernel_backend_override("quant_matmul") == "force"
+    with pytest.raises(ValueError, match="unknown backend arm"):
+        dispatch.resolve("quant_matmul", platform="cpu", m=8, k=16, n=8,
+                         x_dtype=jnp.float32)
 
 
 def test_knob_registry_covers_every_accessor():
-    names = {k.name for k in knobs.KNOBS}
-    for expected in ("AF2_KERNEL_BACKEND", "AF2_KERNEL_BACKEND_<OP>",
-                     "AF2_DISABLE_FLASH_KERNEL", "AF2_DISABLE_QUANT_KERNEL",
-                     "AF2_FLASH_AUTO_MIN_J", "AF2_QUANT_KERNEL",
-                     "AF2_UNFUSE_GATE_EPILOGUE", "AF2_PALLAS_INTERPRET",
-                     "AF2_COMM_OVERLAP", "AF2_COORDINATOR",
-                     "AF2_NUM_PROCESSES", "AF2_PROCESS_ID",
-                     "AF2_AUTO_INIT"):
-        assert expected in names, expected
+    """`KNOBS` is the eight names the package reads, no more: every
+    AF2_* name that ops/knobs.py mentions at all (an accessor's read, a
+    docstring) is one of them, or a per-op spelling of the override."""
+    import inspect
+    import re
+
+    names = [k.name for k in knobs.KNOBS]
+    assert names == ["AF2_KERNEL_BACKEND", "AF2_KERNEL_BACKEND_<OP>",
+                     "AF2_PALLAS_INTERPRET", "AF2_COMM_OVERLAP",
+                     "AF2_COORDINATOR", "AF2_NUM_PROCESSES",
+                     "AF2_PROCESS_ID", "AF2_AUTO_INIT"]
+    mentioned = set(re.findall(r"AF2_[A-Z_]*[A-Z]", inspect.getsource(knobs)))
+    stray = {n for n in mentioned
+             if n not in names and not n.startswith("AF2_KERNEL_BACKEND_")}
+    assert not stray, stray
 
 
 def test_knob_table_in_docs_is_generated():

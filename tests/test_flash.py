@@ -60,53 +60,6 @@ def test_blockwise_matches_dense(B, i, j, tile_elems, kv_block):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-@pytest.mark.parametrize("kv_block", [2048, 16])  # single-shot + streamed
-def test_blockwise_compute_dtype_logits(kv_block):
-    """bf16 score/probability materialization (the streaming path's HBM
-    traffic halver): same math within bf16 rounding, masked keys still
-    exactly excluded, fully-masked rows still zero."""
-    B, i, j, h, dh = 4, 32, 48, 2, 8
-    ks = jax.random.split(jax.random.PRNGKey(1), 4)
-    q = jax.random.normal(ks[0], (B, i, h, dh), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (B, j, h, dh), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (B, j, h, dh), jnp.bfloat16)
-    mask = jax.random.bernoulli(ks[3], 0.7, (B, j))
-    mask = mask.at[:, 0].set(True)
-    mask = mask.at[0].set(False)  # one fully-masked batch row
-    bias = jnp.where(mask, 0.0, float("-inf")).astype(jnp.float32)
-
-    run = lambda ldt: jax.jit(
-        lambda q, k, v, b: blockwise_attention(
-            q, k, v, b, scale=dh**-0.5, kv_block=kv_block,
-            logit_dtype=ldt,
-        )
-    )(q, k, v, bias)
-    f32 = np.asarray(run(None), np.float32)
-    b16 = np.asarray(run(jnp.bfloat16), np.float32)
-    assert np.isfinite(b16).all()
-    # fully-masked row exact zeros in both
-    assert (b16[0] == 0).all() and (f32[0] == 0).all()
-    # bf16-rounding-level agreement on the rest
-    np.testing.assert_allclose(b16[1:], f32[1:], atol=0.04, rtol=0.04)
-
-    # gradients flow and agree to the same order
-    def loss(ldt):
-        def f(q, k, v):
-            return jnp.sum(
-                blockwise_attention(
-                    q, k, v, bias, scale=dh**-0.5, kv_block=kv_block,
-                    logit_dtype=ldt,
-                ).astype(jnp.float32) ** 2
-            )
-        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    gf = loss(None)
-    gb = loss(jnp.bfloat16)
-    for a, b in zip(gf, gb):
-        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        assert np.isfinite(b).all()
-        np.testing.assert_allclose(b, a, atol=0.12, rtol=0.12)
-
-
 @pytest.mark.slow
 def test_blockwise_gradients_match_dense():
     B, i, j, h, dh = 4, 24, 40, 2, 8
@@ -300,15 +253,15 @@ def test_batch_chunked_attention_matches_dense():
 
 
 def test_kernel_disable_env_var(monkeypatch):
-    """AF2_DISABLE_FLASH_KERNEL downgrades auto-dispatch to XLA streaming
-    (bench.py's retry path when a kernel compile regresses on chip).
+    """`AF2_KERNEL_BACKEND=off` downgrades auto-dispatch to XLA streaming
+    (the way out when a kernel compile regresses on chip).
 
     Off-TPU the auto path never reaches the kernel, so the TPU platform
     gate is faked: the negative control (no env var -> kernel invoked)
     proves the fake actually routes to the kernel, making the env-var
     branch non-vacuous."""
     import alphafold2_tpu.ops.flash as flash_mod
-    from alphafold2_tpu.ops import flash_kernel
+    from alphafold2_tpu.ops import dispatch, flash_kernel
 
     calls = []
 
@@ -322,9 +275,11 @@ def test_kernel_disable_env_var(monkeypatch):
     monkeypatch.setattr(flash_mod.jax, "devices", lambda: [FakeTpu()])
     monkeypatch.setattr(flash_kernel, "flash_attention_tpu", spy_kernel)
     monkeypatch.setattr(flash_kernel, "supported", lambda *a: True)
-    # short-j auto-dispatch prefers XLA streaming (measured crossover, see
-    # _AUTO_MIN_J); zero the threshold so these tiny shapes reach the kernel
-    monkeypatch.setenv("AF2_FLASH_AUTO_MIN_J", "0")
+    # short-j auto-dispatch prefers XLA streaming (the measured crossover);
+    # zero it here so these tiny shapes reach the kernel
+    monkeypatch.setattr(dispatch, "_FLASH_KERNEL_MIN_J", 0)
+    for name in ("AF2_KERNEL_BACKEND", "AF2_KERNEL_BACKEND_FLASH_ATTENTION"):
+        monkeypatch.delenv(name, raising=False)
 
     from alphafold2_tpu.ops.flash import flash_attention
 
@@ -337,14 +292,14 @@ def test_kernel_disable_env_var(monkeypatch):
     flash_attention(q, k, v, use_kernel="auto")
     assert calls == ["kernel"]
 
-    # env var set -> auto downgrades to XLA streaming, kernel untouched
-    monkeypatch.setenv("AF2_DISABLE_FLASH_KERNEL", "1")
+    # off -> auto downgrades to XLA streaming, kernel untouched
+    monkeypatch.setenv("AF2_KERNEL_BACKEND", "off")
     out = flash_attention(q, k, v, use_kernel="auto")
     assert calls == ["kernel"]
     assert np.isfinite(np.asarray(out)).all()
 
-    # "0"/"false" mean NOT disabled
-    monkeypatch.setenv("AF2_DISABLE_FLASH_KERNEL", "0")
+    # a per-op "auto" restores the heuristic under the global switch
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", "auto")
     flash_attention(q, k, v, use_kernel="auto")
     assert calls == ["kernel", "kernel"]
 
@@ -356,32 +311,36 @@ def test_kernel_auto_min_j_heuristic(monkeypatch):
     leaves the short crosses to XLA streaming. use_kernel=True still
     forces it."""
     import alphafold2_tpu.ops.flash as flash_mod
-    from alphafold2_tpu.ops import flash_kernel
-    from alphafold2_tpu.ops.flash import kernel_dispatch
+    from alphafold2_tpu.ops import dispatch, flash_kernel
 
     class FakeTpu:
         platform = "tpu"
 
     monkeypatch.setattr(flash_mod.jax, "devices", lambda: [FakeTpu()])
     monkeypatch.setattr(flash_kernel, "supported", lambda *a: True)
-    # an inherited override (e.g. a shell that exported the sweep's
-    # force-kernel setting) must not leak into the default-threshold asserts
-    monkeypatch.delenv("AF2_FLASH_AUTO_MIN_J", raising=False)
+    # an inherited override must not leak into the heuristic's asserts
+    for name in ("AF2_KERNEL_BACKEND", "AF2_KERNEL_BACKEND_FLASH_ATTENTION"):
+        monkeypatch.delenv(name, raising=False)
 
-    # default threshold: short-j auto -> streaming; from the pair
-    # stream's axial shape up -> kernel
-    assert flash_mod._AUTO_MIN_J == 1152
-    assert not kernel_dispatch(128, 864, 64, "auto")
-    assert not kernel_dispatch(3456, 32, 64, "auto")
-    assert not kernel_dispatch(1152, flash_mod._AUTO_MIN_J - 1, 64, "auto")
-    assert kernel_dispatch(1152, flash_mod._AUTO_MIN_J, 64, "auto")
-    assert kernel_dispatch(1152, 4096, 64, "auto")
+    def takes_kernel(i, j, dh, use_kernel):
+        return dispatch.resolve("flash_attention", request=use_kernel,
+                                i=i, j=j, dh=dh) == dispatch.ARM_PALLAS_TPU
+
+    # short-j auto -> streaming; from the pair stream's axial shape up
+    # -> kernel
+    min_j = dispatch._FLASH_KERNEL_MIN_J
+    assert min_j == 1152
+    assert not takes_kernel(128, 864, 64, "auto")
+    assert not takes_kernel(3456, 32, 64, "auto")
+    assert not takes_kernel(1152, min_j - 1, 64, "auto")
+    assert takes_kernel(1152, min_j, 64, "auto")
+    assert takes_kernel(1152, 4096, 64, "auto")
     # forcing bypasses the heuristic at any shape
-    assert kernel_dispatch(16, 16, 8, True)
-    # env override re-admits short-j (the sweep's kernel-on legs)
-    monkeypatch.setenv("AF2_FLASH_AUTO_MIN_J", "0")
-    assert kernel_dispatch(128, 864, 64, "auto")
-    # malformed override fails loudly, not silently-default
-    monkeypatch.setenv("AF2_FLASH_AUTO_MIN_J", "many")
+    assert takes_kernel(16, 16, 8, True)
+    # the override channel re-admits short-j
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", "pallas_tpu")
+    assert takes_kernel(128, 864, 64, "auto")
+    # a name that is no arm fails loudly, not silently-default
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", "many")
     with pytest.raises(ValueError):
-        flash_mod.auto_min_j()
+        takes_kernel(128, 864, 64, "auto")

@@ -155,59 +155,6 @@ def test_flash_attention_dispatch_fused_kernel_vs_xla():
         )
 
 
-def test_unfuse_gate_epilogue_control_arm(monkeypatch):
-    # AF2_UNFUSE_GATE_EPILOGUE (the fused_gate_off sweep arm): same
-    # use_kernel policy for the attention core, gate as a separate XLA
-    # epilogue — must match the fused path's math exactly (the A/B's
-    # whole premise), and must NOT reroute the pair-bias mode (which
-    # cannot unfuse: the bias shapes the softmax)
-    B, i, j, h, dh = 2, 24, 40, 2, 8
-    ks = jax.random.split(jax.random.PRNGKey(3), 5)
-    q, k, v, gate = (
-        jax.random.normal(kk, (B, n, h, dh))
-        for kk, n in zip(ks[:4], (i, j, j, i))
-    )
-    key_bias = jnp.where(
-        jax.random.bernoulli(ks[4], 0.85, (B, j)), 0.0, -jnp.inf
-    ).astype(jnp.float32)
-    fused = flash_attention(q, k, v, key_bias, gate=gate, use_kernel=True)
-    monkeypatch.setenv("AF2_UNFUSE_GATE_EPILOGUE", "1")
-    unfused = flash_attention(q, k, v, key_bias, gate=gate, use_kernel=True)
-    np.testing.assert_allclose(
-        np.asarray(unfused), np.asarray(fused), atol=2e-5
-    )
-    # the unfused arm really is plain-kernel + epilogue
-    from alphafold2_tpu.ops.flash import apply_output_gate
-
-    want = apply_output_gate(
-        flash_attention(q, k, v, key_bias, use_kernel=True), gate
-    )
-    np.testing.assert_allclose(np.asarray(unfused), np.asarray(want))
-
-
-def test_streamed_pair_bias_honors_logit_dtype():
-    # the XLA pair-bias fallback must HONOR logit_dtype, not silently run
-    # f32 (the kernel branch raises for the same knob): bf16 tiles agree
-    # to rounding with f32 but are not bitwise-identical
-    B, i, j, h, dh = 1, 16, 2100, 2, 8
-    ks = jax.random.split(jax.random.PRNGKey(4), 4)
-    q, k, v = (
-        jax.random.normal(kk, (B, n, h, dh))
-        for kk, n in zip(ks[:3], (i, j, j))
-    )
-    pair_bias = jax.random.normal(ks[3], (B, h, i, j)) * 0.5
-
-    def run(ldt):
-        return np.asarray(flash_attention(
-            q, k, v, pair_bias=pair_bias, use_kernel=False,
-            logit_dtype=ldt,
-        ), np.float32)
-
-    f32, b16 = run(None), run(jnp.bfloat16)
-    np.testing.assert_allclose(b16, f32, atol=0.04, rtol=0.04)
-    assert (b16 != f32).any()  # the knob actually changed the math
-
-
 def test_gated_attention_apply_paths_agree():
     # cfg.gate at the attention-op level: dense, flash-XLA, and
     # batch-chunked paths agree on VALID rows (masked query rows keep the

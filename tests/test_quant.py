@@ -176,23 +176,35 @@ def test_forced_kernel_on_unsupported_dtype_raises():
 
 
 def test_env_overrides_route_auto_dispatch(monkeypatch):
-    # AF2_QUANT_KERNEL=force must take the kernel even off-TPU;
-    # "off" and the kill-switch must take the XLA arm; both arms agree
-    # numerically so route is asserted via the dispatch resolver
-    from alphafold2_tpu.ops.quant import quant_dispatch
+    # AF2_KERNEL_BACKEND_QUANT_MATMUL=pallas_tpu must take the kernel even
+    # off-TPU, "off" the XLA arm; both arms agree numerically, so the
+    # route `quant_matmul` itself took is read from the dispatcher's tally
+    from alphafold2_tpu.ops import dispatch
 
-    monkeypatch.setenv("AF2_QUANT_KERNEL", "force")
-    assert quant_dispatch(8, 16, 8, jnp.float32, "auto") is True
-    monkeypatch.setenv("AF2_QUANT_KERNEL", "off")
-    assert quant_dispatch(8, 16, 8, jnp.float32, "auto") is False
-    monkeypatch.setenv("AF2_QUANT_KERNEL", "bogus")
-    with pytest.raises(ValueError, match="AF2_QUANT_KERNEL"):
-        quant_dispatch(8, 16, 8, jnp.float32, "auto")
-    monkeypatch.delenv("AF2_QUANT_KERNEL")
-    monkeypatch.setenv("AF2_DISABLE_QUANT_KERNEL", "1")
-    assert quant_dispatch(8, 16, 8, jnp.float32, "auto") is False
-    # explicit use_kernel wins over the kill-switch (forcing is loud)
-    assert quant_dispatch(8, 16, 8, jnp.float32, True) is True
+    q, s = quantize_weight(_rand_w((16, 8)))
+    x = jnp.ones((8, 16))
+
+    def route(**kw):
+        dispatch.reset_decisions()
+        quant_matmul(x, q, s, **kw)
+        return [entry.split(" @ ")[0] for entry in dispatch.decisions()]
+
+    for name in ("AF2_KERNEL_BACKEND", "AF2_KERNEL_BACKEND_QUANT_MATMUL"):
+        monkeypatch.delenv(name, raising=False)
+    assert route() == ["quant_matmul -> xla_ref"]  # this host's heuristic
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_QUANT_MATMUL", "pallas_tpu")
+    assert route() == ["quant_matmul -> pallas_tpu"]
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_QUANT_MATMUL", "off")
+    assert route() == ["quant_matmul -> xla_ref"]
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_QUANT_MATMUL", "bogus")
+    with pytest.raises(ValueError, match="unknown backend arm 'bogus'"):
+        route()
+    # explicit use_kernel wins over the switch (forcing is loud) and is
+    # no decision of the dispatcher's
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_QUANT_MATMUL", "off")
+    assert route(use_kernel=True) == []
+    assert dispatch.resolve("quant_matmul", request=True, m=8, k=16, n=8,
+                            x_dtype=jnp.float32) == "pallas_tpu"
 
 
 def test_backward_through_quant_matmul_raises():

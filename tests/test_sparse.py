@@ -230,9 +230,10 @@ def test_pallas_kernel_grads_with_fully_masked_rows():
 
 
 def test_sparse_kernel_disable_env_var(monkeypatch):
-    """AF2_DISABLE_FLASH_KERNEL downgrades the sparse auto-dispatch too
-    (bench.py's kernel-off retry must leave no Pallas in the program).
-    Platform and length gates are faked open so only the env var decides;
+    """`AF2_KERNEL_BACKEND=off` downgrades the sparse auto-dispatch too
+    ("no Pallas anywhere" must leave none in the program), and the per-op
+    spelling alone does as well. The platform gate is faked open and the
+    length passes its crossover, so only the env var decides;
     the negative control proves the fake routes to the kernel."""
     import alphafold2_tpu.ops.sparse as sparse_mod
     from alphafold2_tpu.ops import sparse_kernel
@@ -248,7 +249,8 @@ def test_sparse_kernel_disable_env_var(monkeypatch):
     class FakeTpu:
         platform = "tpu"
 
-    monkeypatch.delenv("AF2_DISABLE_FLASH_KERNEL", raising=False)
+    for name in ("AF2_KERNEL_BACKEND", "AF2_KERNEL_BACKEND_SPARSE_ATTENTION"):
+        monkeypatch.delenv(name, raising=False)
     monkeypatch.setattr(sparse_mod.jax, "devices", lambda: [FakeTpu()])
     # sparse.py imports the kernel inside the function at call time, so
     # patching the source module intercepts it
@@ -266,10 +268,14 @@ def test_sparse_kernel_disable_env_var(monkeypatch):
     sparse_mod.sparse_attention_apply(params, cfg, scfg, x)
     assert calls == ["kernel"]
 
-    monkeypatch.setenv("AF2_DISABLE_FLASH_KERNEL", "1")
+    monkeypatch.setenv("AF2_KERNEL_BACKEND", "off")
     sparse_mod.sparse_attention_apply(params, cfg, scfg, x)
     assert calls == ["kernel"]  # kernel NOT invoked again
 
-    monkeypatch.setenv("AF2_DISABLE_FLASH_KERNEL", "false")
+    monkeypatch.setenv("AF2_KERNEL_BACKEND", "auto")
     sparse_mod.sparse_attention_apply(params, cfg, scfg, x)
-    assert calls == ["kernel", "kernel"]  # "false" means enabled
+    assert calls == ["kernel", "kernel"]  # "auto" is the heuristic again
+
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_SPARSE_ATTENTION", "off")
+    sparse_mod.sparse_attention_apply(params, cfg, scfg, x)
+    assert calls == ["kernel", "kernel"]

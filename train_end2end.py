@@ -311,8 +311,7 @@ def main():
     telemetry = build_train_telemetry(
         args, registry=registry, tracer=tracer, logger=logger,
         # pair side is the x3-elongated backbone; MSA columns stay at the
-        # CROP length (data.py builds msa as (b, rows, max_len) — same
-        # accounting as scripts/bench_decompose.py)
+        # CROP length (data.py builds msa as (b, rows, max_len))
         step_flops=train_step_flops(
             ecfg.model, 3 * args.max_len,
             args.msa_rows if args.features == "msa" else 0,
