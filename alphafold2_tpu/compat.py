@@ -44,6 +44,7 @@ __all__ = [
     "pallas_tpu",
     "pcast",
     "process_allgather",
+    "row_major",
     "shard_map",
     "sync_global_devices",
     "typeof_vma",
@@ -116,6 +117,18 @@ def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
     hops)."""
     vma = frozenset().union(*(typeof_vma(o) for o in operands))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def row_major(x):
+    """x held to the row-major device layout (last axis minor), the one a
+    Pallas call reads and writes. For a value that reaches a kernel's
+    wrapper from somewhere that pins no layout (a residual read back from
+    a scan's stack): XLA then lays it out for another consumer and copies
+    it, and what is computed from it, back for the kernel."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
 def pcast(x, axis_names, *, to: str = "varying"):

@@ -4,8 +4,9 @@ latent attention (MLA) and a mixture of experts with shared experts.
 Pure init/apply functions over a parameter pytree, as the rest of
 `models/`. Every size comes from `DecoderConfig`, whose keys are the
 published `config.json`'s; the layers are scanned (the leading dense
-layers as one stack, the MoE layers as another), each layer under
-`jax.checkpoint`.
+layers as one stack, the MoE layers as another), each layer under a
+`jax.checkpoint` that keeps the causal kernel's `out` and `lse` for the
+backward pass and builds the rest of the layer again (`_checkpointed_layer`).
 
 The equations are HF `transformers` `deepseek_v3`'s:
 
@@ -44,7 +45,7 @@ import jax.numpy as jnp
 
 from alphafold2_tpu.ops import moe
 from alphafold2_tpu.ops.core import embedding, linear
-from alphafold2_tpu.ops.flash import flash_attention
+from alphafold2_tpu.ops.flash import causal_checkpoint_policy, flash_attention
 from alphafold2_tpu.telemetry.profiling import scope
 
 
@@ -257,12 +258,20 @@ def _layer(lp, h, cfg: DecoderConfig, is_moe: bool):
         return h + y.reshape(B, L, d), aux
 
 
-def _stack(layers, h, cfg, is_moe):
-    @jax.checkpoint
-    def body(h, lp):
-        return _layer(lp, h, cfg, is_moe)
+def _checkpointed_layer(cfg, is_moe):
+    """One layer as the scans run it, (h, layer params) -> (h, aux), under
+    the layer's `jax.checkpoint`. It keeps the causal kernel's `out` and
+    `lse` (a residual stream's width twice over a layer, and what the
+    backward kernel reads besides q, k, v) and builds everything else
+    again in the backward pass, so the core's forward runs once a step.
+    Where the core takes the XLA arm the layer holds no such name and is
+    recomputed whole."""
+    return jax.checkpoint(lambda h, lp: _layer(lp, h, cfg, is_moe),
+                          policy=causal_checkpoint_policy())
 
-    return jax.lax.scan(body, h, layers)
+
+def _stack(layers, h, cfg, is_moe):
+    return jax.lax.scan(_checkpointed_layer(cfg, is_moe), h, layers)
 
 
 def decoder_apply(params, cfg: DecoderConfig, tokens):

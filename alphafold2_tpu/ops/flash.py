@@ -527,3 +527,36 @@ def causal_kernel_plan(n: int, h: int, dh: int, dv: int, dtype) -> dict | None:
 
     plan = flash_kernel.causal_plan(n, h, dh, dv, jnp.dtype(dtype).itemsize)
     return None if plan is None else plan._asdict()
+
+
+def causal_checkpoint_policy():
+    """The `jax.checkpoint` policy that keeps the causal kernel's two
+    results (`flash_kernel.CAUSAL_SAVED_NAMES`: `out` and `lse`, what its
+    backward reads besides q, k, v) and recomputes everything else, so the
+    kernel's forward runs once a step. With the XLA arm the checkpointed
+    function holds no such name and is recomputed whole."""
+    from alphafold2_tpu.ops import flash_kernel
+
+    return jax.checkpoint_policies.save_only_these_names(
+        *flash_kernel.CAUSAL_SAVED_NAMES)
+
+
+def causal_saved_bytes(batch: int, n: int, h: int, dh: int, dv: int,
+                       dtype) -> dict:
+    """{name: bytes} of what a `jax.checkpoint` under
+    `causal_checkpoint_policy` (a layer of models/decoder.py) keeps of one
+    causal core over `batch` sequences: where the kernel is the arm
+    this host resolves for the shape, its `out` (batch, n padded to the
+    block, h * dv) in the operands' dtype and its `lse`, a float32 a
+    (sequence, head, position); with the XLA arm there are no such names
+    and nothing is kept. For a trainer's start-up log (train_lm.py)."""
+    from alphafold2_tpu.ops import dispatch, flash_kernel
+
+    if dispatch._resolve("flash_attention", i=n, j=n, dh=dh, dv=dv,
+                         causal=True) != dispatch.ARM_PALLAS_TPU:
+        return {}
+    itemsize = jnp.dtype(dtype).itemsize
+    qb = flash_kernel.causal_plan(n, h, dh, dv, itemsize).qb
+    rows = batch * -(-n // qb) * qb
+    return dict(zip(flash_kernel.CAUSAL_SAVED_NAMES,
+                    (rows * h * dv * itemsize, rows * h * 4)))

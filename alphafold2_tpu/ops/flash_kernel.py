@@ -1512,13 +1512,31 @@ def _causal_core(q, k, v, scale, dh, plan):
     return _causal_forward(q, k, v, scale, dh, plan)[0]
 
 
+# The names of the forward kernel's two results where they become the
+# backward's residuals. Under a `jax.checkpoint` whose policy saves these
+# names (models/decoder.py) the backward reads the stored values and the
+# recomputation holds no forward call; outside any policy a name is the
+# identity. Imported here and not at the top so that no line above moves:
+# a Mosaic kernel's serialized body carries its call site's line numbers,
+# and the kernels above are other programs' (PERF.md section 6, PR 28).
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
+
+CAUSAL_SAVED_NAMES = ("attn_core_out", "attn_core_lse")
+
+
 def _causal_fwd(q, k, v, scale, dh, plan):
-    out, lse = _causal_forward(q, k, v, scale, dh, plan)
+    out, lse = map(checkpoint_name,
+                   _causal_forward(q, k, v, scale, dh, plan), CAUSAL_SAVED_NAMES)
     return out, (q, k, v, out, lse)
 
 
 def _causal_bwd(scale, dh, plan, res, do):
     q, k, v, out, lse = res
+    # `out` may come from a checkpoint's saved residuals, whose layout no
+    # call pins: left free, XLA lays it out for W_o's gradient and copies
+    # `do`, which `delta`'s fusion then emits the same way, back for the
+    # kernel (two passes of 134 MB a layer at the decoder's widths)
+    out = compat.row_major(out)
     B, n, H = q.shape
     h = H // dh
     dv = v.shape[-1] // h

@@ -105,3 +105,54 @@ def test_causal_kernel_compiles_for_v5e_at_the_decoders_widths(one_chip,
     grad = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args).compile().as_text()
     assert grad.count(CALL) == 2
     assert " transpose(" not in grad and " pad(" not in grad
+
+
+def test_decoder_stack_keeps_the_cores_results_for_v5e(one_chip, compiled_mode,
+                                                       monkeypatch):
+    """The decoder's dense stack at the language-model cell's widths (2 x
+    8192 tokens, hidden 2048, 32 heads of 192 / 128), two layers scanned:
+    its gradient holds TWO calls of the causal core, the forward kernel in
+    the forward scan and the backward kernel in the backward scan. A layer
+    checkpoint that did not keep `out` and `lse` holds three (the forward
+    again in the backward scan). The saved `out` reaches the backward
+    kernel's wrapper and W_o's gradient as the stack's slice: no copy of
+    its shape beyond those of v, dv and the cotangent, which a bare
+    checkpoint has too."""
+    from alphafold2_tpu.models import decoder
+
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", "pallas_tpu")
+    B, n = 2, 8192
+    cfg = decoder.DecoderConfig(
+        vocab_size=128, hidden_size=2048, num_hidden_layers=2,
+        first_k_dense_replace=2, num_attention_heads=32, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
+        intermediate_size=6144, moe_intermediate_size=768, n_routed_experts=128,
+        num_experts_per_tok=6, n_shared_experts=2, routed_scaling_factor=2.448,
+        rope_theta=1e6)
+
+    def sd(t):
+        return jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+
+    layers = jax.tree_util.tree_map(sd, jax.eval_shape(
+        lambda: decoder.decoder_init(jax.random.PRNGKey(0), cfg))["dense"])
+    h = sd(jax.ShapeDtypeStruct((B, n, cfg.hidden_size), jnp.bfloat16))
+
+    def grad_text():
+        def loss(layers, h):
+            out, _ = decoder._stack(layers, h, cfg, False)
+            return jnp.sum(out.astype(jnp.float32))
+
+        return jax.jit(jax.grad(loss, (0, 1))).lower(layers, h).compile().as_text()
+
+    def copies_of_out(text):
+        shape = f"{B},{n},{cfg.num_attention_heads * cfg.v_head_dim}]"
+        return sum(" copy(" in line and shape in line.split(" copy(")[0]
+                   for line in text.splitlines())
+
+    kept = grad_text()
+    monkeypatch.setattr(
+        decoder, "_checkpointed_layer", lambda cfg, is_moe: jax.checkpoint(
+            lambda h, lp: decoder._layer(lp, h, cfg, is_moe)))
+    bare = grad_text()
+    assert (kept.count(CALL), bare.count(CALL)) == (2, 3)
+    assert copies_of_out(kept) <= copies_of_out(bare)

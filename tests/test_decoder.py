@@ -8,10 +8,12 @@ import importlib.util
 import os
 
 import jax
+import jax.ad_checkpoint
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from alphafold2_tpu.models import decoder
 from alphafold2_tpu.models.decoder import (DecoderConfig, decoder_apply,
                                             decoder_init)
 from alphafold2_tpu.ops import moe
@@ -360,3 +362,87 @@ def test_causal_takes_no_bias_and_noncausal_is_untouched():
         flash_attention(q, q, q, jnp.zeros((1, 8)), causal=True, use_kernel=False)
     with pytest.raises(ValueError, match="no pair bias"):
         flash_attention(q, q, q, gate=q, causal=True, use_kernel=False)
+
+
+# --- what the layer checkpoint saves -----------------------------------------
+
+_ARM = "AF2_KERNEL_BACKEND_FLASH_ATTENTION"
+
+
+def _bare_checkpoint(cfg, is_moe):
+    """The layer under a `jax.checkpoint` without a policy, which recomputes
+    the whole layer, the core's forward kernel included."""
+    return jax.checkpoint(lambda h, lp: decoder._layer(lp, h, cfg, is_moe))
+
+
+def _kernel_call_sites(jaxpr, counts=None):
+    """{kernel function: `pallas_call` equations}, the sub-jaxprs of scan,
+    the checkpoint and the custom_vjp included."""
+    from jax._src import core
+
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["jaxpr"].debug_info.func_name
+            counts[name] = counts.get(name, 0) + 1
+        for sub in core.jaxprs_in_params(eqn.params):
+            _kernel_call_sites(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("checkpoint,forward_sites", [("saved_names", 1), ("bare", 2)])
+def test_differentiated_stack_calls_the_forward_kernel_once(monkeypatch, checkpoint,
+                                                            forward_sites):
+    """The two-layer dense stack's gradient with the kernel arm (interpret
+    mode): ONE call site of the forward kernel and one of the backward
+    kernel, since the checkpoint keeps `out` and `lse`. The case this
+    guards is the bare checkpoint's, whose backward scan runs the forward
+    kernel a second time."""
+    monkeypatch.setenv(_ARM, "pallas_tpu")
+    if checkpoint == "bare":
+        monkeypatch.setattr(decoder, "_checkpointed_layer", _bare_checkpoint)
+    cfg = dataclasses.replace(CFG, num_hidden_layers=2, first_k_dense_replace=2)
+    p = decoder_init(jax.random.PRNGKey(1), cfg)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q: lm_loss_fn(q, cfg, {"tokens": _tokens()})[0]))(p)
+    assert _kernel_call_sites(jaxpr.jaxpr) == {
+        "_causal_fwd_kernel": forward_sites, "_causal_bwd_kernel": 1}
+
+
+@pytest.mark.parametrize("arm", ["pallas_tpu", "xla_ref"])
+def test_layer_checkpoint_keeps_the_kernels_results_and_nothing_else(
+        monkeypatch, capsys, params, arm):
+    """With the kernel arm a layer keeps, besides its inputs, the core's
+    `out` (B, L, h * dv) and `lse`; with the XLA arm it holds no such name
+    and keeps nothing. Either way the loss and every gradient leaf are the
+    bare checkpoint's."""
+    monkeypatch.setenv(_ARM, arm)
+    tokens = _tokens()
+    B, L = tokens.shape
+    layer = jax.tree_util.tree_map(lambda t: t[0], params["moe"])
+    jax.ad_checkpoint.print_saved_residuals(
+        decoder._checkpointed_layer(CFG, True),
+        jnp.zeros((B, L, CFG.hidden_size)), layer)
+    kept = [line for line in capsys.readouterr().out.splitlines()
+            if "from the argument" not in line and "from a constant" not in line]
+    if arm == "xla_ref":
+        assert kept == []
+    else:
+        from alphafold2_tpu.ops.flash_kernel import causal_plan
+
+        h, dv = CFG.num_attention_heads, CFG.v_head_dim
+        qb = causal_plan(L, h, CFG.qk_head_dim, dv, itemsize=4).qb
+        assert len(kept) == 2 and all("flash_kernel.py" in line for line in kept)
+        # the kernel's own row length: L padded to its block
+        assert kept[0].startswith(f"f32[{B},{-(-L // qb) * qb},{h * dv}] ")
+        assert "named 'attn_core_lse'" in kept[1]
+
+    def value_and_grad():
+        return jax.jit(jax.value_and_grad(
+            lambda q: lm_loss_fn(q, CFG, {"tokens": tokens})[0]))(params)
+
+    loss, grads = value_and_grad()
+    monkeypatch.setattr(decoder, "_checkpointed_layer", _bare_checkpoint)
+    want, want_grads = value_and_grad()
+    assert abs(float(loss) - float(want)) <= 1e-6 * abs(float(want))
+    assert _worst(grads, want_grads) < 1e-6

@@ -167,17 +167,20 @@ def main():
                 logger.log(step, metrics)
             if step == start:
                 from alphafold2_tpu.ops import dispatch
-                from alphafold2_tpu.ops.flash import causal_kernel_plan
+                from alphafold2_tpu.ops.flash import (causal_kernel_plan,
+                                                      causal_saved_bytes)
 
                 # the arm each call site took, and what the causal kernel
                 # makes of the core's shape where it is the arm: block,
                 # sub-tile, grid steps a row (the tiles on or below the
-                # diagonal)
+                # diagonal), and the two results of it that each layer's
+                # checkpoint keeps for the backward pass, in bytes a layer
+                core = (args.length, cfg.num_attention_heads, cfg.qk_head_dim,
+                        cfg.v_head_dim, cfg.compute_dtype)
                 logger.event(
                     step, "dispatch", decisions=dispatch.decisions(),
-                    causal_kernel_plan=causal_kernel_plan(
-                        args.length, cfg.num_attention_heads, cfg.qk_head_dim,
-                        cfg.v_head_dim, cfg.compute_dtype))
+                    causal_kernel_plan=causal_kernel_plan(*core),
+                    layer_checkpoint_saves=causal_saved_bytes(args.batch, *core))
             telemetry.step_complete(step)
             if step % 10 == 0 or step == start + args.steps - 1:
                 print(f"step {step}  loss {float(metrics['loss']):.4f}  "
