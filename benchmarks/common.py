@@ -172,6 +172,10 @@ def log(*parts):
     print(*parts, file=sys.stderr, flush=True)
 
 
+#: the first phase of every run: imports, the compile cache, the devices
+IMPORTS_PHASE = "imports_and_device"
+
+
 class Setup:
     """Set-up time by phase, from the start of the process."""
 
@@ -191,6 +195,15 @@ class Setup:
     def table(self) -> dict:
         return {name: round(s, 3) for name, s in self.phases}
 
+    def facts(self) -> dict:
+        """`setup_s` (process start -> first measured operation, all of it)
+        and its two halves: importing JAX and the program and taking the
+        chip, which the machine sets, and everything after, which the
+        program can shorten."""
+        held = dict(self.phases).get(IMPORTS_PHASE, 0.0)
+        return {"setup_s": self.total(), "setup_imports_and_device_s": held,
+                "setup_after_device_s": self.total() - held}
+
 
 def judge(compared: dict):
     """`correct` and the printed comparison from {name: (value, limit)}:
@@ -201,6 +214,17 @@ def judge(compared: dict):
         ok = ok and good
         rows[name] = {"value": value, "limit": limit}
     return ok, rows
+
+
+def judge_values(values: dict, limits: dict):
+    """`judge` of the numbers a kind read against the cell's limits
+    (`nonfinite_losses` is held at 0 everywhere); a number the cell's file
+    gives no limit is logged as read, not held (limits/<cell>.json says
+    why no upper reading separates it)."""
+    limits = dict(limits, nonfinite_losses=0.0)
+    for name in sorted(set(values) - set(limits)):
+        log(f"read, not held: {name} = {values[name]}")
+    return judge({k: (values[k], limits[k]) for k in values if k in limits})
 
 
 def finish(result: dict, compared_rows: dict):
@@ -253,6 +277,176 @@ class CompileWatch:
                 f"window; every shape must be warm before it. Failed run.")
 
 
+class Heartbeat:
+    """A thread that sleeps `every` seconds at a time and keeps the longest
+    it overslept since `take()` was last called. A process that was off the
+    CPU for a second (its machine's host busy, the VM paused) oversleeps by
+    that second; one that waited a second for a late device does not."""
+
+    def __init__(self, every: float = 0.01):
+        import threading
+
+        self.every, self.late, self.alive = every, 0.0, True
+        self.lock = threading.Lock()
+        self.thread = threading.Thread(target=self._beat, daemon=True)
+        self.thread.start()
+
+    def _beat(self):
+        while self.alive:
+            t = time.perf_counter()
+            time.sleep(self.every)
+            with self.lock:
+                self.late = max(self.late, time.perf_counter() - t - self.every)
+
+    def take(self) -> float:
+        with self.lock:
+            late, self.late = self.late, 0.0
+        return late
+
+    def stop(self):
+        self.alive = False
+        self.thread.join()
+
+
+#: (name, file, how its text gives seconds) of the kernel's counters of time
+#: lost to the host: `runq`, this thread runnable but waiting for a CPU;
+#: `steal`, the hypervisor running someone else on our CPUs; `pressure`,
+#: some task of the machine stalled for a CPU
+_HOST_WAITS = (
+    ("runq", "/proc/thread-self/schedstat", lambda t: int(t.split()[1]) * 1e-9),
+    ("steal", "/proc/stat",
+     lambda t: int(t.split("\n", 1)[0].split()[8]) / os.sysconf("SC_CLK_TCK")),
+    ("pressure", "/proc/pressure/cpu",
+     lambda t: int(t.split("\n", 1)[0].rsplit("=", 1)[1]) * 1e-6),
+)
+
+
+def host_waits() -> dict:
+    """Seconds the kernel says were lost to the host so far, by
+    `_HOST_WAITS`. A counter this machine's kernel does not offer is left
+    out (the sealed one-chip machine of PR 31 offered none)."""
+    out = {}
+    for name, path, seconds in _HOST_WAITS:
+        try:
+            with open(path) as f:
+                out[name] = seconds(f.read())
+        except (OSError, IndexError, ValueError):
+            pass
+    return out
+
+
+def step_stats(step_times, window_s: float) -> dict:
+    """The window's two quotients. `train_step_s` is the whole window over
+    ALL its steps: what a trainer pays, stalls included.
+    `train_step_less_slowest_s` leaves the window's slowest step out of
+    both the time and the count: the steadier statistic beside it, equal to
+    it to 0.1% on a window whose steps repeat, unmoved by ONE stalled step
+    (two stalls, or a stall in every window, still show in it)."""
+    steps = len(step_times)
+    return {"steps": steps, "window_s": window_s,
+            "train_step_s": window_s / steps,
+            "train_step_less_slowest_s": ((window_s - max(step_times)) / (steps - 1)
+                                          if steps > 1 else None)}
+
+
+def timed_window(ctx, runner, after_traced_step=None) -> dict:
+    """The measured part of a training cell: with `--trace 1` first
+    `trace_steps` steps under the profiler (`bench.window` / `bench.step`),
+    then `runner.step()` until `--seconds` have passed, nothing compiled in
+    either. `runner.step()` returns (its batch, the fetched loss) and keeps
+    `runner.dispatched_at`, the clock when its dispatch returned.
+
+    Every timed step is logged by four clocks, so that a stalled step says
+    where it stalled: `wall` seconds; `cpu`, this process's CPU seconds
+    (`time.process_time`: all threads); `dispatch`, from the step's start
+    to the dispatch's return (the feed and the call); `wait`, from there to
+    the loss on the host (the device, and the way back); `late`, the most
+    the heartbeat overslept in it (the process was off the CPU that long);
+    `runq`, what the kernel says this thread waited for a CPU in it. The
+    window's `host_waits` are logged whole.
+    """
+    import gc
+
+    import jax
+
+    traffic, seconds = ctx["traffic"], ctx["seconds"]
+    watch = CompileWatch()
+    gc.collect()
+    gc.freeze()
+    losses, clocks, traced = [], [], None
+    with watch:
+        if ctx["trace"]:
+            traced = ctx["trace_dir"]
+            jax.profiler.start_trace(traced)
+            with jax.profiler.TraceAnnotation("bench.window"):
+                for _ in range(traffic["trace_steps"]):
+                    with jax.profiler.TraceAnnotation("bench.step"):
+                        _, value = runner.step()
+                    losses.append(value)
+                    if after_traced_step:
+                        after_traced_step()
+            jax.profiler.stop_trace()
+        beat = Heartbeat()  # after the profiler: it watches the timed steps alone
+        waits0 = waits = host_waits()
+        t0 = time.perf_counter()
+        while True:
+            t_step, cpu, runq = time.perf_counter(), time.process_time(), waits.get("runq")
+            _, value = runner.step()
+            now, waits = time.perf_counter(), host_waits()
+            clocks.append({"wall": now - t_step, "cpu": time.process_time() - cpu,
+                           "dispatch": runner.dispatched_at - t_step,
+                           "wait": now - runner.dispatched_at, "late": beat.take(),
+                           "runq": waits["runq"] - runq if "runq" in waits else None})
+            losses.append(value)
+            if now - t0 >= seconds or ctx["dry"] and len(clocks) >= 2:
+                break
+        window_s = time.perf_counter() - t0
+        beat.stop()
+    watch.check(ctx["cell"]["name"])
+    step_times = [c["wall"] for c in clocks]
+    stats = step_stats(step_times, window_s)
+    by_wall = sorted(range(len(clocks)), key=lambda i: step_times[i])
+    median, slowest = by_wall[len(by_wall) // 2], by_wall[-1]
+    log(f"window: {stats['steps']} steps in {window_s:.4f} s; train_step_s "
+        f"{stats['train_step_s']:.5f}, less its slowest step "
+        f"{stats['train_step_less_slowest_s']}; per-step min {min(step_times):.4f} "
+        f"median {step_times[median]:.4f} max {step_times[slowest]:.4f}; each "
+        f"{[round(t, 4) for t in step_times]}; last loss {losses[-1]:.5f}")
+    for what, i in (("median", median), ("slowest", slowest)):
+        log(f"{what} step {i + 1} by clock (s):",
+            {k: v if v is None else round(v, 4) for k, v in clocks[i].items()})
+    overslept = [c["late"] for c in clocks if c["late"] >= 0.05]
+    log(f"heartbeat: overslept by 50 ms or more in {len(overslept)} of {len(clocks)} "
+        f"steps, at most {max(c['late'] for c in clocks):.4f} s; the kernel's counters "
+        f"over the window (s):", {k: round(waits[k] - waits0[k], 4) for k in waits})
+    stalled = step_times[slowest] > 1.25 * step_times[median]
+    if stalled:
+        log(f"STALLED step {slowest + 1}: {step_times[slowest] - step_times[median]:.3f} s "
+            f"over the median; every step's clocks: "
+            + json.dumps([[c[k] if c[k] is None else round(c[k], 4)
+                           for k in ("wall", "cpu", "dispatch", "wait", "late", "runq")]
+                          for c in clocks]))
+    return {**stats, "losses": losses, "trace_dir": traced, "stalled": stalled}
+
+
+def made_up_facts(scope_keys, phases: dict, **facts) -> dict:
+    """Facts for a kind's `dry_facts()`: the times every training kind
+    reports, made up, and a made-up reduction of a trace in which each of
+    `scope_keys` took `phases` seconds, the optimizer and `unscoped` a
+    little; `facts` are the kind's own."""
+    nothing = dict.fromkeys(phases, 0.0)
+    scopes = {key: dict(phases) for key in scope_keys}
+    scopes["optimizer"] = {**nothing, "other": 0.004}
+    scopes["unscoped"] = {**nothing, "other": 0.002}
+    busy = sum(sum(cell.values()) for cell in scopes.values())
+    reduced = {"busy_s": busy, "window_s": 1.02 * busy, "idle_share": 1 - 1 / 1.02,
+               "scopes": scopes}
+    setup = {"setup_s": 60.0, "setup_imports_and_device_s": 12.0,
+             "setup_after_device_s": 48.0}
+    return {**step_stats([5.0] * 7 + [5.5], 40.5), **setup, "planned_hbm_bytes": 7.4e9,
+            "scopes": reduced, "trace": reduced, **facts}
+
+
 def context(workload, seed, seconds, trace, dry, fault, t_process_start):
     """Everything a kind of run is handed: the cell's files, the devices,
     the compile cache in its fixed place, the built configuration."""
@@ -266,7 +460,7 @@ def context(workload, seed, seconds, trace, dry, fault, t_process_start):
     devices = require_tpu(cell["chips"], dry)
     if not dry:
         peaks_for(devices[0].device_kind)  # an unknown chip fails now, not later
-    setup.mark("imports_and_device")
+    setup.mark(IMPORTS_PHASE)
     seconds = seconds if seconds is not None else bench["run_seconds"]
     log(f"cell {cell['name']} seed {seed} seconds {seconds} trace {int(trace)} "
         f"cache {cache_dir} jax {jax.__version__}")

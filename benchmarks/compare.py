@@ -10,6 +10,18 @@ import jax.numpy as jnp
 from common import key_name
 
 
+def find_mu(opt_state):
+    """Adam's first moment inside the optimizer's state."""
+    if hasattr(opt_state, "mu"):
+        return opt_state.mu
+    if isinstance(opt_state, (tuple, list)):
+        for part in opt_state:
+            found = find_mu(part)
+            if found is not None:
+                return found
+    return None
+
+
 def leaf_paths(tree):
     leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
     return ["/".join(key_name(k) for k in path) for path, _ in leaves]

@@ -152,3 +152,28 @@ def required_train_flops(cfg, n: int, r: int, c: int) -> float:
     twice the forward. What a reversible or rematerialised trunk computes
     again is not counted, so this is the count for a number called mfu."""
     return 3.0 * model_fwd_flops(cfg, n, r, c)
+
+
+# --- what the pair axial core's roofline reads --------------------------------
+#
+# As `flops_lm.attn_core_train_*` count the decoder's core: the work ASKED of
+# the scope `seq_attn/attn_core` in one optimizer step, from the shapes alone
+# and whatever arm or kernel does it. A step REQUIRES 3 x the forward (forward
+# once, backward at twice); what the reversible trunk or a checkpoint computes
+# again is not counted, nor the lanes a kernel pads `dim_head` to.
+
+def attn_core_train_flops(cfg, n: int, r: int = 0, c: int = 0) -> float:
+    """QK^T + AV of the pair stream's two axial passes (rows, then columns:
+    n^2 queries, n keys each, at heads x dim_head), every layer, 3 x forward.
+    The MSA's and the crosses' cores run under scopes of their own."""
+    inner = cfg.heads * cfg.dim_head
+    return 3.0 * cfg.depth * 2 * 4.0 * n * n * n * inner
+
+
+def attn_core_train_bytes(cfg, n: int, r: int = 0, c: int = 0,
+                          itemsize: int = 2) -> float:
+    """The least a step moves through HBM for it at `itemsize` bytes an
+    element: q, k, v read and the output written once a pass, three passes;
+    logits and probabilities never need to leave the chip's fast memory."""
+    once = 4 * n * n * cfg.heads * cfg.dim_head * itemsize
+    return 3.0 * cfg.depth * 2 * once

@@ -1,7 +1,8 @@
 """A language-model training cell: one donated jitted optimizer step per
 call of the decoder's next-token loss on a fresh token batch from the
-seed, the loss fetched every step, until `--seconds` have passed.
-`train_step_s` is the whole window over all its steps.
+seed, the loss fetched every step, until `--seconds` have passed
+(`common.timed_window`). `train_step_s` is the whole window over all its
+steps.
 
 As `train_steps` does: set-up builds ONE object (the compiled step with
 its state), drives it through its first `check_steps` steps by the
@@ -17,8 +18,7 @@ second copy of the weights sits on the device through the window (the
 same call makes them again when the change and the reference need them);
 the router's picks that `route_mismatch_share` reads come from the
 program's forward on those weights after the window, not from the timed
-step; a traced run reduces its own trace by scope before it returns,
-since `run.py` removes the trace directory before the readers run.
+step.
 """
 from __future__ import annotations
 
@@ -61,18 +61,6 @@ def reference_hp(ctx) -> dict:
             "scaling": cfg.routed_scaling_factor, "norm_topk": cfg.norm_topk_prob,
             "held": tuple(cfg.held), "lr": ctx["built"]["tcfg"].learning_rate,
             "bias_rate": cfg.bias_update_rate, **blocks}
-
-
-def _find_mu(opt_state):
-    """Adam's first moment inside the optimizer's state."""
-    if hasattr(opt_state, "mu"):
-        return opt_state.mu
-    if isinstance(opt_state, (tuple, list)):
-        for part in opt_state:
-            found = _find_mu(part)
-            if found is not None:
-                return found
-    return None
 
 
 #: the projections that end a residual branch: `scaled_init_layers`
@@ -142,6 +130,7 @@ class Runner:
         self.vocab = ctx["built"]["cfg"].vocab_size
         self.batch, self.length = shape_of(ctx)
         self.index = 0
+        self.dispatched_at = 0.0
         self.metrics = None
 
     def feed(self):
@@ -171,6 +160,7 @@ class Runner:
             self.state = kept
         else:
             self.state, self.metrics = self.compiled(self.state, dev, rng)
+        self.dispatched_at = time.perf_counter()
         return tokens, float(np.asarray(self.metrics["loss"]))
 
 
@@ -213,7 +203,7 @@ def first_steps(runner, weights, n):
         batches.append(tokens)
         losses.append(value)
         if i == 0:
-            mu = _find_mu(runner.state["opt_state"])
+            mu = compare.find_mu(runner.state["opt_state"])
             grad = [g / 0.1 for g in compare.norms(mu)]
     params0 = weights()
     change = compare.delta_norms(runner.state["params"], params0)
@@ -335,55 +325,38 @@ def control(ctx, q):
     return compared_numbers(ctl, ref, compare.leaf_paths(weights.shapes))
 
 
-def run(ctx):
-    import jax
+def dry_facts(config, traffic):
+    """Facts of the shape `run()` hands on, at the toy sizes of `--dry`
+    with made-up times and a made-up scope table: what the tests of the
+    result line give the readers."""
+    cfg = common.module("builders", config["builder"]).build(config, True)["cfg"]
+    batch, length = traffic["dry"]["batch"], traffic["dry"]["length"]
+    return common.made_up_facts(
+        ("mla_attn/attn_core", "mla_attn/qkv_proj", "moe/experts", "moe/router",
+         "dense_mlp", "lm_head_loss", "decoder_layers"),
+        {"forward": 0.01, "reconstruct": 0.0, "remat": 0.01, "backward": 0.02,
+         "other": 0.0},
+        model_cfg=cfg, lm_shape=(batch, length), trace_steps=traffic["trace_steps"],
+        assignments_held=0.75 * batch * length, moe_load_max_over_mean=1.3)
 
+
+def run(ctx):
     setup, traffic = ctx["setup"], ctx["traffic"]
-    watch = common.CompileWatch()
     weights = Weights(ctx)
     runner = build_runner(ctx, setup, weights)
     n_check = traffic["check_steps"]
     prog_first = first_steps(runner, weights, n_check)
     # the two small reductions above compile once; run the first again so
     # that nothing is left to compile in the window
-    compare.norms(_find_mu(runner.state["opt_state"]))
+    compare.norms(compare.find_mu(runner.state["opt_state"]))
     setup.mark("first_steps_through_the_timed_call")
     log("setup phases (s):", setup.table())
-    setup_s = setup.total()
+    setup_facts = setup.facts()
 
-    gc.collect()
-    gc.freeze()
-    seconds, trace = ctx["seconds"], ctx["trace"]
-    step_times, losses = [], []
-    traced, traced_metrics = None, []
-    with watch:
-        if trace:
-            traced = ctx["trace_dir"]
-            jax.profiler.start_trace(traced)
-            with jax.profiler.TraceAnnotation("bench.window"):
-                for _ in range(traffic["trace_steps"]):
-                    with jax.profiler.TraceAnnotation("bench.step"):
-                        _, value = runner.step()
-                    losses.append(value)
-                    traced_metrics.append(runner.metrics)
-            jax.profiler.stop_trace()
-        t0 = time.perf_counter()
-        while True:
-            t_step = time.perf_counter()
-            _, value = runner.step()
-            now = time.perf_counter()
-            step_times.append(now - t_step)
-            losses.append(value)
-            if now - t0 >= seconds or ctx["dry"] and len(step_times) >= 2:
-                break
-        window_s = time.perf_counter() - t0
-    watch.check(ctx["cell"]["name"])
-    steps = len(step_times)
-    train_step_s = window_s / steps
-    log(f"window: {steps} steps in {window_s:.4f} s; per-step min "
-        f"{min(step_times):.4f} median {sorted(step_times)[steps // 2]:.4f} "
-        f"max {max(step_times):.4f}; each {[round(t, 4) for t in step_times]}; "
-        f"last loss {losses[-1]:.5f}")
+    traced_metrics = []
+    window = common.timed_window(
+        ctx, runner, after_traced_step=lambda: traced_metrics.append(runner.metrics))
+    steps, losses = window["steps"], window.pop("losses")
 
     # the router's own counts: the traced steps' where there are any, else
     # the window's last step
@@ -392,16 +365,6 @@ def run(ctx):
     skew = float(np.mean([np.asarray(m["moe_load_max_over_mean"]) for m in counted]))
     log(f"expert load: {held:.1f} assignments held a MoE layer, most-loaded over "
         f"mean {skew:.4f}")
-    scopes = None
-    if traced and not ctx["dry"]:
-        import scope_reduce
-
-        scopes = scope_reduce.reduce_scopes(traced)
-        log(scope_reduce.format_table(scopes))
-        for label, scope_key, phase, secs in scopes["top_paths"][:16]:
-            log(f"top path {secs:.4f} s {scope_key} {phase}: {label[-150:]}")
-        for label, scope_key, phase, secs in scopes["top_unscoped"][:8]:
-            log(f"unscoped {secs:.4f} s {phase}: {label[-150:]}")
 
     planned = common.planned_peak(runner.compiled)
     device = common.device_block(ctx["devices"], planned)
@@ -423,19 +386,12 @@ def run(ctx):
     values = compared_numbers(prog_first, ref_first, names)
     finite = all(np.isfinite(losses))
     values["nonfinite_losses"] = 0.0 if finite else 1.0
-    limits = dict(ctx["limits"], nonfinite_losses=0.0)
-    for name in sorted(set(values) - set(limits)):
-        # no upper reading separates it from the control (limits/<cell>.json)
-        log(f"read, not held: {name} = {values[name]}")
-    correct, rows = common.judge({k: (values[k], limits[k]) for k in values if k in limits})
+    correct, rows = common.judge_values(values, ctx["limits"])
 
     facts = {
-        "train_step_s": train_step_s, "setup_s": setup_s, "steps": steps,
-        "window_s": window_s, "model_cfg": ctx["built"]["cfg"],
+        **setup_facts, **window, "model_cfg": ctx["built"]["cfg"],
         "lm_shape": shape_of(ctx), "planned_hbm_bytes": planned,
-        "trace_dir": traced, "trace_steps": traffic["trace_steps"],
-        "scopes": scopes, "assignments_held": held,
-        "moe_load_max_over_mean": skew,
+        "assignments_held": held, "moe_load_max_over_mean": skew,
     }
     return {"correct": correct, "attempted": steps + n_check, "failed": 0 if finite else 1,
             "facts": facts, "device": device, "compared": rows}

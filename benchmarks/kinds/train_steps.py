@@ -1,6 +1,7 @@
 """A training cell: one donated jitted optimizer step per call on fresh
 examples from the seed, the loss fetched every step, until `--seconds`
-have passed. `train_step_s` is the whole window over all its steps.
+have passed (`common.timed_window`). `train_step_s` is the whole window
+over all its steps.
 
 Set-up builds ONE object, the compiled step with its state, drives it
 through its first `check_steps` steps by the window's own call and feed,
@@ -20,18 +21,6 @@ import traffic_gen
 from common import log
 
 
-def _find_mu(opt_state):
-    """Adam's first moment inside the optimizer's state."""
-    if hasattr(opt_state, "mu"):
-        return opt_state.mu
-    if isinstance(opt_state, (tuple, list)):
-        for part in opt_state:
-            found = _find_mu(part)
-            if found is not None:
-                return found
-    return None
-
-
 class Runner:
     """The timed path: the compiled step, its state, its feed."""
 
@@ -39,6 +28,7 @@ class Runner:
         self.ctx, self.loss, self.prog = ctx, loss, prog
         self.state, self.compiled, self.shape = state, compiled, shape
         self.index = 0
+        self.dispatched_at = 0.0
 
     def feed(self):
         import jax
@@ -60,6 +50,7 @@ class Runner:
             self.state = kept
         else:
             self.state, metrics = self.compiled(self.state, dev, rng)
+        self.dispatched_at = time.perf_counter()
         return batch, float(np.asarray(metrics["loss"]))
 
 
@@ -106,7 +97,7 @@ def first_steps(runner, params0, n):
         batches.append(batch)
         losses.append(value)
         if i == 0:
-            mu = _find_mu(runner.state["opt_state"])
+            mu = compare.find_mu(runner.state["opt_state"])
             grad = [g / 0.1 for g in compare.norms(mu)]
     change = compare.delta_norms(runner.state["params"], params0)
     jax.block_until_ready(runner.state)
@@ -141,27 +132,35 @@ def follow_reference(ctx, loss, params0, batches, q=None):
             "change": compare.delta_norms(params, params0)}
 
 
+def gradient_numbers(prog_grad, ref_grad, names):
+    """The first gradient's two numbers from every leaf's norm on both
+    sides. `grad_gap`: the worst of the leaves whose reference norm is the
+    median leaf's or more: a widest gap, which swings from seed to seed
+    with the few leaves whose gradient is a sum of cancelling terms (the
+    two position tables, the refiner's scalar biases: PERF.md section 2).
+    `grad_gap_median`: the median used leaf's gap, steady from seed to seed
+    and two hundred times under what the control reads. The worst of ALL
+    leaves is printed beside them."""
+    gaps = compare.leaf_gaps(prog_grad, ref_grad)
+    for i in sorted(range(len(gaps)), key=lambda i: -gaps[i])[:4]:
+        log(f"gradient leaf {names[i]}: gap {gaps[i]:.4g} prog {prog_grad[i]:.6g} "
+            f"ref {ref_grad[i]:.6g}")
+    live = sorted(g for g, r in zip(gaps, ref_grad) if r > 0)
+    log(f"grad_gap over all leaves (not held): {max(gaps):.6g}; median leaf's gap "
+        f"{live[len(live) // 2]:.6g}")
+    gap, where = compare.worst_leaf_gap(prog_grad, ref_grad,
+                                        compare.larger_half(ref_grad))
+    log(f"worst gradient leaf of the larger half: {names[where]} prog "
+        f"{prog_grad[where]:.6g} ref {ref_grad[where]:.6g}")
+    return {"grad_gap": gap, "grad_gap_median": live[len(live) // 2]}
+
+
 def compared_numbers(prog, ref, names):
     """{name: value} of every number `correct` holds, and which leaf."""
     out = {}
     for i, (a, b) in enumerate(zip(prog["losses"], ref["losses"])):
         out[f"loss{i + 1}_gap"] = compare.rel(a, b)
-    # the first gradient by the worst of the leaves whose reference norm is
-    # the median leaf's or more; the worst of all leaves is printed beside it
-    # (a scalar bias whose gradient is a sum of cancelling terms swings with
-    # bfloat16 rounding alone: PERF.md section 2)
-    gaps = compare.leaf_gaps(prog["grad"], ref["grad"])
-    for i in sorted(range(len(gaps)), key=lambda i: -gaps[i])[:4]:
-        log(f"gradient leaf {names[i]}: gap {gaps[i]:.4g} prog {prog['grad'][i]:.6g} "
-            f"ref {ref['grad'][i]:.6g}")
-    live = sorted(g for g, r in zip(gaps, ref["grad"]) if r > 0)
-    log(f"grad_gap over all leaves (not held): {max(gaps):.6g}; median leaf's gap "
-        f"{live[len(live) // 2]:.6g}")
-    gap, where = compare.worst_leaf_gap(prog["grad"], ref["grad"],
-                                        compare.larger_half(ref["grad"]))
-    out["grad_gap"] = gap
-    log(f"worst gradient leaf of the larger half: {names[where]} prog "
-        f"{prog['grad'][where]:.6g} ref {ref['grad'][where]:.6g}")
+    out.update(gradient_numbers(prog["grad"], ref["grad"], names))
     keep = compare.moved_leaves(ref["grad"])
     gap, where = compare.worst_leaf_gap(prog["change"], ref["change"], keep)
     out["change_gap"] = gap
@@ -170,53 +169,53 @@ def compared_numbers(prog, ref, names):
     return out
 
 
-def run(ctx):
-    import jax
+def control(ctx, q):
+    """The control's numbers: the reference with `q` on every operand put
+    in the program's place, against the reference itself."""
+    traffic, built = ctx["traffic"], ctx["built"]
+    loss = common.module("losses", traffic["loss"])
+    prog = loss.program(built)
+    params0 = common.make_params(prog["param_shapes"], common.seed_key(ctx["seed"]),
+                                 stacked=prog["stacked"])
+    shape = example_shape(built, traffic)
+    batches = [traffic_gen.train_batch(shape, ctx["seed"], i)
+               for i in range(traffic["check_steps"])]
+    ref = follow_reference(ctx, loss, params0, batches)
+    ctl = follow_reference(ctx, loss, params0, batches, q)
+    log("losses control", ctl["losses"], "reference", ref["losses"])
+    return compared_numbers(ctl, ref, compare.leaf_paths(params0))
 
+
+def dry_facts(config, traffic):
+    """Facts of the shape `run()` hands on, at the toy sizes of `--dry`
+    with made-up times and a made-up scope table: what the tests of the
+    result line give the readers."""
+    built = common.module("builders", config["builder"]).build(config, True)
+    n = built["crop"] * traffic["atoms_per_residue"]
+    return common.made_up_facts(
+        ("seq_attn/attn_core", "seq_attn/qkv_proj", "seq_ff/geglu", "seq_ff2/geglu",
+         "seq_cross/attn_core", "msa_cross/attn_core", "msa_attn/attn_core",
+         "msa_ff/geglu", "msa_ff2/geglu", "trunk", "refiner", "mds"),
+        {"forward": 0.01, "reconstruct": 0.01, "remat": 0.005, "backward": 0.02,
+         "other": 0.0},
+        model_cfg=built["ecfg"].model, grid=(n, built["msa_rows"], built["crop"]),
+        trace_steps=traffic["trace_steps"])
+
+
+def run(ctx):
     setup, traffic = ctx["setup"], ctx["traffic"]
-    watch = common.CompileWatch()
     runner, params0 = build_runner(ctx, setup)
     n_check = traffic["check_steps"]
     prog_first = first_steps(runner, params0, n_check)
     # the two small reductions above compile once; run the first again so
     # that nothing is left to compile in the window
-    compare.norms(_find_mu(runner.state["opt_state"]))
+    compare.norms(compare.find_mu(runner.state["opt_state"]))
     setup.mark("first_steps_through_the_timed_call")
     log("setup phases (s):", setup.table())
-    setup_s = setup.total()
+    setup_facts = setup.facts()
 
-    gc.collect()
-    gc.freeze()
-    seconds, trace = ctx["seconds"], ctx["trace"]
-    step_times, losses = [], []
-    traced = None
-    with watch:
-        if trace:
-            traced = ctx["trace_dir"]
-            jax.profiler.start_trace(traced)
-            with jax.profiler.TraceAnnotation("bench.window"):
-                for _ in range(traffic["trace_steps"]):
-                    with jax.profiler.TraceAnnotation("bench.step"):
-                        _, value = runner.step()
-                    losses.append(value)
-            jax.profiler.stop_trace()
-        t0 = time.perf_counter()
-        while True:
-            t_step = time.perf_counter()
-            _, value = runner.step()
-            now = time.perf_counter()
-            step_times.append(now - t_step)
-            losses.append(value)
-            if now - t0 >= seconds or ctx["dry"] and len(step_times) >= 2:
-                break
-        window_s = time.perf_counter() - t0
-    watch.check(ctx["cell"]["name"])
-    steps = len(step_times)
-    train_step_s = window_s / steps
-    log(f"window: {steps} steps in {window_s:.4f} s; per-step min "
-        f"{min(step_times):.4f} median {sorted(step_times)[steps // 2]:.4f} "
-        f"max {max(step_times):.4f}; each {[round(t, 4) for t in step_times]}; "
-        f"last loss {losses[-1]:.5f}")
+    window = common.timed_window(ctx, runner)
+    steps, losses = window["steps"], window.pop("losses")
 
     planned = common.planned_peak(runner.compiled)
     device = common.device_block(ctx["devices"], planned)
@@ -233,19 +232,13 @@ def run(ctx):
     values = compared_numbers(prog_first, ref_first, names)
     finite = all(np.isfinite(losses))
     values["nonfinite_losses"] = 0.0 if finite else 1.0
-    limits = dict(ctx["limits"], nonfinite_losses=0.0)
-    for name in sorted(set(values) - set(limits)):
-        # no upper reading separates it from the control (limits/<cell>.json)
-        log(f"read, not held: {name} = {values[name]}")
-    correct, rows = common.judge({k: (values[k], limits[k]) for k in values if k in limits})
+    correct, rows = common.judge_values(values, ctx["limits"])
 
     n = shape["crop"] * shape["atoms_per_residue"]
     facts = {
-        "train_step_s": train_step_s, "setup_s": setup_s, "steps": steps,
-        "window_s": window_s, "model_cfg": model_cfg,
+        **setup_facts, **window, "model_cfg": model_cfg,
         "grid": (n, shape["msa_rows"], shape["crop"]),
         "planned_hbm_bytes": planned,
-        "trace_dir": traced,
     }
     return {"correct": correct, "attempted": steps + n_check, "failed": 0 if finite else 1,
             "facts": facts, "device": device, "compared": rows}

@@ -47,6 +47,27 @@ def metrics_of(bench: dict, cell_name: str, group: str, facts: dict) -> dict:
     return out
 
 
+def reduce_trace_once(facts: dict, traffic: dict):
+    """The traced run's one reduction (`scope_reduce.reduce_scopes`), made
+    before the trace is removed: the scope x phase table for the readers,
+    busy and idle time for the `device` entry, the `breakdown`."""
+    import scope_reduce
+
+    try:
+        reduced = scope_reduce.reduce_scopes(facts["trace_dir"])
+    finally:
+        shutil.rmtree(facts["trace_dir"], ignore_errors=True)
+    common.log(scope_reduce.format_table(reduced))
+    for label, scope_key, phase, secs in reduced["top_paths"][:16]:
+        common.log(f"top path {secs:.4f} s {scope_key} {phase}: {label[-150:]}")
+    for label, scope_key, phase, secs in reduced["top_unscoped"][:8]:
+        common.log(f"unscoped {secs:.4f} s {phase}: {label[-150:]}")
+    facts["trace"] = reduced
+    facts.setdefault("scopes", reduced)
+    facts.setdefault("trace_steps", traffic.get("trace_steps"))
+    return reduced
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -66,18 +87,15 @@ def main() -> int:
     facts = out["facts"]
 
     if args.dry:
+        shutil.rmtree(ctx["trace_dir"], ignore_errors=True)
         print(json.dumps({"dry": True, "workload": cell["name"],
                           "correct": out["correct"], "compared": out["compared"]}))
         return 0
 
+    facts["device_kind"] = devices[0].device_kind
     device, reduced = dict(out["device"]), None
     if args.trace:
-        import trace_reduce
-
-        reduced = trace_reduce.reduce_trace(facts["trace_dir"])
-        shutil.rmtree(facts["trace_dir"], ignore_errors=True)
-        facts["trace"] = reduced
-        facts["device_kind"] = devices[0].device_kind
+        reduced = reduce_trace_once(facts, ctx["traffic"])
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
     group = "per_layer" if args.trace else "end_to_end"

@@ -39,9 +39,16 @@ container's left out), printed as achieved TFLOP/s and GB/s UNDER XLA'S
 NAME: the benchmark's analytic count (`flops.py`) stays the yardstick for
 any share of a peak.
 
-Idle gaps are named by the host span that covers them, as
-`trace_reduce.py` does for `bench.*`, here over every documented prefix:
-an enabled `telemetry.Tracer` writes its spans on the profiler's clock.
+Idle gaps are named by the host span that covers most of them, over
+every documented prefix: the benchmark's `bench.*` and, since an enabled
+`telemetry.Tracer` writes its spans on the profiler's clock, the
+program's own.
+
+This is the ONE reduction of a traced run (`run.py` makes it once, before
+it removes the trace): the same pass gives the result line's `busy_s`,
+`window_s`, idle share and `breakdown`, whose `device_ops` are self
+seconds summed by `trace_reduce.op_label` (`seq_attn/attn_core backward`;
+the HLO name where an operation has no documented scope).
 """
 from __future__ import annotations
 
@@ -56,7 +63,7 @@ import xplane
 HOST_SPAN_PREFIXES = ("bench.", "train.", "serving.", "fleet.", "featurize.",
                       "predict.")
 PHASES = ("forward", "reconstruct", "remat", "backward", "other")
-UNSCOPED = "unscoped"
+UNSCOPED = trace_reduce.UNSCOPED
 _CONTAINERS = ("while", "call", "conditional")
 # the wrappers JAX's transformations put around a name-stack element
 _WRAPPED = re.compile(r"^(?:(?:jvp|transpose|vmap)\()+([A-Za-z0-9_]*)\)+$")
@@ -195,16 +202,20 @@ def reduce_scopes(trace_dir_or_file: str, window_span: str = "bench.window"):
         window = (min(every), max(every))
     spans = [h for h in host if h[2] != window_span]
     n = len(devices)
-    table, xla, cache, by_path, by_category = {}, {}, {}, {}, {}
+    table, xla, cache, by_path, by_category, by_label = {}, {}, {}, {}, {}, {}
     busy_s = sum_self = 0.0
     gaps_named, n_ops = [], 0
     for entry in devices.values():
+        modules, mi = sorted(entry["modules"]), 0
         busy, gaps = trace_reduce.busy_and_gaps(entry["ops"], window)
         busy_s += busy / n
         gaps_named.extend([trace_reduce.attribute(g, spans), g[1] - g[0]] for g in gaps)
         n_ops += len(entry["ops"])
-        for seconds, i in self_times(entry["ops"], window):
-            row = entry["ops"][i][2]
+        for seconds, i in self_times(entry["ops"], window):  # in order of start
+            start, _, row = entry["ops"][i]
+            while mi + 1 < len(modules) and modules[mi + 1][0] <= start:
+                mi += 1
+            program = modules[mi][2] if modules and modules[mi][0] <= start else "?"
             tf_op = row.get("tf_op", "")
             if tf_op not in cache:
                 cache[tf_op] = classify(tf_op, names)
@@ -215,6 +226,8 @@ def reduce_scopes(trace_dir_or_file: str, window_span: str = "bench.window"):
             category = row.get("hlo_category", "?")
             label = tf_op or f"<no tf_op: {category}>"
             by_path[label] = by_path.get(label, 0.0) + seconds / n
+            label = trace_reduce.op_label(key, phase, program, row["name"])
+            by_label[label] = by_label.get(label, 0.0) + seconds / n
             cats = by_category.setdefault(key, {})
             cats[category] = cats.get(category, 0.0) + seconds / n
             if not is_container(row):
@@ -231,6 +244,10 @@ def reduce_scopes(trace_dir_or_file: str, window_span: str = "bench.window"):
     return {
         "busy_s": busy_s,
         "window_s": window_s,
+        "idle_share": 1.0 - busy_s / window_s if window_s > 0 else None,
+        # [name, self seconds] of the ten heaviest: the result line's breakdown
+        "device_ops": [[k, v] for k, v in
+                       sorted(by_label.items(), key=lambda kv: -kv[1])[:10]],
         "sum_self_s": sum_self,
         "residue_s": busy_s - sum_self,
         "scopes": table,

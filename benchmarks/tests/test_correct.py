@@ -117,6 +117,60 @@ def test_control_on_the_chip_failed_the_shipped_limits():
             assert correct is False, r
 
 
+PROBE = os.path.join(common.HERE, "records", "leaf_probe_train_e2e_pr31.jsonl")
+
+
+def _probe():
+    """({(seed, side): every leaf's gradient norm}, leaf names) of PR 31's
+    read of the first gradient on the chip (tools/leaf_probe.py, call a1)."""
+    with open(PROBE) as f:
+        head, *rows = [json.loads(line) for line in f if line.strip()]
+    return {(r["seed"], r["side"]): r["grad"] for r in rows}, head["names"]
+
+
+def _gradient_rows(side, against):
+    from kinds import train_steps
+
+    limits = common.load_json("limits", "train_e2e.json")["limits"]
+    sides, names = _probe()
+    for (seed, which), grad in sorted(sides.items()):
+        if which == side:
+            values = train_steps.gradient_numbers(grad, sides[seed, against], names)
+            yield seed, values, common.judge({k: (v, limits[k]) for k, v in values.items()})[0]
+
+
+def test_recorded_seeds_pass_the_gradients_rule():
+    """The eight seeds read on the chip, the three that an unchanged
+    program had failed or nearly failed among them (2604: 0.230 before
+    PR 26; 2781: 0.184; 192659059: 0.30612, refused by the driver's check of
+    PR 27 at the limit 0.3), against the reference at either precision."""
+    for against in ("high", "highest"):
+        rows = {seed: (values, ok) for seed, values, ok in _gradient_rows("program", against)}
+        assert len(rows) == 8 and {2604, 2781, 192659059} <= set(rows)
+        assert all(ok for _, ok in rows.values()), rows
+        assert rows[192659059][0]["grad_gap"] == pytest.approx(0.306, abs=1e-3)
+        assert rows[2781][0]["grad_gap"] == pytest.approx(0.184, abs=1e-3)
+        # the steady number reads a hundredth of the widest
+        assert max(v["grad_gap_median"] for v, _ in rows.values()) < 0.003
+
+
+def test_the_reference_agrees_with_itself_at_both_precisions():
+    rows = list(_gradient_rows("high", "highest"))
+    assert len(rows) == 8
+    assert all(values["grad_gap"] < 1e-3 for _, values, _ in rows), rows
+
+
+def test_recorded_control_gradients_fail_the_gradients_rule():
+    limits = common.load_json("limits", "train_e2e.json")["limits"]
+    rows = list(_gradient_rows("fp8", "high"))
+    assert len(rows) == 3
+    for seed, values, ok in rows:
+        assert not ok
+        # each of the two numbers alone fails it, with room
+        assert values["grad_gap"] > 1.5 * limits["grad_gap"]
+        assert values["grad_gap_median"] > 10 * limits["grad_gap_median"]
+
+
 @pytest.mark.parametrize("workload,fault", [("train_e2e", "state_unchanged")])
 def test_broken_timed_path_is_not_correct(workload, fault):
     sound = _dry(workload)

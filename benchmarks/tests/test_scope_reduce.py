@@ -14,6 +14,8 @@ import os
 import jax
 import pytest
 
+import common
+import run
 import scope_reduce
 import trace_reduce
 import xplane
@@ -110,11 +112,29 @@ def test_reader_agrees_with_profile_data():
                and row["bytes_accessed"] > 1e8 for row in fusions)
 
 
+def _by_profile_data(path, window_span):
+    """(busy_s, window_s) of the first TPU plane as `jax.profiler.
+    ProfileData` reads the file: the second witness of the reader."""
+    data = jax.profiler.ProfileData.from_file(path)
+    ops, marks = [], []
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                span = (e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9, e.name)
+                if plane.name.startswith("/device:TPU:") and line.name == "XLA Ops":
+                    ops.append(span)
+                elif plane.name == "/host:CPU" and e.name == window_span:
+                    marks.append(span)
+    window = (min(s for s, _, _ in marks), max(e for _, e, _ in marks))
+    return trace_reduce.busy_and_gaps(ops, window)[0], window[1] - window[0]
+
+
 def test_a_trace_without_names_is_all_unscoped():
     out = scope_reduce.reduce_scopes(SMALL, window_span="bench.step")
-    ref = trace_reduce.reduce_trace(SMALL, window_span="bench.step")
+    busy, _ = _by_profile_data(SMALL, "bench.step")
     # ProfileData rounds to nanoseconds, the events are kept in picoseconds
-    assert out["busy_s"] == pytest.approx(ref["busy_s"], rel=1e-5)
+    assert out["busy_s"] == pytest.approx(busy, rel=1e-5)
+    assert all(name.startswith("jit_f/") for name, _ in out["device_ops"])
     assert list(out["scopes"]) == ["unscoped"]
     assert out["scopes"]["unscoped"]["other"] == pytest.approx(out["busy_s"])
     # four 4096^3 bf16 matmuls an execution: near the chip's peak by XLA's count
@@ -128,11 +148,11 @@ def scoped():
 
 
 def test_recorded_step_self_times_add_up_to_busy(scoped):
-    ref = trace_reduce.reduce_trace(SCOPED)
+    busy, window_s = _by_profile_data(SCOPED, "bench.window")
     # the toy's ten thousand events last a few hundred nanoseconds each, and
     # ProfileData rounds every start and duration to a nanosecond
-    assert scoped["busy_s"] == pytest.approx(ref["busy_s"], rel=2e-3)
-    assert scoped["window_s"] == pytest.approx(ref["window_s"], rel=1e-5)
+    assert scoped["busy_s"] == pytest.approx(busy, rel=2e-3)
+    assert scoped["window_s"] == pytest.approx(window_s, rel=1e-5)
     assert scoped["sum_self_s"] == pytest.approx(scoped["busy_s"], rel=1e-6)
     assert abs(scoped["residue_s"]) < 1e-6 * scoped["busy_s"]
     total = sum(sum(cell.values()) for cell in scoped["scopes"].values())
@@ -173,3 +193,77 @@ def test_recorded_step_reports_unscoped_and_names_gaps(scoped):
     names = {name for _, _, name in host}
     assert {"bench.window", "bench.step", "train.step", "train.metrics_fetch"} <= names
     assert scoped["idle_gaps"][0][0].startswith(("bench.", "train."))
+
+
+# --- the traced result line, read from that one reduction (PR 31) --------------
+
+def _scope_share_metrics():
+    import glob
+
+    names = [os.path.basename(p)[:-len(".json")]
+             for p in sorted(glob.glob(os.path.join(common.HERE, "metrics", "*.json")))]
+    return [n for n in names
+            if common.load_json("metrics", n + ".json")["reader"] == "scope_share"
+            and not n.startswith("lm.")]
+
+
+def test_the_trunk_has_eight_scope_shares():
+    assert len(_scope_share_metrics()) == 8
+
+
+@pytest.mark.parametrize("name", _scope_share_metrics())
+def test_recorded_step_gives_every_trunk_share(scoped, name):
+    value = run.read_metric(name, {"scopes": scoped})
+    assert value is not None and 0 < value < 100
+    if name.startswith(("trunk.", "tail.")):
+        assert value > 1.0  # every layer of the toy takes a visible share
+
+
+def test_recorded_step_shares_add_up(scoped):
+    """The five layers, the optimizer and the unnamed rest, with the three
+    scopes that have no metric of their own, are the whole busy time."""
+    listed = [n for n in _scope_share_metrics() if "recompute" not in n]
+    total = sum(run.read_metric(n, {"scopes": scoped}) for n in listed)
+    rest = 100.0 * scope_reduce.seconds_of(
+        scoped["scopes"], outer=("embed", "template_tower", "trunk")) / scoped["busy_s"]
+    assert total + rest == pytest.approx(100.0, abs=1e-6)
+
+
+def test_recorded_step_names_device_ops_by_scope(scoped):
+    ops = scoped["device_ops"]
+    assert len(ops) == 10 and ops == sorted(ops, key=lambda kv: -kv[1])
+    phases = tuple(" " + p for p in scope_reduce.PHASES)
+    named = [name for name, _ in ops if name.endswith(phases)]
+    assert len(named) >= 8, ops
+    assert all(name.split(" ")[0].split("/")[0] in profiling.OUTER_SCOPES for name in named)
+    # self seconds: the ten heaviest cannot outweigh the device's busy time
+    assert sum(seconds for _, seconds in ops) <= scoped["busy_s"] * (1 + 1e-9)
+    assert 0 < scoped["idle_share"] < 1
+
+
+def test_a_traced_run_reduces_its_trace_once(tmp_path, monkeypatch):
+    """`run.reduce_trace_once`: one pass over the capture feeds the readers
+    (`scopes`), the device entry and the breakdown (`trace`), and the
+    capture is gone afterwards."""
+    import shutil
+
+    trace_dir = tmp_path / "trace"
+    trace_dir.mkdir()
+    shutil.copy(SCOPED, trace_dir / "t.xplane.pb")
+    calls = []
+    real = scope_reduce.reduce_scopes
+    monkeypatch.setattr(scope_reduce, "reduce_scopes",
+                        lambda *a, **k: calls.append(a) or real(*a, **k))
+    facts = {"trace_dir": str(trace_dir)}
+    reduced = run.reduce_trace_once(facts, {"trace_steps": 2})
+    assert len(calls) == 1 and not trace_dir.exists()
+    assert facts["trace"] is facts["scopes"] is reduced and facts["trace_steps"] == 2
+    assert run.read_metric("device.idle_share.train", facts) == pytest.approx(
+        100.0 * reduced["idle_share"])
+    # a kind that brings its own table keeps it
+    trace_dir.mkdir()
+    shutil.copy(SCOPED, trace_dir / "t.xplane.pb")
+    own = {"scopes": {}, "busy_s": 1.0}
+    facts = {"trace_dir": str(trace_dir), "scopes": own, "trace_steps": 3}
+    run.reduce_trace_once(facts, {"trace_steps": 2})
+    assert facts["scopes"] is own and facts["trace_steps"] == 3

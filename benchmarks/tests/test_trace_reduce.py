@@ -1,10 +1,13 @@
 """The reduction from a profiler trace to busy time, idle share and named
 gaps, on a small trace recorded on a TPU v5e (three executions of a
-four-matmul program under `bench.step`, 20 ms sleeps under `bench.wait`)."""
+four-matmul program under `bench.step`, 20 ms sleeps under `bench.wait`):
+`trace_reduce.py`'s primitives and the one reduction built from them,
+`scope_reduce.reduce_scopes`."""
 import os
 
 import pytest
 
+import scope_reduce
 import trace_reduce
 
 TRACE = os.path.join(os.path.dirname(__file__), "data", "small.xplane.pb")
@@ -32,8 +35,21 @@ def test_names():
     assert trace_reduce.module_name("jit_f(1549198243489773811)") == "jit_f"
 
 
+@pytest.mark.parametrize("scope,phase,want", [
+    ("seq_ff/geglu", "backward", "seq_ff/geglu backward"),
+    ("seq_attn/attn_core", "reconstruct", "seq_attn/attn_core reconstruct"),
+    ("optimizer", "other", "optimizer other"),
+    # no documented scope: the HLO name stays, under its program
+    ("unscoped", "other", "jit_train_step/while.2202"),
+    ("unscoped/attn_core", "forward", "jit_train_step/while.2202"),
+])
+def test_an_operation_is_named_by_scope_and_phase(scope, phase, want):
+    assert trace_reduce.op_label(scope, phase, "jit_train_step(123)",
+                                 "%while.2202 = (s32[]) while(...)") == want
+
+
 def test_recorded_trace():
-    out = trace_reduce.reduce_trace(TRACE, window_span="bench.step")
+    out = scope_reduce.reduce_scopes(TRACE, window_span="bench.step")
     # three executions of about 2.8 ms each inside a 52 ms window
     assert out["n_ops"] == 24
     assert 0.007 < out["busy_s"] < 0.009
@@ -47,5 +63,5 @@ def test_recorded_trace():
 
 
 def test_whole_trace_without_a_window_span():
-    out = trace_reduce.reduce_trace(TRACE, window_span="no.such.span")
+    out = scope_reduce.reduce_scopes(TRACE, window_span="no.such.span")
     assert out["busy_s"] > 0.008 and out["window_s"] > out["busy_s"]

@@ -1,9 +1,10 @@
 """Read the control of a cell on the chip at the cell's own size: the
 plain reference put in the program's place and computed in 8-bit floating
 point (reference/lowprec.py), compared with the reference itself by the
-same arithmetic that decides `correct` and held to the limits the cell
-ships with: `correct` has to come out false. One JSON line per seed goes
-to chiprun_out/records/control_<workload>.jsonl.
+same arithmetic that decides `correct` (the cell's kind brings it:
+`kinds/<kind>.py control(ctx, q)`) and held to the limits the cell ships
+with: `correct` has to come out false. One JSON line per seed goes to
+chiprun_out/records/control_<workload>.jsonl.
 
     python benchmarks/tools/control.py <workload> <seed> [<seed> ...]
 """
@@ -18,26 +19,7 @@ sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.dirname(HERE))
 
 import common  # noqa: E402
-import compare  # noqa: E402
-import traffic_gen  # noqa: E402
 from reference import lowprec  # noqa: E402
-
-
-def train_control(ctx, q):
-    from kinds import train_steps
-
-    traffic, built = ctx["traffic"], ctx["built"]
-    loss = common.module("losses", traffic["loss"])
-    prog = loss.program(built)
-    params0 = common.make_params(prog["param_shapes"], common.seed_key(ctx["seed"]),
-                                 stacked=prog["stacked"])
-    shape = train_steps.example_shape(built, traffic)
-    batches = [traffic_gen.train_batch(shape, ctx["seed"], i)
-               for i in range(traffic["check_steps"])]
-    ref = train_steps.follow_reference(ctx, loss, params0, batches)
-    ctl = train_steps.follow_reference(ctx, loss, params0, batches, q)
-    common.log("losses control", ctl["losses"], "reference", ref["losses"])
-    return train_steps.compared_numbers(ctl, ref, compare.leaf_paths(params0))
 
 
 def main():
@@ -47,10 +29,8 @@ def main():
     for seed in seeds:
         t = time.perf_counter()
         ctx = common.context(workload, seed, None, False, False, None, T0)
-        values = train_control(ctx, lowprec.fp8)
-        limits = ctx["limits"]
-        correct, rows = common.judge({k: (v, limits[k]) for k, v in values.items()
-                                      if k in limits})
+        values = common.module("kinds", ctx["traffic"]["kind"]).control(ctx, lowprec.fp8)
+        correct, rows = common.judge_values(values, ctx["limits"])
         record = {"workload": workload, "seed": seed, "control": "fp8",
                   "correct": correct, "compared": rows, "numbers": values,
                   "seconds": round(time.perf_counter() - t, 1)}

@@ -4,13 +4,12 @@
     python benchmarks/tools/scopes.py --xplane <file or directory> [--window-span <name>]
 
 With `--workload` the cell's kind runs with tracing on, through
-`common.context` and `kinds/<kind>.run` exactly as `run.py --trace 1` runs
-it (a TPU is required), and the trace is reduced by scope BEFORE it is
-deleted (`run.py` deletes it before its readers run, which is why the
-scope metrics are not in BENCHMARK.json yet: PERF.md section 7). The table
-goes to standard output, the record (call, table, the metrics of
-`metrics/*.json` that read `facts["scopes"]`, what tracing cost) to
-`--out`, by default a new file under `benchmarks/records/`.
+`common.context`, `kinds/<kind>.run` and `run.reduce_trace_once` exactly as
+`run.py --trace 1` runs it (a TPU is required): the same reduction the
+result line's per-layer metrics read, kept whole. The table goes to
+standard output, the record (call, table, the metrics of `metrics/*.json`
+that read `facts["scopes"]`, what tracing cost) to `--out`, by default a
+new file under `benchmarks/records/`.
 
 With `--xplane` any capture is reduced: a trainer's `--profile-dir`, a
 server's `/profilez`. Its window is the whole trace unless `--window-span`
@@ -26,7 +25,6 @@ import argparse  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -57,11 +55,7 @@ def run_cell(args):
                          None, T_PROCESS_START)
     out = common.module("kinds", ctx["traffic"]["kind"]).run(ctx)
     facts = out["facts"]
-    try:
-        reduced = scope_reduce.reduce_scopes(facts["trace_dir"])
-    finally:
-        shutil.rmtree(facts["trace_dir"], ignore_errors=True)
-    facts["scopes"] = reduced
+    reduced = run.reduce_trace_once(facts, ctx["traffic"])
     record = {"workload": args.workload, "seed": args.seed,
               "device": out["device"], "correct": out["correct"]}
     steps = ctx["traffic"].get("trace_steps")
