@@ -1,14 +1,25 @@
-"""A causal decoder language model of the `deepseek_v3` family: multi-head
-latent attention (MLA) and a mixture of experts with shared experts.
+"""Causal decoder language models of TWO families, chosen by the class of
+the configuration that `decoder_init` / `decoder_apply` are handed (a
+file's `model_type` picks the class: train_lm.py `config_from_file`):
+
+  * `DecoderConfig`, `deepseek_v3`: multi-head latent attention (MLA) and
+    a mixture of experts with shared experts;
+  * `ZayaConfig`, `zaya` (ZAYA1): compressed convolutional attention (CCA)
+    in a latent narrower than the residual stream with grouped keys, a
+    top-1 mixture picked by an MLP router that carries a state from layer
+    to layer, a scaled residual stream, a tied head.
 
 Pure init/apply functions over a parameter pytree, as the rest of
-`models/`. Every size comes from `DecoderConfig`, whose keys are the
-published `config.json`'s; the layers are scanned (the leading dense
-layers as one stack, the MoE layers as another), each layer under a
-`jax.checkpoint` that keeps the causal kernel's `out` and `lse` for the
-backward pass and builds the rest of the layer again (`_checkpointed_layer`).
+`models/`. Every size comes from the configuration, whose keys are the
+published `config.json`'s; the layers are scanned (`deepseek_v3`: the
+leading dense layers as one stack, the MoE layers as another; `zaya`: one
+stack whose carry is the residual stream AND the router's state), each
+layer under a `jax.checkpoint` that keeps the causal kernel's `out` and
+`lse` for the backward pass and builds the rest of the layer again
+(`_checkpointed_layer`). Both share the skeleton, the causal core (ops/flash.py), the
+expert layer after its router (ops/moe.py) and the loss (training/lm.py).
 
-The equations are HF `transformers` `deepseek_v3`'s:
+`deepseek_v3`'s equations are HF `transformers`':
 
   block   h += MLA(RMSNorm(h)); h += MLP(RMSNorm(h)); the first
           `first_k_dense_replace` layers' MLP is a SwiGLU of
@@ -33,12 +44,52 @@ Departures, each without effect on the logits:
   * one chip's share: `experts_held` of the router's `n_routed_experts`
     are computed here (ops/moe.py) and `vocab_size` is the slice of the
     vocabulary this chip holds.
+
+`zaya`'s equations are the CCA paper's (Figliolia et al., "Compressed
+Convolutional Attention", arXiv:2510.04476, the CCGQA form) and the ZAYA1
+report's (Anthony et al., arXiv:2511.17127: CCA, the ZAYA1 router,
+residual scaling). d the hidden size, h query heads and hk key heads of
+dh lanes, g = h / hk, x_t a sublayer's RMSNorm'd input; every convolution
+pads on the left only, so nothing sees a later token:
+
+  block   for each sublayer f (CCA, then experts) with its own RMSNorm:
+          h = (a * h + c) + f(RMSNorm(h)), a and c learned vectors of d;
+          final RMSNorm; logits = h E^T, E the embedding table.
+  CCA     q0 = x W_q (d -> h dh), k0 = x W_k (d -> hk dh), no bias;
+          [qc | kc] = Conv_b(Conv_a([q0 | k0])) over the sequence: Conv_a
+          depthwise (one filter of `cca_time0` taps a channel), Conv_b
+          grouped by head (dh -> dh channels a head, `cca_time1` taps),
+          both with bias; the mean of q and k goes around them:
+          q = qc + (q0 + repeat_g(k0)) / 2, k = kc + (mean_g(q0) + k0) / 2
+          by head; q = sqrt(dh) q / |q|, k = sqrt(dh) tau k / |k| over a
+          head's lanes, tau one learned scalar a key head;
+          v = [x_t W_v1 | x_(t-1) W_v2] (d -> hk dh / 2 each, x_(-1) = 0):
+          the first half of the key heads' values are of this token, the
+          second half's of the one before; RoPE on the first
+          `partial_rotary_factor` of each head's lanes of q and k; causal
+          softmax(q k^T / sqrt(dh)), each key head serving its g query
+          heads; the h dh outputs through W_o.
+  router  r_l = RMSNorm(x) W_down (d -> `router_hidden_size`);
+          r_l += gamma_l * r_(l-1) (gamma_l a learned vector, r_(-1) = 0:
+          the state the layer scan carries); s = W_3 gelu(W_2 gelu(W_1
+          r_l)) with biases; p = softmax(s) in float32; the pick is
+          argmax(p + b), b the balancing bias (ops/moe.py `route_softmax`,
+          `bias_update`); the sublayer gives p[pick] SwiGLU_pick(x).
+
+Departures and what the published config does not fix (the configuration
+file's `assumed` argues each): RoPE rotates interleaved pairs, as above (a
+fixed permutation of lanes that the convolutions' and projections' weights
+absorb); gelu is the tanh form; no mixture-of-depths arm (the config has
+no key for one); the balancing bias moves by `bias_update`, not by the
+report's own controller; no cross-document mask; `tie_word_embeddings`
+must be true; one chip's share as above (`experts_held`, with no shared
+expert a token whose expert is absent gets nothing from the sublayer).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import ClassVar, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +102,9 @@ from alphafold2_tpu.telemetry.profiling import scope
 
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
+    model_type: ClassVar[str] = "deepseek_v3"
+    router_width_key: ClassVar[str] = "n_routed_experts"
+
     vocab_size: int
     hidden_size: int
     num_hidden_layers: int
@@ -112,6 +166,68 @@ class DecoderConfig:
         return jnp.dtype(self.dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class ZayaConfig:
+    model_type: ClassVar[str] = "zaya"
+    router_width_key: ClassVar[str] = "num_experts"
+
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    moe_intermediate_size: int
+    num_experts: int  # the router's width
+    num_experts_per_tok: int = 1
+    router_hidden_size: int = 256
+    cca_time0: int = 2
+    cca_time1: int = 2
+    partial_rotary_factor: float = 0.5
+    rope_theta: float = 5000000.0
+    rms_norm_eps: float = 1e-5
+    tie_word_embeddings: bool = True
+    initializer_range: float = 0.02
+    scaled_init_layers: int = 0  # as DecoderConfig's
+    experts_held: Optional[Tuple[int, int]] = None
+    bias_update_rate: float = 0.001
+    dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if not self.tie_word_embeddings:
+            raise ValueError("ZayaConfig: tie_word_embeddings must be true "
+                             "(the head is the embedding table)")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("ZayaConfig: the key heads must divide the query heads")
+        if self.num_key_value_heads % 2:
+            raise ValueError("ZayaConfig: half of the key heads take the "
+                             "previous token's values, so they must be even")
+        lo, hi = self.held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return tuple(self.experts_held or (0, self.num_experts))
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor) // 2 * 2
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def v_head_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+
 # --- init ---------------------------------------------------------------------
 
 def _w(key, shape, std):
@@ -157,7 +273,74 @@ def _layers_init(key, n, cfg, mlp):
             "mlp": mlp(km)}
 
 
-def decoder_init(key, cfg: DecoderConfig):
+def _cca_init(key, n, cfg: ZayaConfig):
+    d, std = cfg.hidden_size, cfg.initializer_range
+    h, hk, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    kq, kk, ka, kb, k1, k2, ko = jax.random.split(key, 7)
+    zeros = jnp.zeros((n, (h + hk) * dh), jnp.float32)
+    return {
+        "q": _w(kq, (n, d, h * dh), std),
+        "k": _w(kk, (n, d, hk * dh), std),
+        # taps oldest first: tap j reads position t - (taps - 1) + j
+        "conv_a": {**_w(ka, (n, cfg.cca_time0, (h + hk) * dh), std), "b": zeros},
+        "conv_b": {**_w(kb, (n, cfg.cca_time1, h + hk, dh, dh), std), "b": zeros},
+        "tau": jnp.ones((n, hk), jnp.float32),
+        "v1": _w(k1, (n, d, hk * dh // 2), std),
+        "v2": _w(k2, (n, d, hk * dh // 2), std),
+        "o": _w(ko, (n, h * dh, d), _out_std(cfg)),
+    }
+
+
+#: the scale the router MLP's three layers start at, whatever
+#: `initializer_range` is. At N(0, 0.02) the scores out of the three layers
+#: spread 0.009 and softmax is flat to 5e-4 across the experts: less than
+#: one step (0.001) of `ops/moe.py bias_update`, so after the first step
+#: every token follows the balancing bias and the load swings between the
+#: experts from step to step (PERF.md section 6, PR 32: both scales on the
+#: chip, the same seeds)
+ROUTER_MLP_STD = 0.04
+
+
+def _router_init(key, n, cfg: ZayaConfig):
+    d, r, std = cfg.hidden_size, cfg.router_hidden_size, cfg.initializer_range
+    kd, k1, k2, k3 = jax.random.split(key, 4)
+
+    def fc(k, fan_in, fan_out):
+        return {**_w(k, (n, fan_in, fan_out), ROUTER_MLP_STD),
+                "b": jnp.zeros((n, fan_out), jnp.float32)}
+
+    return {"norm": _scale(d, (n,)), "reduce": _w(kd, (n, d, r), std),
+            "gamma": jnp.ones((n, r), jnp.float32),
+            "fc1": fc(k1, r, r), "fc2": fc(k2, r, r),
+            "fc3": fc(k3, r, cfg.num_experts)}
+
+
+def _residual_init(d, n):
+    return {"a": jnp.ones((n, d), jnp.float32), "c": jnp.zeros((n, d), jnp.float32)}
+
+
+def zaya_init(key, cfg: ZayaConfig):
+    """As `deepseek_init`; besides: convolution and router-MLP biases 0,
+    `tau`, `gamma` and the residual scale `a` 1, its shift `c` 0. ONE
+    stack, `moe` (every layer has the expert sublayer); no `head`."""
+    d, n = cfg.hidden_size, cfg.num_hidden_layers
+    lo, hi = cfg.held
+    ke, ka, kr, kx = jax.random.split(key, 4)
+    layers = {
+        "attn_res": _residual_init(d, n), "attn_norm": _scale(d, (n,)),
+        "attn": _cca_init(ka, n, cfg),
+        "mlp_res": _residual_init(d, n), "mlp_norm": _scale(d, (n,)),
+        "mlp": {"router": _router_init(kr, n, cfg),
+                "bias": jnp.zeros((n, cfg.num_experts), jnp.float32),
+                "experts": _swiglu_init(kx, (n, hi - lo), d,
+                                        cfg.moe_intermediate_size, cfg)},
+    }
+    return {"embed": {"table": cfg.initializer_range * jax.random.normal(
+                ke, (cfg.vocab_size, d), jnp.float32)},
+            "final_norm": _scale(d), "moe": layers}
+
+
+def deepseek_init(key, cfg: DecoderConfig):
     """N(0, initializer_range) weights (`scaled_init_layers` narrows the
     residual branches' last projections), unit norms, zero selection bias;
     layers stacked on a leading axis (`dense`: the leading dense layers,
@@ -240,6 +423,104 @@ def mla_apply(params, x, cfg: DecoderConfig):
         return linear(params["o"], out.reshape(B, L, h * dv), dtype)
 
 
+def _shift(x, steps: int):
+    """x (B, L, ...) moved `steps` positions later, zeros in front."""
+    if not steps:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (steps, 0)
+    return jnp.pad(x, pad)[:, :x.shape[1]]
+
+
+def _causal_conv(params, x, product):
+    """sum_j product(x moved taps - 1 - j later, w[j]) + b: a convolution
+    over the sequence that pads on the left only. x: (B, L, H, dh)."""
+    w = params["w"]
+    taps = w.shape[0]
+    y = sum(product(_shift(x, taps - 1 - j), w[j]) for j in range(taps))
+    return y + params["b"].reshape(x.shape[2:])
+
+
+def cca_apply(params, x, cfg: ZayaConfig):
+    """Compressed convolutional attention with grouped keys. x: (B, L, d)."""
+    B, L, _ = x.shape
+    h, hk, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    g, dtype, f32 = h // hk, cfg.compute_dtype, jnp.float32
+    with scope("qkv_proj"):
+        q0 = linear(params["q"], x, dtype).reshape(B, L, h, dh)
+        k0 = linear(params["k"], x, dtype).reshape(B, L, hk, dh)
+    with scope("conv_mix"):
+        qk = jnp.concatenate([q0, k0], axis=2)
+        qk = _causal_conv(params["conv_a"], qk.astype(f32),
+                          lambda t, w: t * w.reshape(h + hk, dh)).astype(dtype)
+        qk = _causal_conv(
+            params["conv_b"], qk,
+            lambda t, w: jnp.einsum("blhc,hcd->blhd", t, w.astype(dtype),
+                                    preferred_element_type=f32))
+        q0, k0 = q0.astype(f32), k0.astype(f32)
+        q = qk[:, :, :h] + (q0 + jnp.repeat(k0, g, axis=2)) / 2
+        k = qk[:, :, h:] + (jnp.mean(q0.reshape(B, L, hk, g, dh), axis=3) + k0) / 2
+    with scope("qk_norm_rope"):
+        def unit(t):  # sqrt(dh) t / |t| over a head's lanes
+            return t * jax.lax.rsqrt(jnp.mean(jnp.square(t), axis=-1, keepdims=True))
+
+        rot = cfg.rotary_dim
+
+        def turned(t):
+            return jnp.concatenate(
+                [rope(t[..., :rot], cfg.rope_theta), t[..., rot:]], axis=-1)
+
+        q = turned(unit(q)).astype(dtype)
+        k = turned(unit(k) * params["tau"][:, None]).astype(dtype)
+    with scope("value_shift"):
+        v = jnp.concatenate([linear(params["v1"], x, dtype),
+                             _shift(linear(params["v2"], x, dtype), 1)], axis=-1)
+    out = flash_attention(q, k, v.reshape(B, L, hk, dh), causal=True,
+                          scale=dh ** -0.5)
+    with scope("out_proj"):
+        return linear(params["o"], out.reshape(B, L, h * dh), dtype)
+
+
+def _dense_f32(params, x):
+    return jnp.matmul(x, params["w"], precision=jax.lax.Precision.HIGHEST) + params["b"]
+
+
+def zaya_router_logits(params, x, r_prev, cfg: ZayaConfig):
+    """The ZAYA1 router's state and scores, in float32. x: (N, d) the
+    expert sublayer's normed input; r_prev: (N, router_hidden_size) the
+    state of the layer before. Returns (logits (N, E), r (N, R))."""
+    xn = rms_norm(params["norm"], x.astype(jnp.float32), cfg.rms_norm_eps)
+    r = jnp.matmul(xn, params["reduce"]["w"], precision=jax.lax.Precision.HIGHEST)
+    r = r + params["gamma"] * r_prev
+    t = jax.nn.gelu(_dense_f32(params["fc1"], r))
+    t = jax.nn.gelu(_dense_f32(params["fc2"], t))
+    return _dense_f32(params["fc3"], t), r
+
+
+def _scaled_residual_add(params, h, y):
+    """(a * h + c) + y in float32, back in h's dtype."""
+    with scope("residual_scale"):
+        return (h.astype(jnp.float32) * params["a"] + params["c"] + y).astype(h.dtype)
+
+
+def _zaya_layer(lp, carry, cfg: ZayaConfig):
+    """One `zaya` layer on the scan's carry (h (B, L, d), r (B L, R))."""
+    h, r = carry
+    with scope("cca_attn"):
+        y = cca_apply(lp["attn"], rms_norm(lp["attn_norm"], h, cfg.rms_norm_eps), cfg)
+    h = _scaled_residual_add(lp["attn_res"], h, y)
+    B, L, d = h.shape
+    with scope("moe"):
+        x = rms_norm(lp["mlp_norm"], h, cfg.rms_norm_eps).reshape(B * L, d)
+        with scope("router"):
+            logits, r = zaya_router_logits(lp["mlp"]["router"], x, r, cfg)
+            routing = moe.route_softmax(logits, lp["mlp"]["bias"],
+                                        cfg.num_experts_per_tok)
+        y, aux = moe.moe_apply(lp["mlp"], x, routing, held=cfg.held)
+    h = _scaled_residual_add(lp["mlp_res"], h, y.reshape(B, L, d))
+    return (h, r), aux
+
+
 def _layer(lp, h, cfg: DecoderConfig, is_moe: bool):
     with scope("mla_attn"):
         h = h + mla_apply(lp["attn"], rms_norm(lp["attn_norm"], h,
@@ -251,30 +532,65 @@ def _layer(lp, h, cfg: DecoderConfig, is_moe: bool):
             return h + moe.swiglu(lp["mlp"], x, cfg.compute_dtype), None
     with scope("moe"):
         x = rms_norm(lp["mlp_norm"], h, cfg.rms_norm_eps).reshape(B * L, d)
-        y, aux = moe.moe_apply(
-            lp["mlp"], x, top_k=cfg.num_experts_per_tok,
-            scaling=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
-            held=cfg.held)
+        with scope("router"):
+            routing = moe.route(
+                lp["mlp"], x, top_k=cfg.num_experts_per_tok,
+                scaling=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob)
+        y, aux = moe.moe_apply(lp["mlp"], x, routing, held=cfg.held)
         return h + y.reshape(B, L, d), aux
 
 
-def _checkpointed_layer(cfg, is_moe):
-    """One layer as the scans run it, (h, layer params) -> (h, aux), under
-    the layer's `jax.checkpoint`. It keeps the causal kernel's `out` and
-    `lse` (a residual stream's width twice over a layer, and what the
-    backward kernel reads besides q, k, v) and builds everything else
-    again in the backward pass, so the core's forward runs once a step.
-    Where the core takes the XLA arm the layer holds no such name and is
-    recomputed whole."""
-    return jax.checkpoint(lambda h, lp: _layer(lp, h, cfg, is_moe),
+def _checkpointed_layer(layer):
+    """`layer` (layer params, carry) -> (carry, aux) as the scans run it,
+    (carry, layer params) -> (carry, aux), under the layer's
+    `jax.checkpoint`. It keeps the causal kernel's `out` and `lse` (a
+    residual stream's width twice over a layer, and what the backward
+    kernel reads besides q, k, v) and builds everything else again in the
+    backward pass, so the core's forward runs once a step. Where the core
+    takes the XLA arm the layer holds no such name and is recomputed
+    whole. The carry is whatever the family's layer hands on: `h`, or
+    `(h, r)` with the router's state."""
+    return jax.checkpoint(lambda carry, lp: layer(lp, carry),
                           policy=causal_checkpoint_policy())
 
 
-def _stack(layers, h, cfg, is_moe):
-    return jax.lax.scan(_checkpointed_layer(cfg, is_moe), h, layers)
+def _stack(layer, carry, layers):
+    return jax.lax.scan(_checkpointed_layer(layer), carry, layers)
 
 
-def decoder_apply(params, cfg: DecoderConfig, tokens):
+def _deepseek_layers(params, h, cfg: DecoderConfig):
+    aux = {}
+    if "dense" in params:
+        h, _ = _stack(lambda lp, c: _layer(lp, c, cfg, False), h, params["dense"])
+    if "moe" in params:
+        h, aux = _stack(lambda lp, c: _layer(lp, c, cfg, True), h, params["moe"])
+    return h, aux
+
+
+def _zaya_layers(params, h, cfg: ZayaConfig):
+    B, L, _ = h.shape
+    r = jnp.zeros((B * L, cfg.router_hidden_size), jnp.float32)
+    (h, _), aux = _stack(lambda lp, c: _zaya_layer(lp, c, cfg), (h, r),
+                         params["moe"])
+    return h, aux
+
+
+#: what differs between the families, by the configuration's class: (the
+#: init, the layer stacks on the embedded tokens). The class itself names
+#: its `model_type`, its key for the router's width and the core's
+#: `qk_head_dim` / `v_head_dim`
+_FAMILY = {DecoderConfig: (deepseek_init, _deepseek_layers),
+           ZayaConfig: (zaya_init, _zaya_layers)}
+#: model_type -> the configuration's class (train_lm.py `config_from_file`)
+FAMILIES = {cls.model_type: cls for cls in _FAMILY}
+
+
+def decoder_init(key, cfg):
+    """The family's parameter tree: `deepseek_init`'s or `zaya_init`'s."""
+    return _FAMILY[type(cfg)][0](key, cfg)
+
+
+def decoder_apply(params, cfg, tokens):
     """tokens (B, L) int -> (hidden (B, L, d) after the final norm, in the
     compute dtype; aux {"load": (n_moe, E), "picks": (n_moe, B*L, top_k)},
     empty without MoE layers)."""
@@ -282,14 +598,10 @@ def decoder_apply(params, cfg: DecoderConfig, tokens):
         # rows from the float32 table, so that the table's gradient adds
         # up in float32 however often a token repeats
         h = embedding(params["embed"], tokens).astype(cfg.compute_dtype)
-    aux = {}
     # what the layer scans do themselves (a layer's slice of the stacked
     # parameters, its gradient's write-back, the carried residual stream)
     with scope("decoder_layers"):
-        if "dense" in params:
-            h, _ = _stack(params["dense"], h, cfg, False)
-        if "moe" in params:
-            h, aux = _stack(params["moe"], h, cfg, True)
+        h, aux = _FAMILY[type(cfg)][1](params, h, cfg)
     with scope("lm_head_loss"):
         h = rms_norm(params["final_norm"], h, cfg.rms_norm_eps)
     return h, aux

@@ -292,6 +292,16 @@ def _causal_attention_arms(q, k, v, key_bias, scale, use_kernel, kernel_qb,
                          "mask is the causal one alone")
     i, dh = q.shape[1], q.shape[-1]
     j, dv = k.shape[1], v.shape[-1]
+    # grouped keys: each key head serves `group` query heads in a row.
+    # Either arm takes one head count, so k and v are repeated here (their
+    # gradients add up over a group through the repeat's transpose)
+    group, rest = divmod(q.shape[2], k.shape[2])
+    if rest or v.shape[2] != k.shape[2]:
+        raise ValueError(f"causal flash_attention: {k.shape[2]} key and "
+                         f"{v.shape[2]} value heads do not serve {q.shape[2]} "
+                         "query heads")
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     arm = dispatch.resolve("flash_attention", request=use_kernel, i=i, j=j,
                            dh=dh, dv=dv, causal=True)
     if arm == dispatch.ARM_PALLAS_TPU:
@@ -420,7 +430,9 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
     result and pair-bias streams through `streamed_fused_attention`.
 
     `causal=True` is self-attention (i = j) under the lower-triangular
-    mask alone, with `v`'s head size free of q's and k's (ops/flash_kernel.py
+    mask alone, with `v`'s head size free of q's and k's and, where k and
+    v have fewer heads than q, each key head serving the q.shape[2] /
+    k.shape[2] query heads that follow one another (ops/flash_kernel.py
     `flash_attention_causal_bnhd`, where kernel_qb / kernel_kb force the
     block and the sub-tile of a step; `causal_blockwise_attention` off the
     kernel): only tiles on or below the diagonal. No bias, no gate.

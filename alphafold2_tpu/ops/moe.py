@@ -15,11 +15,19 @@ follows the load the router gave, not the bound. A chunk holds
 `CHUNK_OVER_EXPECTED` times the load the layer expects from its inputs
 (`chunk_rows_for`).
 
-Router (DeepSeek-V3's `noaux_tc`, as HF `deepseek_v3` computes it with
-`n_group` = `topk_group` = 1): `s = sigmoid(x W_g)` in float32; the top-k
-of `s + b` are picked (`b` a selection bias that carries no gradient and
-is moved after each step by `bias_update`); the weights are `s` at the
-picked experts, divided by their sum, times `routed_scaling_factor`.
+The layer is also TOLD its routing: `moe_apply` takes (picks, weights,
+load) from its caller, so that a model with a router of its own shares
+everything after it. Both routers here pick the top-k of `scores + b`
+(`pick`: `b` a selection bias that carries no gradient and is moved after
+each step by `bias_update`) and weigh by the scores at the picks:
+
+  * `route` (DeepSeek-V3's `noaux_tc`, as HF `deepseek_v3` computes it
+    with `n_group` = `topk_group` = 1): `s = sigmoid(x W_g)` in float32;
+    the weights are `s` at the picks, divided by their sum, times
+    `routed_scaling_factor`;
+  * `route_softmax` (ZAYA1): the caller's logits (models/decoder.py: an
+    MLP over a state carried from layer to layer) through a softmax in
+    float32; the weight is the picked probability itself.
 """
 
 from __future__ import annotations
@@ -86,19 +94,35 @@ def grouped_matmul(x, w, group_sizes, use_kernel="auto"):
     return jax.lax.ragged_dot(x, w, group_sizes)
 
 
+def pick(scores, bias, top_k: int):
+    """The top-k of `scores + bias` over ALL experts. scores: (N, E)
+    float32. Returns (idx (N, top_k) int32, the scores at the picks
+    (N, top_k), load (E,) float32: how many of the N * top_k assignments
+    each expert received). The bias enters the choice and not the weight."""
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    load = jnp.zeros((scores.shape[-1],), jnp.float32).at[idx.reshape(-1)].add(1.0)
+    return idx, w, jax.lax.stop_gradient(load)
+
+
 def route(params, x, *, top_k: int, scaling: float, norm_topk: bool):
-    """Scores over ALL experts and the picks. x: (N, d). Returns
-    (idx (N, top_k) int32, weights (N, top_k) float32, load (E,) float32:
-    how many of the N * top_k assignments each expert received)."""
+    """Sigmoid scores over ALL experts and the picks. x: (N, d). Returns
+    (idx, weights, load) as `pick` does, the weights normalised and
+    scaled."""
     s = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), params["router"]["w"],
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(params["bias"]), top_k)
-    w = jnp.take_along_axis(s, idx, axis=-1)
+    idx, w, load = pick(s, params["bias"], top_k)
     if norm_topk:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    load = jnp.zeros((s.shape[-1],), jnp.float32).at[idx.reshape(-1)].add(1.0)
     return idx, w * scaling, load
+
+
+def route_softmax(logits, bias, top_k: int = 1):
+    """Softmax probabilities over ALL experts from the caller's `logits`
+    (N, E), in float32, and the picks; the weight of a pick is its
+    probability. Returns (idx, weights, load) as `pick` does."""
+    return pick(jax.nn.softmax(logits.astype(jnp.float32), axis=-1), bias, top_k)
 
 
 def bias_update(bias, load, rate: float):
@@ -172,18 +196,18 @@ def experts_apply(params, x, idx, weights, *, held, chunk_rows: int):
     return out
 
 
-def moe_apply(params, x, *, top_k: int, scaling: float, norm_topk: bool, held):
-    """One MoE feed-forward on x (N, d): the held routed experts' part
-    plus the shared experts in full. Returns (y (N, d) in x.dtype,
-    {"load": (E,), "picks": (N, top_k)})."""
-    with scope("router"):
-        idx, weights, load = route(params, x, top_k=top_k, scaling=scaling,
-                                   norm_topk=norm_topk)
-    routed = experts_apply(
+def moe_apply(params, x, routing, *, held):
+    """One MoE feed-forward on x (N, d) under the caller's `routing` =
+    (idx, weights, load) of `route` or `route_softmax`: the held routed
+    experts' part, plus the shared experts in full where the layer has any
+    (without one, a token whose experts are all absent gets nothing).
+    Returns (y (N, d) in x.dtype, {"load": (E,), "picks": (N, top_k)})."""
+    idx, weights, load = routing
+    y = experts_apply(
         params["experts"], x, idx, weights, held=held,
-        chunk_rows=chunk_rows_for(x.shape[0], top_k, held[1] - held[0],
-                                  params["router"]["w"].shape[-1]))
-    with scope("shared_expert"):
-        shared = swiglu(params["shared"], x, x.dtype)
-    return ((routed + shared.astype(jnp.float32)).astype(x.dtype),
-            {"load": jax.lax.stop_gradient(load), "picks": idx})
+        chunk_rows=chunk_rows_for(x.shape[0], idx.shape[1], held[1] - held[0],
+                                  load.shape[-1]))
+    if "shared" in params:
+        with scope("shared_expert"):
+            y = y + swiglu(params["shared"], x, x.dtype).astype(jnp.float32)
+    return y.astype(x.dtype), {"load": load, "picks": idx}

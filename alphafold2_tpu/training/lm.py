@@ -1,6 +1,6 @@
 """Language-model training on the shared harness: the next-token loss of
-the decoder (models/decoder.py), its parameters, its step metrics and a
-seeded token source.
+the decoder (models/decoder.py: either family, by the configuration's
+class), its parameters, its step metrics and a seeded token source.
 
 `make_train_step(cfg, tcfg, loss_fn=lm_loss_fn,
 aux_update=lm_aux_update(cfg))` is the whole wiring: `lm_loss_fn` has the
@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from alphafold2_tpu.models.decoder import DecoderConfig, decoder_apply, decoder_init
+from alphafold2_tpu.models.decoder import decoder_apply, decoder_init
 from alphafold2_tpu.ops.moe import bias_update
 from alphafold2_tpu.telemetry.profiling import scope
 
@@ -27,11 +27,11 @@ from alphafold2_tpu.telemetry.profiling import scope
 LOSS_BLOCK_ROWS = 2048
 
 
-def lm_params_init(key, cfg: DecoderConfig):
+def lm_params_init(key, cfg):
     return decoder_init(key, cfg)
 
 
-def lm_train_state_init(key, cfg: DecoderConfig, tcfg):
+def lm_train_state_init(key, cfg, tcfg):
     """The harness's TrainState for the decoder: params, optimizer state,
     step (the twin of `train_state_init` / `e2e_train_state_init`)."""
     from alphafold2_tpu.training.harness import make_optimizer
@@ -42,12 +42,14 @@ def lm_train_state_init(key, cfg: DecoderConfig, tcfg):
 
 
 def blocked_cross_entropy(hidden, head_w, targets, weights,
-                          block_rows: int = LOSS_BLOCK_ROWS):
+                          block_rows: int = LOSS_BLOCK_ROWS, tied: bool = False):
     """sum_r weights[r] * (logsumexp(hidden[r] @ head_w) - logit[targets[r]])
     with the logits in float32, `block_rows` rows at a time (each block
     under `jax.checkpoint`, so the backward builds them again instead of
-    keeping them). hidden: (N, d); head_w: (d, V) in the compute dtype;
-    targets: (N,) int; weights: (N,) float32."""
+    keeping them). hidden: (N, d); head_w: (d, V) in the compute dtype, or
+    with `tied` the embedding table (V, d) itself, contracted over its d
+    (no transposed copy is made); targets: (N,) int; weights: (N,)
+    float32."""
     n, d = hidden.shape
     block = min(block_rows, n)
     pad = (-n) % block
@@ -59,7 +61,9 @@ def blocked_cross_entropy(hidden, head_w, targets, weights,
 
     @jax.checkpoint
     def one(h, t, w):
-        logits = jnp.dot(h, head_w, preferred_element_type=jnp.float32)
+        logits = jax.lax.dot_general(
+            h, head_w, (((1,), (1 if tied else 0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
         return jnp.sum(w * (lse - picked))
@@ -74,9 +78,11 @@ def blocked_cross_entropy(hidden, head_w, targets, weights,
     return total
 
 
-def lm_loss_fn(params, cfg: DecoderConfig, batch, rng=None):
+def lm_loss_fn(params, cfg, batch, rng=None):
     """Next-token cross-entropy over the held vocabulary slice: the mean
-    over the L - 1 targets of each sequence, then over sequences.
+    over the L - 1 targets of each sequence, then over sequences. A tree
+    without a `head` is tied: the logits are against the embedding table,
+    whose gradient is then the sum of the lookup's and the head's.
     batch: {"tokens": (B, L) int32}. Returns (loss, {"load": each MoE
     layer's expert load}, empty without MoE layers): what the step sums
     over its microbatches. `rng` is unused (no dropout)."""
@@ -89,18 +95,20 @@ def lm_loss_fn(params, cfg: DecoderConfig, batch, rng=None):
         targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
         weights = jnp.broadcast_to(
             (jnp.arange(L) < L - 1).astype(jnp.float32), (B, L))
+        tied = "head" not in params
+        head_w = params["embed"]["table"] if tied else params["head"]["w"]
         total = blocked_cross_entropy(
-            hidden.reshape(B * L, -1),
-            params["head"]["w"].astype(cfg.compute_dtype),
-            targets.reshape(-1), weights.reshape(-1))
+            hidden.reshape(B * L, -1), head_w.astype(cfg.compute_dtype),
+            targets.reshape(-1), weights.reshape(-1), tied=tied)
         # the picks are per token: nothing a step sums over microbatches
         return total / (B * (L - 1)), {k: v for k, v in aux.items() if k != "picks"}
 
 
-def lm_aux_update(cfg: DecoderConfig):
+def lm_aux_update(cfg):
     """`aux_update` for `make_train_step`: (params after the optimizer,
     aux summed over the step's microbatches) -> (params with each MoE
-    layer's selection bias moved, step metrics). Per MoE layer:
+    layer's selection bias moved, step metrics). Either family keeps the
+    bias at `moe/mlp/bias` (`zaya`'s one stack is `moe`). Per MoE layer:
     assignments held here and most-loaded over mean load of the held
     experts (none is dropped: the expert layer has no capacity)."""
     lo, hi = cfg.held

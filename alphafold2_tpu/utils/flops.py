@@ -140,11 +140,11 @@ def train_step_flops(
     return grad_accum * mult * model_fwd_flops(cfg, n, r, c)
 
 
-def decoder_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
-    """Matmul FLOPs one forward of the decoder language model REQUIRES on
-    `batch` sequences of `length` tokens, by op (models/decoder.py,
-    training/lm.py), summed over the layers. `cfg` is any object with
-    DecoderConfig's fields.
+def deepseek_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
+    """Matmul FLOPs one forward of the `deepseek_v3` decoder language model
+    REQUIRES on `batch` sequences of `length` tokens, by op
+    (models/decoder.py, training/lm.py), summed over the layers. `cfg` is
+    any object with DecoderConfig's fields.
 
     The attention core counts the causal half of the logits only: each
     query and the keys at or before it, L (L + 1) / 2 pairs a sequence and
@@ -175,6 +175,37 @@ def decoder_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict
         "shared_expert": n_moe * 2.0 * n * 3 * d * cfg.n_shared_experts * f,
         "head": 2.0 * batch * (length - 1) * d * cfg.vocab_size,
     }
+
+
+def zaya_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
+    """The same for the `zaya` family (`cfg`: any object with ZayaConfig's
+    fields): CCA's four projections at their latent widths, the grouped
+    convolution (the depthwise one is no matrix product), the causal half
+    of the logits at the QUERY heads' count (grouped keys save bytes, not
+    operations), the router's down-projection and MLP, the assignments
+    HELD, the tied head's L - 1 rows a sequence."""
+    n = batch * length
+    d, h, hk, dh = (cfg.hidden_size, cfg.num_attention_heads,
+                    cfg.num_key_value_heads, cfg.head_dim)
+    layers, r = cfg.num_hidden_layers, cfg.router_hidden_size
+    lo, hi = cfg.experts_held or (0, cfg.num_experts)
+    if assignments is None:
+        assignments = n * cfg.num_experts_per_tok * (hi - lo) / cfg.num_experts
+    pairs = batch * h * length * (length + 1) / 2.0
+    return {
+        "cca_proj": layers * 2.0 * n * d * (2 * h * dh + 2 * hk * dh),
+        "cca_conv": layers * 2.0 * n * cfg.cca_time1 * (h + hk) * dh * dh,
+        "attn_core": layers * 2.0 * pairs * 2 * dh,
+        "router": layers * 2.0 * n * (d * r + 2 * r * r + r * cfg.num_experts),
+        "experts": layers * 2.0 * assignments * 3 * d * cfg.moe_intermediate_size,
+        "head": 2.0 * batch * (length - 1) * d * cfg.vocab_size,
+    }
+
+
+def decoder_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
+    """The family's count, by the configuration's `model_type`."""
+    count = {"deepseek_v3": deepseek_fwd_op_flops, "zaya": zaya_fwd_op_flops}
+    return count[cfg.model_type](cfg, batch, length, assignments)
 
 
 def decoder_fwd_flops(cfg, batch: int, length: int, assignments=None) -> float:

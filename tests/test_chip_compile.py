@@ -139,7 +139,8 @@ def test_decoder_stack_keeps_the_cores_results_for_v5e(one_chip, compiled_mode,
 
     def grad_text():
         def loss(layers, h):
-            out, _ = decoder._stack(layers, h, cfg, False)
+            out, _ = decoder._stack(
+                lambda lp, c: decoder._layer(lp, c, cfg, False), h, layers)
             return jnp.sum(out.astype(jnp.float32))
 
         return jax.jit(jax.grad(loss, (0, 1))).lower(layers, h).compile().as_text()
@@ -151,8 +152,8 @@ def test_decoder_stack_keeps_the_cores_results_for_v5e(one_chip, compiled_mode,
 
     kept = grad_text()
     monkeypatch.setattr(
-        decoder, "_checkpointed_layer", lambda cfg, is_moe: jax.checkpoint(
-            lambda h, lp: decoder._layer(lp, h, cfg, is_moe)))
+        decoder, "_checkpointed_layer",
+        lambda layer: jax.checkpoint(lambda h, lp: layer(lp, h)))
     bare = grad_text()
     assert (kept.count(CALL), bare.count(CALL)) == (2, 3)
     assert copies_of_out(kept) <= copies_of_out(bare)

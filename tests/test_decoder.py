@@ -369,10 +369,10 @@ def test_causal_takes_no_bias_and_noncausal_is_untouched():
 _ARM = "AF2_KERNEL_BACKEND_FLASH_ATTENTION"
 
 
-def _bare_checkpoint(cfg, is_moe):
+def _bare_checkpoint(layer):
     """The layer under a `jax.checkpoint` without a policy, which recomputes
     the whole layer, the core's forward kernel included."""
-    return jax.checkpoint(lambda h, lp: decoder._layer(lp, h, cfg, is_moe))
+    return jax.checkpoint(lambda h, lp: layer(lp, h))
 
 
 def _kernel_call_sites(jaxpr, counts=None):
@@ -421,7 +421,7 @@ def test_layer_checkpoint_keeps_the_kernels_results_and_nothing_else(
     B, L = tokens.shape
     layer = jax.tree_util.tree_map(lambda t: t[0], params["moe"])
     jax.ad_checkpoint.print_saved_residuals(
-        decoder._checkpointed_layer(CFG, True),
+        decoder._checkpointed_layer(lambda lp, h: decoder._layer(lp, h, CFG, True)),
         jnp.zeros((B, L, CFG.hidden_size)), layer)
     kept = [line for line in capsys.readouterr().out.splitlines()
             if "from the argument" not in line and "from a constant" not in line]

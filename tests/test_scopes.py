@@ -51,10 +51,11 @@ def lower_template_forward():
         jax.ShapeDtypeStruct((1, 2, 8, 8), jnp.int32))
 
 
-def lower_toy_lm_step():
-    """The decoder's train step (one dense and one MoE layer) at toy
-    widths: the names of models/decoder.py, ops/moe.py, training/lm.py."""
-    from alphafold2_tpu.models.decoder import DecoderConfig
+def lower_toy_lm_step(family="deepseek_v3"):
+    """The decoder's train step at toy widths (`deepseek_v3`: one dense
+    and one MoE layer; `zaya`: two layers): the names of
+    models/decoder.py, ops/moe.py, training/lm.py."""
+    from alphafold2_tpu.models.decoder import DecoderConfig, ZayaConfig
     from alphafold2_tpu.training.harness import make_optimizer
     from alphafold2_tpu.training.lm import (lm_aux_update, lm_loss_fn,
                                             lm_params_init)
@@ -66,6 +67,12 @@ def lower_toy_lm_step():
         moe_intermediate_size=16, n_routed_experts=4, num_experts_per_tok=2,
         n_shared_experts=1, routed_scaling_factor=2.0, experts_held=(0, 2),
         dtype="float32")
+    if family == "zaya":
+        cfg = ZayaConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            moe_intermediate_size=16, num_experts=4, router_hidden_size=8,
+            experts_held=(0, 2), dtype="float32")
     tcfg = TrainConfig(grad_accum=1)
     state = jax.eval_shape(
         lambda k: (lambda p: {"params": p, "opt_state": make_optimizer(tcfg).init(p),
@@ -92,7 +99,8 @@ def op_paths(compiled):
 def paths():
     return (op_paths(lower_toy_step().compile())
             | op_paths(lower_template_forward().compile())
-            | op_paths(lower_toy_lm_step().compile()))
+            | op_paths(lower_toy_lm_step().compile())
+            | op_paths(lower_toy_lm_step("zaya").compile()))
 
 
 @pytest.mark.parametrize("name", profiling.SCOPES)

@@ -1,6 +1,10 @@
-"""Language-model pretraining entry point: the `deepseek_v3` decoder
-(models/decoder.py: latent attention, a mixture of experts with shared
-experts) on the shared harness.
+"""Language-model pretraining entry point: the decoder (models/decoder.py)
+on the shared harness. It has TWO families, and a configuration file's
+`model_type` chooses: `deepseek_v3` (latent attention, a mixture of
+experts with shared experts; the default, and the toy) and `zaya`
+(compressed convolutional attention with grouped keys, a top-1 mixture
+picked by an MLP router that carries state from layer to layer, a scaled
+residual stream, a tied head).
 
 One jitted, donated optimizer step (`make_train_step` with `lm_loss_fn`
 and `lm_aux_update`: the same builder `train_pre.py` and
@@ -20,12 +24,13 @@ Usage: python train_lm.py [--steps N] [--config FILE] [--batch 2] [--len 8192]
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
 import jax
 
-from alphafold2_tpu.models.decoder import DecoderConfig
+from alphafold2_tpu.models.decoder import FAMILIES, DecoderConfig
 from alphafold2_tpu.telemetry import (
     MetricRegistry,
     add_observability_args,
@@ -55,33 +60,43 @@ _TOY = dict(
     v_head_dim=32, kv_lora_rank=64, intermediate_size=256,
     moe_intermediate_size=64, n_routed_experts=8, num_experts_per_tok=2,
     n_shared_experts=1, routed_scaling_factor=2.5)
-_FIELDS = {f.name for f in DecoderConfig.__dataclass_fields__.values()}
 
 
-def config_from_file(path: str, dtype: str) -> DecoderConfig:
-    """A DecoderConfig from a file of the published config.json's keys
-    (and, under `assumed_values`, what config.json does not give).
-    Where the file states `experts_held`, its `n_routed_experts` counts
-    the experts held and `published.n_routed_experts` is the router's
-    width; where it states `layers`, that is the depth to build and
-    `num_hidden_layers` is the source's (benchmarks/configs/)."""
+def config_from_file(path: str, dtype: str):
+    """A DecoderConfig or a ZayaConfig, by the file's `model_type`
+    (`deepseek_v3` where it has none), from the published config.json's
+    keys (and, under `assumed_values`, what config.json does not give).
+    Where the file states `experts_held`, its count of experts is of those
+    held and `published` has the router's width; where it states `layers`,
+    that is the depth to build and `num_hidden_layers` is the source's
+    (benchmarks/configs/). `zaya`'s `rope_theta` is its `rope_parameters`'
+    for the `hybrid` layers."""
     with open(path) as f:
         raw = json.load(f)
+    model_type = raw.get("model_type", "deepseek_v3")
+    if model_type not in FAMILIES:
+        raise SystemExit(f"{path}: model_type {model_type!r} is not one of "
+                         f"{sorted(FAMILIES)} (models/decoder.py)")
+    cls = FAMILIES[model_type]
+    fields = {f.name for f in dataclasses.fields(cls)}
     sizes = {k: v for k, v in {**raw, **raw.get("assumed_values", {})}.items()
-             if k in _FIELDS and k != "dtype"}
+             if k in fields and k != "dtype"}
     if "experts_held" in raw:
         sizes["experts_held"] = tuple(raw["experts_held"])
-        sizes["n_routed_experts"] = raw["published"]["n_routed_experts"]
+        sizes[cls.router_width_key] = raw["published"][cls.router_width_key]
     if "layers" in raw:
         sizes["num_hidden_layers"] = raw["layers"]
-    return DecoderConfig(dtype=dtype, **sizes)
+    if "rope_parameters" in raw:
+        sizes["rope_theta"] = float(raw["rope_parameters"]["hybrid"]["rope_theta"])
+    return cls(dtype=dtype, **sizes)
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--config", default=None,
-                    help="JSON file of the published config.json's keys")
+                    help="JSON file of the published config.json's keys; its "
+                    "model_type picks the family (deepseek_v3 or zaya)")
     ap.add_argument("--batch", type=int, default=2, help="sequences a microbatch")
     ap.add_argument("--len", dest="length", type=int, default=256)
     ap.add_argument("--accum", type=int, default=1)
