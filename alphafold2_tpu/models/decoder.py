@@ -96,7 +96,7 @@ import jax.numpy as jnp
 
 from alphafold2_tpu.ops import moe
 from alphafold2_tpu.ops.core import embedding, linear
-from alphafold2_tpu.ops.flash import causal_checkpoint_policy, flash_attention
+from alphafold2_tpu.ops.flash import core_checkpoint_policy, flash_attention
 from alphafold2_tpu.telemetry.profiling import scope
 
 
@@ -551,7 +551,7 @@ def _checkpointed_layer(layer):
     whole. The carry is whatever the family's layer hands on: `h`, or
     `(h, r)` with the router's state."""
     return jax.checkpoint(lambda carry, lp: layer(lp, carry),
-                          policy=causal_checkpoint_policy())
+                          policy=core_checkpoint_policy())
 
 
 def _stack(layer, carry, layers):

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from alphafold2_tpu.ops.core import _uniform, linear, linear_init, dropout
-from alphafold2_tpu.ops.flash import flash_attention
+from alphafold2_tpu.ops.flash import core_checkpoint_policy, flash_attention
 from alphafold2_tpu.telemetry.profiling import scope
 
 # switch to the blockwise path when the full logit tensor (B*h*i*j) would
@@ -357,14 +357,25 @@ def _dense_attention(cfg, q, k, v, mask, context_mask, tie_dim, has_context,
     return out
 
 
+def _checkpointed_chunk(body):
+    """One batch chunk's whole op under its `jax.checkpoint`. What it keeps
+    for the backward pass is the attention kernel's `out` and `lse`, where
+    the chunk's core is the whole-row kernel (one more activation's width
+    over the folded batch, and the kernel's forward is not run again); q, k,
+    v are three times that and are built again, as is everything else. A
+    chunk whose core is the XLA streaming arm or the materialized-logits
+    arm carries no such name and is recomputed whole."""
+    return jax.checkpoint(body, policy=core_checkpoint_policy())
+
+
 def _batch_chunked_attention(params, cfg: AttentionConfig, x, *, context, mask, context_mask):
     """Run attention_apply in chunks over the (folded) batch axis.
 
     Each chunk re-runs the full op (QKV projection, attention, output
-    projection) under jax.checkpoint, so no projection ever materializes
-    over the whole folded batch — the memory bound that lets the crop-384
-    pair stream (1.3M tokens) run on one chip. Deterministic (no-dropout)
-    path only; the caller gates on that.
+    projection) under jax.checkpoint (`_checkpointed_chunk`), so no
+    projection ever materializes over the whole folded batch — the memory
+    bound that lets the crop-384 pair stream (1.3M tokens) run on one chip.
+    Deterministic (no-dropout) path only; the caller gates on that.
     """
     B = x.shape[0]
     chunk = cfg.batch_chunk
@@ -401,7 +412,7 @@ def _batch_chunked_attention(params, cfg: AttentionConfig, x, *, context, mask, 
         )
 
     nb = (B + pad) // chunk
-    out = jax.lax.map(jax.checkpoint(body), jnp.arange(nb))
+    out = jax.lax.map(_checkpointed_chunk(body), jnp.arange(nb))
     out = out.reshape((nb * chunk,) + out.shape[2:])
     return out[:B] if pad else out
 

@@ -541,22 +541,34 @@ def causal_kernel_plan(n: int, h: int, dh: int, dv: int, dtype) -> dict | None:
     return None if plan is None else plan._asdict()
 
 
-def causal_checkpoint_policy():
-    """The `jax.checkpoint` policy that keeps the causal kernel's two
-    results (`flash_kernel.CAUSAL_SAVED_NAMES`: `out` and `lse`, what its
-    backward reads besides q, k, v) and recomputes everything else, so the
-    kernel's forward runs once a step. With the XLA arm the checkpointed
-    function holds no such name and is recomputed whole."""
+def core_checkpoint_policy():
+    """The `jax.checkpoint` policy that keeps an attention kernel's two
+    results (`flash_kernel.SAVED_NAMES`: `out` and `lse`, what its backward
+    reads besides q, k, v) and recomputes everything else, so the kernel's
+    forward is not run again for the backward pass: the causal form's under
+    a decoder layer's checkpoint (models/decoder.py), the whole-row form's
+    under a batch chunk's (ops/attention.py). With the XLA arm, or the
+    kernel's streaming form, the checkpointed function holds no such name
+    and is recomputed whole."""
     from alphafold2_tpu.ops import flash_kernel
 
     return jax.checkpoint_policies.save_only_these_names(
-        *flash_kernel.CAUSAL_SAVED_NAMES)
+        *flash_kernel.SAVED_NAMES)
+
+
+def _saved_bytes(rows: int, width: int, heads: int, dtype) -> dict:
+    """{name: bytes} of a kernel's `out` (rows, width) in the operands'
+    dtype and its `lse`, a float32 a (row, head)."""
+    from alphafold2_tpu.ops import flash_kernel
+
+    return dict(zip(flash_kernel.SAVED_NAMES,
+                    (rows * width * jnp.dtype(dtype).itemsize, rows * heads * 4)))
 
 
 def causal_saved_bytes(batch: int, n: int, h: int, dh: int, dv: int,
                        dtype) -> dict:
     """{name: bytes} of what a `jax.checkpoint` under
-    `causal_checkpoint_policy` (a layer of models/decoder.py) keeps of one
+    `core_checkpoint_policy` (a layer of models/decoder.py) keeps of one
     causal core over `batch` sequences: where the kernel is the arm
     this host resolves for the shape, its `out` (batch, n padded to the
     block, h * dv) in the operands' dtype and its `lse`, a float32 a
@@ -567,8 +579,25 @@ def causal_saved_bytes(batch: int, n: int, h: int, dh: int, dv: int,
     if dispatch._resolve("flash_attention", i=n, j=n, dh=dh, dv=dv,
                          causal=True) != dispatch.ARM_PALLAS_TPU:
         return {}
-    itemsize = jnp.dtype(dtype).itemsize
-    qb = flash_kernel.causal_plan(n, h, dh, dv, itemsize).qb
-    rows = batch * -(-n // qb) * qb
-    return dict(zip(flash_kernel.CAUSAL_SAVED_NAMES,
-                    (rows * h * dv * itemsize, rows * h * 4)))
+    qb = flash_kernel.causal_plan(n, h, dh, dv, jnp.dtype(dtype).itemsize).qb
+    return _saved_bytes(batch * -(-n // qb) * qb, h * dv, h, dtype)
+
+
+def rows_saved_bytes(batch: int, i: int, j: int, h: int, dh: int,
+                     dtype) -> dict:
+    """{name: bytes} of what the batch chunks' checkpoints (ops/attention.py
+    `_batch_chunked_attention`) keep of one chunked attention pass over
+    `batch` folded rows of i queries and j keys: where this host resolves
+    the kernel arm for the shape AND the kernel takes it in its whole-row
+    form, `out` (batch, i padded to 128, h * dh) and `lse` (batch, h, i
+    padded); else {} (the XLA arm and the streaming form carry no name, the
+    chunk is recomputed whole). For a trainer's start-up log
+    (train_end2end.py)."""
+    from alphafold2_tpu.ops import dispatch, flash_kernel
+
+    if (dispatch._resolve("flash_attention", i=i, j=j, dh=dh)
+            != dispatch.ARM_PALLAS_TPU
+            or flash_kernel.rows_plan(i, j, h, dh,
+                                      jnp.dtype(dtype).itemsize) is None):
+        return {}
+    return _saved_bytes(batch * -(-i // 128) * 128, h * dh, h, dtype)

@@ -700,8 +700,8 @@ def _rows_core(q, k, v, key_bias, scale, g, dh, rows):
 
 
 def _rows_fwd(q, k, v, key_bias, scale, g, dh, rows):
-    out, res = _rows_forward(q, k, v, key_bias, scale, g, dh, rows)
-    return out, res + (q.shape[1], k.shape[1])
+    _, res = _rows_forward(q, k, v, key_bias, scale, g, dh, rows)
+    return _rows_named(res + (q.shape[1], k.shape[1]))
 
 
 def _rows_bwd(scale, g, dh, rows, res, do):
@@ -1512,21 +1512,21 @@ def _causal_core(q, k, v, scale, dh, plan):
     return _causal_forward(q, k, v, scale, dh, plan)[0]
 
 
-# The names of the forward kernel's two results where they become the
-# backward's residuals. Under a `jax.checkpoint` whose policy saves these
-# names (models/decoder.py) the backward reads the stored values and the
-# recomputation holds no forward call; outside any policy a name is the
-# identity. Imported here and not at the top so that no line above moves:
-# a Mosaic kernel's serialized body carries its call site's line numbers,
-# and the kernels above are other programs' (PERF.md section 6, PR 28).
+# The names of a forward kernel's two results where they become the
+# backward's residuals, in this form and in the whole-row form alike. Under
+# a `jax.checkpoint` whose policy saves them (`ops/flash.py`) the backward
+# reads the stored values and the recomputation holds no forward call; in
+# no policy a name is the identity. Imported here and not at the top so that
+# no line above moves: a Mosaic kernel's serialized body carries its call
+# sites' line numbers (PERF.md section 6, PR 28; `_rows_named` ends the file).
 from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 
-CAUSAL_SAVED_NAMES = ("attn_core_out", "attn_core_lse")
+SAVED_NAMES = ("attn_core_out", "attn_core_lse")
 
 
 def _causal_fwd(q, k, v, scale, dh, plan):
     out, lse = map(checkpoint_name,
-                   _causal_forward(q, k, v, scale, dh, plan), CAUSAL_SAVED_NAMES)
+                   _causal_forward(q, k, v, scale, dh, plan), SAVED_NAMES)
     return out, (q, k, v, out, lse)
 
 
@@ -1597,3 +1597,14 @@ def flash_attention_causal_bnhd(q, k, v, scale, qb=None, kb=None):
 
     out = _causal_core(flat(q), flat(k), flat(v), scale, dh, plan)
     return out[:, :n].reshape(B, n, h, dv)
+
+
+def _rows_named(res):
+    """(output, residuals) of the whole-row forward rule `_rows_fwd`, the
+    kernel's padded `out` and its `lse` under SAVED_NAMES as `_causal_fwd`
+    has them. The output is cut from the NAMED `out`, so a checkpoint that
+    keeps the two names leaves the forward call nothing live to compute.
+    It stands here, after every causal call site, for the reason above."""
+    qp, kp, vp, bias3, out, lse, i0, j0 = res
+    out, lse = map(checkpoint_name, (out, lse), SAVED_NAMES)
+    return out[:, :i0], (qp, kp, vp, bias3, out, lse, i0, j0)

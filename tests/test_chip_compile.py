@@ -107,6 +107,13 @@ def test_causal_kernel_compiles_for_v5e_at_the_decoders_widths(one_chip,
     assert " transpose(" not in grad and " pad(" not in grad
 
 
+def _copies_of(shape, text):
+    """` copy(` operations of a compiled step's text whose result has `shape`."""
+    dims = ",".join(map(str, shape)) + "]"
+    return sum(" copy(" in line and dims in line.split(" copy(")[0]
+               for line in text.splitlines())
+
+
 def test_decoder_stack_keeps_the_cores_results_for_v5e(one_chip, compiled_mode,
                                                        monkeypatch):
     """The decoder's dense stack at the language-model cell's widths (2 x
@@ -145,15 +152,49 @@ def test_decoder_stack_keeps_the_cores_results_for_v5e(one_chip, compiled_mode,
 
         return jax.jit(jax.grad(loss, (0, 1))).lower(layers, h).compile().as_text()
 
-    def copies_of_out(text):
-        shape = f"{B},{n},{cfg.num_attention_heads * cfg.v_head_dim}]"
-        return sum(" copy(" in line and shape in line.split(" copy(")[0]
-                   for line in text.splitlines())
-
     kept = grad_text()
     monkeypatch.setattr(
         decoder, "_checkpointed_layer",
         lambda layer: jax.checkpoint(lambda h, lp: layer(lp, h)))
     bare = grad_text()
     assert (kept.count(CALL), bare.count(CALL)) == (2, 3)
-    assert copies_of_out(kept) <= copies_of_out(bare)
+    out = (B, n, cfg.num_attention_heads * cfg.v_head_dim)
+    assert _copies_of(out, kept) <= _copies_of(out, bare)
+
+
+def test_batch_chunks_keep_the_whole_row_cores_results_for_v5e(
+        one_chip, compiled_mode, monkeypatch):
+    """One axial pass of the pair stream at the training cell's widths (dim
+    256, 8 heads of 64, rows of 1152) over two batch chunks of 96: its
+    gradient holds TWO calls of the whole-row core, the forward kernel in
+    the forward map and the backward kernel in the backward map. A chunk
+    checkpoint that did not keep `out` and `lse` holds three (the forward
+    again in the backward map). The saved `out` comes back as the stack's
+    slice: no more copies of its shape than a bare checkpoint has."""
+    from alphafold2_tpu.ops import attention
+
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_FLASH_ATTENTION", "pallas_tpu")
+    chunk, n = 96, 1152
+    cfg = attention.AttentionConfig(dim=256, heads=8, dim_head=64,
+                                    dtype=jnp.bfloat16, batch_chunk=chunk)
+
+    def sd(t):
+        return jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(sd, jax.eval_shape(
+        lambda: attention.attention_init(jax.random.PRNGKey(0), cfg)))
+    x = sd(jax.ShapeDtypeStruct((2 * chunk, n, cfg.dim), jnp.bfloat16))
+
+    def grad_text():
+        def loss(params, x):
+            # squared, so that the backward pass needs the forward map's result
+            return jnp.sum(attention.attention_apply(params, cfg, x).astype(jnp.float32) ** 2)
+
+        return jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+
+    kept = grad_text()
+    monkeypatch.setattr(attention, "_checkpointed_chunk", jax.checkpoint)
+    bare = grad_text()
+    assert (kept.count(CALL), bare.count(CALL)) == (2, 3)
+    out = (chunk, n, cfg.inner_dim)
+    assert _copies_of(out, kept) <= _copies_of(out, bare)

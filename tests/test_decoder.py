@@ -12,6 +12,7 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jaxpr_tools import kernel_call_sites
 
 from alphafold2_tpu.models import decoder
 from alphafold2_tpu.models.decoder import (DecoderConfig, decoder_apply,
@@ -375,21 +376,6 @@ def _bare_checkpoint(layer):
     return jax.checkpoint(lambda h, lp: layer(lp, h))
 
 
-def _kernel_call_sites(jaxpr, counts=None):
-    """{kernel function: `pallas_call` equations}, the sub-jaxprs of scan,
-    the checkpoint and the custom_vjp included."""
-    from jax._src import core
-
-    counts = {} if counts is None else counts
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            name = eqn.params["jaxpr"].debug_info.func_name
-            counts[name] = counts.get(name, 0) + 1
-        for sub in core.jaxprs_in_params(eqn.params):
-            _kernel_call_sites(sub, counts)
-    return counts
-
-
 @pytest.mark.parametrize("checkpoint,forward_sites", [("saved_names", 1), ("bare", 2)])
 def test_differentiated_stack_calls_the_forward_kernel_once(monkeypatch, checkpoint,
                                                             forward_sites):
@@ -405,7 +391,7 @@ def test_differentiated_stack_calls_the_forward_kernel_once(monkeypatch, checkpo
     p = decoder_init(jax.random.PRNGKey(1), cfg)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q: lm_loss_fn(q, cfg, {"tokens": _tokens()})[0]))(p)
-    assert _kernel_call_sites(jaxpr.jaxpr) == {
+    assert kernel_call_sites(jaxpr.jaxpr) == {
         "_causal_fwd_kernel": forward_sites, "_causal_bwd_kernel": 1}
 
 

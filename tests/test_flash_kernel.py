@@ -2,12 +2,19 @@
 einsum oracle, run in interpreter mode on CPU (the same single-code-path
 strategy as the block-sparse kernel tests)."""
 
+import dataclasses
+
 import jax
+import jax.ad_checkpoint
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jaxpr_tools import kernel_call_sites
 
-from alphafold2_tpu.ops.flash import flash_attention
+from alphafold2_tpu.ops import attention
+from alphafold2_tpu.ops.attention import (AttentionConfig, attention_apply,
+                                          attention_init)
+from alphafold2_tpu.ops.flash import flash_attention, rows_saved_bytes
 from alphafold2_tpu.ops.flash_kernel import flash_attention_tpu, supported
 
 
@@ -341,3 +348,117 @@ def test_streaming_form_unchanged_at_long_j():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(direct))
     want = blockwise_attention(q, k, v, bias)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+
+
+# --- what a batch chunk's checkpoint keeps -----------------------------------
+
+_ARM = "AF2_KERNEL_BACKEND_FLASH_ATTENTION"
+# four folded rows in chunks of two; 200 positions pad to the kernel's 256
+_CHUNKED = AttentionConfig(dim=32, heads=2, dim_head=64, flash=True, batch_chunk=2)
+_ROWS, _N = 4, 200
+
+
+def _chunked_case():
+    params = attention_init(jax.random.PRNGKey(0), _CHUNKED)
+    x = jax.random.normal(jax.random.PRNGKey(1), (_ROWS, _N, _CHUNKED.dim))
+    mask = jnp.ones((_ROWS, _N), bool).at[:, -5:].set(False)
+    return params, x, mask
+
+
+def _bare_checkpoint(monkeypatch):
+    """The chunk under a `jax.checkpoint` without a policy, which builds
+    the whole chunk again, the core's forward kernel included."""
+    monkeypatch.setattr(attention, "_checkpointed_chunk", jax.checkpoint)
+
+
+def _kept_by_one_chunk(capsys, cfg, params, x, **chunk):
+    """The lines `print_saved_residuals` gives for one chunk's whole op
+    under the chunk's checkpoint, its arguments and constants left out."""
+    inner = dataclasses.replace(cfg, batch_chunk=0)
+    jax.ad_checkpoint.print_saved_residuals(
+        attention._checkpointed_chunk(
+            lambda p, x, **rest: attention_apply(p, inner, x, **rest)),
+        params, x, **chunk)
+    return [line for line in capsys.readouterr().out.splitlines()
+            if "from the argument" not in line and "from a constant" not in line]
+
+
+def _nbytes(line):
+    """Bytes of the array a `print_saved_residuals` line names: `f32[2,256,128] ...`."""
+    dtype, dims = line.split("]")[0].split("[")
+    return (int(np.prod([int(d) for d in dims.split(",")]))
+            * jnp.dtype({"f32": "float32", "bf16": "bfloat16"}[dtype]).itemsize)
+
+
+@pytest.mark.parametrize("checkpoint,forward_sites", [("saved_names", 1), ("bare", 2)])
+def test_differentiated_chunks_call_the_forward_kernel_once(monkeypatch, checkpoint,
+                                                            forward_sites):
+    """The gradient of a batch-chunked `attention_apply` with the kernel arm
+    (interpret mode, a shape the whole-row form takes): ONE call site of the
+    forward kernel and one of the backward kernel, since the chunk's
+    checkpoint keeps `out` and `lse`. The case this guards is the bare
+    checkpoint's, whose backward map runs the forward kernel again."""
+    monkeypatch.setenv(_ARM, "pallas_tpu")
+    if checkpoint == "bare":
+        _bare_checkpoint(monkeypatch)
+    params, x, mask = _chunked_case()
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, x: jnp.sum(attention_apply(p, _CHUNKED, x, mask=mask) ** 2),
+        (0, 1)))(params, x)
+    assert kernel_call_sites(jaxpr.jaxpr) == {
+        "_rows_fwd_kernel": forward_sites, "_rows_bwd_kernel": 1}
+
+
+@pytest.mark.parametrize("arm", ["pallas_tpu", "xla_ref"])
+def test_chunk_checkpoint_keeps_the_kernels_results_and_nothing_else(
+        monkeypatch, capsys, arm):
+    """With the kernel arm a chunk keeps, besides its arguments, the core's
+    `out` (chunk, i padded, h * dh) and `lse`, which is what
+    `rows_saved_bytes` says of it; with the XLA arm it holds no such name
+    and keeps nothing. Either way the value and every gradient are the
+    bare checkpoint's."""
+    monkeypatch.setenv(_ARM, arm)
+    params, x, mask = _chunked_case()
+    chunk, h, dh = _CHUNKED.batch_chunk, _CHUNKED.heads, _CHUNKED.dim_head
+    kept = _kept_by_one_chunk(capsys, _CHUNKED, params, x[:chunk], mask=mask[:chunk])
+    saved = rows_saved_bytes(chunk, _N, _N, h, dh, jnp.float32)
+    if arm == "xla_ref":
+        assert kept == [] and saved == {}
+    else:
+        assert len(kept) == 2 and all("flash_kernel.py" in line for line in kept)
+        assert kept[0].startswith(f"f32[{chunk},256,{h * dh}] ")
+        assert "named 'attn_core_lse'" in kept[1]
+        assert saved == {"attn_core_out": _nbytes(kept[0]),
+                         "attn_core_lse": _nbytes(kept[1])}
+        # the kernel's streaming form (long keys) carries no name
+        assert rows_saved_bytes(chunk, _N, 4096, h, dh, jnp.float32) == {}
+
+    def value_and_grad():
+        return jax.jit(jax.value_and_grad(
+            lambda p, x: jnp.sum(attention_apply(p, _CHUNKED, x, mask=mask) ** 2),
+            (0, 1)))(params, x)
+
+    value, grads = value_and_grad()
+    _bare_checkpoint(monkeypatch)
+    want, want_grads = value_and_grad()
+    assert abs(float(value) - float(want)) <= 1e-6 * abs(float(want))
+    for got, ref in zip(jax.tree_util.tree_leaves(grads),
+                        jax.tree_util.tree_leaves(want_grads)):
+        assert float(jnp.max(jnp.abs(got - ref))) <= 1e-6 * float(jnp.max(jnp.abs(ref)))
+
+
+def test_cross_attention_chunk_keeps_nothing(monkeypatch, capsys):
+    """A cross-attention chunk (`context` given, few keys: the
+    materialized-logits arm, which never reaches the dispatcher) holds no
+    kernel's name, whatever arm the dispatcher would take: it is built
+    again whole, as before."""
+    monkeypatch.setenv(_ARM, "pallas_tpu")
+    cfg = dataclasses.replace(_CHUNKED, flash="auto")
+    params, x, mask = _chunked_case()
+    context = jax.random.normal(jax.random.PRNGKey(2), (_ROWS, 32, cfg.dim))
+    chunk = cfg.batch_chunk
+    assert _kept_by_one_chunk(capsys, cfg, params, x[:chunk], mask=mask[:chunk],
+                              context=context[:chunk]) == []
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(attention_apply(
+        p, cfg, x, mask=mask, context=context) ** 2)))(params)
+    assert kernel_call_sites(jaxpr.jaxpr) == {}

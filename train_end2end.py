@@ -479,10 +479,23 @@ def main():
                 logger.log(step, metrics)
             if step == start:
                 # the step has traced and compiled: which arm every
-                # dispatched call site of it runs, at the shapes it saw
+                # dispatched call site of it runs, at the shapes it saw, and
+                # the bytes that the batch chunks' checkpoints keep of ONE
+                # axial pass of the pair stream (ops/attention.py
+                # _checkpointed_chunk): the whole-row kernel's two results
+                # where that is the pass's core, nothing elsewhere
                 from alphafold2_tpu.ops import dispatch
+                from alphafold2_tpu.ops.flash import rows_saved_bytes
 
-                logger.event(step, "dispatch", decisions=dispatch.decisions())
+                m, side = ecfg.model, 3 * args.max_len
+                rows = args.batch * side  # the other axis folds into the batch
+                kernel_core = (0 < m.attn_batch_chunk < rows and not m.attn_gate
+                               and not m.attn_dropout and m.attn_flash is not False)
+                logger.event(
+                    step, "dispatch", decisions=dispatch.decisions(),
+                    chunk_checkpoint_saves=rows_saved_bytes(
+                        rows, side, side, m.heads, m.dim_head, m.dtype)
+                    if kernel_core else {})
             telemetry.step_complete(step)
             if args.eval_every and (step + 1) % args.eval_every == 0:
                 # structure quality on the last microbatch (the reference's
