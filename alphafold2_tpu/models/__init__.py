@@ -13,6 +13,7 @@ from alphafold2_tpu.models.alphafold2 import (
 from alphafold2_tpu.models.convert import convert_alphafold2
 from alphafold2_tpu.models.decoder import (
     DecoderConfig,
+    MellumConfig,
     ZayaConfig,
     decoder_apply,
     decoder_init,
@@ -43,6 +44,7 @@ from alphafold2_tpu.models.embedder import (
 
 __all__ = [
     "DecoderConfig",
+    "MellumConfig",
     "ZayaConfig",
     "decoder_apply",
     "decoder_init",

@@ -1,5 +1,5 @@
-"""Causal decoder language models of TWO families, chosen by the class of
-the configuration that `decoder_init` / `decoder_apply` are handed (a
+"""Causal decoder language models of THREE families, chosen by the class
+of the configuration that `decoder_init` / `decoder_apply` are handed (a
 file's `model_type` picks the class: train_lm.py `config_from_file`):
 
   * `DecoderConfig`, `deepseek_v3`: multi-head latent attention (MLA) and
@@ -7,16 +7,24 @@ file's `model_type` picks the class: train_lm.py `config_from_file`):
   * `ZayaConfig`, `zaya` (ZAYA1): compressed convolutional attention (CCA)
     in a latent narrower than the residual stream with grouped keys, a
     top-1 mixture picked by an MLP router that carries a state from layer
-    to layer, a scaled residual stream, a tied head.
+    to layer, a scaled residual stream, a tied head;
+  * `MellumConfig`, `mellum` (Mellum 2): grouped-query attention with a
+    per-head RMSNorm on q and k, sliding-window and full causal layers
+    mixed by `layer_types`, each kind with its own position table (YaRN on
+    the full layers), a softmax top-k mixture of narrow experts with no
+    shared expert and no balancing bias, an untied head.
 
 Pure init/apply functions over a parameter pytree, as the rest of
 `models/`. Every size comes from the configuration, whose keys are the
 published `config.json`'s; the layers are scanned (`deepseek_v3`: the
 leading dense layers as one stack, the MoE layers as another; `zaya`: one
-stack whose carry is the residual stream AND the router's state), each
+stack whose carry is the residual stream AND the router's state; `mellum`:
+one stack in the published order, scanned a whole period of `layer_types`
+at a time with each run of one kind scanned inside it, so that the window
+is static where the core is called), each
 layer under a `jax.checkpoint` that keeps the causal kernel's `out` and
 `lse` for the backward pass and builds the rest of the layer again
-(`_checkpointed_layer`). Both share the skeleton, the causal core (ops/flash.py), the
+(`_checkpointed_layer`). All share the skeleton, the causal core (ops/flash.py), the
 expert layer after its router (ops/moe.py) and the loss (training/lm.py).
 
 `deepseek_v3`'s equations are HF `transformers`':
@@ -84,15 +92,56 @@ no key for one); the balancing bias moves by `bias_update`, not by the
 report's own controller; no cross-document mask; `tie_word_embeddings`
 must be true; one chip's share as above (`experts_held`, with no shared
 expert a token whose expert is absent gets nothing from the sublayer).
+
+`mellum`'s equations follow from the published keys (`model_type: mellum`;
+the key set is the Qwen3-MoE family's plus `layer_types` and a
+`rope_parameters` section a layer kind). d the hidden size, h query heads
+and hk key heads of dh lanes (dh is its own key, not d / h), g = h / hk:
+
+  block   h += GQA_l(RMSNorm(h)); h += MoE(RMSNorm(h)) for every layer
+          (`mlp_layer_types` all `sparse`: no leading dense layer); final
+          RMSNorm; logits = h W_head (untied).
+  GQA     q = x W_q (d -> h dh), k = x W_k, v = x W_v (d -> hk dh each), no
+          bias; RMSNorm over the dh lanes of each head of q and of k, each
+          with its own learned scale that the heads share; RoPE over all dh
+          lanes; softmax(q k^T / sqrt(dh) + mask_l), key head j serving
+          query heads [j g, (j + 1) g); the h dh outputs through W_o.
+  kinds   by `layer_types`. `sliding_attention`: query i sees keys j with
+          i - `sliding_window` < j <= i (that many keys, its own among
+          them: `transformers`' convention) and turns by
+          `rope_parameters.sliding_attention` (plain RoPE).
+          `full_attention`: j <= i, and `rope_parameters.full_attention`:
+          YaRN as `transformers` computes it, statically, whatever the
+          length (`yarn_inv_freq`): with f_i = theta^(-2i/dh), inv_freq_i =
+          f_i / factor * (1 - m_i) + f_i * m_i, m_i = 1 - clip((i - low) /
+          (high - low), 0, 1), [low, high] the correction range of
+          (`beta_fast`, `beta_slow`, dh, theta,
+          `original_max_position_embeddings`); cos and sin are multiplied
+          by `attention_factor`, so a full layer's logits carry its square.
+  MoE     p = softmax(x W_r) over ALL `num_experts` in float32; the
+          `num_experts_per_tok` largest; with `norm_topk_prob` their weights
+          divided by their sum; y = sum_e w_e SwiGLU_e(x) at
+          `moe_intermediate_size`. No shared expert, no selection bias, no
+          auxiliary loss (the config has no key for any).
+
+Departures and what the published config does not fix (the configuration
+file's `assumed` argues each): the per-head q / k RMSNorm and softmax
+before top-k are the Qwen3-MoE family's, whose key set this is; the
+multi-token-prediction head the model card mentions has no key and is left
+out; RoPE rotates interleaved pairs, as above; no cross-document mask;
+`tie_word_embeddings` must be false; one chip's share as above.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import ClassVar, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from alphafold2_tpu.ops import moe
 from alphafold2_tpu.ops.core import embedding, linear
@@ -228,6 +277,107 @@ class ZayaConfig:
         return jnp.dtype(self.dtype)
 
 
+#: `mellum`'s two kinds of layer
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+def _frozen(tree):
+    """A nested dict as nested tuples of sorted (key, value) pairs, so that
+    a frozen configuration stays hashable; `dict(...)` of a level undoes it."""
+    if isinstance(tree, dict):
+        return tuple(sorted((k, _frozen(v)) for k, v in tree.items()))
+    return tree
+
+
+@dataclasses.dataclass(frozen=True)
+class MellumConfig:
+    model_type: ClassVar[str] = "mellum"
+    router_width_key: ClassVar[str] = "num_experts"
+
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    moe_intermediate_size: int
+    num_experts: int  # the router's width
+    num_experts_per_tok: int
+    # one kind a layer, in order: whole periods, a period a run of
+    # `sliding_attention` layers that one `full_attention` layer ends
+    layer_types: Tuple[str, ...]
+    # keys a query of a `sliding_attention` layer sees, its own among them;
+    # None: such a layer sees every key before it, as a full layer does
+    sliding_window: Optional[int]
+    # the published dict, a section a layer kind (a dict is taken and kept
+    # as nested tuples; `rope_of` gives a section back as a dict)
+    rope_parameters: tuple
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    tie_word_embeddings: bool = False
+    initializer_range: float = 0.02
+    scaled_init_layers: int = 0  # as DecoderConfig's
+    experts_held: Optional[Tuple[int, int]] = None
+    dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "rope_parameters", _frozen(self.rope_parameters))
+        if self.tie_word_embeddings:
+            raise ValueError("MellumConfig: tie_word_embeddings must be false "
+                             "(the head is a projection of its own)")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("MellumConfig: the key heads must divide the query heads")
+        unknown = set(self.layer_types) - {SLIDING, FULL}
+        if unknown:
+            raise ValueError(f"MellumConfig: layer_types has {sorted(unknown)}; "
+                             f"a layer is {SLIDING!r} or {FULL!r}")
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError(f"MellumConfig: {len(self.layer_types)} layer_types "
+                             f"for {self.num_hidden_layers} layers")
+        period = self.period
+        if period * (self.num_hidden_layers // len(period)) != self.layer_types:
+            raise ValueError(
+                f"MellumConfig: layer_types {self.layer_types} is no whole "
+                f"number of its period {period}")
+        for kind in period:
+            if kind not in dict(self.rope_parameters):
+                raise ValueError(f"MellumConfig: rope_parameters has no {kind!r}")
+        lo, hi = self.held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return tuple(self.experts_held or (0, self.num_experts))
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The layers up to the first full one: what the stack repeats."""
+        if FULL not in self.layer_types:
+            return self.layer_types[:1]
+        return self.layer_types[:self.layer_types.index(FULL) + 1]
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.sliding_window if kind == SLIDING else None
+
+    def rope_of(self, kind: str) -> dict:
+        return dict(dict(self.rope_parameters)[kind])
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def v_head_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+
 # --- init ---------------------------------------------------------------------
 
 def _w(key, shape, std):
@@ -340,6 +490,30 @@ def zaya_init(key, cfg: ZayaConfig):
             "final_norm": _scale(d), "moe": layers}
 
 
+def mellum_init(key, cfg: MellumConfig):
+    """As `deepseek_init`, without a selection bias: ONE stack, `moe`, the
+    layers of both kinds in the published order; the q / k norms' scales 1."""
+    d, n, std = cfg.hidden_size, cfg.num_hidden_layers, cfg.initializer_range
+    h, hk, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    lo, hi = cfg.held
+    ke, kq, kk, kv, ko, kr, kx, kh = jax.random.split(key, 8)
+    layers = {
+        "attn_norm": _scale(d, (n,)),
+        "attn": {"q": _w(kq, (n, d, h * dh), std), "k": _w(kk, (n, d, hk * dh), std),
+                 "v": _w(kv, (n, d, hk * dh), std),
+                 "q_norm": _scale(dh, (n,)), "k_norm": _scale(dh, (n,)),
+                 "o": _w(ko, (n, h * dh, d), _out_std(cfg))},
+        "mlp_norm": _scale(d, (n,)),
+        "mlp": {"router": _w(kr, (n, d, cfg.num_experts), std),
+                "experts": _swiglu_init(kx, (n, hi - lo), d,
+                                        cfg.moe_intermediate_size, cfg)},
+    }
+    return {"embed": {"table": std * jax.random.normal(
+                ke, (cfg.vocab_size, d), jnp.float32)},
+            "final_norm": _scale(d), "head": _w(kh, (d, cfg.vocab_size), std),
+            "moe": layers}
+
+
 def deepseek_init(key, cfg: DecoderConfig):
     """N(0, initializer_range) weights (`scaled_init_layers` narrows the
     residual branches' last projections), unit norms, zero selection bias;
@@ -385,14 +559,21 @@ def rms_norm(params, x, eps):
     return (y * params["scale"]).astype(x.dtype)
 
 
-def rope(x, theta: float):
+def rope(x, theta: float, inv_freq=None, attention_factor=None):
     """Rotate the interleaved pairs (x_2i, x_2i+1) of the last axis by
-    position * theta^(-2i/d). x: (B, L, ..., d), positions 0..L-1."""
+    position * theta^(-2i/d), or by position * `inv_freq`[i] where a table
+    (d / 2,) is given; `attention_factor` multiplies cos and sin (YaRN).
+    x: (B, L, ..., d), positions 0..L-1."""
     L, d = x.shape[1], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(L, dtype=jnp.float32)[:, None] * inv[None, :]
     ang = ang.reshape((1, L) + (1,) * (x.ndim - 3) + (d // 2,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if attention_factor is not None:
+        cos, sin = cos * attention_factor, sin * attention_factor
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
@@ -421,6 +602,62 @@ def mla_apply(params, x, cfg: DecoderConfig):
     out = flash_attention(q, k, v, causal=True, scale=cfg.qk_head_dim ** -0.5)
     with scope("out_proj"):
         return linear(params["o"], out.reshape(B, L, h * dv), dtype)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's (dim / 2,) rotation frequencies as `transformers` computes
+    them (`_compute_yarn_parameters`, `truncate` on), whatever the sequence
+    length: pairs that turn more than `beta_fast` times over `original_max`
+    positions keep theta^(-2i/dim), pairs that turn less than `beta_slow`
+    times are slowed `factor` times, a linear ramp over the pair index
+    between."""
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_turning(turns):  # the (fractional) pair that turns `turns` times
+        return dim * math.log(original_max / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    keep = 1.0 - np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return f / factor * (1.0 - keep) + f * keep
+
+
+def _rope_table(section: dict, dim: int):
+    """(theta, inv_freq or None, attention_factor or None) of one section
+    of `rope_parameters`: `default` is plain RoPE, `yarn` the table above."""
+    theta, kind = float(section["rope_theta"]), section.get("rope_type", "default")
+    if kind == "default":
+        return theta, None, None
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: the decoder turns by `default` "
+                         "or `yarn`")
+    inv = yarn_inv_freq(dim, theta, section["factor"],
+                        section["original_max_position_embeddings"],
+                        section["beta_fast"], section["beta_slow"])
+    return theta, inv, section.get("attention_factor")
+
+
+def gqa_apply(params, x, cfg: MellumConfig, kind: str):
+    """Grouped-query attention of one layer kind (its window, its position
+    table), q and k normed by head. x: (B, L, d)."""
+    B, L, _ = x.shape
+    h, hk, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    dtype = cfg.compute_dtype
+    with scope("qkv_proj"):
+        q = linear(params["q"], x, dtype).reshape(B, L, h, dh)
+        k = linear(params["k"], x, dtype).reshape(B, L, hk, dh)
+        v = linear(params["v"], x, dtype).reshape(B, L, hk, dh)
+    with scope("qk_norm_rope"):
+        table = _rope_table(cfg.rope_of(kind), dh)
+        q = rope(rms_norm(params["q_norm"], q, cfg.rms_norm_eps), *table)
+        k = rope(rms_norm(params["k_norm"], k, cfg.rms_norm_eps), *table)
+    out = flash_attention(q, k, v, causal=True, window=cfg.window_of(kind),
+                          scale=dh ** -0.5)
+    with scope("out_proj"):
+        return linear(params["o"], out.reshape(B, L, h * dh), dtype)
 
 
 def _shift(x, steps: int):
@@ -521,6 +758,21 @@ def _zaya_layer(lp, carry, cfg: ZayaConfig):
     return (h, r), aux
 
 
+def _mellum_layer(lp, h, cfg: MellumConfig, kind: str):
+    with scope("gqa_attn"):
+        h = h + gqa_apply(lp["attn"], rms_norm(lp["attn_norm"], h,
+                                               cfg.rms_norm_eps), cfg, kind)
+    B, L, d = h.shape
+    with scope("moe"):
+        x = rms_norm(lp["mlp_norm"], h, cfg.rms_norm_eps).reshape(B * L, d)
+        with scope("router"):
+            routing = moe.route_softmax(
+                moe.router_logits(lp["mlp"], x), None, cfg.num_experts_per_tok,
+                norm_topk=cfg.norm_topk_prob)
+        y, aux = moe.moe_apply(lp["mlp"], x, routing, held=cfg.held)
+        return h + y.reshape(B, L, d), aux
+
+
 def _layer(lp, h, cfg: DecoderConfig, is_moe: bool):
     with scope("mla_attn"):
         h = h + mla_apply(lp["attn"], rms_norm(lp["attn_norm"], h,
@@ -575,18 +827,56 @@ def _zaya_layers(params, h, cfg: ZayaConfig):
     return h, aux
 
 
+def _runs(kinds):
+    """[(kind, start, stop)] of the runs of one kind in `kinds`."""
+    starts = [i for i, kind in enumerate(kinds) if i == 0 or kind != kinds[i - 1]]
+    return [(kinds[a], a, b) for a, b in zip(starts, starts[1:] + [len(kinds)])]
+
+
+def _mellum_layers(params, h, cfg: MellumConfig):
+    """The layers in the published order with the window static at each
+    call of the core: a scan over whole periods of `layer_types`, whose
+    body scans each run of one kind (a run of one layer is called
+    directly), every layer under `_checkpointed_layer`. The aux comes back
+    a row a layer, in that order."""
+    period, n = cfg.period, cfg.num_hidden_layers
+
+    def at(tree, index):
+        return jax.tree_util.tree_map(lambda t: t[index], tree)
+
+    def one_period(h, pp):
+        rows = []
+        for kind, a, b in _runs(period):
+            layer = functools.partial(_mellum_layer, cfg=cfg, kind=kind)
+            if b - a > 1:
+                h, aux = _stack(layer, h, at(pp, slice(a, b)))
+            else:
+                h, aux = _checkpointed_layer(layer)(h, at(pp, a))
+                aux = at(aux, None)
+            rows.append(aux)
+        return h, jax.tree_util.tree_map(lambda *ts: jnp.concatenate(ts), *rows)
+
+    periods = jax.tree_util.tree_map(
+        lambda t: t.reshape((n // len(period), len(period)) + t.shape[1:]),
+        params["moe"])
+    h, aux = jax.lax.scan(one_period, h, periods)
+    return h, jax.tree_util.tree_map(lambda t: t.reshape((n,) + t.shape[2:]), aux)
+
+
 #: what differs between the families, by the configuration's class: (the
 #: init, the layer stacks on the embedded tokens). The class itself names
 #: its `model_type`, its key for the router's width and the core's
 #: `qk_head_dim` / `v_head_dim`
 _FAMILY = {DecoderConfig: (deepseek_init, _deepseek_layers),
-           ZayaConfig: (zaya_init, _zaya_layers)}
+           ZayaConfig: (zaya_init, _zaya_layers),
+           MellumConfig: (mellum_init, _mellum_layers)}
 #: model_type -> the configuration's class (train_lm.py `config_from_file`)
 FAMILIES = {cls.model_type: cls for cls in _FAMILY}
 
 
 def decoder_init(key, cfg):
-    """The family's parameter tree: `deepseek_init`'s or `zaya_init`'s."""
+    """The family's parameter tree: `deepseek_init`'s, `zaya_init`'s or
+    `mellum_init`'s."""
     return _FAMILY[type(cfg)][0](key, cfg)
 
 

@@ -234,16 +234,20 @@ def blockwise_attention(
 
 
 def causal_blockwise_attention(q, k, v, *, scale=None, block: int = 1024,
-                               remat: bool = True):
+                               remat: bool = True, window: int | None = None):
     """Exact causal self-attention, streamed: softmax(QK^T * scale) V under
     the lower-triangular mask, with a value head size of its own.
 
     q, k: (B, n, h, dh); v: (B, n, h, dv). Queries walk in tiles of
-    `block`; tile t streams the t key blocks below the diagonal unmasked
-    (`stream_block` under `lax.scan`) and then the one block the diagonal
-    crosses under its triangular mask. Blocks above the diagonal are never
-    built: the loop over tiles is a Python loop, so each tile's trip count
-    is static. Returns (B, n, h, dv) in q.dtype."""
+    `block`; tile t streams the key blocks below the diagonal that need no
+    mask (`stream_block` under `lax.scan`) and then, each under its mask,
+    the blocks that do: the one the diagonal crosses and, with a `window`
+    (query i sees keys j with i - window < j <= i), those the band's lower
+    edge crosses. Blocks above the diagonal or wholly behind the band are
+    never built: the loop over tiles is a Python loop, so each tile's trip
+    count is static. Returns (B, n, h, dv) in q.dtype."""
+    from alphafold2_tpu.ops import flash_kernel
+
     B, n, h, dh = q.shape
     dv = v.shape[-1]
     scale = dh ** -0.5 if scale is None else scale
@@ -254,14 +258,28 @@ def causal_blockwise_attention(q, k, v, *, scale=None, block: int = 1024,
                    for t in (q, k, v))
     nb = (n + pad) // block
     at = jnp.arange(block)
-    diagonal = jnp.where(at[None, :] <= at[:, None], 0.0, _NEG_INF)[None, None]
+
+    def mask(d):
+        """The additive mask of the block d blocks under the diagonal."""
+        apart = at[:, None] - at[None, :] + d * block  # query - key
+        seen = apart >= 0
+        if window is not None:
+            seen &= apart < window
+        return jnp.where(seen, 0.0, _NEG_INF)[None, None]
+
+    # in blocks under the diagonal, the band's geometry as the kernel's
+    # schedule has it: the blocks that the band's lower edge crosses take
+    # a mask (they are the farthest a tile reaches), those nearer none
+    crossed = flash_kernel.masked_distances(window, block)[:0:-1]
+    reach = nb if window is None else flash_kernel.window_blocks(window, block)
+    edge = min(crossed, default=reach + 1)
 
     def cut(t):  # (nb, B, block, h, d)
         return t.reshape(B, nb, block, h, t.shape[-1]).transpose(1, 0, 2, 3, 4)
 
     ks, vs = cut(k), cut(v)
 
-    def tile(qt, k_below, v_below, k_diag, v_diag):
+    def tile(qt, k_plain, v_plain, masked):
         carry = (jnp.full((B, h, block), _NEG_INF, jnp.float32),
                  jnp.zeros((B, h, block), jnp.float32),
                  jnp.zeros((B, h, block, dv), jnp.float32))
@@ -269,27 +287,36 @@ def causal_blockwise_attention(q, k, v, *, scale=None, block: int = 1024,
         def body(c, blk):
             return stream_block(qt, blk[0], blk[1], None, *c, scale), None
 
-        if k_below.shape[0]:
-            carry, _ = jax.lax.scan(body, carry, (k_below, v_below))
-        _, l, acc = stream_block(qt, k_diag, v_diag, None, *carry, scale,
-                                 bias2d_blk=diagonal)
+        if k_plain.shape[0]:
+            carry, _ = jax.lax.scan(body, carry, (k_plain, v_plain))
+        for k_blk, v_blk, bias in masked:
+            carry = stream_block(qt, k_blk, v_blk, None, *carry, scale,
+                                 bias2d_blk=bias)
+        _, l, acc = carry
         return jnp.transpose(acc / l[..., None], (0, 2, 1, 3)).astype(q.dtype)
 
     if remat:
         tile = jax.checkpoint(tile)
-    out = [tile(q[:, t * block:(t + 1) * block], ks[:t], vs[:t], ks[t], vs[t])
-           for t in range(nb)]
+    out = []
+    for t in range(nb):
+        plain = slice(t - min(t, edge - 1), t)
+        masked = [d for d in crossed if d <= t] + [0]
+        out.append(tile(q[:, t * block:(t + 1) * block], ks[plain], vs[plain],
+                        [(ks[t - d], vs[t - d], mask(d)) for d in masked]))
     out = jnp.concatenate(out, axis=1) if nb > 1 else out[0]
     return out[:, :n] if pad else out
 
 
 def _causal_attention_arms(q, k, v, key_bias, scale, use_kernel, kernel_qb,
-                           kernel_kb, blockwise_kwargs):
+                           kernel_kb, window, blockwise_kwargs):
     from alphafold2_tpu.ops import dispatch, flash_kernel
 
     if key_bias is not None:
         raise ValueError("causal flash_attention takes no key bias: the "
-                         "mask is the causal one alone")
+                         "mask is the causal one, or its band under `window`")
+    if window is not None and window < 1:
+        raise ValueError(f"causal flash_attention: a window of {window} keys "
+                         "holds no key; every query sees its own")
     i, dh = q.shape[1], q.shape[-1]
     j, dv = k.shape[1], v.shape[-1]
     # grouped keys: each key head serves `group` query heads in a row.
@@ -306,8 +333,8 @@ def _causal_attention_arms(q, k, v, key_bias, scale, use_kernel, kernel_qb,
                            dh=dh, dv=dv, causal=True)
     if arm == dispatch.ARM_PALLAS_TPU:
         return flash_kernel.flash_attention_causal_bnhd(
-            q, k, v, scale, qb=kernel_qb, kb=kernel_kb)
-    kwargs = {}
+            q, k, v, scale, qb=kernel_qb, kb=kernel_kb, window=window)
+    kwargs = {"window": window}
     if "remat" in blockwise_kwargs:
         kwargs["remat"] = blockwise_kwargs["remat"]
     if "kv_block" in blockwise_kwargs:
@@ -401,7 +428,7 @@ def hop_attention_lse(qf, kf, vf, bias, scale):
 
 
 def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
-                    scale=None, use_kernel="auto", causal=False,
+                    scale=None, use_kernel="auto", causal=False, window=None,
                     kernel_qb=None, kernel_kb=None, **blockwise_kwargs):
     """Exact attention: fused Pallas kernel on TPU, XLA blockwise otherwise.
 
@@ -430,15 +457,23 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
     result and pair-bias streams through `streamed_fused_attention`.
 
     `causal=True` is self-attention (i = j) under the lower-triangular
-    mask alone, with `v`'s head size free of q's and k's and, where k and
+    mask, with `v`'s head size free of q's and k's and, where k and
     v have fewer heads than q, each key head serving the q.shape[2] /
     k.shape[2] query heads that follow one another (ops/flash_kernel.py
     `flash_attention_causal_bnhd`, where kernel_qb / kernel_kb force the
     block and the sub-tile of a step; `causal_blockwise_attention` off the
-    kernel): only tiles on or below the diagonal. No bias, no gate.
+    kernel): only tiles on or below the diagonal. With `window` (a static
+    whole number of keys, causal calls only) query i sees keys j with
+    i - window < j <= i, its own the last of them (sliding-window layers;
+    `transformers`' convention): either arm then builds only the tiles of
+    that band, and the call's device operations carry the name
+    `attn_core_window`. No bias, no gate.
     """
+    if window is not None and not causal:
+        raise ValueError("flash_attention: `window` is the causal band's; "
+                         "it needs causal=True")
     # whichever arm runs, its device operations carry the one name
-    with scope("attn_core"):
+    with scope("attn_core" if window is None else "attn_core_window"):
         if causal:
             if pair_bias is not None or gate is not None:
                 raise ValueError("causal flash_attention takes no pair "
@@ -446,7 +481,7 @@ def flash_attention(q, k, v, key_bias=None, *, pair_bias=None, gate=None,
             scale = q.shape[-1] ** -0.5 if scale is None else scale
             return _causal_attention_arms(
                 q, k, v, key_bias, scale, use_kernel, kernel_qb, kernel_kb,
-                blockwise_kwargs)
+                window, blockwise_kwargs)
         return _flash_attention_arms(
             q, k, v, key_bias, pair_bias=pair_bias, gate=gate, scale=scale,
             use_kernel=use_kernel, kernel_qb=kernel_qb, kernel_kb=kernel_kb,
@@ -529,16 +564,23 @@ def _flash_attention_arms(q, k, v, key_bias, *, pair_bias, gate, scale,
     )
 
 
-def causal_kernel_plan(n: int, h: int, dh: int, dv: int, dtype) -> dict | None:
+def causal_kernel_plan(n: int, h: int, dh: int, dv: int, dtype,
+                       window: int | None = None) -> dict | None:
     """What the causal kernel makes of self-attention over n positions
     with h heads of dh (q, k) and dv (v): heads a grid step, block,
-    sub-tile, grid steps a (batch, head group) row (the tiles on or below
-    the diagonal) and planned VMEM; None where the kernel does not take
-    the shape. For a trainer's start-up log (train_lm.py)."""
+    sub-tile, grid steps a (batch, head group) row (`tiles`: those on or
+    below the diagonal, under a `window` those of its band only, beside
+    `tiles_triangle`, the triangle's count) and planned VMEM; None where
+    the kernel does not take the shape. For a trainer's start-up log
+    (train_lm.py)."""
     from alphafold2_tpu.ops import flash_kernel
 
-    plan = flash_kernel.causal_plan(n, h, dh, dv, jnp.dtype(dtype).itemsize)
-    return None if plan is None else plan._asdict()
+    itemsize = jnp.dtype(dtype).itemsize
+    plan = flash_kernel.causal_plan(n, h, dh, dv, itemsize, window=window)
+    if plan is None:
+        return None
+    triangle = flash_kernel.causal_plan(n, h, dh, dv, itemsize).tiles
+    return {**plan._asdict(), "tiles_triangle": triangle}
 
 
 def core_checkpoint_policy():

@@ -1186,14 +1186,24 @@ def flash_attention_fused(q, k, v, bias, scale, *, gate=None, qb=None,
 #     window is 256 lanes, which is what the 128-wide array makes of a
 #     contraction of 192 anyway;
 #   * the backward builds s, p, dp, ds once a tile for all three
-#     gradients: five dots and one exp.
+#     gradients: five dots and one exp;
+#   * with a static `window` (a query sees the `window` keys that end with
+#     its own: sliding-window layers) the walk is the BAND: the tiles a
+#     query block reaches, `causal_schedule`'s `window_blocks` under the
+#     diagonal and no further (8192 positions, blocks and window of 1024:
+#     15 tiles a row where the triangle has 36). A tile that the band's
+#     lower edge crosses takes a second iota mask, built only there as the
+#     diagonal's is built only on the diagonal, and a sub-tile there takes
+#     only the queries (keys) that still reach it. `window=None` is the
+#     triangle: the same tables and the same kernels as before there was a
+#     window.
 #
 # Numerics are the streaming form's: operands in the input dtype, f32
 # logits from the dot's accumulator, finite max sentinel, p and ds cast to
 # the operand dtype for their dots. There is no key bias (the mask is the
-# causal one alone) and every row sees its own key, so no row is without
-# mass and lse stays finite. What each part bought on the chip: PERF.md
-# section 5, the micro-measurement of PR 28.
+# causal one, or the causal band of a window) and every row sees its own
+# key, so no row is without mass and lse stays finite. What each part
+# bought on the chip: PERF.md section 5, the micro-measurement of PR 28.
 
 # what a causal kernel's grid step may plan in VMEM (v5e: 128 MiB); a row
 # whose resident dq needs more takes the XLA arm
@@ -1210,6 +1220,7 @@ class CausalPlan(NamedTuple):
     kb: int       # sub-tile inside a step
     tiles: int    # grid steps a (batch, head group) row
     vmem: int     # planned bytes of the backward step, the larger kernel
+    window: int | None = None  # keys a query sees, its own the last; None: all before it
 
 
 def _lane_group(dh: int, dv: int) -> int:
@@ -1240,11 +1251,19 @@ def _causal_vmem_bytes(n, g, dh, dv, qb, kb, itemsize):
 
 
 def causal_plan(n: int, h: int, dh: int, dv: int, itemsize: int = 2,
-                qb: int | None = None, kb: int | None = None):
+                qb: int | None = None, kb: int | None = None,
+                window: int | None = None):
     """The causal form's plan for self-attention over n positions with h
     heads of dh (q, k) and dv (v), or None where the row's resident dq
     passes _CAUSAL_VMEM_CAP (the call then takes the XLA arm). qb / kb
-    force the blocks (tests, block tuning); kb must divide qb."""
+    force the blocks (tests, block tuning); kb must divide qb.
+
+    `window` (a query sees the `window` keys that end with its own) changes
+    the TILES a row walks (`causal_schedule`: the band's, not the
+    triangle's) and nothing else of the plan: the blocks, the head group
+    and the backward's resident dq, so the length the kernel takes
+    (`supported_causal`) is the same with and without one. A window of n
+    or more is the plain triangle and comes back as None."""
     if qb is None:
         qb = pick_block(n, target=_CAUSAL_QB)
     if kb is None:
@@ -1259,33 +1278,60 @@ def causal_plan(n: int, h: int, dh: int, dv: int, itemsize: int = 2,
     vmem = _causal_vmem_bytes(nb * qb, g, dh, dv, qb, kb, itemsize)
     if vmem > _CAUSAL_VMEM_CAP:
         return None
-    return CausalPlan(g, qb, kb, nb * (nb + 1) // 2, vmem)
+    if window is not None and window >= n:
+        window = None
+    tiles = causal_schedule(nb, window_blocks=window_blocks(window, qb)).shape[1]
+    return CausalPlan(g, qb, kb, tiles, vmem, window)
 
 
 def supported_causal(i: int, j: int, dh: int, dv: int) -> bool:
     """Shapes the causal form takes: self-attention (i = j), both head
     sizes sublane-aligned, and a row whose resident dq fits the plan (at
-    the head group the head sizes ask for, float32 operands)."""
+    the head group the head sizes ask for, float32 operands). A window
+    does not enter: `causal_plan` says why."""
     return (i == j and dh % 8 == 0 and dh <= 512 and dv % 8 == 0
             and dv <= 512
             and causal_plan(i, _lane_group(dh, dv), dh, dv, 4) is not None)
 
 
-def causal_schedule(nb: int, key_major: bool = False):
-    """The triangular walk over nb x nb blocks as a (4, nb (nb + 1) / 2)
-    int32 table: rows `query block`, `key block`, `first`, `last`. Every
-    pair with key <= query appears once. Query-major (the forward): a
-    query block's keys ascend, `first` / `last` flag its first and last
-    tile. Key-major (the backward): a key block's queries ascend from the
-    diagonal, the flags are the key block's."""
+def window_blocks(window: int | None, qb: int) -> int | None:
+    """How many blocks under the diagonal a query block of qb still
+    reaches with `window`: the tile d blocks under it holds a pair in the
+    band where its nearest pair does, d qb - (qb - 1) < window."""
+    return None if window is None else (window + qb - 2) // qb
+
+
+def masked_distances(window: int | None, qb: int) -> tuple:
+    """The distances d under the diagonal whose tile needs a mask: the
+    diagonal's own, and with a window those the band's lower edge crosses
+    (the tile's farthest pair, d qb + qb - 1 apart, lies outside)."""
+    if window is None:
+        return (0,)
+    back = window_blocks(window, qb)
+    return (0,) + tuple(d for d in range(1, back + 1) if (d + 1) * qb - 1 >= window)
+
+
+def causal_schedule(nb: int, key_major: bool = False,
+                    window_blocks: int | None = None):
+    """The walk over nb x nb blocks as a (4, tiles) int32 table: rows
+    `query block`, `key block`, `first`, `last`. Every pair with key <=
+    query appears once: nb (nb + 1) / 2 tiles; with `window_blocks` only
+    the pairs at most that many blocks under the diagonal (the band).
+    Query-major (the forward): a query block's keys ascend, `first` /
+    `last` flag its first and last tile. Key-major (the backward): a key
+    block's queries ascend from the diagonal, the flags are the key
+    block's."""
     import numpy as np
 
+    back = nb if window_blocks is None else window_blocks
     if key_major:
-        pairs = [(qi, ki) for ki in range(nb) for qi in range(ki, nb)]
-        flags = [(qi == ki, qi == nb - 1) for qi, ki in pairs]
+        pairs = [(qi, ki) for ki in range(nb)
+                 for qi in range(ki, min(nb - 1, ki + back) + 1)]
+        flags = [(qi == ki, qi == min(nb - 1, ki + back)) for qi, ki in pairs]
     else:
-        pairs = [(qi, ki) for qi in range(nb) for ki in range(qi + 1)]
-        flags = [(ki == 0, ki == qi) for qi, ki in pairs]
+        pairs = [(qi, ki) for qi in range(nb)
+                 for ki in range(max(0, qi - back), qi + 1)]
+        flags = [(ki == max(0, qi - back), ki == qi) for qi, ki in pairs]
     return np.array([[q for q, _ in pairs], [k for _, k in pairs],
                      [f for f, _ in flags], [l for _, l in flags]], np.int32)
 
@@ -1318,11 +1364,17 @@ def _own_lanes(sel, x):
     return x if sel is None else _keep(sel, x.astype(jnp.float32)).astype(x.dtype)
 
 
-def _causal_mask_t(st, k0, q0):
-    """Transposed logits (keys down, queries across) under the mask."""
+def _causal_mask_t(st, k0, q0, window=None, diagonal=True):
+    """Transposed logits (keys down, queries across) under the mask: the
+    causal one on a `diagonal` tile, the band's lower edge with a
+    `window`. k0, q0: the first key's and the first query's position,
+    from any common origin."""
     keys = k0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
     queries = q0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-    return jnp.where(keys <= queries, st, _M0)
+    if window is None:
+        return jnp.where(keys <= queries, st, _M0)
+    seen = keys > queries - window
+    return jnp.where((keys <= queries) & seen if diagonal else seen, st, _M0)
 
 
 _NT = (((1,), (1,)), ((), ()))  # (m, d) x (n, d) -> (m, n)
@@ -1332,16 +1384,46 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _on_diagonal(sched_ref, t, tile):
-    """tile(True) where step t's tile lies on the diagonal, else
-    tile(False)."""
-    diagonal = sched_ref[0, t] == sched_ref[1, t]
-    pl.when(diagonal)(lambda: tile(True))
-    pl.when(jnp.logical_not(diagonal))(lambda: tile(False))
+def _by_distance(sched_ref, t, tile, window, qb):
+    """tile(d) where step t's tile lies d blocks under the diagonal, for
+    each d of `masked_distances` (static: the tiles that take a mask, the
+    diagonal's among them), and tile(None) on every other step, where the
+    walk has any (the triangle always; a band only if it reaches past
+    its masked tiles)."""
+    qi, ki = sched_ref[0, t], sched_ref[1, t]
+    masked = masked_distances(window, qb)
+    hits = [qi == ki + d if d else qi == ki for d in masked]
+    for d, hit in zip(masked, hits):
+        pl.when(hit)(functools.partial(tile, d))
+    if window is None or window_blocks(window, qb) >= len(masked):
+        pl.when(jnp.logical_not(functools.reduce(jnp.logical_or, hits)))(
+            lambda: tile(None))
+
+
+def _seen_span(d, c, qb, kb, window, keys_of_queries):
+    """[lo, hi) of a tile's other side that sub-tile c works with, in whole
+    sub-tiles: the forward's queries that see key sub-tile c, or (with
+    `keys_of_queries`) the backward's keys that query sub-tile c sees. d:
+    the tile's distance under the diagonal, None where it takes no mask.
+    On the diagonal a key sub-tile is seen from its own queries on; where
+    the band's lower edge crosses, up to the last query that reaches it."""
+    lo, hi = 0, qb
+    if keys_of_queries:
+        if d == 0:
+            hi = (c + 1) * kb
+        if d is not None and window is not None:
+            lo = max(0, (c * kb + d * qb - window + 1) // kb * kb)
+    else:
+        if d == 0:
+            lo = c * kb
+        if d is not None and window is not None:
+            hi = min(qb, _round_up(max(0, (c + 1) * kb - 1 + window - d * qb), kb))
+    return lo, hi
 
 
 def _causal_fwd_kernel(sched_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
-                       qm_scr, m_scr, l_scr, acc_scr, *, scale, g, dh, dv, kb):
+                       qm_scr, m_scr, l_scr, acc_scr, *, scale, g, dh, dv, kb,
+                       window=None):
     t = pl.program_id(2)
     qi = sched_ref[0, t]
     qb = q_ref.shape[1]
@@ -1355,28 +1437,31 @@ def _causal_fwd_kernel(sched_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
         for hh, (w0, w1, sel) in enumerate(wins):
             qm_scr[hh, :, :w1 - w0] = _own_lanes(sel, q_ref[0, :, w0:w1])
 
-    def tile(diagonal):
+    def tile(d):
         for hh, (w0, w1, _) in enumerate(wins):
             for c in range(qb // kb):
-                # on the diagonal, sub-tile c is seen from its own queries on
-                lo = c * kb if diagonal else 0
+                # the queries that see key sub-tile c (`_seen_span`)
+                lo, hi = _seen_span(d, c, qb, kb, window, False)
+                if lo >= hi:
+                    continue
                 keys = slice(c * kb, (c + 1) * kb)
-                st = _dot(k_ref[0, keys, w0:w1], qm_scr[hh, lo:, :w1 - w0],
+                st = _dot(k_ref[0, keys, w0:w1], qm_scr[hh, lo:hi, :w1 - w0],
                           _NT) * scale
-                if diagonal:
-                    st = _causal_mask_t(st, 0, 0)
+                if d is not None:
+                    st = _causal_mask_t(st, 0, d * qb + lo - c * kb, window,
+                                        d == 0)
                 v = v_ref[0, keys, hh * dv:(hh + 1) * dv]
-                m = m_scr[hh, :, lo:]                       # (1, qb - lo)
+                m = m_scr[hh, :, lo:hi]                     # (1, hi - lo)
                 m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
                 alpha = jnp.exp(m - m_new)
                 pt = jnp.exp(st - m_new)
-                l_scr[hh, :, lo:] = l_scr[hh, :, lo:] * alpha + jnp.sum(
+                l_scr[hh, :, lo:hi] = l_scr[hh, :, lo:hi] * alpha + jnp.sum(
                     pt, axis=0, keepdims=True)
-                acc_scr[hh, :, lo:] = acc_scr[hh, :, lo:] * alpha + _dot(
-                    v, pt.astype(v.dtype), _TN)             # (dv, qb - lo)
-                m_scr[hh, :, lo:] = m_new
+                acc_scr[hh, :, lo:hi] = acc_scr[hh, :, lo:hi] * alpha + _dot(
+                    v, pt.astype(v.dtype), _TN)             # (dv, hi - lo)
+                m_scr[hh, :, lo:hi] = m_new
 
-    _on_diagonal(sched_ref, t, tile)
+    _by_distance(sched_ref, t, tile, window, qb)
 
     @pl.when(sched_ref[3, t] == 1)
     def _finish():
@@ -1390,7 +1475,7 @@ def _causal_fwd_kernel(sched_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
 def _causal_bwd_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                        delta_ref, dq_ref, dk_ref, dv_ref,
                        km_scr, dq_scr, dk_scr, dv_scr, *,
-                       scale, g, dh, dv, kb, tiles):
+                       scale, g, dh, dv, kb, tiles, window=None):
     t = pl.program_id(2)
     qi = sched_ref[0, t]
     qb = k_ref.shape[1]
@@ -1407,29 +1492,31 @@ def _causal_bwd_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         for hh, (w0, w1, sel) in enumerate(wins):
             km_scr[hh, :, :w1 - w0] = _own_lanes(sel, k_ref[0, :, w0:w1])
 
-    def tile(diagonal):
+    def tile(d):
         r0 = pl.multiple_of(qi * qb, qb)
         for hh, (w0, w1, _) in enumerate(wins):
             lanes = slice(hh * dv, (hh + 1) * dv)
             for c in range(qb // kb):
-                # on the diagonal, query chunk c sees the keys up to its own
-                hi = (c + 1) * kb if diagonal else qb
+                # the keys that query chunk c sees (`_seen_span`)
+                lo, hi = _seen_span(d, c, qb, kb, window, True)
+                if lo >= hi:
+                    continue
                 rows = slice(c * kb, (c + 1) * kb)
                 q = q_ref[0, rows, w0:w1]
                 do = do_ref[0, rows, lanes]
-                k = km_scr[hh, :hi, :w1 - w0]
-                st = _dot(k, q, _NT) * scale                # (hi, kb)
-                if diagonal:
-                    st = _causal_mask_t(st, 0, c * kb)
+                k = km_scr[hh, lo:hi, :w1 - w0]
+                st = _dot(k, q, _NT) * scale                # (hi - lo, kb)
+                if d is not None:
+                    st = _causal_mask_t(st, lo, d * qb + c * kb, window, d == 0)
                 pt = jnp.exp(st - lse_ref[0, hh, qi, rows][None, :])
-                dpt = _dot(v_ref[0, :hi, lanes], do, _NT)
+                dpt = _dot(v_ref[0, lo:hi, lanes], do, _NT)
                 dst = (pt * (dpt - delta_ref[0, hh, qi, rows][None, :])).astype(
                     q.dtype)
-                dv_scr[:hi, lanes] += _dot(pt.astype(do.dtype), do)
-                dk_scr[hh, :hi, :w1 - w0] += _dot(dst, q)
+                dv_scr[lo:hi, lanes] += _dot(pt.astype(do.dtype), do)
+                dk_scr[hh, lo:hi, :w1 - w0] += _dot(dst, q)
                 dq_scr[pl.ds(r0 + c * kb, kb), w0:w1] += _dot(dst, k, _TN)
 
-    _on_diagonal(sched_ref, t, tile)
+    _by_distance(sched_ref, t, tile, window, qb)
 
     @pl.when(sched_ref[3, t] == 1)
     def _finish():
@@ -1485,7 +1572,7 @@ def _causal_forward(q, k, v, scale, dh, plan):
     q_at_q, v_at_q, k_at_k, v_at_k, rows, ww = _causal_specs(plan, n, dh, dv)
     return pl.pallas_call(
         functools.partial(_causal_fwd_kernel, scale=scale, g=g, dh=dh, dv=dv,
-                          kb=kb),
+                          kb=kb, window=plan.window),
         out_shape=[
             _out_struct((B, n, h * dv), q.dtype, q, k, v),
             _out_struct((B, h, n // qb, qb), jnp.float32, q, k, v),
@@ -1504,7 +1591,8 @@ def _causal_forward(q, k, v, scale, dh, plan):
         ),
         compiler_params=_causal_params(plan),
         interpret=_interpret(),
-    )(jnp.asarray(causal_schedule(n // qb)), q, k, v)
+    )(jnp.asarray(causal_schedule(
+        n // qb, window_blocks=window_blocks(plan.window, qb))), q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -1548,7 +1636,7 @@ def _causal_bwd(scale, dh, plan, res, do):
     whole = pl.BlockSpec((1, n, g * dh), lambda b, p, t, sched: (b, 0, p))
     return tuple(pl.pallas_call(
         functools.partial(_causal_bwd_kernel, scale=scale, g=g, dh=dh, dv=dv,
-                          kb=kb, tiles=plan.tiles),
+                          kb=kb, tiles=plan.tiles, window=plan.window),
         out_shape=[
             _out_struct(q.shape, q.dtype, q, k, v, do),
             _out_struct(k.shape, k.dtype, q, k, v, do),
@@ -1568,22 +1656,28 @@ def _causal_bwd(scale, dh, plan, res, do):
         ),
         compiler_params=_causal_params(plan),
         interpret=_interpret(),
-    )(jnp.asarray(causal_schedule(n // qb, key_major=True)), q, k, v, do, lse,
-      delta))
+    )(jnp.asarray(causal_schedule(
+        n // qb, key_major=True,
+        window_blocks=window_blocks(plan.window, qb))), q, k, v, do, lse, delta))
 
 
 _causal_core.defvjp(_causal_fwd, _causal_bwd)
 
 
-def flash_attention_causal_bnhd(q, k, v, scale, qb=None, kb=None):
+def flash_attention_causal_bnhd(q, k, v, scale, qb=None, kb=None, window=None):
     """Causal self-attention in the model's layout. q, k: (B, n, h, dh);
     v: (B, n, h, dv). Returns (B, n, h, dv). The kernels read the operands
     as (B, n, h * d), `causal_plan`'s heads a grid step; n pads to the
     block (a padded key lies past every real query, a padded query row is
-    cut away). qb / kb force the block and the sub-tile."""
+    cut away). qb / kb force the block and the sub-tile. `window` (static):
+    query i sees keys j with i - window < j <= i, any whole number of keys
+    from 1 up; None, or n and more, is the plain triangle."""
     B, n, h, dh = q.shape
     dv = v.shape[-1]
-    plan = causal_plan(n, h, dh, dv, q.dtype.itemsize, qb, kb)
+    if window is not None and window < 1:
+        raise ValueError(f"causal kernel: a window of {window} keys holds no "
+                         "key; every query sees its own")
+    plan = causal_plan(n, h, dh, dv, q.dtype.itemsize, qb, kb, window)
     if plan is None:
         raise ValueError(
             f"causal kernel: a row of n={n} at h={h} dh={dh} dv={dv} does "

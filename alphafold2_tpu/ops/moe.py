@@ -17,9 +17,10 @@ follows the load the router gave, not the bound. A chunk holds
 
 The layer is also TOLD its routing: `moe_apply` takes (picks, weights,
 load) from its caller, so that a model with a router of its own shares
-everything after it. Both routers here pick the top-k of `scores + b`
+everything after it. The routers here pick the top-k of `scores + b`
 (`pick`: `b` a selection bias that carries no gradient and is moved after
-each step by `bias_update`) and weigh by the scores at the picks:
+each step by `bias_update`; a router without one hands None) and weigh by
+the scores at the picks:
 
   * `route` (DeepSeek-V3's `noaux_tc`, as HF `deepseek_v3` computes it
     with `n_group` = `topk_group` = 1): `s = sigmoid(x W_g)` in float32;
@@ -27,7 +28,10 @@ each step by `bias_update`) and weigh by the scores at the picks:
     `routed_scaling_factor`;
   * `route_softmax` (ZAYA1): the caller's logits (models/decoder.py: an
     MLP over a state carried from layer to layer) through a softmax in
-    float32; the weight is the picked probability itself.
+    float32; the weight is the picked probability itself;
+  * `route_softmax` with `norm_topk` and no bias (Mellum 2, the Qwen3-MoE
+    rule): `softmax(x W_g)` (`router_logits`) over all experts, the top-k,
+    their probabilities divided by their sum.
 """
 
 from __future__ import annotations
@@ -98,31 +102,45 @@ def pick(scores, bias, top_k: int):
     """The top-k of `scores + bias` over ALL experts. scores: (N, E)
     float32. Returns (idx (N, top_k) int32, the scores at the picks
     (N, top_k), load (E,) float32: how many of the N * top_k assignments
-    each expert received). The bias enters the choice and not the weight."""
-    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    each expert received). The bias enters the choice and not the weight;
+    None where the router has none."""
+    chosen_by = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    _, idx = jax.lax.top_k(chosen_by, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     load = jnp.zeros((scores.shape[-1],), jnp.float32).at[idx.reshape(-1)].add(1.0)
     return idx, w, jax.lax.stop_gradient(load)
+
+
+def router_logits(params, x):
+    """x W_g over ALL experts, in float32. x: (N, d)."""
+    return jnp.matmul(x.astype(jnp.float32), params["router"]["w"],
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _over_their_sum(w):
+    return w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
 
 
 def route(params, x, *, top_k: int, scaling: float, norm_topk: bool):
     """Sigmoid scores over ALL experts and the picks. x: (N, d). Returns
     (idx, weights, load) as `pick` does, the weights normalised and
     scaled."""
-    s = jax.nn.sigmoid(jnp.matmul(
-        x.astype(jnp.float32), params["router"]["w"],
-        precision=jax.lax.Precision.HIGHEST))
+    s = jax.nn.sigmoid(router_logits(params, x))
     idx, w, load = pick(s, params["bias"], top_k)
     if norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = _over_their_sum(w)
     return idx, w * scaling, load
 
 
-def route_softmax(logits, bias, top_k: int = 1):
+def route_softmax(logits, bias, top_k: int = 1, norm_topk: bool = False):
     """Softmax probabilities over ALL experts from the caller's `logits`
     (N, E), in float32, and the picks; the weight of a pick is its
-    probability. Returns (idx, weights, load) as `pick` does."""
-    return pick(jax.nn.softmax(logits.astype(jnp.float32), axis=-1), bias, top_k)
+    probability, with `norm_topk` over the sum of the token's picked ones.
+    `bias` None: the router has no selection bias. Returns (idx, weights,
+    load) as `pick` does."""
+    idx, w, load = pick(jax.nn.softmax(logits.astype(jnp.float32), axis=-1),
+                        bias, top_k)
+    return idx, _over_their_sum(w) if norm_topk else w, load
 
 
 def bias_update(bias, load, rate: float):

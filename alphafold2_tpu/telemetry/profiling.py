@@ -58,11 +58,11 @@ TAIL_SCOPES = (
 #: the decoder language models (models/decoder.py, training/lm.py):
 #: `decoder_layers` is the scans over the layers (what is left to it: the
 #: slices of the stacked parameters, the gradient's write-back), `mla_attn`
-#: (`deepseek_v3`) or `cca_attn` (`zaya`), `dense_mlp` and `moe` a layer's
-#: halves, `residual_scale` the `zaya` block's a * h + c, `lm_head_loss` the
-#: final norm, the head (tied or not) and the cross-entropy
+#: (`deepseek_v3`), `cca_attn` (`zaya`) or `gqa_attn` (`mellum`), `dense_mlp`
+#: and `moe` a layer's halves, `residual_scale` the `zaya` block's a * h + c,
+#: `lm_head_loss` the final norm, the head (tied or not) and the cross-entropy
 DECODER_SCOPES = ("lm_embed", "decoder_layers", "mla_attn", "dense_mlp", "moe",
-                  "lm_head_loss", "cca_attn", "residual_scale")
+                  "lm_head_loss", "cca_attn", "residual_scale", "gqa_attn")
 #: opt.update + apply_updates + global_norm (training/harness.py)
 OPTIMIZER_SCOPE = "optimizer"
 OUTER_SCOPES = (MODEL_SCOPES + TRUNK_OP_SCOPES + TAIL_SCOPES + DECODER_SCOPES
@@ -73,10 +73,12 @@ TRUNK_INNER_SCOPES = ("qkv_proj", "attn_core", "out_proj", "kv_compress", "geglu
 #: `attn_core` and `out_proj`), inside the expert layer (ops/moe.py), and
 #: inside compressed convolutional attention (`conv_mix`: both convolutions
 #: and the q-k mean; `qk_norm_rope`; `value_shift`: the values, half of them
-#: the previous token's)
+#: the previous token's); `attn_core_window` is the causal core under a
+#: sliding window (ops/flash.py: `mellum`'s window layers), `attn_core`
+#: the core without one
 DECODER_INNER_SCOPES = ("kv_down_up", "rope", "router", "dispatch", "experts",
                         "combine", "shared_expert", "conv_mix", "qk_norm_rope",
-                        "value_shift")
+                        "value_shift", "attn_core_window")
 INNER_SCOPES = TRUNK_INNER_SCOPES + DECODER_INNER_SCOPES
 #: phase marker: the body of the reversible trunk's hand-written backward,
 #: so that a `jvp(...)` under it reads as the reconstruction and not as the

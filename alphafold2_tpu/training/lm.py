@@ -1,5 +1,5 @@
 """Language-model training on the shared harness: the next-token loss of
-the decoder (models/decoder.py: either family, by the configuration's
+the decoder (models/decoder.py: any of its families, by the configuration's
 class), its parameters, its step metrics and a seeded token source.
 
 `make_train_step(cfg, tcfg, loss_fn=lm_loss_fn,
@@ -107,10 +107,12 @@ def lm_loss_fn(params, cfg, batch, rng=None):
 def lm_aux_update(cfg):
     """`aux_update` for `make_train_step`: (params after the optimizer,
     aux summed over the step's microbatches) -> (params with each MoE
-    layer's selection bias moved, step metrics). Either family keeps the
-    bias at `moe/mlp/bias` (`zaya`'s one stack is `moe`). Per MoE layer:
-    assignments held here and most-loaded over mean load of the held
-    experts (none is dropped: the expert layer has no capacity)."""
+    layer's selection bias moved, step metrics). A family that has the
+    bias keeps it at `moe/mlp/bias` (`zaya`'s one stack is `moe`); one
+    whose router has none (`mellum`) has nothing moved. Per MoE layer, a
+    row a layer in the published order: assignments held here and
+    most-loaded over mean load of the held experts (none is dropped: the
+    expert layer has no capacity)."""
     lo, hi = cfg.held
 
     def update(params, aux):
@@ -118,9 +120,10 @@ def lm_aux_update(cfg):
             return params, {}
         load = aux["load"]  # (n_moe, E)
         mlp = params["moe"]["mlp"]
-        moved = bias_update(mlp["bias"], load, cfg.bias_update_rate)
-        params = {**params, "moe": {**params["moe"],
-                                    "mlp": {**mlp, "bias": moved}}}
+        if "bias" in mlp:
+            moved = bias_update(mlp["bias"], load, cfg.bias_update_rate)
+            params = {**params, "moe": {**params["moe"],
+                                        "mlp": {**mlp, "bias": moved}}}
         held = load[:, lo:hi]
         metrics = {
             "moe_assignments_held": jnp.sum(held, axis=-1),

@@ -202,9 +202,45 @@ def zaya_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
     }
 
 
+def band_pairs(length: int, window) -> float:
+    """(query, key) pairs a sequence and head under the causal mask: each
+    query and the keys at or before it, at most `window` of them (None:
+    all). length (length + 1) / 2 without a window."""
+    w = length if window is None else min(window, length)
+    return w * (w + 1) / 2.0 + (length - w) * w
+
+
+def mellum_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
+    """The same for the `mellum` family (`cfg`: any object with
+    MellumConfig's fields): the four projections at the query and key
+    heads' widths, the core of the window layers over the BAND
+    (`attn_core_window`) and of the full layers over the triangle
+    (`attn_core`), both at the QUERY heads' count, the router, the
+    assignments HELD, the untied head's L - 1 rows a sequence."""
+    n = batch * length
+    d, h, hk, dh = (cfg.hidden_size, cfg.num_attention_heads,
+                    cfg.num_key_value_heads, cfg.head_dim)
+    layers = cfg.num_hidden_layers
+    kinds = list(cfg.layer_types)[:layers]
+    n_window = sum(kind == "sliding_attention" for kind in kinds)
+    lo, hi = cfg.experts_held or (0, cfg.num_experts)
+    if assignments is None:
+        assignments = n * cfg.num_experts_per_tok * (hi - lo) / cfg.num_experts
+    pair_flops = 2.0 * batch * h * 2 * dh
+    return {
+        "gqa_proj": layers * 2.0 * n * d * (2 * h * dh + 2 * hk * dh),
+        "attn_core_window": n_window * pair_flops * band_pairs(length, cfg.sliding_window),
+        "attn_core": (layers - n_window) * pair_flops * band_pairs(length, None),
+        "router": layers * 2.0 * n * d * cfg.num_experts,
+        "experts": layers * 2.0 * assignments * 3 * d * cfg.moe_intermediate_size,
+        "head": 2.0 * batch * (length - 1) * d * cfg.vocab_size,
+    }
+
+
 def decoder_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
     """The family's count, by the configuration's `model_type`."""
-    count = {"deepseek_v3": deepseek_fwd_op_flops, "zaya": zaya_fwd_op_flops}
+    count = {"deepseek_v3": deepseek_fwd_op_flops, "zaya": zaya_fwd_op_flops,
+             "mellum": mellum_fwd_op_flops}
     return count[cfg.model_type](cfg, batch, length, assignments)
 
 

@@ -107,6 +107,38 @@ def test_causal_kernel_compiles_for_v5e_at_the_decoders_widths(one_chip,
     assert " transpose(" not in grad and " pad(" not in grad
 
 
+@pytest.mark.parametrize("window,tiles", [(1024, 15), (1000, 15), (1536, 21)],
+                         ids=["the_cells", "no_multiple_of_the_block", "a_block_and_a_half"])
+def test_windowed_causal_kernel_compiles_for_v5e_at_the_decoders_widths(
+        one_chip, compiled_mode, window, tiles):
+    """The `mellum` cell's window layers, 2 x 8192 tokens, 32 query heads
+    over 4 key heads of 128: one forward and ONE backward kernel on the
+    band's tiles (15 of the triangle's 36 at the published window), the
+    sub-tiles the band's edge cuts sliced on the tiling."""
+    B, n, h, hk, dh = 2, 8192, 32, 4, 128
+    plan = flash_kernel.causal_plan(n, h, dh, dh, window=window)
+    assert (plan.tiles, plan.window, plan.qb) == (tiles, window, 1024)
+    assert flash_kernel.causal_plan(n, h, dh, dh).tiles == 36
+
+    def sd(heads):
+        return jax.ShapeDtypeStruct((B, n, heads * dh), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def fwd(q, k, v):
+        out = flash_attention(q.reshape(B, n, h, dh), k.reshape(B, n, hk, dh),
+                              v.reshape(B, n, hk, dh), causal=True, window=window,
+                              use_kernel=True)
+        return out.reshape(B, n, h * dh)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    args = (sd(h), sd(hk), sd(hk))
+    assert jax.jit(fwd).lower(*args).compile().as_text().count(CALL) == 1
+    grad = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args).compile().as_text()
+    assert grad.count(CALL) == 2
+
+
 def _copies_of(shape, text):
     """` copy(` operations of a compiled step's text whose result has `shape`."""
     dims = ",".join(map(str, shape)) + "]"

@@ -383,7 +383,7 @@ _PAIR_AXIAL = dict(i=1152, j=1152, dh=64)
 _PROBE = dict(i=1152, j=4096, dh=64)
 _CAUSAL_8K = dict(i=8192, j=8192, dh=192, dv=128, causal=True)
 _QUANT = dict(m=4096, k=512, n=512, x_dtype="float32")
-_CELL_PLAN = {"g": 2, "qb": 1024, "kb": 256, "tiles": 36}
+_CELL_PLAN = {"g": 2, "qb": 1024, "kb": 256, "tiles": 36, "window": None}
 _BELOW_CROSSOVER = [dict(i=3456, j=32, dh=64), dict(i=128, j=864, dh=64),
                     dict(i=384, j=384, dh=64)]
 

@@ -53,9 +53,11 @@ def lower_template_forward():
 
 def lower_toy_lm_step(family="deepseek_v3"):
     """The decoder's train step at toy widths (`deepseek_v3`: one dense
-    and one MoE layer; `zaya`: two layers): the names of
-    models/decoder.py, ops/moe.py, training/lm.py."""
-    from alphafold2_tpu.models.decoder import DecoderConfig, ZayaConfig
+    and one MoE layer; `zaya`: two layers; `mellum`: one period of two
+    window layers and a full one): the names of models/decoder.py,
+    ops/moe.py, training/lm.py."""
+    from alphafold2_tpu.models.decoder import (FULL, SLIDING, DecoderConfig,
+                                               MellumConfig, ZayaConfig)
     from alphafold2_tpu.training.harness import make_optimizer
     from alphafold2_tpu.training.lm import (lm_aux_update, lm_loss_fn,
                                             lm_params_init)
@@ -72,6 +74,14 @@ def lower_toy_lm_step(family="deepseek_v3"):
             vocab_size=64, hidden_size=32, num_hidden_layers=2,
             num_attention_heads=4, num_key_value_heads=2, head_dim=8,
             moe_intermediate_size=16, num_experts=4, router_hidden_size=8,
+            experts_held=(0, 2), dtype="float32")
+    if family == "mellum":
+        cfg = MellumConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=3,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
+            layer_types=(SLIDING, SLIDING, FULL), sliding_window=6,
+            rope_parameters={SLIDING: {"rope_theta": 1e4}, FULL: {"rope_theta": 1e4}},
             experts_held=(0, 2), dtype="float32")
     tcfg = TrainConfig(grad_accum=1)
     state = jax.eval_shape(
@@ -100,7 +110,8 @@ def paths():
     return (op_paths(lower_toy_step().compile())
             | op_paths(lower_template_forward().compile())
             | op_paths(lower_toy_lm_step().compile())
-            | op_paths(lower_toy_lm_step("zaya").compile()))
+            | op_paths(lower_toy_lm_step("zaya").compile())
+            | op_paths(lower_toy_lm_step("mellum").compile()))
 
 
 @pytest.mark.parametrize("name", profiling.SCOPES)

@@ -1,10 +1,13 @@
 """Language-model pretraining entry point: the decoder (models/decoder.py)
-on the shared harness. It has TWO families, and a configuration file's
+on the shared harness. It has THREE families, and a configuration file's
 `model_type` chooses: `deepseek_v3` (latent attention, a mixture of
-experts with shared experts; the default, and the toy) and `zaya`
+experts with shared experts; the default, and the toy), `zaya`
 (compressed convolutional attention with grouped keys, a top-1 mixture
 picked by an MLP router that carries state from layer to layer, a scaled
-residual stream, a tied head).
+residual stream, a tied head) and `mellum` (grouped-query attention in
+sliding-window and full causal layers mixed by `layer_types`, YaRN on the
+full ones, a softmax top-k mixture of narrow experts with no shared
+expert, an untied head).
 
 One jitted, donated optimizer step (`make_train_step` with `lm_loss_fn`
 and `lm_aux_update`: the same builder `train_pre.py` and
@@ -63,14 +66,15 @@ _TOY = dict(
 
 
 def config_from_file(path: str, dtype: str):
-    """A DecoderConfig or a ZayaConfig, by the file's `model_type`
-    (`deepseek_v3` where it has none), from the published config.json's
-    keys (and, under `assumed_values`, what config.json does not give).
-    Where the file states `experts_held`, its count of experts is of those
-    held and `published` has the router's width; where it states `layers`,
-    that is the depth to build and `num_hidden_layers` is the source's
-    (benchmarks/configs/). `zaya`'s `rope_theta` is its `rope_parameters`'
-    for the `hybrid` layers."""
+    """A DecoderConfig, a ZayaConfig or a MellumConfig, by the file's
+    `model_type` (`deepseek_v3` where it has none), from the published
+    config.json's keys (and, under `assumed_values`, what config.json does
+    not give). Where the file states `experts_held`, its count of experts
+    is of those held and `published` has the router's width; where it
+    states `layers`, that is the depth to build and `num_hidden_layers` is
+    the source's (benchmarks/configs/). `zaya`'s `rope_theta` is its
+    `rope_parameters`' for the `hybrid` layers; `mellum` takes
+    `rope_parameters` whole and the first `layers` of `layer_types`."""
     with open(path) as f:
         raw = json.load(f)
     model_type = raw.get("model_type", "deepseek_v3")
@@ -86,8 +90,10 @@ def config_from_file(path: str, dtype: str):
         sizes[cls.router_width_key] = raw["published"][cls.router_width_key]
     if "layers" in raw:
         sizes["num_hidden_layers"] = raw["layers"]
-    if "rope_parameters" in raw:
+    if "rope_parameters" in raw and "rope_theta" in fields:
         sizes["rope_theta"] = float(raw["rope_parameters"]["hybrid"]["rope_theta"])
+    if "layer_types" in fields:
+        sizes["layer_types"] = raw["layer_types"][:sizes["num_hidden_layers"]]
     return cls(dtype=dtype, **sizes)
 
 
@@ -96,7 +102,7 @@ def main():
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--config", default=None,
                     help="JSON file of the published config.json's keys; its "
-                    "model_type picks the family (deepseek_v3 or zaya)")
+                    "model_type picks the family (deepseek_v3, zaya or mellum)")
     ap.add_argument("--batch", type=int, default=2, help="sequences a microbatch")
     ap.add_argument("--len", dest="length", type=int, default=256)
     ap.add_argument("--accum", type=int, default=1)
@@ -188,13 +194,19 @@ def main():
                 # the arm each call site took, and what the causal kernel
                 # makes of the core's shape where it is the arm: block,
                 # sub-tile, grid steps a row (the tiles on or below the
-                # diagonal), and the two results of it that each layer's
-                # checkpoint keeps for the backward pass, in bytes a layer
+                # diagonal; a family with sliding-window layers has their
+                # plan beside it: the band's tiles and the triangle's), and
+                # the two results of it that each layer's checkpoint keeps
+                # for the backward pass, in bytes a layer
                 core = (args.length, cfg.num_attention_heads, cfg.qk_head_dim,
                         cfg.v_head_dim, cfg.compute_dtype)
+                window = getattr(cfg, "sliding_window", None)
+                plans = {} if window is None else {
+                    "causal_kernel_plan_window": causal_kernel_plan(
+                        *core, window=window)}
                 logger.event(
                     step, "dispatch", decisions=dispatch.decisions(),
-                    causal_kernel_plan=causal_kernel_plan(*core),
+                    causal_kernel_plan=causal_kernel_plan(*core), **plans,
                     layer_checkpoint_saves=causal_saved_bytes(args.batch, *core))
             telemetry.step_complete(step)
             if step % 10 == 0 or step == start + args.steps - 1:
