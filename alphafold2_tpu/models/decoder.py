@@ -882,8 +882,8 @@ def decoder_init(key, cfg):
 
 def decoder_apply(params, cfg, tokens):
     """tokens (B, L) int -> (hidden (B, L, d) after the final norm, in the
-    compute dtype; aux {"load": (n_moe, E), "picks": (n_moe, B*L, top_k)},
-    empty without MoE layers)."""
+    compute dtype; aux {"load": (n_moe, E), "picks": (n_moe, B*L, top_k),
+    "rows_walked": (n_moe,)}, empty without MoE layers)."""
     with scope("lm_embed"):
         # rows from the float32 table, so that the table's gradient adds
         # up in float32 however often a token repeats

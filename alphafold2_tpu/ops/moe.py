@@ -8,12 +8,21 @@ and scatters the results back, weighted. What the absent experts would
 add is left out (on a deployment it arrives through the exchange, which
 one chip runs without); nothing here stands in for other chips.
 
-No capacity and no dropped token: the sorted rows are walked in chunks
-up to the worst case (every token's every pick held here), and a chunk
-past the last held assignment is skipped by `lax.cond`, so the work
-follows the load the router gave, not the bound. A chunk holds
-`CHUNK_OVER_EXPECTED` times the load the layer expects from its inputs
-(`chunk_rows_for`).
+No capacity and no dropped token: the sorted rows are walked in blocks
+of `block_rows_for` rows by a loop whose bound is the number of LIVE
+blocks (`ceil(held assignments / block rows)`, data: a while loop), so
+the work follows the load the router gave, up to the worst case (every
+token's every pick held here) and not beyond the load by more than a
+block. The bound being data, autodiff cannot transpose the loop: `_walk`
+is a `jax.custom_vjp` whose backward is a second loop over the same
+blocks, written by hand across blocks (the float32 carries of the
+experts' weight gradients, of x's and of the routing weights') and left
+to autodiff within one (`jax.vjp` of `_block_rows_out` under
+`jax.checkpoint`, so the grouped products keep their own pairing).
+Nothing in a layer's backward reads the layer's output, so under the
+layer's checkpoint (models/decoder.py) the second forward of the loop is
+dead code and XLA removes it. `rows_walked` is the loop's own statement of
+its work, a step metric (`training/lm.py`: `moe_rows_walked`).
 
 The layer is also TOLD its routing: `moe_apply` takes (picks, weights,
 load) from its caller, so that a model with a router of its own shares
@@ -36,6 +45,8 @@ the scores at the picks:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -44,25 +55,32 @@ from alphafold2_tpu.ops.core import pallas_interpret
 from alphafold2_tpu.telemetry.profiling import scope
 
 
-#: rows of a chunk over the rows the layer expects to hold. A chunk past
-#: the load is skipped, yet costs the backward a pass over the experts'
-#: weight gradients, and a second LIVE chunk its gathers and scatters, so
-#: one chunk should hold every load short of a collapsed router's; what
-#: a chunk's rows cost whether they hold an assignment or not (the
-#: gathers, the masks) grows with the factor
-CHUNK_OVER_EXPECTED = 2
-#: the grouped kernels' largest row tile: chunks are whole tiles
+#: the grouped kernels' largest row tile: blocks are whole tiles
 ROW_TILE = 512
+#: rows of a block over the load a balanced router gives. On the chip a
+#: live block costs 2-6 ms a layer whatever it holds (a pass over the
+#: float32 weight-gradient carries, and each grouped kernel's visit of
+#: every expert's tiles) against 0.3-0.5 us a row walked beyond the load,
+#: so one block should hold the load the layer usually gets, with the
+#: least room above it that the load's swing allows: a quarter (a load
+#: beyond it takes a second block; `benchmarks/records/moe_block_sweep_pr36.jsonl`)
+BLOCK_OVER_EXPECTED = 1.25
 
 
-def chunk_rows_for(n_tokens: int, top_k: int, n_held: int, n_experts: int) -> int:
-    """Sorted token-assignments the expert layer takes at a time:
-    `CHUNK_OVER_EXPECTED` times the expected load of a balanced router
+def block_rows_for(n_tokens: int, top_k: int, n_held: int, n_experts: int) -> int:
+    """Sorted token-assignments the expert loop takes at a time:
+    `BLOCK_OVER_EXPECTED` times the load a balanced router gives
     (n_tokens * top_k * n_held / n_experts), in whole row tiles, at most
     the worst case."""
     expected = n_tokens * top_k * n_held / n_experts
-    tiles = -(-int(CHUNK_OVER_EXPECTED * expected) // ROW_TILE)
+    tiles = -(-int(BLOCK_OVER_EXPECTED * expected) // ROW_TILE)
     return min(tiles * ROW_TILE, n_tokens * min(top_k, n_held))
+
+
+def rows_walked(assignments_held, block_rows: int):
+    """Sorted rows the expert loop visits for `assignments_held` held
+    assignments: its live blocks times a block's rows."""
+    return jnp.ceil(assignments_held / block_rows) * block_rows
 
 
 def grouped_kernel_supported(m: int, k: int, n: int) -> bool:
@@ -157,7 +175,107 @@ def swiglu(params, x, dtype):
     return (jax.nn.silu(x @ w["gate"]) * (x @ w["up"])) @ w["down"]
 
 
-def experts_apply(params, x, idx, weights, *, held, chunk_rows: int):
+def _block_of(plan, i, block_rows: int):
+    """Block `i` of the sorted rows: its tokens, routing weights, group
+    sizes clipped to the block, and which of its rows hold an assignment."""
+    tok, w_sorted, starts, ends, total = plan
+    start = i * block_rows
+    t = jax.lax.dynamic_slice(tok, (start,), (block_rows,))
+    wc = jax.lax.dynamic_slice(w_sorted, (start,), (block_rows,))
+    sizes = (jnp.clip(ends, start, start + block_rows)
+             - jnp.clip(starts, start, start + block_rows))
+    live = (start + jnp.arange(block_rows) < total)[:, None]
+    return start, t, wc, sizes, live
+
+
+def _block_rows_out(xc, wc, w, sizes, live):
+    """What one block's rows add to their tokens, (block_rows, d) float32:
+    the rows' experts' SwiGLU of `xc`, times the routing weights `wc`."""
+
+    def keep(part):
+        # the rows past the last held assignment belong to no group: what
+        # a grouped product gives for them, forward and backward, is
+        # unspecified and stops here both ways
+        return jnp.where(live, part, 0)
+
+    with scope("dispatch"):
+        xc = keep(xc)
+    with scope("experts"):
+        gate = keep(grouped_matmul(xc, w["gate"], sizes))
+        up = keep(grouped_matmul(xc, w["up"], sizes))
+        y = keep(grouped_matmul(keep(jax.nn.silu(gate) * up), w["down"], sizes))
+    with scope("combine"):
+        return y.astype(jnp.float32) * wc[:, None]
+
+
+def _live_blocks(total, block_rows: int):
+    return (total + block_rows - 1) // block_rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk(block_rows, x, experts, w_sorted, tok, starts, ends, total):
+    """sum over the LIVE blocks of the sorted rows of `_block_rows_out`,
+    scattered to the rows' tokens: (N, d) float32. A loop whose bound is
+    data, so the vjp is written by hand across blocks (`_walk_bwd`)."""
+    return _walk_fwd(block_rows, x, experts, w_sorted, tok, starts, ends, total)[0]
+
+
+def _walk_fwd(block_rows, x, experts, w_sorted, tok, starts, ends, total):
+    w = {name: leaf.astype(x.dtype) for name, leaf in experts.items()}
+    plan = (tok, w_sorted, starts, ends, total)
+
+    def block(i, out):
+        _, t, wc, sizes, live = _block_of(plan, i, block_rows)
+        with scope("dispatch"):
+            xc = x[t]
+        y = _block_rows_out(xc, wc, w, sizes, live)
+        with scope("combine"):
+            return out.at[t].add(y)
+
+    out = jax.lax.fori_loop(0, _live_blocks(total, block_rows), block,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out, (x, experts, plan)
+
+
+def _walk_bwd(block_rows, residuals, g):
+    x, experts, plan = residuals
+    w = {name: leaf.astype(x.dtype) for name, leaf in experts.items()}
+    w_sorted, total = plan[1], plan[4]
+
+    def block(i, carry):
+        dx, dw, dw_sorted = carry
+        start, t, wc, sizes, live = _block_of(plan, i, block_rows)
+        with scope("dispatch"):
+            xc = x[t]
+        with scope("combine"):
+            gy = g[t]
+        # within a block the vjp is autodiff's (the grouped products keep
+        # their own pairing); its forward runs again under jax.checkpoint
+        _, pull = jax.vjp(
+            jax.checkpoint(lambda xc, wc, w: _block_rows_out(xc, wc, w, sizes, live)),
+            xc, wc, w)
+        dxc, dwc, dwb = pull(gy)
+        with scope("dispatch"):
+            dx = dx.at[t].add(dxc.astype(jnp.float32))
+        with scope("experts"):
+            dw = {name: dw[name] + dwb[name].astype(jnp.float32) for name in dw}
+        with scope("combine"):
+            dw_sorted = jax.lax.dynamic_update_slice(dw_sorted, dwc, (start,))
+        return dx, dw, dw_sorted
+
+    dx, dw, dw_sorted = jax.lax.fori_loop(
+        0, _live_blocks(total, block_rows), block,
+        (jnp.zeros(x.shape, jnp.float32),
+         {name: jnp.zeros(leaf.shape, jnp.float32) for name, leaf in w.items()},
+         jnp.zeros_like(w_sorted)))
+    dw = {name: dw[name].astype(experts[name].dtype) for name in dw}
+    return dx.astype(x.dtype), dw, dw_sorted, None, None, None, None
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
+def experts_apply(params, x, idx, weights, *, held, block_rows: int):
     """The held experts' part of `sum_e w_e SwiGLU_e(x)`. x: (N, d) in the
     compute dtype; idx, weights: (N, top_k) from `route`; params: `gate`,
     `up` (E_held, d, f) and `down` (E_held, f, d). Returns (N, d)
@@ -165,7 +283,6 @@ def experts_apply(params, x, idx, weights, *, held, chunk_rows: int):
     lo, hi = held
     n_held = hi - lo
     N, top_k = idx.shape
-    dtype = x.dtype
     with scope("dispatch"):
         flat = idx.reshape(-1)
         local = jnp.where((flat >= lo) & (flat < hi), flat - lo, n_held)
@@ -175,43 +292,12 @@ def experts_apply(params, x, idx, weights, *, held, chunk_rows: int):
         starts, total = ends - counts, ends[-1]
         # the worst case: every pick of every token held here
         rows = N * min(top_k, n_held)
-        chunk_rows = min(chunk_rows, rows)
-        n_chunks = -(-rows // chunk_rows)
-        pad = n_chunks * chunk_rows - rows
+        block_rows = min(block_rows, rows)
+        pad = -rows % block_rows
         tok = jnp.pad(order[:rows] // top_k, (0, pad))
         w_sorted = jnp.pad(weights.reshape(-1)[order[:rows]], (0, pad))
-    w = {name: params[name]["w"].astype(dtype) for name in ("gate", "up", "down")}
-
-    def run(out, start):
-        with scope("dispatch"):
-            t = jax.lax.dynamic_slice(tok, (start,), (chunk_rows,))
-            wc = jax.lax.dynamic_slice(w_sorted, (start,), (chunk_rows,))
-            sizes = (jnp.clip(ends, start, start + chunk_rows)
-                     - jnp.clip(starts, start, start + chunk_rows))
-            live = (start + jnp.arange(chunk_rows) < total)[:, None]
-
-            def keep(part):
-                # the rows past the last held assignment belong to no
-                # group: what a grouped product gives for them, forward
-                # and backward, is unspecified and stops here both ways
-                return jnp.where(live, part, 0)
-
-            xc = keep(x[t])
-        with scope("experts"):
-            gate = keep(grouped_matmul(xc, w["gate"], sizes))
-            up = keep(grouped_matmul(xc, w["up"], sizes))
-            y = keep(grouped_matmul(keep(jax.nn.silu(gate) * up), w["down"], sizes))
-        with scope("combine"):
-            return out.at[t].add(y.astype(jnp.float32) * wc[:, None])
-
-    def chunk(out, c):
-        start = c * chunk_rows
-        return jax.lax.cond(start < total, lambda o: run(o, start),
-                            lambda o: o, out), None
-
-    out = jnp.zeros(x.shape, jnp.float32)
-    out, _ = jax.lax.scan(jax.checkpoint(chunk), out, jnp.arange(n_chunks))
-    return out
+    experts = {name: params[name]["w"] for name in ("gate", "up", "down")}
+    return _walk(block_rows, x, experts, w_sorted, tok, starts, ends, total)
 
 
 def moe_apply(params, x, routing, *, held):
@@ -219,13 +305,16 @@ def moe_apply(params, x, routing, *, held):
     (idx, weights, load) of `route` or `route_softmax`: the held routed
     experts' part, plus the shared experts in full where the layer has any
     (without one, a token whose experts are all absent gets nothing).
-    Returns (y (N, d) in x.dtype, {"load": (E,), "picks": (N, top_k)})."""
+    Returns (y (N, d) in x.dtype, {"load": (E,), "picks": (N, top_k),
+    "rows_walked": () float32, the sorted rows the expert loop visits})."""
     idx, weights, load = routing
-    y = experts_apply(
-        params["experts"], x, idx, weights, held=held,
-        chunk_rows=chunk_rows_for(x.shape[0], idx.shape[1], held[1] - held[0],
-                                  load.shape[-1]))
+    lo, hi = held
+    block_rows = block_rows_for(x.shape[0], idx.shape[1], hi - lo, load.shape[-1])
+    y = experts_apply(params["experts"], x, idx, weights, held=held,
+                      block_rows=block_rows)
     if "shared" in params:
         with scope("shared_expert"):
             y = y + swiglu(params["shared"], x, x.dtype).astype(jnp.float32)
-    return y.astype(x.dtype), {"load": load, "picks": idx}
+    return y.astype(x.dtype), {
+        "load": load, "picks": idx,
+        "rows_walked": rows_walked(jnp.sum(load[lo:hi]), block_rows)}

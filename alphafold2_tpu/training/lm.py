@@ -110,9 +110,10 @@ def lm_aux_update(cfg):
     layer's selection bias moved, step metrics). A family that has the
     bias keeps it at `moe/mlp/bias` (`zaya`'s one stack is `moe`); one
     whose router has none (`mellum`) has nothing moved. Per MoE layer, a
-    row a layer in the published order: assignments held here and
-    most-loaded over mean load of the held experts (none is dropped: the
-    expert layer has no capacity)."""
+    row a layer in the published order: assignments held here, the sorted
+    rows the expert loop walked for them (`ops.moe.rows_walked`: its live
+    blocks, whole) and most-loaded over mean load of the held experts
+    (none is dropped: the expert layer has no capacity)."""
     lo, hi = cfg.held
 
     def update(params, aux):
@@ -127,6 +128,7 @@ def lm_aux_update(cfg):
         held = load[:, lo:hi]
         metrics = {
             "moe_assignments_held": jnp.sum(held, axis=-1),
+            "moe_rows_walked": aux["rows_walked"],
             "moe_load_max_over_mean": jnp.max(held, axis=-1)
             / jnp.maximum(jnp.mean(held, axis=-1), 1.0),
         }

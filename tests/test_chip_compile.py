@@ -230,3 +230,38 @@ def test_batch_chunks_keep_the_whole_row_cores_results_for_v5e(
     assert (kept.count(CALL), bare.count(CALL)) == (2, 3)
     out = (chunk, n, cfg.inner_dim)
     assert _copies_of(out, kept) <= _copies_of(out, bare)
+
+
+def test_expert_loop_compiles_once_a_direction_for_v5e(one_chip, compiled_mode,
+                                                       monkeypatch):
+    """The expert layer at the sliding-window cell's widths (2 x 8192
+    tokens of 2304, top-8 of 64, 16 experts of 896 held) under a
+    layer-like checkpoint: Mosaic takes the megablox kernels inside a loop
+    whose bound is data, the loop has no branch, and the compiled gradient
+    holds the grouped kernels of ONE forward loop (3) and ONE backward loop
+    (its block's recomputation, the transposes and `tgmm`: 9), none for
+    the checkpoint's second forward, whose result nothing reads."""
+    from alphafold2_tpu.ops import moe
+
+    monkeypatch.setenv("AF2_KERNEL_BACKEND_GROUPED_MATMUL", "pallas_tpu")
+    n, d, f, held = 2 * 8192, 2304, 896, (0, 16)
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {
+        "router": {"w": sd((d, 64))},
+        "experts": {"gate": {"w": sd((16, d, f))}, "up": {"w": sd((16, d, f))},
+                    "down": {"w": sd((16, f, d))}}}
+
+    def layer(p, h):
+        routing = moe.route_softmax(moe.router_logits(p, h), None, 8, norm_topk=True)
+        return h + moe.moe_apply(p, h, routing, held=held)[0]
+
+    def loss(p, h):
+        return jnp.sum(jax.checkpoint(layer)(p, h).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        params, sd((n, d), jnp.bfloat16)).compile().as_text()
+    assert text.count(CALL) == 12
+    assert " conditional(" not in text

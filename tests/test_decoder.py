@@ -93,7 +93,8 @@ def test_loss_and_gradients_match_reference(params, held):
     assert abs(float(loss) - float(want)) < 2e-5 * float(want)
     assert _worst(grads, want_grads) < 2e-3
     assert float(jnp.max(jnp.abs(grads["moe"]["mlp"]["bias"]))) == 0.0
-    assert set(aux) == {"load"}  # what a step sums; the picks are per token
+    # what a step sums; the picks are per token
+    assert set(aux) == {"load", "rows_walked"}
     np.testing.assert_array_equal(aux["load"], load)
     got_picks = decoder_apply(p, cfg, tokens)[1]["picks"]
     np.testing.assert_array_equal(np.sort(got_picks, -1), np.sort(picks, -1))
@@ -117,8 +118,12 @@ def test_two_train_steps_follow_reference(params):
         np.testing.assert_allclose(metrics["moe_assignments_held"], held.sum(-1))
         np.testing.assert_allclose(metrics["moe_load_max_over_mean"],
                                    held.max(-1) / held.mean(-1), rtol=1e-6)
+        block = moe.block_rows_for(tokens.size, CFG.num_experts_per_tok,
+                                   CFG.n_routed_experts, CFG.n_routed_experts)
+        np.testing.assert_array_equal(
+            metrics["moe_rows_walked"], np.ceil(held.sum(-1) / block) * block)
         assert set(metrics) == {"loss", "grad_norm", "moe_assignments_held",
-                                "moe_load_max_over_mean"}
+                                "moe_rows_walked", "moe_load_max_over_mean"}
     # Adam moves every leaf by about lr a step: compare the changes
     moved = jax.tree_util.tree_map(lambda a, b: a - b, state["params"], params)
     want_moved = jax.tree_util.tree_map(lambda a, b: a - b, ref_p, params)
@@ -141,14 +146,44 @@ def test_scaled_init_narrows_the_residual_branches_last_projections():
     assert abs(float(jnp.std(p["embed"]["table"])) / 0.02 - 1.0) < 0.1
 
 
-@pytest.mark.parametrize("n_tokens,top_k,n_held,n_experts,want", [
-    (16384, 6, 16, 128, 24576),  # twice the 12288 expected, whole tiles
-    (16384, 6, 128, 128, 98304),  # all experts held: the worst case
-    (1000, 6, 16, 128, 1536),  # 1500 rounded up to tiles of 512
-    (128, 2, 2, 8, 256),  # small: the worst case bounds it
-])
-def test_chunk_rows_follow_the_expected_load(n_tokens, top_k, n_held, n_experts, want):
-    assert moe.chunk_rows_for(n_tokens, top_k, n_held, n_experts) == want
+# (tokens, picks a token, held, experts): the three decoder cells' layers
+# (kanana2, zaya1, mellum2 at 2 x 8192 tokens) and two small ones
+_LAYER_SHAPES = [
+    pytest.param(16384, 6, 16, 128, id="kanana2"),
+    pytest.param(16384, 1, 8, 16, id="zaya1"),
+    pytest.param(16384, 8, 16, 64, id="mellum2"),
+    pytest.param(1000, 6, 16, 128, id="1000_tokens"),
+    pytest.param(128, 2, 2, 8, id="toy"),
+]
+
+
+@pytest.mark.parametrize("n_tokens,top_k,n_held,n_experts", _LAYER_SHAPES)
+def test_block_rows_are_whole_tiles_within_the_worst_case(n_tokens, top_k, n_held,
+                                                          n_experts):
+    block = moe.block_rows_for(n_tokens, top_k, n_held, n_experts)
+    worst = n_tokens * min(top_k, n_held)
+    assert 0 < block <= worst
+    assert block % moe.ROW_TILE == 0 or block == worst
+    # the same rule for every family: one block holds the load a balanced
+    # router gives and a quarter more, to the tile
+    expected = n_tokens * top_k * n_held / n_experts
+    assert block == worst or 0 <= block - 1.25 * expected < moe.ROW_TILE
+    assert moe.block_rows_for(2 * n_tokens, top_k, n_held, n_experts) >= block
+
+
+@pytest.mark.parametrize("n_tokens,top_k,n_held,n_experts", _LAYER_SHAPES)
+def test_rows_walked_are_the_live_blocks(n_tokens, top_k, n_held, n_experts):
+    block = moe.block_rows_for(n_tokens, top_k, n_held, n_experts)
+    worst = n_tokens * min(top_k, n_held)
+    assert float(moe.rows_walked(0, block)) == 0.0
+    for held in (1, block - 1, block, block + 1, worst // 2, worst):
+        walked = float(moe.rows_walked(held, block))
+        assert walked % block == 0
+        assert held <= walked < held + block
+    # a balanced router's load is one block: a quarter more than it holds
+    expected = n_tokens * top_k * n_held / n_experts
+    if expected >= 8 * moe.ROW_TILE:
+        assert float(moe.rows_walked(expected, block)) == block <= 1.25 * expected
 
 
 def test_bias_update_moves_toward_the_mean():
@@ -173,7 +208,7 @@ def test_shares_add_up_to_the_uncut_layer():
     routed = sum(
         moe.experts_apply(
             jax.tree_util.tree_map(lambda t: t[lo:lo + 2], p["experts"]),
-            x, idx, w, held=(lo, lo + 2), chunk_rows=40)
+            x, idx, w, held=(lo, lo + 2), block_rows=40)
         for lo in range(0, 8, 2))
     whole = routed + moe.swiglu(p["shared"], x, jnp.float32)
     want, _, _ = reference.moe(p, x, _hp(CFG, held=(0, 8)))
@@ -189,8 +224,8 @@ def test_every_token_to_one_held_expert_drops_none():
     idx, w, load = moe.route(p, x, **kw)
     assert float(load[5]) == 80.0
     share = jax.tree_util.tree_map(lambda t: t[4:6], p["experts"])
-    # chunks of 16 rows: the 80 assignments of expert 5 span five of them
-    got = moe.experts_apply(share, x, idx, w, held=(4, 6), chunk_rows=16)
+    # blocks of 16 rows: the 80 assignments of expert 5 span five of them
+    got = moe.experts_apply(share, x, idx, w, held=(4, 6), block_rows=16)
     w5 = jnp.sum(jnp.where(idx == 5, w, 0.0), -1)
     w4 = jnp.sum(jnp.where(idx == 4, w, 0.0), -1)
     e = lambda i: jax.tree_util.tree_map(lambda t: t[i], p["experts"])  # noqa: E731
@@ -198,6 +233,62 @@ def test_every_token_to_one_held_expert_drops_none():
             + w4[:, None] * moe.swiglu(e(4), x, jnp.float32))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
     assert float(jnp.min(jnp.abs(w5))) > 0.0  # every token's part is there
+
+
+def _picks_with_held(n_tokens: int, n_held_picks: int):
+    """(n_tokens, 2) picks over 8 experts of which exactly `n_held_picks`
+    lie in [2, 6): slot 0 of token 0, 1, ..., then slot 1, a token's two
+    picks distinct; the rest go to experts 0, 1, 6, 7."""
+    idx = np.stack([np.arange(n_tokens) % 2, 6 + np.arange(n_tokens) % 2], -1)
+    for j in range(n_held_picks):
+        token, slot = j % n_tokens, j // n_tokens
+        idx[token, slot] = 2 + (token + slot) % 4
+    return jnp.asarray(idx, jnp.int32)
+
+
+def _dense_share(share, x, idx, w, held):
+    """sum over the held experts e of (w at e's picks) * SwiGLU_e(x), every
+    expert over every token."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(*held):
+        one = jax.tree_util.tree_map(lambda t: t[e - held[0]], share)
+        we = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
+        out = out + we[:, None] * moe.swiglu(one, x, jnp.float32)
+    return out
+
+
+@pytest.mark.parametrize("n_held_picks", [
+    pytest.param(0, id="none_held-zero_trips"),
+    pytest.param(20, id="inside_one_block"),
+    pytest.param(64, id="exactly_two_blocks"),
+    pytest.param(65, id="one_row_over"),
+    pytest.param(96, id="every_pick_held-worst_case"),
+])
+def test_expert_loop_matches_the_dense_sum_at_every_load(n_held_picks):
+    """48 tokens x 2 picks in blocks of 32 rows: value and the gradients to
+    the experts, x and the routing weights, whatever the loop's bound."""
+    p = _moe_params(jax.random.PRNGKey(11))
+    share = jax.tree_util.tree_map(lambda t: t[2:6], p["experts"])
+    x = jax.random.normal(jax.random.PRNGKey(12), (48, 64))
+    w = jax.random.uniform(jax.random.PRNGKey(13), (48, 2), minval=0.2, maxval=1.0)
+    idx = _picks_with_held(48, n_held_picks)
+    assert int(jnp.sum((idx >= 2) & (idx < 6))) == n_held_picks
+
+    def value_and_grads(layer):
+        return jax.jit(jax.value_and_grad(
+            lambda share, x, w: jnp.sum(jnp.sin(layer(share, x, w) + 0.3)),
+            argnums=(0, 1, 2)))(share, x, w)
+
+    got = value_and_grads(lambda share, x, w: moe.experts_apply(
+        share, x, idx, w, held=(2, 6), block_rows=32))
+    want = value_and_grads(lambda share, x, w: _dense_share(share, x, idx, w, (2, 6)))
+    for g, t in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, t, rtol=2e-5, atol=2e-6)
+    if n_held_picks == 0:
+        out = moe.experts_apply(share, x, idx, w, held=(2, 6), block_rows=32)
+        assert float(jnp.max(jnp.abs(out))) == 0.0
+        for g in jax.tree_util.tree_leaves(got[1]):
+            assert float(jnp.max(jnp.abs(g))) == 0.0
 
 
 def test_rows_of_no_group_stay_out_of_result_and_gradient(monkeypatch):
@@ -230,7 +321,7 @@ def test_rows_of_no_group_stay_out_of_result_and_gradient(monkeypatch):
     def run():
         return jax.value_and_grad(
             lambda share, x, w: jnp.sum(jnp.sin(moe.experts_apply(
-                share, x, idx, w, held=(2, 4), chunk_rows=64))),
+                share, x, idx, w, held=(2, 4), block_rows=64))),
             argnums=(0, 1, 2))(share, x, w)
 
     want = run()

@@ -109,7 +109,7 @@ def test_loss_logits_and_every_gradient_leaf_match_reference(params, held):
     assert (jax.tree_util.tree_structure(grads)
             == jax.tree_util.tree_structure(want_grads))
     assert _worst(grads, want_grads) < 2e-3
-    assert set(aux) == {"load"} and aux["load"].shape == (8, 8)
+    assert set(aux) == {"load", "rows_walked"} and aux["load"].shape == (8, 8)
     np.testing.assert_array_equal(aux["load"], load)
     hidden, full = decoder_apply(p, cfg, tokens)
     np.testing.assert_array_equal(full["picks"], picks)
@@ -140,6 +140,9 @@ def test_two_train_steps_follow_reference(params):
         np.testing.assert_allclose(metrics["moe_assignments_held"],
                                    np.asarray(load).sum(-1))
         assert metrics["moe_load_max_over_mean"].shape == (8,)
+        assert metrics["moe_rows_walked"].shape == (8,)
+        assert np.all(np.asarray(metrics["moe_rows_walked"])
+                      >= np.asarray(metrics["moe_assignments_held"]))
     moved = jax.tree_util.tree_map(lambda a, b: a - b, state["params"], params)
     want_moved = jax.tree_util.tree_map(lambda a, b: a - b, ref_p, params)
     assert _worst(moved, want_moved) < 0.05

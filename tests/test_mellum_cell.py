@@ -206,9 +206,13 @@ def test_shipped_configuration_counts(bench):
             == 3.0 * 3 * n * (2 * 4096 + 2 * 512) * 2)
     assert (flops_mellum.attn_core_full_train_bytes(cfg, 2, 8192)
             == 3.0 * 1 * n * (2 * 4096 + 2 * 512) * 2)
-    # no drop: the chunks cover 8 picks a token over 16 held of 64
+    # no drop: the loop's blocks reach 8 picks a token over 16 held of 64;
+    # a load of 35 100 rows (the cell's most by seed) is one block of 40 960
     from alphafold2_tpu.ops import moe
-    assert moe.chunk_rows_for(n, 8, 16, 64) == 2 * 32768
+    block = moe.block_rows_for(n, 8, 16, 64)
+    assert block == 40960
+    assert float(moe.rows_walked(n * 8, block)) >= n * 8
+    assert float(moe.rows_walked(35100, block)) == block < 1.25 * 35100
 
 
 def _records(name):

@@ -106,12 +106,17 @@ def op_paths(compiled):
 
 
 @pytest.fixture(scope="module")
-def paths():
+def mellum_step():
+    return lower_toy_lm_step("mellum").compile()
+
+
+@pytest.fixture(scope="module")
+def paths(mellum_step):
     return (op_paths(lower_toy_step().compile())
             | op_paths(lower_template_forward().compile())
             | op_paths(lower_toy_lm_step().compile())
             | op_paths(lower_toy_lm_step("zaya").compile())
-            | op_paths(lower_toy_lm_step("mellum").compile()))
+            | op_paths(mellum_step))
 
 
 @pytest.mark.parametrize("name", profiling.SCOPES)
@@ -126,6 +131,55 @@ def test_every_documented_scope_is_in_the_compiled_step(paths, name):
         assert backward, f"{name} is not under {marker}"
     else:
         assert forward
+
+
+@pytest.fixture(scope="module")
+def lm_op_names(mellum_step):
+    """Every operation's whole name in the compiled toy decoder step."""
+    return set(re.findall(r'op_name="([^"]+)"', mellum_step.as_text()))
+
+
+# the expert layer's backward is a loop of its own (ops/moe.py _walk_bwd):
+# under the layer's transpose, the caller's `moe`, then the loop's body
+_BACKWARD_LOOP = r"transpose\(jvp\(decoder_layers\)\)/.*/moe/while/body/"
+
+
+@pytest.mark.parametrize("inner", ["dispatch", "experts", "combine"])
+def test_expert_backward_loop_carries_the_layers_names(lm_op_names, inner):
+    in_loop = re.compile(_BACKWARD_LOOP + rf"(?:.*/)?{inner}/")
+    assert any(in_loop.search(name) for name in lm_op_names)
+
+
+def test_expert_backward_loop_recomputes_its_block_as_remat(lm_op_names):
+    again = re.compile(_BACKWARD_LOOP + r".*rematted_computation/experts/")
+    assert any(again.search(name) for name in lm_op_names)
+
+
+def test_checkpointed_expert_layer_is_one_loop_a_direction_and_no_conditional():
+    """A layer-like `jax.checkpoint` around `moe_apply`: the compiled
+    gradient holds the expert loop twice (forward, backward) and not a
+    third time for the checkpoint's second forward, whose result nothing
+    reads; a loop over live blocks has no branch for a skipped one."""
+    from alphafold2_tpu.ops import moe
+
+    n, d, f = 64, 32, 16
+    shapes = {"gate": (2, d, f), "up": (2, d, f), "down": (2, f, d)}
+    params = {
+        "router": {"w": jax.ShapeDtypeStruct((d, 4), jnp.float32)},
+        "experts": {k: {"w": jax.ShapeDtypeStruct(v, jnp.float32)}
+                    for k, v in shapes.items()}}
+
+    def layer(p, h):
+        x = jnp.tanh(h)
+        routing = moe.route_softmax(moe.router_logits(p, x), None, 2, norm_topk=True)
+        return h + moe.moe_apply(p, x, routing, held=(1, 3))[0]
+
+    compiled = jax.jit(jax.grad(
+        lambda p, h: jnp.sum(jnp.sin(jax.checkpoint(layer)(p, h))),
+        argnums=(0, 1))).lower(
+            params, jax.ShapeDtypeStruct((n, d), jnp.float32)).compile().as_text()
+    assert len(re.findall(r" while\(", compiled)) == 2
+    assert not re.search(r" conditional\(", compiled)
 
 
 def test_scopes_are_two_levels_and_undocumented_names_are_refused():

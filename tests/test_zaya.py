@@ -102,7 +102,7 @@ def test_loss_logits_and_every_gradient_leaf_match_reference(params, held):
             == jax.tree_util.tree_structure(want_grads))
     assert _worst(grads, want_grads) < 2e-3
     assert float(jnp.max(jnp.abs(grads["moe"]["mlp"]["bias"]))) == 0.0
-    assert set(aux) == {"load"}
+    assert set(aux) == {"load", "rows_walked"}
     np.testing.assert_array_equal(aux["load"], load)
     hidden, full = decoder_apply(p, cfg, tokens)
     np.testing.assert_array_equal(full["picks"], picks)
