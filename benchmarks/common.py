@@ -85,6 +85,15 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
 
 
+def weights_seed(ctx) -> int:
+    """The seed of what a cell IS: its weights and anything else of the
+    model it trains (a decoder cell's rank -> id map). The traffic file's
+    `weights_seed` where it states one, so that every run of the cell
+    trains one model and only what it is fed (drawn from `--seed`)
+    differs; else the run's `--seed`."""
+    return ctx["traffic"].get("weights_seed", ctx["seed"])
+
+
 def key_name(k) -> str:
     """One step of a tree path as a plain string."""
     return str(getattr(k, "key", getattr(k, "idx", k)))
@@ -349,12 +358,14 @@ def step_stats(step_times, window_s: float) -> dict:
                                           if steps > 1 else None)}
 
 
-def timed_window(ctx, runner, after_traced_step=None) -> dict:
+def timed_window(ctx, runner, after_traced_step=None, after_timed_step=None) -> dict:
     """The measured part of a training cell: with `--trace 1` first
     `trace_steps` steps under the profiler (`bench.window` / `bench.step`),
     then `runner.step()` until `--seconds` have passed, nothing compiled in
     either. `runner.step()` returns (its batch, the fetched loss) and keeps
-    `runner.dispatched_at`, the clock when its dispatch returned.
+    `runner.dispatched_at`, the clock when its dispatch returned. The two
+    callbacks run after each traced and each timed step: a kind keeps
+    there what the step returned on the device, to read after the window.
 
     Every timed step is logged by four clocks, so that a stalled step says
     where it stalled: `wall` seconds; `cpu`, this process's CPU seconds
@@ -398,6 +409,8 @@ def timed_window(ctx, runner, after_traced_step=None) -> dict:
                            "wait": now - runner.dispatched_at, "late": beat.take(),
                            "runq": waits["runq"] - runq if "runq" in waits else None})
             losses.append(value)
+            if after_timed_step:
+                after_timed_step()
             if now - t0 >= seconds or ctx["dry"] and len(clocks) >= 2:
                 break
         window_s = time.perf_counter() - t0

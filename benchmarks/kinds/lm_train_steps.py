@@ -9,16 +9,21 @@ its state), drives it through its first `check_steps` steps by the
 window's own call and feed (the warm-up too) and hands the same object to
 the window; after the window the plain reference
 (`reference/decoder_lm.py`) follows those steps from the same weights and
-batches. The weights are drawn HERE from the seed (`param_maker`: by each
-leaf's role, at the scales the configuration file assumes; only the
-layout comes from the program), so that a fault of the program's own
-init cannot hide on both sides; `tests/test_lm_cell.py` holds the
-program's init to the same scales. What differs from `train_steps`: NO
-second copy of the weights sits on the device through the window (the
-same call makes them again when the change and the reference need them);
-the router's picks that `route_mismatch_share` reads come from the
-program's forward on those weights after the window, not from the timed
-step.
+batches. The weights are drawn HERE (`param_maker`: by each leaf's role,
+at the scales the configuration file assumes; only the layout comes from
+the program), so that a fault of the program's own init cannot hide on
+both sides; `tests/test_lm_cell.py` holds the program's init to the same
+scales. They and the rank -> id map are drawn from `common.weights_seed`
+(the traffic file's `weights_seed` where it states one: every run of the
+cell trains one model), the batches from `--seed`. What differs from
+`train_steps`: NO second copy of the weights sits on the device through
+the window (the same call makes them again when the change and the
+reference need them); the router's picks that `route_mismatch_share`
+reads come from the program's forward on those weights after the window,
+not from the timed step. The window's steps state the rows the expert loop walked and the
+assignments it held, a MoE layer each (`moe_rows_walked`,
+`moe_assignments_held`): they stay on the device through the window and
+are read once after it (`expert_window`).
 """
 from __future__ import annotations
 
@@ -32,22 +37,37 @@ import compare
 from common import log
 
 
+def id_map(vocab: int, map_seed: int) -> np.ndarray:
+    """The rank -> id map: a permutation of the vocabulary drawn from
+    `map_seed`."""
+    return np.random.default_rng([map_seed, 11]).permutation(vocab)
+
+
 def token_batch(vocab: int, batch: int, length: int, seed: int, index: int,
-                exponent: float) -> np.ndarray:
-    """(batch, length) int32 ids, a function of (seed, index): ranks from
-    a Zipf law p(rank) ~ rank^-exponent over the vocabulary, the rank ->
-    id map a permutation drawn from the seed."""
+                exponent: float, map_seed: int) -> np.ndarray:
+    """(batch, length) int32 ids, a function of (seed, index) and the
+    rank -> id map: ranks from a Zipf law p(rank) ~ rank^-exponent over
+    the vocabulary, drawn from (seed, index); the map `id_map(vocab,
+    map_seed)`."""
     p = np.arange(1, vocab + 1, dtype=np.float64) ** -exponent
     cdf = np.cumsum(p / p.sum())
-    ids = np.random.default_rng([seed, 11]).permutation(vocab)
     u = np.random.default_rng([seed, 12, index]).random((batch, length))
-    return ids[np.minimum(np.searchsorted(cdf, u), vocab - 1)].astype(np.int32)
+    ranks = np.minimum(np.searchsorted(cdf, u), vocab - 1)
+    return id_map(vocab, map_seed)[ranks].astype(np.int32)
 
 
 def shape_of(ctx):
     traffic = ctx["traffic"]
     part = traffic["dry"] if ctx["dry"] else traffic
     return part["batch"], part["length"]
+
+
+def cell_batch(ctx, index: int) -> np.ndarray:
+    """The run's token batch `index`: ranks from `--seed`, ids by the
+    cell's map (`common.weights_seed`)."""
+    batch, length = shape_of(ctx)
+    return token_batch(ctx["built"]["cfg"].vocab_size, batch, length, ctx["seed"], index,
+                       ctx["traffic"]["zipf_exponent"], common.weights_seed(ctx))
 
 
 def reference_hp(ctx) -> dict:
@@ -105,8 +125,8 @@ def param_maker(shapes, assumed: dict):
 
 
 class Weights:
-    """The seed's weights by `param_maker`, made again whenever asked: no
-    second copy lives through the window."""
+    """The cell's weights by `param_maker` from `common.weights_seed`, made
+    again whenever asked: no second copy lives through the window."""
 
     def __init__(self, ctx):
         import jax
@@ -114,7 +134,7 @@ class Weights:
         from alphafold2_tpu.training.lm import lm_params_init
 
         cfg = ctx["built"]["cfg"]
-        self.key = common.seed_key(ctx["seed"])
+        self.key = common.seed_key(common.weights_seed(ctx))
         self.shapes = jax.eval_shape(lambda k: lm_params_init(k, cfg), self.key)
         self.make = param_maker(self.shapes, ctx["config"]["assumed_values"])
 
@@ -127,8 +147,7 @@ class Runner:
 
     def __init__(self, ctx, state, compiled):
         self.ctx, self.state, self.compiled = ctx, state, compiled
-        self.vocab = ctx["built"]["cfg"].vocab_size
-        self.batch, self.length = shape_of(ctx)
+        self.batch = shape_of(ctx)[0]
         self.index = 0
         self.dispatched_at = 0.0
         self.metrics = None
@@ -136,8 +155,7 @@ class Runner:
     def feed(self):
         import jax
 
-        tokens = token_batch(self.vocab, self.batch, self.length, self.ctx["seed"],
-                             self.index, self.ctx["traffic"]["zipf_exponent"])
+        tokens = cell_batch(self.ctx, self.index)
         self.index += 1
         fed = tokens
         if self.ctx.get("fault") == "half_batch":  # tests only
@@ -213,7 +231,7 @@ def first_steps(runner, weights, n):
 
 
 def program_picks(ctx, weights, tokens):
-    """The experts the program's router picks for `tokens` on the seed's
+    """The experts the program's router picks for `tokens` on the cell's
     weights, (MoE layers, tokens, top_k): the program's forward at the
     timed sizes and precision, outside the timed step."""
     import jax
@@ -314,15 +332,42 @@ def control(ctx, q):
     """The control's numbers: the reference with `q` on every operand put
     in the program's place, against the reference itself."""
     weights = Weights(ctx)
-    batch, length = shape_of(ctx)
-    vocab = ctx["built"]["cfg"].vocab_size
-    batches = [token_batch(vocab, batch, length, ctx["seed"], i,
-                           ctx["traffic"]["zipf_exponent"])
-               for i in range(ctx["traffic"]["check_steps"])]
+    batches = [cell_batch(ctx, i) for i in range(ctx["traffic"]["check_steps"])]
     ref = follow_reference(ctx, weights, batches)
     ctl = follow_reference(ctx, weights, batches, q)
     log("losses control", ctl["losses"], "reference", ref["losses"])
     return compared_numbers(ctl, ref, compare.leaf_paths(weights.shapes))
+
+
+def expert_window(step_metrics) -> dict:
+    """The window's expert loop from its steps' own metrics, read from the
+    device once: rows walked and assignments held, (steps, MoE layers),
+    under the facts' names `moe_rows_walked_by_step` / `moe_held_by_step`;
+    each layer's held load and the blocks it walked are logged. Empty
+    where the steps state no rows walked (a tree before the loop)."""
+    import jax
+
+    if not step_metrics or "moe_rows_walked" not in step_metrics[0]:
+        return {}
+    rows, held = (np.stack(a) for a in zip(*jax.device_get(
+        [(m["moe_rows_walked"], m["moe_assignments_held"]) for m in step_metrics])))
+    for layer in range(held.shape[1]):
+        walked, count = np.unique(rows[:, layer], return_counts=True)
+        log(f"window's expert layer {layer}: held mean {held[:, layer].mean():.1f} "
+            f"min {held[:, layer].min():.0f} max {held[:, layer].max():.0f}; rows walked "
+            f"{dict(zip(walked.astype(int).tolist(), count.tolist()))} of {len(rows)} steps")
+    return {"moe_rows_walked_by_step": rows, "moe_held_by_step": held}
+
+
+def made_up_expert_window(cfg, shape) -> dict:
+    """`expert_window`'s facts for `dry_facts()`: 4 steps of 2 MoE layers
+    at 0.8 of a block of the cell's plan, one layer-step at 1.2 (it walks
+    a second block)."""
+    block = common.module("readers", "moe_extra_block_share").block_rows(cfg, shape)
+    held = np.full((4, 2), 0.8 * block)
+    held[3, 1] = 1.2 * block
+    return {"moe_rows_walked_by_step": np.ceil(held / block) * block,
+            "moe_held_by_step": held}
 
 
 def dry_facts(config, traffic):
@@ -337,7 +382,8 @@ def dry_facts(config, traffic):
         {"forward": 0.01, "reconstruct": 0.0, "remat": 0.01, "backward": 0.02,
          "other": 0.0},
         model_cfg=cfg, lm_shape=(batch, length), trace_steps=traffic["trace_steps"],
-        assignments_held=0.75 * batch * length, moe_load_max_over_mean=1.3)
+        assignments_held=0.75 * batch * length, moe_load_max_over_mean=1.3,
+        **made_up_expert_window(cfg, (batch, length)))
 
 
 def run(ctx):
@@ -353,10 +399,12 @@ def run(ctx):
     log("setup phases (s):", setup.table())
     setup_facts = setup.facts()
 
-    traced_metrics = []
+    traced_metrics, window_metrics = [], []
     window = common.timed_window(
-        ctx, runner, after_traced_step=lambda: traced_metrics.append(runner.metrics))
+        ctx, runner, after_traced_step=lambda: traced_metrics.append(runner.metrics),
+        after_timed_step=lambda: window_metrics.append(runner.metrics))
     steps, losses = window["steps"], window.pop("losses")
+    experts = expert_window(window_metrics)
 
     # the router's own counts: the traced steps' where there are any, else
     # the window's last step
@@ -373,7 +421,7 @@ def run(ctx):
 
     log("dispatch decisions:", dispatch.decisions())
     runner.state = runner.compiled = runner.metrics = None
-    del counted, traced_metrics
+    del counted, traced_metrics, window_metrics
     gc.collect()
     memory_line(ctx["devices"], "program's state was dropped")
 
@@ -391,7 +439,7 @@ def run(ctx):
     facts = {
         **setup_facts, **window, "model_cfg": ctx["built"]["cfg"],
         "lm_shape": shape_of(ctx), "planned_hbm_bytes": planned,
-        "assignments_held": held, "moe_load_max_over_mean": skew,
+        "assignments_held": held, "moe_load_max_over_mean": skew, **experts,
     }
     return {"correct": correct, "attempted": steps + n_check, "failed": 0 if finite else 1,
             "facts": facts, "device": device, "compared": rows}

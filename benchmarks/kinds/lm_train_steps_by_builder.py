@@ -21,10 +21,11 @@ the next decoder brings a builder and a reference and no third kind:
 What `correct` holds is `lm_train_steps`' own: `loss1_gap` / `loss2_gap`,
 `grad_gap`, `change_gap`, `route_mismatch_share` (`compared_numbers`),
 non-finite losses, from the timed call's first two steps at the timed
-sizes. The feed (`token_batch`), the runner with its faults
-(`build_runner` -> `Runner`), the first steps and the comparison (with
-`route_mismatch_share`) are imported from there; what is here is what
-named MLA's reference.
+sizes. The feed (`cell_batch`: the id map from `common.weights_seed`, the
+batches from `--seed`), the runner with its faults
+(`build_runner` -> `Runner`), the first steps, the comparison (with
+`route_mismatch_share`) and the window's expert loop (`expert_window`) are
+imported from there; what is here is what named MLA's reference.
 """
 from __future__ import annotations
 
@@ -37,8 +38,9 @@ import numpy as np
 import common
 import compare
 from common import log
-from kinds.lm_train_steps import (build_runner, compared_numbers, first_steps,
-                                  memory_line, shape_of, token_batch)
+from kinds.lm_train_steps import (build_runner, cell_batch, compared_numbers,
+                                  expert_window, first_steps, made_up_expert_window,
+                                  memory_line, shape_of)
 
 
 def reference_of(ctx):
@@ -78,8 +80,8 @@ def param_maker(shapes, assumed: dict, leaf_rule):
 
 
 class Weights:
-    """The seed's weights by `param_maker`, made again whenever asked: no
-    second copy lives through the window."""
+    """The cell's weights by `param_maker` from `common.weights_seed`, made
+    again whenever asked: no second copy lives through the window."""
 
     def __init__(self, ctx):
         import jax
@@ -87,7 +89,7 @@ class Weights:
         from alphafold2_tpu.training.lm import lm_params_init
 
         cfg = ctx["built"]["cfg"]
-        self.key = common.seed_key(ctx["seed"])
+        self.key = common.seed_key(common.weights_seed(ctx))
         self.shapes = jax.eval_shape(lambda k: lm_params_init(k, cfg), self.key)
         self.make = param_maker(self.shapes, ctx["config"]["assumed_values"],
                                 ctx["built"]["leaf_rule"])
@@ -97,7 +99,7 @@ class Weights:
 
 
 def program_picks(ctx, weights, tokens):
-    """The experts the program's router picks for `tokens` on the seed's
+    """The experts the program's router picks for `tokens` on the cell's
     weights: the builder's forward at the timed sizes and precision,
     outside the timed step."""
     import jax
@@ -154,11 +156,7 @@ def control(ctx, q):
     """The control's numbers: the reference with `q` on every operand put
     in the program's place, against the reference itself."""
     weights = Weights(ctx)
-    batch, length = shape_of(ctx)
-    vocab = ctx["built"]["cfg"].vocab_size
-    batches = [token_batch(vocab, batch, length, ctx["seed"], i,
-                           ctx["traffic"]["zipf_exponent"])
-               for i in range(ctx["traffic"]["check_steps"])]
+    batches = [cell_batch(ctx, i) for i in range(ctx["traffic"]["check_steps"])]
     ref = follow_reference(ctx, weights, batches)
     ctl = follow_reference(ctx, weights, batches, q)
     log("losses control", ctl["losses"], "reference", ref["losses"])
@@ -177,7 +175,8 @@ def dry_facts(config, traffic):
          "other": 0.0},
         model_cfg=built["cfg"], lm_shape=(batch, length),
         trace_steps=traffic["trace_steps"],
-        assignments_held=0.5 * batch * length, moe_load_max_over_mean=1.3)
+        assignments_held=0.5 * batch * length, moe_load_max_over_mean=1.3,
+        **made_up_expert_window(built["cfg"], (batch, length)))
 
 
 def run(ctx):
@@ -195,10 +194,12 @@ def run(ctx):
 
     held_at_check = np.asarray(runner.metrics["moe_assignments_held"]).tolist()
 
-    traced_metrics = []
+    traced_metrics, window_metrics = [], []
     window = common.timed_window(
-        ctx, runner, after_traced_step=lambda: traced_metrics.append(runner.metrics))
+        ctx, runner, after_traced_step=lambda: traced_metrics.append(runner.metrics),
+        after_timed_step=lambda: window_metrics.append(runner.metrics))
     steps, losses = window["steps"], window.pop("losses")
+    experts = expert_window(window_metrics)
 
     # the router's own counts: the traced steps' where there are any, else
     # the window's last step
@@ -216,7 +217,7 @@ def run(ctx):
 
     log("dispatch decisions:", dispatch.decisions())
     runner.state = runner.compiled = runner.metrics = None
-    del counted, traced_metrics
+    del counted, traced_metrics, window_metrics
     gc.collect()
     memory_line(ctx["devices"], "program's state was dropped")
 
@@ -234,7 +235,7 @@ def run(ctx):
     facts = {
         **setup_facts, **window, "model_cfg": ctx["built"]["cfg"],
         "lm_shape": shape_of(ctx), "planned_hbm_bytes": planned,
-        "assignments_held": held, "moe_load_max_over_mean": skew,
+        "assignments_held": held, "moe_load_max_over_mean": skew, **experts,
     }
     return {"correct": correct, "attempted": steps + n_check, "failed": 0 if finite else 1,
             "facts": facts, "device": device, "compared": rows}

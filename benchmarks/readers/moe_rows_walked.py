@@ -1,33 +1,18 @@
-"""Sorted rows the expert layer walks a MoE layer over the assignments it
-holds there, a ratio (unit `x`, at least 1: the rows beyond the load are
-gathered, masked and scattered like the live ones): the program's own plan
-for the cell's tokens and the configuration's picks and experts, over
-`facts["assignments_held"]` (the router's own count, the mean of the
-counted steps' layers; the plan is asked at that mean). A tree with the
-loop over live blocks states its plan as `ops.moe.rows_walked` of
-`block_rows_for`; one from before it walked whole chunks of
-`chunk_rows_for` rows, a chunk past the load skipped: its live chunks
-times a chunk's rows. None where the configuration has no expert layer or
-the program neither plan."""
-import math
+"""Sorted rows the expert layer walked over the assignments it held, a
+ratio (unit `x`, at least 1: the rows beyond the load are gathered, masked
+and scattered like the live ones), over every step and MoE layer of the
+timed window: the sum of the steps' own `moe_rows_walked` over the sum of
+their `moe_assignments_held` (`facts["moe_rows_walked_by_step"]` and
+`facts["moe_held_by_step"]`, (steps, MoE layers), kept on the device
+through the window and read after it). A layer-step that walks a second
+block counts with the rows it walked. None where the program's steps state
+no rows walked (a tree from before the loop over live blocks) or hold
+nothing."""
+import numpy as np
 
 
 def read(facts: dict, args: dict):
-    cfg, shape = facts.get("model_cfg"), facts.get("lm_shape")
-    held = facts.get("assignments_held")
-    width_key = getattr(cfg, "router_width_key", None)
-    if shape is None or not held or width_key is None:
+    rows, held = facts.get("moe_rows_walked_by_step"), facts.get("moe_held_by_step")
+    if rows is None or held is None or not np.sum(held):
         return None
-    lo, hi = cfg.held
-    plan = (shape[0] * shape[1], cfg.num_experts_per_tok, hi - lo,
-            getattr(cfg, width_key))
-    # a configuration with a router came from models/decoder.py, which
-    # imports ops/moe.py itself
-    from alphafold2_tpu.ops import moe
-
-    if hasattr(moe, "rows_walked"):
-        return float(moe.rows_walked(held, moe.block_rows_for(*plan))) / held
-    if hasattr(moe, "chunk_rows_for"):
-        chunk = moe.chunk_rows_for(*plan)
-        return math.ceil(held / chunk) * chunk / held
-    return None
+    return float(np.sum(rows) / np.sum(held))

@@ -40,6 +40,21 @@ def test_per_layer_metrics_of_a_cell(cell):
     assert len(shares) >= 8 and all(0 < v < 100 for v in shares)
 
 
+DECODER_CELLS = [c for c in CELLS
+                 if common.load_cell(c)[3]["kind"].startswith("lm_train_steps")]
+
+
+@pytest.mark.parametrize("cell", DECODER_CELLS)
+def test_every_decoder_line_reads_the_expert_loops_cliff(cell):
+    """The window's own rows walked over held, and the share of its
+    layer-steps that walked a second block, are on every decoder cell's
+    traced line."""
+    got = run.metrics_of(BENCH, cell, "per_layer", _facts(cell))
+    assert got["moe.extra_block_share.lm_train"]["unit"] == "x"
+    assert 0 < got["moe.extra_block_share.lm_train"]["value"] < 1
+    assert got["moe.rows_walked_over_held.lm_train"]["value"] >= 1
+
+
 def test_a_reader_that_finds_nothing_leaves_its_metric_out():
     facts = _facts("train_e2e")
     del facts["trace"], facts["scopes"]
