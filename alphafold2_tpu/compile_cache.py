@@ -10,6 +10,10 @@ between runs: no tempfile, pid or timestamp.
     is set in code.
   * unset — `<checkout>/.jax_cache` (git-ignored), so two runs from one
     checkout share compiled programs.
+
+Either way it installs the process's compile recorder first
+(`telemetry/compile_record.py`), so that every compile after it is timed by
+phase and function and known as a compile or a cache load.
 """
 
 from __future__ import annotations
@@ -22,6 +26,9 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def enable_compile_cache() -> str:
     """Turn the persistent compile cache on; returns the directory in force."""
+    from alphafold2_tpu.telemetry import compile_record
+
+    compile_record.install()
     placed = os.environ.get(ENV_VAR)
     if placed:
         return placed

@@ -5,7 +5,10 @@ ParaFold arxiv 2111.06340 both credit phase-level measurement for their
 scaling results):
 
   * `trace`     — span-based tracer; Chrome trace-event / Perfetto JSON
-                  and JSONL exporters; `NULL_TRACER` no-op default.
+                  exporter; `NULL_TRACER` no-op default.
+  * `compile_record` — the process's one listener on JAX's compile
+                  phases (trace, lower, XLA compile or cache load), as
+                  records, running totals and `compile.*` spans.
   * `registry`  — counters / gauges / histograms with Prometheus text
                   exposition and JSON snapshots; `LatencyHistogram` lives
                   here now.
@@ -98,6 +101,7 @@ from alphafold2_tpu.telemetry.slo import (
     default_slo_config,
 )
 from alphafold2_tpu.telemetry.trace import NULL_TRACER, Tracer, new_trace_id
+from alphafold2_tpu.telemetry import compile_record
 
 
 def add_telemetry_args(ap):
@@ -117,9 +121,14 @@ def add_telemetry_args(ap):
 def tracer_from_args(args) -> Tracer:
     """A live tracer when --trace-out or (the trainers') --profile-dir was
     given, NULL_TRACER otherwise: a profiler capture then holds the host
-    spans beside the device's operations (telemetry/trace.py)."""
+    spans beside the device's operations (telemetry/trace.py). A live
+    tracer gets the process's compile recorder attached, so every later
+    trace, lowering, compile and cache load is a `compile.*` span under
+    the span that caused it (telemetry/compile_record.py)."""
     if getattr(args, "trace_out", None) or getattr(args, "profile_dir", None):
-        return Tracer(enabled=True, max_spans=args.trace_max_spans)
+        tracer = Tracer(enabled=True, max_spans=args.trace_max_spans)
+        compile_record.attach(tracer)  # JAX's compile phases as its spans
+        return tracer
     return NULL_TRACER
 
 
@@ -167,6 +176,7 @@ __all__ = [
     "add_observability_args",
     "add_telemetry_args",
     "build_train_telemetry",
+    "compile_record",
     "default_slo_config",
     "device_memory_gauges",
     "finish_trace",

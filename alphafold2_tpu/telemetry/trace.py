@@ -9,7 +9,6 @@ into a nestable, thread-safe span with attributes, exportable as:
   * Chrome trace-event JSON (`export_chrome`): open in Perfetto
     (https://ui.perfetto.dev) or chrome://tracing — per-thread timelines
     with nesting rendered from same-tid ts/dur containment;
-  * JSONL (`export_jsonl`): one span per line for ad-hoc analysis;
   * an in-process summary (`summary()`): per-span-name count / total /
     mean / max seconds, the payload `ServingEngine.stats()` embeds.
 
@@ -24,6 +23,12 @@ Cost contract: a DISABLED tracer is near-zero-cost — `span()` returns a
 shared no-op singleton (no allocation, no lock, no record), so
 instrumentation can stay in production code paths unconditionally. Use
 the module-level `NULL_TRACER` as the default wiring value.
+
+Every span records `parent`, the name of the span open on its thread
+when it began (None at the top): the span that caused it. A span measured
+elsewhere (`add`) takes the open span as its parent and sits one level
+under it, so JAX's compile phases (`telemetry/compile_record.py`) land
+inside the `train.step` or `serving_compile` that triggered them.
 
 Memory is bounded: at most `max_spans` completed spans are retained;
 further spans are counted in `dropped` (reported in `summary()` and the
@@ -82,7 +87,7 @@ class _Span:
     """One live span; created by `Tracer.span` and recorded on exit."""
 
     __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "_depth",
-                 "_annotation")
+                 "_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict):
         self._tracer = tracer
@@ -96,7 +101,7 @@ class _Span:
         return self
 
     def __enter__(self):
-        self._depth = self._tracer._push()
+        self._depth, self._parent = self._tracer._push(self.name)
         self._annotation = TraceAnnotation(self.name)
         self._annotation.__enter__()
         self._t0 = self._tracer._clock()
@@ -108,9 +113,8 @@ class _Span:
         self._tracer._pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
-        self._tracer._record(
-            self.name, self.cat, self._t0, dur, self._depth, self.attrs
-        )
+        self._tracer._record(self.name, self.cat, self._t0, dur, self._depth,
+                             self._parent, self.attrs)
         return False
 
 
@@ -151,14 +155,18 @@ class Tracer:
             end_at: Optional[float] = None, **attrs):
         """Record a span measured elsewhere (e.g. queue wait computed from
         a request's submit timestamp): ends at `end_at` (default: now) on
-        this tracer's clock, started `duration_s` earlier. Such a span
-        (`serving.queue_wait`, the pipelined `serving.execute`) is in this
-        tracer's exports only: a profiler annotation cannot be written
-        after the fact, so it is not on `/host:CPU` of a capture."""
+        this tracer's clock, started `duration_s` earlier, one level under
+        the span open on this thread, which is its `parent`. Such a span
+        (`serving.queue_wait`, the pipelined `serving.execute`, the
+        `compile.*` phases) is in this tracer's exports only: a profiler
+        annotation cannot be written after the fact, so it is not on
+        `/host:CPU` of a capture."""
         if not self.enabled:
             return
         end = self._clock() if end_at is None else end_at
-        self._record(name, cat, end - duration_s, duration_s, 0, attrs)
+        stack = self._stack()
+        self._record(name, cat, end - duration_s, duration_s, len(stack),
+                     stack[-1] if stack else None, attrs)
 
     @contextlib.contextmanager
     def bind_trace(self, trace):
@@ -185,15 +193,28 @@ class Tracer:
         bound = getattr(self._tls, "trace", None)
         return bound if isinstance(bound, str) else None
 
-    def _push(self) -> int:
-        depth = getattr(self._tls, "depth", 0)
-        self._tls.depth = depth + 1
-        return depth
+    def open_span(self) -> Optional[str]:
+        """The name of the innermost span open on this thread, if any."""
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    def _stack(self) -> list:
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
+    def _push(self, name: str):
+        """Open `name` on this thread: its (depth, parent)."""
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        stack.append(name)
+        return len(stack) - 1, parent
 
     def _pop(self):
-        self._tls.depth = getattr(self._tls, "depth", 1) - 1
+        self._stack().pop()
 
-    def _record(self, name, cat, t0, dur, depth, attrs):
+    def _record(self, name, cat, t0, dur, depth, parent, attrs):
         bound = getattr(self._tls, "trace", None)
         if isinstance(bound, str):
             if "trace_id" not in attrs:
@@ -206,6 +227,7 @@ class Tracer:
             "ts_s": t0 - self._t_origin,
             "dur_s": dur,
             "depth": depth,
+            "parent": parent,
             "tid": threading.get_ident(),
             "thread": threading.current_thread().name,
             "attrs": attrs,
@@ -266,8 +288,8 @@ class Tracer:
     def chrome_trace(self) -> dict:
         """Chrome trace-event JSON object format: complete ("ph": "X")
         events in microseconds, one per span, plus thread-name metadata so
-        Perfetto labels the worker/client timelines. Nesting needs no
-        parent links — same-tid ts/dur containment renders the stack."""
+        Perfetto labels the worker/client timelines. Same-tid ts/dur
+        containment renders the stack; `args.parent` names it."""
         with self._lock:
             spans = list(self._spans)
             dropped = self.dropped
@@ -291,7 +313,8 @@ class Tracer:
                 "dur": round(s["dur_s"] * 1e6, 3),
                 "pid": 1,
                 "tid": tid,
-                "args": {**s["attrs"], "depth": s["depth"]},
+                "args": {**s["attrs"], "depth": s["depth"],
+                         "parent": s["parent"]},
             })
         out = {"traceEvents": events, "displayTimeUnit": "ms"}
         if dropped:
@@ -303,13 +326,6 @@ class Tracer:
         chrome://tracing (docs/OBSERVABILITY.md)."""
         with open(path, "w") as fh:
             json.dump(self.chrome_trace(), fh)
-
-    def export_jsonl(self, path: str):
-        """One span record per line (append mode: successive phases of one
-        run accumulate into one stream)."""
-        with open(path, "a") as fh:
-            for s in self.spans():
-                fh.write(json.dumps(s) + "\n")
 
 
 #: shared disabled tracer — the default for every instrumented call site,

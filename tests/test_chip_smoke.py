@@ -49,15 +49,33 @@ _CACHE_PROBE = (
 )
 
 
+#: the helper with every `jax.config.update` it makes written down
+_PLACED_PROBE = (
+    "import json\n"
+    "import jax\n"
+    "set_in_code = []\n"
+    "update = jax.config.update\n"
+    "jax.config.update = lambda name, value: (set_in_code.append(name),\n"
+    "                                         update(name, value))\n"
+    "from alphafold2_tpu.compile_cache import enable_compile_cache\n"
+    "from alphafold2_tpu.telemetry import compile_record\n"
+    "print(enable_compile_cache())\n"
+    "print(jax.config.jax_compilation_cache_dir)\n"
+    "print(json.dumps(set_in_code))\n"
+    "print(compile_record.RECORDER._installed)\n"
+)
+
+
 def test_compile_cache_is_placed_from_outside(tmp_path):
     placed = str(tmp_path / "placed")
-    out = _run(_CACHE_PROBE, env_extra={"JAX_COMPILATION_CACHE_DIR": placed})
+    out = _run(_PLACED_PROBE, env_extra={"JAX_COMPILATION_CACHE_DIR": placed})
     assert out.returncode == 0, out.stderr[-400:]
-    path, touched_jax = out.stdout.split()
+    path, in_force, set_in_code, recorder = out.stdout.split()
     assert path == placed
-    # nothing set in code on that path: the helper did not even import jax
-    # (which reads the variable itself at import)
-    assert touched_jax == "False"
+    # nothing set in code on that path: JAX read the variable itself at
+    # import, and the helper only installed the compile recorder
+    assert in_force == placed and set_in_code == "[]"
+    assert recorder == "True"
 
 
 def test_compile_cache_default_is_one_path_inside_the_checkout(tmp_path):

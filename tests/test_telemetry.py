@@ -84,14 +84,22 @@ class TestTracer:
         assert any(e["ph"] == "M" and e["name"] == "thread_name"
                    for e in doc["traceEvents"])
 
-    def test_jsonl_export(self, tmp_path):
+    def test_every_span_records_its_parent(self):
         tr = Tracer()
-        with tr.span("one"):
-            pass
-        path = str(tmp_path / "spans.jsonl")
-        tr.export_jsonl(path)
-        recs = [json.loads(line) for line in open(path)]
-        assert [r["name"] for r in recs] == ["one"]
+        with tr.span("train.step"):
+            with tr.span("inner"):
+                tr.add("measured", 0.001, cat="x")
+            tr.add("beside", 0.001)
+            assert tr.open_span() == "train.step"
+        tr.add("top", 0.001)
+        assert tr.open_span() is None
+        by_name = {s["name"]: s for s in tr.spans()}
+        assert {n: (s["parent"], s["depth"]) for n, s in by_name.items()} == {
+            "train.step": (None, 0), "inner": ("train.step", 1),
+            "measured": ("inner", 2), "beside": ("train.step", 1), "top": (None, 0)}
+        args = {e["name"]: e["args"] for e in tr.chrome_trace()["traceEvents"]
+                if e["ph"] == "X"}
+        assert args["measured"]["parent"] == "inner"
 
     def test_retention_bound_counts_drops(self):
         tr = Tracer(max_spans=2)

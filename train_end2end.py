@@ -23,6 +23,7 @@ from alphafold2_tpu.telemetry import (
     add_observability_args,
     add_telemetry_args,
     build_train_telemetry,
+    compile_record,
     finish_trace,
     observability_enabled,
     per_process_metrics_path,
@@ -496,6 +497,9 @@ def main():
                     chunk_checkpoint_saves=rows_saved_bytes(
                         rows, side, side, m.heads, m.dim_head, m.dtype)
                     if kernel_core else {})
+                # how much of the start was compiling, and whether the
+                # compile cache served it
+                logger.event(step, "compile", **compile_record.totals(top=5))
             telemetry.step_complete(step)
             if args.eval_every and (step + 1) % args.eval_every == 0:
                 # structure quality on the last microbatch (the reference's

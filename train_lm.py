@@ -39,6 +39,7 @@ from alphafold2_tpu.telemetry import (
     add_observability_args,
     add_telemetry_args,
     build_train_telemetry,
+    compile_record,
     finish_trace,
     observability_enabled,
     tracer_from_args,
@@ -208,6 +209,9 @@ def main():
                     step, "dispatch", decisions=dispatch.decisions(),
                     causal_kernel_plan=causal_kernel_plan(*core), **plans,
                     layer_checkpoint_saves=causal_saved_bytes(args.batch, *core))
+                # how much of the start was compiling, and whether the
+                # compile cache served it
+                logger.event(step, "compile", **compile_record.totals(top=5))
             telemetry.step_complete(step)
             if step % 10 == 0 or step == start + args.steps - 1:
                 print(f"step {step}  loss {float(metrics['loss']):.4f}  "

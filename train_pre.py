@@ -17,11 +17,11 @@ import jax
 
 from alphafold2_tpu.models import Alphafold2Config
 from alphafold2_tpu.telemetry import (
-    CompileTracker,
     MetricRegistry,
     add_observability_args,
     add_telemetry_args,
     build_train_telemetry,
+    compile_record,
     device_memory_gauges,
     finish_trace,
     flops_gauges,
@@ -259,8 +259,6 @@ def main():
     # ops plane / flight recorder is mounted; no-op otherwise
     registry = MetricRegistry(
         enabled=tracer.enabled or observability_enabled(args))
-    compile_tracker = CompileTracker(registry, tracer=tracer,
-                                     prefix="train_compile")
     from alphafold2_tpu.utils.flops import train_step_flops
 
     telemetry = build_train_telemetry(
@@ -422,18 +420,9 @@ def main():
                 batch = next(batches)
             batch.pop("bucket", None)  # shape bookkeeping, not model input
             step_bucket = telemetry.step_bucket()
-            if step == start and tracer.enabled:
-                # the first call blocks through trace+compile before the
-                # async dispatch: its wall time IS the harness-jit
-                # compile event
-                with compile_tracker.track(kind="train_step"):
-                    with tracer.span("train.step", cat="train", step=step), \
-                            telemetry.account(step_bucket):
-                        state, metrics = train_step(state, batch, step_rng)
-            else:
-                with tracer.span("train.step", cat="train", step=step), \
-                        telemetry.account(step_bucket):
-                    state, metrics = train_step(state, batch, step_rng)
+            with tracer.span("train.step", cat="train", step=step), \
+                    telemetry.account(step_bucket):
+                state, metrics = train_step(state, batch, step_rng)
             if eval_loss_fn is not None and (step + 1) % args.eval_every == 0:
                 metrics = dict(metrics)
                 with tracer.span("train.eval", cat="train", step=step), \
@@ -445,6 +434,10 @@ def main():
             with tracer.span("train.metrics_fetch", cat="train",
                              step=step), telemetry.account(step_bucket):
                 logger.log(step, metrics)
+            if step == start:
+                # how much of the start was compiling, and whether the
+                # compile cache served it
+                logger.event(step, "compile", **compile_record.totals(top=5))
             telemetry.step_complete(step)
             if step % 10 == 0 or step == start + args.steps - 1:
                 dt = time.time() - t0
