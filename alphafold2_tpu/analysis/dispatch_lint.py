@@ -14,7 +14,7 @@ failure, not a review nit — this pass makes four properties static:
   * **DISPATCH003** — no module under ``alphafold2_tpu/`` outside
     ``ops/`` imports a Pallas kernel module
     (``ops/flash_kernel.py`` / ``ops/sparse_kernel.py`` /
-    ``ops/quant_kernel.py``) directly: call sites must go through the
+    ``ops/quant_kernel.py`` / ``ops/geglu_kernel.py``) directly: call sites must go through the
     op modules, whose arm choice routes through the registry.
     ``analysis/`` is exempt — the smoke/lowering passes construct
     kernels ON PURPOSE to verify them.
@@ -48,7 +48,8 @@ from alphafold2_tpu.analysis.common import (
 PASS = "dispatch"
 TEST_FILE = Path("tests") / "test_dispatch.py"
 
-_KERNEL_MODULES = ("flash_kernel", "sparse_kernel", "quant_kernel")
+_KERNEL_MODULES = ("flash_kernel", "sparse_kernel", "quant_kernel",
+                   "geglu_kernel")
 _KERNEL_DOTTED = tuple(
     f"alphafold2_tpu.ops.{m}" for m in _KERNEL_MODULES
 )

@@ -138,7 +138,9 @@ class Alphafold2Config:
     trunk_schedule: str = "serial"
     # chunk feed-forward token axes into blocks of this many tokens (0 =
     # off): bounds the GEGLU 8*dim intermediate, which at crop 384 is the
-    # largest single activation in the trunk
+    # largest single activation in the trunk. Only the XLA arm's: the
+    # kernel arm (ops/geglu_kernel.py) keeps it in VMEM over the whole
+    # token axis and ignores the chunk
     ff_chunk_size: int = 0
     template_attn_depth: int = 2
     dtype: Any = jnp.float32

@@ -5,7 +5,8 @@ hardware stack by putting one dispatch surface over per-hardware
 kernels; FastFold (arxiv 2203.00854) chose the execution strategy per
 workload shape. This module is that surface for this repo: every hot op
 (dense/fused flash attention, the int8 fused-dequant matmul, block-
-sparse attention, the ring-attention hop, the experts' grouped product)
+sparse attention, the ring-attention hop, the experts' grouped product,
+the GEGLU feed-forward block)
 registers two named ARMS —
 
   * ``pallas_tpu`` — the Pallas Mosaic kernel (interpret mode off-TPU,
@@ -17,8 +18,9 @@ registers two named ARMS —
 and the choice of arm happens in ONE place (`resolve`): platform ->
 shape gate and measured crossover -> override. The op modules
 (ops/flash.py, ops/quant.py, ops/sparse.py, ops/moe.py,
-parallel/sequence.py) call `resolve(op, request, **shapes)`, compare
-with `ARM_PALLAS_TPU` and keep their own wiring. What can steer it:
+ops/feedforward.py, parallel/sequence.py) call
+`resolve(op, request, **shapes)`, compare with `ARM_PALLAS_TPU` and keep
+their own wiring. What can steer it:
 
   * a caller's ``use_kernel=True/False`` forces the kernel/XLA arm
     (loud `ValueError` when forcing an unsupported shape — forcing must
@@ -83,6 +85,13 @@ ARM_XLA_REF = "xla_ref"
 # (benchmarks/records/micro_attn_core_pr26.jsonl, PERF.md section 5).
 # Nothing shorter was measured: the crosses (j = 32, 864) stay on XLA.
 _FLASH_KERNEL_MIN_J = 1152
+
+# measured crossover for the GEGLU kernel (v5e, jax 0.9.0, dim 256, mult 4;
+# benchmarks/records/micro_geglu_*.jsonl): forward / gradient 0.26 / 0.68
+# ms against the XLA arm's 0.41 / 0.98 at 16 384 rows, 0.24 / 0.62 against
+# 0.23 / 0.70 at 4096 (a tie), 0.71 / 1.72 against 1.76 / 4.94 at 49 152
+# (train_e2e's MSA stream; its pair stream has 1 327 104)
+_GEGLU_KERNEL_MIN_ROWS = 16384
 
 # measured crossover for the block-sparse kernel (v5e @ block=128:
 # kernel 2.2x faster at n=8192, XLA ~1.3x faster at n=2048 — ops/sparse.py)
@@ -434,6 +443,38 @@ register(OpSpec(
     auto=_grouped_auto,
     probe={"m": 4096, "k": 2048, "n": 768, "groups": 16},
     parity_test="test_parity_grouped_matmul",
+))
+
+
+def _geglu_supported(platform, *, rows, dim, hidden, itemsize, dropout=False,
+                     quantized=False, **_):
+    from alphafold2_tpu.ops import geglu_kernel
+
+    return (not dropout and not quantized
+            and geglu_kernel.plan(rows, dim, hidden, itemsize) is not None)
+
+
+def _geglu_auto(platform: str, s: dict) -> str:
+    if (platform == "tpu" and s["rows"] >= _GEGLU_KERNEL_MIN_ROWS
+            and _geglu_supported(platform, **s)):
+        return ARM_PALLAS_TPU
+    return ARM_XLA_REF
+
+
+register(OpSpec(
+    name="geglu_ff",
+    arms=(
+        Arm(ARM_PALLAS_TPU, _geglu_supported,
+            "ops/geglu_kernel.py geglu_ff: the whole block over row tiles, "
+            "the intermediate in VMEM, one backward kernel that saves x "
+            "only (interpret off-TPU)"),
+        Arm(ARM_XLA_REF, _always,
+            "ops/feedforward.py _ff_core, chunked by ff_chunk_size"),
+    ),
+    auto=_geglu_auto,
+    probe={"rows": 1327104, "dim": 256, "hidden": 1024, "itemsize": 2,
+           "dropout": False, "quantized": False},
+    parity_test="test_parity_geglu_ff",
 ))
 
 

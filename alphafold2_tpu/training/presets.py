@@ -110,7 +110,8 @@ def north_star_e2e_config(
         # needed at north-star scale; chunking tiny shapes just adds
         # lax.map dispatch). Chunk and tile sizes are depth-aware
         # (models/config.py depth_aware_attn_defaults)
-        # bound the 2048-wide GEGLU intermediate on the pair stream
+        # bound the 2048-wide GEGLU intermediate on the pair stream where
+        # the XLA arm runs it (the kernel arm keeps it in VMEM, unchunked)
         ff_chunk_size=32768 if tier == "north_star" else 0,
         **attn_knobs,
     )

@@ -265,3 +265,34 @@ def test_expert_loop_compiles_once_a_direction_for_v5e(one_chip, compiled_mode,
         params, sd((n, d), jnp.bfloat16)).compile().as_text()
     assert text.count(CALL) == 12
     assert " conditional(" not in text
+
+
+def test_geglu_kernels_compile_for_v5e_at_the_pair_streams_width(one_chip,
+                                                                 compiled_mode):
+    """The pair stream's feed-forward in `train_e2e`, 1152^2 rows of 256
+    through mult 4: one forward kernel, and a gradient of ONE forward and
+    ONE backward kernel, with no transpose of the rows around them."""
+    from alphafold2_tpu.ops import geglu_kernel
+
+    rows, d, h = 1152 * 1152, 256, 1024
+    assert geglu_kernel.plan(rows, d, h, 2) is not None
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"proj_in": {"w": sd((d, 2 * h)), "b": sd((2 * h,))},
+              "proj_out": {"w": sd((h, d)), "b": sd((d,))}}
+    x = sd((rows, d), jnp.bfloat16)
+
+    def fwd(p, x):
+        return geglu_kernel.geglu_ff(p, x, jnp.bfloat16)
+
+    def loss(p, x):
+        return jnp.sum(jnp.square(fwd(p, x).astype(jnp.float32)))
+
+    assert jax.jit(fwd).lower(params, x).compile().as_text().count(CALL) == 1
+    grad = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    assert grad.count(CALL) == 2
+    assert not any(" transpose(" in line and f"[{rows}," in line
+                   for line in grad.splitlines())
+    assert " pad(" not in grad

@@ -290,6 +290,149 @@ def test_parity_merge_lse(monkeypatch, dtype, n, nk):
                                atol=atol)
 
 
+def _ff_grads(fn, params, x, dy):
+    """(out, dparams, dx) of one GEGLU call as float32 numpy."""
+    out, vjp = jax.vjp(fn, params, x)
+    dp, dx = vjp(dy.astype(out.dtype))
+    leaves = [out, dx] + jax.tree_util.tree_leaves(dp)
+    return [np.asarray(t, np.float32) for t in leaves]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("shape", [(3, 50, 128), (2, 1100, 256)],
+                         ids=["rows150-dim128", "rows2200-dim256"])
+def test_parity_geglu_ff(monkeypatch, dtype, shape):
+    """Kernel arm (interpret) == `_ff_core`, forward and the gradient of x,
+    W_in, b_in, W_out and b_out, at mult 4: 150 rows fill one padded tile,
+    2200 three tiles of 1024 (the last one padded), so the weights'
+    gradients accumulate across grid steps."""
+    from alphafold2_tpu.ops.feedforward import (feed_forward_apply,
+                                                feed_forward_init)
+
+    dim = shape[-1]
+    ks = jax.random.split(jax.random.PRNGKey(12), 3)
+    params = feed_forward_init(ks[0], dim)
+    x = jax.random.normal(ks[1], shape, jnp.float32)
+    dy = jax.random.normal(ks[2], shape, jnp.float32)
+    rows = shape[0] * shape[1]
+    got = {}
+    for arm in ("pallas_tpu", "xla_ref"):
+        monkeypatch.setenv("AF2_KERNEL_BACKEND_GEGLU_FF", arm)
+        dispatch.reset_decisions()
+        got[arm] = _ff_grads(
+            lambda p, x: feed_forward_apply(p, x, dtype=dtype), params, x, dy)
+        assert dispatch.decisions() == {
+            f"geglu_ff -> {arm} @ rows={rows} dim={dim} hidden={4 * dim} "
+            f"itemsize={jnp.dtype(dtype).itemsize} dropout=False "
+            f"quantized=False": 1}
+    # bf16: the XLA arm rounds the projection, the product and the bias
+    # sums to bf16; the kernel keeps them in float32
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for k, x in zip(got["pallas_tpu"], got["xla_ref"]):
+        assert k.shape == x.shape
+        np.testing.assert_allclose(k, x, atol=tol * max(1.0, np.abs(x).max()))
+
+
+def test_geglu_kernel_keeps_the_intermediate_in_float32(monkeypatch):
+    """Against a float32 reference, the kernel at bf16 is at least as close
+    as the XLA arm at bf16 (it rounds the intermediate only as the second
+    matmul's operand), in the output and in every gradient."""
+    from alphafold2_tpu.ops import geglu_kernel
+    from alphafold2_tpu.ops.feedforward import _ff_core, feed_forward_init
+
+    monkeypatch.setattr(geglu_kernel, "_TILE", 128)
+    ks = jax.random.split(jax.random.PRNGKey(13), 3)
+    params = feed_forward_init(ks[0], 128)
+    x = jax.random.normal(ks[1], (300, 128), jnp.float32)
+    dy = jax.random.normal(ks[2], (300, 128), jnp.float32)
+    want = _ff_grads(lambda p, x: _ff_core(p, x, 0.0, None, jnp.float32),
+                     params, x, dy)
+    xla = _ff_grads(lambda p, x: _ff_core(p, x, 0.0, None, jnp.bfloat16),
+                    params, x, dy)
+    kernel = _ff_grads(lambda p, x: geglu_kernel.geglu_ff(p, x, jnp.bfloat16),
+                       params, x, dy)
+    for w, a, k in zip(want, xla, kernel):
+        assert np.abs(k - w).max() <= np.abs(a - w).max()
+
+
+def test_geglu_erf_matches_lax_erf():
+    """Mosaic has no `lax.erf`: the kernels' float32 erf is XLA's rational
+    form, within 2e-7 of `lax.erf` on a dense grid over [-6, 6]. Both are
+    compiled at XLA's default optimization level: the suite's
+    `jax_disable_most_optimizations` also stops the multiply-adds
+    contracting to the fused ones XLA's own erf is written with, which
+    moves the last bits (4.2e-7 at most)."""
+    from alphafold2_tpu.ops.geglu_kernel import erf
+
+    x = jnp.linspace(-6.0, 6.0, 1_200_001, dtype=jnp.float32)
+    opts = {"xla_backend_optimization_level": 3,
+            "xla_llvm_disable_expensive_passes": False}
+    got, want = (jax.jit(f).lower(x).compile(compiler_options=opts)(x)
+                 for f in (erf, jax.lax.erf))
+    assert float(jnp.max(jnp.abs(got - want))) <= 2e-7
+
+
+_GEGLU_PAIR = dict(rows=1327104, dim=256, hidden=1024, itemsize=2,
+                   dropout=False, quantized=False)
+
+
+@pytest.mark.parametrize("platform,shapes,env,arm", [
+    ("tpu", _GEGLU_PAIR, None, "pallas_tpu"),              # train_e2e's pair stream
+    ("tpu", dict(_GEGLU_PAIR, rows=16384), None, "pallas_tpu"),  # the crossover
+    ("tpu", dict(_GEGLU_PAIR, rows=16383), None, "xla_ref"),
+    ("tpu", dict(_GEGLU_PAIR, rows=49152), None, "pallas_tpu"),  # its MSA stream
+    ("tpu", dict(_GEGLU_PAIR, dropout=True), None, "xla_ref"),
+    ("tpu", dict(_GEGLU_PAIR, quantized=True), None, "xla_ref"),
+    ("tpu", dict(_GEGLU_PAIR, dim=96, hidden=384), None, "xla_ref"),  # off the lanes
+    # the VMEM plan fits at bf16 and not at float32
+    ("tpu", dict(_GEGLU_PAIR, dim=640, hidden=2560), None, "pallas_tpu"),
+    ("tpu", dict(_GEGLU_PAIR, dim=640, hidden=2560, itemsize=4), None,
+     "xla_ref"),
+    ("cpu", _GEGLU_PAIR, None, "xla_ref"),
+    ("tpu", _GEGLU_PAIR, {"AF2_KERNEL_BACKEND_GEGLU_FF": "off"}, "xla_ref"),
+    ("cpu", _GEGLU_PAIR, {"AF2_KERNEL_BACKEND_GEGLU_FF": "pallas_tpu"},
+     "pallas_tpu"),
+], ids=["pair", "crossover", "under", "msa", "dropout", "int8", "dim96", "dim640-bf16",
+        "dim640-f32", "cpu", "off", "forced"])
+def test_geglu_ff_choice(monkeypatch, platform, shapes, env, arm):
+    for name, value in (env or {}).items():
+        monkeypatch.setenv(name, value)
+    assert dispatch.resolve("geglu_ff", platform=platform, **shapes) == arm
+
+
+@pytest.mark.parametrize("what", ["dropout", "int8"])
+def test_geglu_ff_dropout_and_int8_take_the_xla_arm(monkeypatch, what):
+    """A live dropout and the int8 serving tree keep the XLA arm, even
+    where auto would take the kernel; forcing the kernel there fails
+    loudly."""
+    from alphafold2_tpu.ops import feedforward
+    from alphafold2_tpu.ops.quant import quantize_tree
+
+    params = feedforward.feed_forward_init(jax.random.PRNGKey(14), 128)
+    x = jax.random.normal(jax.random.PRNGKey(15), (2, 64, 128))
+    kw = {}
+    if what == "dropout":
+        kw = dict(dropout_rate=0.1, rng=jax.random.PRNGKey(16))
+    else:
+        params = quantize_tree(params, lambda path, w: True)
+        assert "qw" in params["proj_in"] and "qw" in params["proj_out"]
+    monkeypatch.setattr(dispatch, "_GEGLU_KERNEL_MIN_ROWS", 1)
+    monkeypatch.setattr(dispatch, "_platform", lambda: "tpu")
+    dispatch.reset_decisions()
+    out = feedforward.feed_forward_apply(params, x, **kw)
+    assert out.shape == x.shape
+    [key] = [k for k in dispatch.decisions() if k.startswith("geglu_ff")]
+    assert key.startswith(
+        "geglu_ff -> xla_ref @ rows=128 dim=128 hidden=512 itemsize=4")
+    assert f"{'dropout' if what == 'dropout' else 'quantized'}=True" in key
+    # the same shape without dropout and with float weights takes the kernel
+    assert dispatch.resolve("geglu_ff", rows=128, dim=128, hidden=512,
+                            itemsize=4, dropout=False,
+                            quantized=False) == "pallas_tpu"
+    with pytest.raises(ValueError, match="does not support"):
+        feedforward.feed_forward_apply(params, x, use_kernel=True, **kw)
+
+
 # ---------------------------------------------------------------------------
 # resolution semantics
 # ---------------------------------------------------------------------------
@@ -298,7 +441,7 @@ def test_parity_merge_lse(monkeypatch, dtype, n, nk):
 def test_registry_shape():
     assert dispatch.ops() == ("flash_attention", "fused_attention",
                               "quant_matmul", "sparse_attention",
-                              "merge_lse", "grouped_matmul")
+                              "merge_lse", "grouped_matmul", "geglu_ff")
     for op in dispatch.ops():
         spec = dispatch.get(op)
         # two arms an op: the kernel, and the reference every platform

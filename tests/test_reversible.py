@@ -72,6 +72,43 @@ def test_grad_parity_reversible_vs_autodiff(with_rng):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+def test_grad_parity_geglu_kernel_arm(monkeypatch):
+    """The reversible trunk's backward runs each feed-forward's forward
+    under `jax.vjp` (the reconstruct pass): with the GEGLU kernel forced
+    (interpret mode here) its outputs and gradients equal the XLA arm's.
+    dim 128 puts the feed-forward on whole lane tiles."""
+    import dataclasses
+
+    from alphafold2_tpu.ops import dispatch
+
+    cfg = dataclasses.replace(CFG, dim=128, depth=2)
+    params = reversible_trunk_init(jax.random.PRNGKey(0), cfg)
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(B, N, N, cfg.dim).astype(np.float32))
+    m = jnp.asarray(rng.randn(B, R, C, cfg.dim).astype(np.float32))
+
+    def loss(p, x, m):
+        xo, mo = reversible_trunk_apply(p, cfg, x, m, reverse=True)
+        return jnp.sum(xo ** 2) + jnp.sum(mo ** 2)
+
+    got = {}
+    for arm in ("pallas_tpu", "xla_ref"):
+        monkeypatch.setenv("AF2_KERNEL_BACKEND_GEGLU_FF", arm)
+        dispatch.reset_decisions()
+        got[arm] = jax.value_and_grad(loss, argnums=(0, 1, 2))(params, x, m)
+        arms = {k.split(" @ ")[0] for k in dispatch.decisions()
+                if k.startswith("geglu_ff")}
+        assert arms == {f"geglu_ff -> {arm}"}
+    (v_k, g_k), (v_x, g_x) = got["pallas_tpu"], got["xla_ref"]
+    np.testing.assert_allclose(v_k, v_x, rtol=1e-5)
+    flat_k, flat_x = (jax.tree_util.tree_leaves(g) for g in (g_k, g_x))
+    assert len(flat_k) == len(flat_x)
+    for a, b in zip(flat_k, flat_x):
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b,
+                                   atol=1e-4 * max(1.0, np.abs(b).max()))
+
+
 @pytest.mark.slow
 def test_grad_parity_with_dropout_keys():
     """With dropout ON, the custom backward must re-derive the same keys the
