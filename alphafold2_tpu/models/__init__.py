@@ -14,6 +14,7 @@ from alphafold2_tpu.models.convert import convert_alphafold2
 from alphafold2_tpu.models.decoder import (
     DecoderConfig,
     MellumConfig,
+    OuroConfig,
     ZayaConfig,
     decoder_apply,
     decoder_init,
@@ -45,6 +46,7 @@ from alphafold2_tpu.models.embedder import (
 __all__ = [
     "DecoderConfig",
     "MellumConfig",
+    "OuroConfig",
     "ZayaConfig",
     "decoder_apply",
     "decoder_init",

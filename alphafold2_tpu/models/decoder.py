@@ -1,4 +1,4 @@
-"""Causal decoder language models of THREE families, chosen by the class
+"""Causal decoder language models of FOUR families, chosen by the class
 of the configuration that `decoder_init` / `decoder_apply` are handed (a
 file's `model_type` picks the class: train_lm.py `config_from_file`):
 
@@ -12,7 +12,12 @@ file's `model_type` picks the class: train_lm.py `config_from_file`):
     per-head RMSNorm on q and k, sliding-window and full causal layers
     mixed by `layer_types`, each kind with its own position table (YaRN on
     the full layers), a softmax top-k mixture of narrow experts with no
-    shared expert and no balancing bias, an untied head.
+    shared expert and no balancing bias, an untied head;
+  * `OuroConfig`, `ouro` (Ouro, a looped language model): a dense stack of
+    multi-head attention and SwiGLU layers with an RMSNorm before AND after
+    each sublayer, the whole stack run `total_ut_steps` times over the same
+    weights, each pass ending in the final norm, an untied head and an exit
+    gate; the only family with no expert layer.
 
 Pure init/apply functions over a parameter pytree, as the rest of
 `models/`. Every size comes from the configuration, whose keys are the
@@ -21,7 +26,8 @@ leading dense layers as one stack, the MoE layers as another; `zaya`: one
 stack whose carry is the residual stream AND the router's state; `mellum`:
 one stack in the published order, scanned a whole period of `layer_types`
 at a time with each run of one kind scanned inside it, so that the window
-is static where the core is called), each
+is static where the core is called; `ouro`: one stack, scanned once a
+pass), each
 layer under a `jax.checkpoint` that keeps the causal kernel's `out` and
 `lse` for the backward pass and builds the rest of the layer again
 (`_checkpointed_layer`). All share the skeleton, the causal core (ops/flash.py), the
@@ -130,6 +136,37 @@ before top-k are the Qwen3-MoE family's, whose key set this is; the
 multi-token-prediction head the model card mentions has no key and is left
 out; RoPE rotates interleaved pairs, as above; no cross-document mask;
 `tie_word_embeddings` must be false; one chip's share as above.
+
+`ouro`'s equations follow from the published keys (`model_type: ouro`; the
+Ouro paper, Zhu et al., "Scaling Latent Reasoning via Looped Language
+Models", arXiv:2510.25741). d the hidden size, h query and h key heads of
+dh lanes (no grouping), T = `total_ut_steps`:
+
+  layer   a = Attn(RMSNorm_in(h)); h = h + RMSNorm_in2(a);
+          m = SwiGLU(RMSNorm_post(h)); h = h + RMSNorm_post2(m): four
+          norms a layer, each with its own learned scale (the sandwich).
+  Attn    q, k, v = x W_q, x W_k, x W_v (d -> h dh each), no bias and no
+          q / k norm (`gqa_apply` at g = 1 on a layer without the norms);
+          RoPE over all dh lanes at `rope_theta`; softmax(q k^T / sqrt(dh))
+          under the causal mask; W_o. SwiGLU(x) = (silu(x W_gate) *
+          x W_up) W_down at `intermediate_size`.
+  passes  h(0) = Embed(x); h(t) = RMSNorm_f(Layers(h(t-1))) for t = 1..T,
+          the SAME `layers` layers with the same weights in every pass; the
+          normed state is what the next pass reads and what the pass's head
+          (logits(t) = h(t) W_head, untied) and exit gate (lambda_t =
+          sigmoid(h(t) w_g + b_g), a Linear(d, 1) with a bias, in float32)
+          read.
+  exit    p(t) = lambda_t prod_{j<t} (1 - lambda_j) for t < T, p(T) =
+          prod_{j<T} (1 - lambda_j); the loss (training/lm.py) is the mean
+          over targets of sum_t p(t) CE_t - beta H(p), the paper's stage I.
+
+Departures and what the published config does not fix (the configuration
+file's `assumed` argues each): the sandwich norms and the gate's input
+and bias come from the paper and the family's modelling code, not from a
+key; `beta` has no key; the stage-II gate training and early exit are left
+out (`early_exit_threshold` 1 runs every pass); RoPE rotates interleaved
+pairs, as above; no cross-document mask; `layers` of the published
+`num_hidden_layers` are built (a pipeline stage's share).
 """
 
 from __future__ import annotations
@@ -378,6 +415,63 @@ class MellumConfig:
         return jnp.dtype(self.dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class OuroConfig:
+    model_type: ClassVar[str] = "ouro"
+
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int  # the published depth
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    intermediate_size: int
+    total_ut_steps: int  # T: the passes of the stack
+    # the depth that is built and run, of num_hidden_layers; None: all
+    layers: Optional[int] = None
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-6
+    tie_word_embeddings: bool = False
+    initializer_range: float = 0.02
+    scaled_init_layers: int = 0  # as DecoderConfig's
+    # the weight of the exit distribution's entropy in the loss
+    beta: float = 0.1
+    dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if self.layers is None:
+            object.__setattr__(self, "layers", self.num_hidden_layers)
+        if self.total_ut_steps < 1:
+            raise ValueError(f"OuroConfig: total_ut_steps {self.total_ut_steps}; "
+                             "the stack runs at least once")
+        if not 1 <= self.layers <= self.num_hidden_layers:
+            raise ValueError(f"OuroConfig: layers {self.layers} is not a depth of "
+                             f"the {self.num_hidden_layers} published layers")
+        if self.tie_word_embeddings:
+            raise ValueError("OuroConfig: tie_word_embeddings must be false "
+                             "(the head is a projection of its own)")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("OuroConfig: the key heads must divide the query heads")
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return None
+
+    def rope_of(self, kind: str) -> dict:
+        return {"rope_type": "default", "rope_theta": self.rope_theta}
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def v_head_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+
 # --- init ---------------------------------------------------------------------
 
 def _w(key, shape, std):
@@ -514,6 +608,31 @@ def mellum_init(key, cfg: MellumConfig):
             "moe": layers}
 
 
+def ouro_init(key, cfg: OuroConfig):
+    """As `mellum_init`: ONE stack, `dense` (every layer is of one kind),
+    with four norms a layer, each scale 1, and no q / k norm; beside it the
+    exit gate, a Linear(d, 1) whose weight is N(0, initializer_range) and
+    whose bias is 0."""
+    d, n, std = cfg.hidden_size, cfg.layers, cfg.initializer_range
+    h, hk, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    ke, kq, kk, kv, ko, km, kh, kg = jax.random.split(key, 8)
+    layers = {
+        "attn_norm": _scale(d, (n,)),
+        "attn": {"q": _w(kq, (n, d, h * dh), std), "k": _w(kk, (n, d, hk * dh), std),
+                 "v": _w(kv, (n, d, hk * dh), std),
+                 "o": _w(ko, (n, h * dh, d), _out_std(cfg))},
+        "attn_out_norm": _scale(d, (n,)),
+        "mlp_norm": _scale(d, (n,)),
+        "mlp": _swiglu_init(km, (n,), d, cfg.intermediate_size, cfg),
+        "mlp_out_norm": _scale(d, (n,)),
+    }
+    return {"embed": {"table": std * jax.random.normal(
+                ke, (cfg.vocab_size, d), jnp.float32)},
+            "final_norm": _scale(d), "head": _w(kh, (d, cfg.vocab_size), std),
+            "exit_gate": {**_w(kg, (d, 1), std), "b": jnp.zeros((1,), jnp.float32)},
+            "dense": layers}
+
+
 def deepseek_init(key, cfg: DecoderConfig):
     """N(0, initializer_range) weights (`scaled_init_layers` narrows the
     residual branches' last projections), unit norms, zero selection bias;
@@ -640,9 +759,10 @@ def _rope_table(section: dict, dim: int):
     return theta, inv, section.get("attention_factor")
 
 
-def gqa_apply(params, x, cfg: MellumConfig, kind: str):
+def gqa_apply(params, x, cfg, kind: str):
     """Grouped-query attention of one layer kind (its window, its position
-    table), q and k normed by head. x: (B, L, d)."""
+    table), q and k normed by head where the layer has the norms (`mellum`;
+    an `ouro` layer has none). x: (B, L, d)."""
     B, L, _ = x.shape
     h, hk, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     dtype = cfg.compute_dtype
@@ -652,8 +772,12 @@ def gqa_apply(params, x, cfg: MellumConfig, kind: str):
         v = linear(params["v"], x, dtype).reshape(B, L, hk, dh)
     with scope("qk_norm_rope"):
         table = _rope_table(cfg.rope_of(kind), dh)
-        q = rope(rms_norm(params["q_norm"], q, cfg.rms_norm_eps), *table)
-        k = rope(rms_norm(params["k_norm"], k, cfg.rms_norm_eps), *table)
+
+        def normed(t, name):
+            return rms_norm(params[name], t, cfg.rms_norm_eps) if name in params else t
+
+        q = rope(normed(q, "q_norm"), *table)
+        k = rope(normed(k, "k_norm"), *table)
     out = flash_attention(q, k, v, causal=True, window=cfg.window_of(kind),
                           scale=dh ** -0.5)
     with scope("out_proj"):
@@ -773,6 +897,20 @@ def _mellum_layer(lp, h, cfg: MellumConfig, kind: str):
         return h + y.reshape(B, L, d), aux
 
 
+def _ouro_layer(lp, h, cfg: OuroConfig):
+    """One `ouro` layer: each sublayer between an RMSNorm of its input and
+    one of its output, the second under `sandwich_norm`."""
+    eps = cfg.rms_norm_eps
+    with scope("gqa_attn"):
+        a = gqa_apply(lp["attn"], rms_norm(lp["attn_norm"], h, eps), cfg, FULL)
+    with scope("sandwich_norm"):
+        h = h + rms_norm(lp["attn_out_norm"], a, eps)
+    with scope("dense_mlp"):
+        m = moe.swiglu(lp["mlp"], rms_norm(lp["mlp_norm"], h, eps), cfg.compute_dtype)
+    with scope("sandwich_norm"):
+        return h + rms_norm(lp["mlp_out_norm"], m, eps), None
+
+
 def _layer(lp, h, cfg: DecoderConfig, is_moe: bool):
     with scope("mla_attn"):
         h = h + mla_apply(lp["attn"], rms_norm(lp["attn_norm"], h,
@@ -863,27 +1001,57 @@ def _mellum_layers(params, h, cfg: MellumConfig):
     return h, jax.tree_util.tree_map(lambda t: t.reshape((n,) + t.shape[2:]), aux)
 
 
+def exit_gate_logits(params, h):
+    """z = h w_g + b_g of each token, in float32; lambda = sigmoid(z) is the
+    chance of leaving after this pass. h: (B, L, d), a pass's normed
+    state."""
+    z = jnp.matmul(h.astype(jnp.float32), params["w"],
+                   precision=jax.lax.Precision.HIGHEST) + params["b"]
+    return z[..., 0]
+
+
+def _ouro_layers(params, h, cfg: OuroConfig):
+    """The stack run `total_ut_steps` times over the same stacked
+    parameters: a Python loop of passes, each a scan over the layers under
+    `_checkpointed_layer`, each followed by the final norm and the exit
+    gate. The normed state is what the next pass reads. Returns (the T
+    normed states, a list of (B, L) arrays, each read by its own pass's
+    head; {"gate_logits": (T, B, L) float32})."""
+    layer = functools.partial(_ouro_layer, cfg=cfg)
+    states, gates = [], []
+    for _ in range(cfg.total_ut_steps):
+        h, _ = _stack(layer, h, params["dense"])
+        with scope("exit_gate"):
+            h = rms_norm(params["final_norm"], h, cfg.rms_norm_eps)
+            gates.append(exit_gate_logits(params["exit_gate"], h))
+        states.append(h)
+    return states, {"gate_logits": jnp.stack(gates)}
+
+
 #: what differs between the families, by the configuration's class: (the
 #: init, the layer stacks on the embedded tokens). The class itself names
 #: its `model_type`, its key for the router's width and the core's
 #: `qk_head_dim` / `v_head_dim`
 _FAMILY = {DecoderConfig: (deepseek_init, _deepseek_layers),
            ZayaConfig: (zaya_init, _zaya_layers),
-           MellumConfig: (mellum_init, _mellum_layers)}
+           MellumConfig: (mellum_init, _mellum_layers),
+           OuroConfig: (ouro_init, _ouro_layers)}
 #: model_type -> the configuration's class (train_lm.py `config_from_file`)
 FAMILIES = {cls.model_type: cls for cls in _FAMILY}
 
 
 def decoder_init(key, cfg):
-    """The family's parameter tree: `deepseek_init`'s, `zaya_init`'s or
-    `mellum_init`'s."""
+    """The family's parameter tree: `deepseek_init`'s, `zaya_init`'s,
+    `mellum_init`'s or `ouro_init`'s."""
     return _FAMILY[type(cfg)][0](key, cfg)
 
 
 def decoder_apply(params, cfg, tokens):
     """tokens (B, L) int -> (hidden (B, L, d) after the final norm, in the
     compute dtype; aux {"load": (n_moe, E), "picks": (n_moe, B*L, top_k),
-    "rows_walked": (n_moe,)}, empty without MoE layers)."""
+    "rows_walked": (n_moe,)}, empty without MoE layers). A family with no
+    expert layer has none of these: `ouro` returns a list of its T passes'
+    normed states in the place of `hidden` and {"gate_logits": (T, B, L)}."""
     with scope("lm_embed"):
         # rows from the float32 table, so that the table's gradient adds
         # up in float32 however often a token repeats
@@ -892,6 +1060,8 @@ def decoder_apply(params, cfg, tokens):
     # parameters, its gradient's write-back, the carried residual stream)
     with scope("decoder_layers"):
         h, aux = _FAMILY[type(cfg)][1](params, h, cfg)
+    if "gate_logits" in aux:
+        return h, aux  # a looped stack: each pass ended in the final norm
     with scope("lm_head_loss"):
         h = rms_norm(params["final_norm"], h, cfg.rms_norm_eps)
     return h, aux

@@ -57,12 +57,17 @@ TAIL_SCOPES = (
 )
 #: the decoder language models (models/decoder.py, training/lm.py):
 #: `decoder_layers` is the scans over the layers (what is left to it: the
-#: slices of the stacked parameters, the gradient's write-back), `mla_attn`
-#: (`deepseek_v3`), `cca_attn` (`zaya`) or `gqa_attn` (`mellum`), `dense_mlp`
-#: and `moe` a layer's halves, `residual_scale` the `zaya` block's a * h + c,
-#: `lm_head_loss` the final norm, the head (tied or not) and the cross-entropy
+#: slices of the stacked parameters, the gradient's write-back; `ouro`'s
+#: stack gathers four passes' contributions there), `mla_attn`
+#: (`deepseek_v3`), `cca_attn` (`zaya`) or `gqa_attn` (`mellum`, `ouro`),
+#: `dense_mlp` and `moe` a layer's halves, `residual_scale` the `zaya`
+#: block's a * h + c, `sandwich_norm` the `ouro` layer's norm of each
+#: sublayer's output, `exit_gate` an `ouro` pass's final norm, its exit gate,
+#: the exit distribution and its entropy, `lm_head_loss` the final norm, the
+#: head (tied or not) and the cross-entropy (`ouro`: every pass's head)
 DECODER_SCOPES = ("lm_embed", "decoder_layers", "mla_attn", "dense_mlp", "moe",
-                  "lm_head_loss", "cca_attn", "residual_scale", "gqa_attn")
+                  "lm_head_loss", "cca_attn", "residual_scale", "gqa_attn",
+                  "sandwich_norm", "exit_gate")
 #: opt.update + apply_updates + global_norm (training/harness.py)
 OPTIMIZER_SCOPE = "optimizer"
 OUTER_SCOPES = (MODEL_SCOPES + TRUNK_OP_SCOPES + TAIL_SCOPES + DECODER_SCOPES

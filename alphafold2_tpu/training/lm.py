@@ -9,7 +9,11 @@ harness's `(params, cfg, batch, rng)` signature and returns
 place that handles what lies outside the gradient: the router's
 selection bias `b` (a parameter leaf whose gradient is zero, because the
 forward reads it through `stop_gradient`) is moved by each layer's
-expert load, and the load becomes step metrics.
+expert load, and the load becomes step metrics. The looped family
+(`ouro`) has no expert layer: its loss is the expected next-token loss
+under the exit distribution its gates give each token, less `beta` times
+that distribution's entropy, and its step metrics are each pass's loss and
+the mean exit step.
 """
 
 from __future__ import annotations
@@ -49,14 +53,15 @@ def blocked_cross_entropy(hidden, head_w, targets, weights,
     keeping them). hidden: (N, d); head_w: (d, V) in the compute dtype, or
     with `tied` the embedding table (V, d) itself, contracted over its d
     (no transposed copy is made); targets: (N,) int; weights: (N,)
-    float32."""
+    float32, or (N, K): K weightings of the same rows' losses, one sum
+    each (K,), for the price of one head."""
     n, d = hidden.shape
     block = min(block_rows, n)
     pad = (-n) % block
     if pad:
         hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
         targets = jnp.pad(targets, (0, pad))
-        weights = jnp.pad(weights, (0, pad))
+        weights = jnp.pad(weights, ((0, pad),) + ((0, 0),) * (weights.ndim - 1))
     nb = (n + pad) // block
 
     @jax.checkpoint
@@ -66,16 +71,58 @@ def blocked_cross_entropy(hidden, head_w, targets, weights,
             preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
-        return jnp.sum(w * (lse - picked))
+        ce = lse - picked
+        return jnp.sum(w * (ce if w.ndim == 1 else ce[:, None]), axis=0)
 
     def body(total, blk):
         return total + one(*blk), None
 
     total, _ = jax.lax.scan(
-        body, jnp.zeros((), jnp.float32),
+        body, jnp.zeros(weights.shape[1:], jnp.float32),
         (hidden.reshape(nb, block, d), targets.reshape(nb, block),
-         weights.reshape(nb, block)))
+         weights.reshape((nb, block) + weights.shape[1:])))
     return total
+
+
+def exit_log_distribution(gate_logits):
+    """log p(t) of the exit distribution, in float32, from the T passes'
+    gate logits z (T, ...): lambda_t = sigmoid(z_t), p(t) = lambda_t
+    prod_{j<t} (1 - lambda_j) for t < T, p(T) = prod_{j<T} (1 - lambda_j),
+    so that the T of them sum to 1; the last pass's gate is not read. Sums
+    of log sigmoid(z) and log sigmoid(-z), so that no product underflows."""
+    z = gate_logits.astype(jnp.float32)
+    zero = jnp.zeros_like(z[:1])
+    stayed = jnp.concatenate(
+        [zero, jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)])
+    return stayed + jnp.concatenate([jax.nn.log_sigmoid(z[:-1]), zero])
+
+
+def exit_entropy(log_p):
+    """H(p) = -sum_t p(t) log p(t) over the leading axis of log p."""
+    return -jnp.sum(jnp.exp(log_p) * log_p, axis=0)
+
+
+def _expected_loss(states, gate_logits, head_w, targets, weights, beta):
+    """The looped family's objective, summed over the rows: sum_t p(t)
+    CE_t - beta H(p) of each target, with ONE `blocked_cross_entropy` a
+    pass whose weights are p(t) * mask (the gate's gradient comes back
+    through them) and, beside them, the mask (that pass's own loss, for
+    the step metrics). Returns (the sum, aux of sums over the rows)."""
+    rows = targets.size
+    mask = weights.reshape(-1)
+    with scope("exit_gate"):
+        log_p = exit_log_distribution(gate_logits).reshape(len(states), rows)
+        p = jnp.exp(log_p)
+        entropy = jnp.sum(mask * exit_entropy(log_p))
+        steps = jnp.arange(1, len(states) + 1, dtype=jnp.float32)
+        exit_step = jnp.sum(mask * jnp.sum(steps[:, None] * p, axis=0))
+    sums = jnp.stack([
+        blocked_cross_entropy(h.reshape(rows, -1), head_w, targets.reshape(-1),
+                              jnp.stack([p[t] * mask, mask], axis=-1))
+        for t, h in enumerate(states)])
+    aux = {"loop_ce_sum": sums[:, 1], "loop_exit_step_sum": exit_step,
+           "loop_targets": jnp.sum(mask)}
+    return jnp.sum(sums[:, 0]) - beta * entropy, aux
 
 
 def lm_loss_fn(params, cfg, batch, rng=None):
@@ -85,7 +132,9 @@ def lm_loss_fn(params, cfg, batch, rng=None):
     whose gradient is then the sum of the lookup's and the head's.
     batch: {"tokens": (B, L) int32}. Returns (loss, {"load": each MoE
     layer's expert load}, empty without MoE layers): what the step sums
-    over its microbatches. `rng` is unused (no dropout)."""
+    over its microbatches. The looped family's loss is `_expected_loss`'s
+    mean over the targets, its aux the sums its step metrics are made of.
+    `rng` is unused (no dropout)."""
     tokens = batch["tokens"]
     B, L = tokens.shape
     hidden, aux = decoder_apply(params, cfg, tokens)
@@ -97,6 +146,11 @@ def lm_loss_fn(params, cfg, batch, rng=None):
             (jnp.arange(L) < L - 1).astype(jnp.float32), (B, L))
         tied = "head" not in params
         head_w = params["embed"]["table"] if tied else params["head"]["w"]
+        if "gate_logits" in aux:  # a looped stack: a list of passes' states
+            total, aux = _expected_loss(hidden, aux["gate_logits"],
+                                        head_w.astype(cfg.compute_dtype),
+                                        targets, weights, cfg.beta)
+            return total / (B * (L - 1)), aux
         total = blocked_cross_entropy(
             hidden.reshape(B * L, -1), head_w.astype(cfg.compute_dtype),
             targets.reshape(-1), weights.reshape(-1), tied=tied)
@@ -113,12 +167,20 @@ def lm_aux_update(cfg):
     row a layer in the published order: assignments held here, the sorted
     rows the expert loop walked for them (`ops.moe.rows_walked`: its live
     blocks, whole) and most-loaded over mean load of the held experts
-    (none is dropped: the expert layer has no capacity)."""
-    lo, hi = cfg.held
+    (none is dropped: the expert layer has no capacity). A family without
+    an expert layer moves nothing; the looped one (`ouro`) reports
+    `loop_pass_loss` (T,), each pass's mean next-token loss over the
+    targets, and `loop_exit_step_mean`, the mean over the targets of
+    sum_t t p(t)."""
 
     def update(params, aux):
         if "load" not in aux:
-            return params, {}
+            if "loop_ce_sum" not in aux:
+                return params, {}
+            n = aux["loop_targets"]
+            return params, {"loop_pass_loss": aux["loop_ce_sum"] / n,
+                            "loop_exit_step_mean": aux["loop_exit_step_sum"] / n}
+        lo, hi = cfg.held
         load = aux["load"]  # (n_moe, E)
         mlp = params["moe"]["mlp"]
         if "bias" in mlp:

@@ -237,10 +237,34 @@ def mellum_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
     }
 
 
+def ouro_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
+    """The same for the `ouro` family (`cfg`: any object with OuroConfig's
+    fields), counting EVERY pass: `total_ut_steps` x `layers` layer
+    applications (the four projections, the causal half of the logits at
+    the query heads' count, the SwiGLU) and, a pass, the exit gate's one
+    output and the untied head's L - 1 rows a sequence. Every parameter of
+    the stack is used T times a step, so 6 x parameters x tokens reads
+    about T times low. `assignments` is unused (no expert layer)."""
+    del assignments
+    n = batch * length
+    d, h, hk, dh = (cfg.hidden_size, cfg.num_attention_heads,
+                    cfg.num_key_value_heads, cfg.head_dim)
+    passes = cfg.total_ut_steps
+    applications = passes * cfg.layers
+    pairs = batch * h * length * (length + 1) / 2.0
+    return {
+        "attn_proj": applications * 2.0 * n * d * (2 * h * dh + 2 * hk * dh),
+        "attn_core": applications * 2.0 * pairs * 2 * dh,
+        "dense_mlp": applications * 2.0 * n * 3 * d * cfg.intermediate_size,
+        "exit_gate": passes * 2.0 * n * d,
+        "head": passes * 2.0 * batch * (length - 1) * d * cfg.vocab_size,
+    }
+
+
 def decoder_fwd_op_flops(cfg, batch: int, length: int, assignments=None) -> dict:
     """The family's count, by the configuration's `model_type`."""
     count = {"deepseek_v3": deepseek_fwd_op_flops, "zaya": zaya_fwd_op_flops,
-             "mellum": mellum_fwd_op_flops}
+             "mellum": mellum_fwd_op_flops, "ouro": ouro_fwd_op_flops}
     return count[cfg.model_type](cfg, batch, length, assignments)
 
 

@@ -54,10 +54,10 @@ def lower_template_forward():
 def lower_toy_lm_step(family="deepseek_v3"):
     """The decoder's train step at toy widths (`deepseek_v3`: one dense
     and one MoE layer; `zaya`: two layers; `mellum`: one period of two
-    window layers and a full one): the names of models/decoder.py,
-    ops/moe.py, training/lm.py."""
+    window layers and a full one; `ouro`: two layers run twice): the names
+    of models/decoder.py, ops/moe.py, training/lm.py."""
     from alphafold2_tpu.models.decoder import (FULL, SLIDING, DecoderConfig,
-                                               MellumConfig, ZayaConfig)
+                                               MellumConfig, OuroConfig, ZayaConfig)
     from alphafold2_tpu.training.harness import make_optimizer
     from alphafold2_tpu.training.lm import (lm_aux_update, lm_loss_fn,
                                             lm_params_init)
@@ -83,6 +83,11 @@ def lower_toy_lm_step(family="deepseek_v3"):
             layer_types=(SLIDING, SLIDING, FULL), sliding_window=6,
             rope_parameters={SLIDING: {"rope_theta": 1e4}, FULL: {"rope_theta": 1e4}},
             experts_held=(0, 2), dtype="float32")
+    if family == "ouro":
+        cfg = OuroConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=4, layers=2,
+            num_attention_heads=4, num_key_value_heads=4, head_dim=8,
+            intermediate_size=48, total_ut_steps=2, dtype="float32")
     tcfg = TrainConfig(grad_accum=1)
     state = jax.eval_shape(
         lambda k: (lambda p: {"params": p, "opt_state": make_optimizer(tcfg).init(p),
@@ -116,7 +121,8 @@ def paths(mellum_step):
             | op_paths(lower_template_forward().compile())
             | op_paths(lower_toy_lm_step().compile())
             | op_paths(lower_toy_lm_step("zaya").compile())
-            | op_paths(mellum_step))
+            | op_paths(mellum_step)
+            | op_paths(lower_toy_lm_step("ouro").compile()))
 
 
 @pytest.mark.parametrize("name", profiling.SCOPES)
@@ -245,3 +251,23 @@ def test_null_tracer_stays_the_shared_singleton(captured):
     # a span recorded after the fact is in the tracer's own export only
     assert "serving.queue_wait" in tracer.summary()
     assert "serving.queue_wait" not in host
+
+
+def test_the_looped_step_names_its_passes_parts():
+    """The `ouro` step: each pass's final norm and gate under `exit_gate`,
+    the sublayers' output norms under `sandwich_norm`, the attention with
+    no q / k norm under `gqa_attn`, the dense SwiGLU under `dense_mlp`, the
+    heads under `lm_head_loss`, in the forward and in the backward."""
+    names = set(re.findall(r'op_name="([^"]+)"',
+                           lower_toy_lm_step("ouro").compile().as_text()))
+    def bare(name):
+        return [re.sub(r"^(?:(?:jvp|transpose)\()+|\)+$", "", e)
+                for e in name.split(";")[0].split("/")]
+
+    for scope in ("exit_gate", "sandwich_norm", "gqa_attn/qkv_proj", "gqa_attn/attn_core",
+                  "dense_mlp", "lm_head_loss", "decoder_layers"):
+        outer, _, inner = scope.partition("/")
+        found = [n for n in names if outer in bare(n)
+                 and (not inner or inner in bare(n)[bare(n).index(outer):])]
+        assert found, scope
+        assert any("transpose(" in n for n in found), scope

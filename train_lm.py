@@ -1,13 +1,15 @@
 """Language-model pretraining entry point: the decoder (models/decoder.py)
-on the shared harness. It has THREE families, and a configuration file's
+on the shared harness. It has FOUR families, and a configuration file's
 `model_type` chooses: `deepseek_v3` (latent attention, a mixture of
 experts with shared experts; the default, and the toy), `zaya`
 (compressed convolutional attention with grouped keys, a top-1 mixture
 picked by an MLP router that carries state from layer to layer, a scaled
-residual stream, a tied head) and `mellum` (grouped-query attention in
+residual stream, a tied head), `mellum` (grouped-query attention in
 sliding-window and full causal layers mixed by `layer_types`, YaRN on the
 full ones, a softmax top-k mixture of narrow experts with no shared
-expert, an untied head).
+expert, an untied head) and `ouro` (a dense stack with sandwich norms run
+`total_ut_steps` times over the same weights, a head and an exit gate
+after every pass, the loss weighted by the gates' exit distribution).
 
 One jitted, donated optimizer step (`make_train_step` with `lm_loss_fn`
 and `lm_aux_update`: the same builder `train_pre.py` and
@@ -67,13 +69,14 @@ _TOY = dict(
 
 
 def config_from_file(path: str, dtype: str):
-    """A DecoderConfig, a ZayaConfig or a MellumConfig, by the file's
-    `model_type` (`deepseek_v3` where it has none), from the published
-    config.json's keys (and, under `assumed_values`, what config.json does
-    not give). Where the file states `experts_held`, its count of experts
-    is of those held and `published` has the router's width; where it
-    states `layers`, that is the depth to build and `num_hidden_layers` is
-    the source's (benchmarks/configs/). `zaya`'s `rope_theta` is its
+    """A DecoderConfig, a ZayaConfig, a MellumConfig or an OuroConfig, by
+    the file's `model_type` (`deepseek_v3` where it has none), from the
+    published config.json's keys (and, under `assumed_values`, what
+    config.json does not give). Where the file states `experts_held`, its
+    count of experts is of those held and `published` has the router's
+    width; where it states `layers`, that is the depth to build and
+    `num_hidden_layers` is the source's (benchmarks/configs/; `ouro` keeps
+    both, as its own fields). `zaya`'s `rope_theta` is its
     `rope_parameters`' for the `hybrid` layers; `mellum` takes
     `rope_parameters` whole and the first `layers` of `layer_types`."""
     with open(path) as f:
@@ -89,7 +92,7 @@ def config_from_file(path: str, dtype: str):
     if "experts_held" in raw:
         sizes["experts_held"] = tuple(raw["experts_held"])
         sizes[cls.router_width_key] = raw["published"][cls.router_width_key]
-    if "layers" in raw:
+    if "layers" in raw and "layers" not in fields:
         sizes["num_hidden_layers"] = raw["layers"]
     if "rope_parameters" in raw and "rope_theta" in fields:
         sizes["rope_theta"] = float(raw["rope_parameters"]["hybrid"]["rope_theta"])
@@ -103,7 +106,8 @@ def main():
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--config", default=None,
                     help="JSON file of the published config.json's keys; its "
-                    "model_type picks the family (deepseek_v3, zaya or mellum)")
+                    "model_type picks the family (deepseek_v3, zaya, mellum "
+                    "or ouro)")
     ap.add_argument("--batch", type=int, default=2, help="sequences a microbatch")
     ap.add_argument("--len", dest="length", type=int, default=256)
     ap.add_argument("--accum", type=int, default=1)
@@ -172,20 +176,30 @@ def main():
                     telemetry.account(step_bucket):
                 state, metrics = train_step(
                     state, batch, jax.random.fold_in(base_rng, step))
-            # the router's load is per MoE layer: one scalar a layer for
-            # the log and the registry
+            # the router's load is per MoE layer, the looped family's loss
+            # per pass: one scalar a layer (a pass) for the log and the
+            # registry
             by_layer = {k: metrics.pop(k) for k in list(metrics)
                         if k.startswith("moe_")}
+            by_pass = {k: metrics.pop(k) for k in ("loop_pass_loss",) if k in metrics}
             # the fetch is the step's device sync
             with tracer.span("train.metrics_fetch", cat="train", step=step), \
                     telemetry.account(step_bucket):
-                for name, values in jax.device_get(by_layer).items():
+                by_layer, by_pass = jax.device_get((by_layer, by_pass))
+                for name, values in by_layer.items():
                     for layer, value in enumerate(values):
                         metrics[f"{name}_l{layer}"] = float(value)
                         registry.gauge(
                             f"train_{name}", help="router load of a MoE layer "
                             "(training/lm.py lm_aux_update)",
                             layer=str(layer)).set(float(value))
+                for name, values in by_pass.items():
+                    for t, value in enumerate(values, start=1):
+                        metrics[f"{name}_t{t}"] = float(value)
+                        registry.gauge(
+                            f"train_{name}", help="next-token loss of a pass of "
+                            "the looped stack (training/lm.py lm_aux_update)",
+                            ut_step=str(t)).set(float(value))
                 logger.log(step, metrics)
             if step == start:
                 from alphafold2_tpu.ops import dispatch
